@@ -132,8 +132,7 @@ class Backend(abc.ABC):
     def dispatch_chat_completion(self, request: ChatRequest) -> ChatCompletion:
         """``chat_completion`` wrapped in the reliability layer: circuit-breaker
         gate, budget check, bounded retry with backoff (the shape the reference
-        inherits from the OpenAI client's 2-retry exponential backoff, and that
-        bench.py's relay-flap probes proved locally), plus the
+        inherits from the OpenAI client's 2-retry exponential backoff), plus the
         ``backend.dispatch`` failpoint. This is what the resources layer calls;
         ``chat_completion`` stays the single-attempt primitive."""
         breaker = self.circuit_breaker

@@ -76,8 +76,10 @@ class BackendConfig(BaseModel):
     # Model-config overrides
     dtype: Optional[str] = None  # e.g. "bfloat16" | "float32"
     max_seq_len: Optional[int] = None
-    attention_impl: Optional[str] = None  # prefill: "xla" | "flash"
-    decode_attention_impl: Optional[str] = None  # decode: "xla" | "flash"
+    # "xla" | "flash" ("flash" = the Pallas kernel on TPU, the XLA reference
+    # elsewhere; see ops/attention.py::resolve_attention_impl)
+    attention_impl: Optional[str] = None  # prefill
+    decode_attention_impl: Optional[str] = None  # decode
     # Weight quantization: None (model dtype), "int8" (per-channel symmetric;
     # halves decode HBM traffic — the LATENCY config, ~75% of peak bandwidth
     # on v5e), or "int4" (group-wise symmetric via the Pallas w4a16 kernel —
@@ -124,9 +126,10 @@ class BackendConfig(BaseModel):
     # Hard cap on the coalesced device batch (rows). None = the scheduler's
     # default (64), further tightened per request by the HBM memory model.
     max_batch_rows: Optional[int] = None
-    # Per-device HBM for the memory model. None = autodetect from
-    # device.memory_stats() (falls back to 16 GiB when the platform doesn't
-    # report, e.g. CPU meshes — effectively unbounded for test models).
+    # Per-device HBM for the memory model. None = read it from
+    # device.memory_stats(): on a TPU a device that does not report raises;
+    # on the CPU (test meshes, toy models) there is no HBM and the model
+    # plans against 16 GiB — effectively unbounded.
     hbm_bytes: Optional[int] = None
     # Fraction of HBM the memory model may plan against; the rest absorbs
     # XLA temporaries, fragmentation, and compile-time scratch.
@@ -145,9 +148,10 @@ class BackendConfig(BaseModel):
     debug_endpoints: bool = False
     # -- self-healing supervision (PR 4) ----------------------------------
     # Hung-launch watchdog budget: clamp(base + multiplier * max_new_tokens
-    # * per-token EWMA) seconds per device launch. The generous min floor
-    # absorbs first-launch compile time; the EWMA learns steady-state decode
-    # latency and tightens the budget from there.
+    # * per-token EWMA) seconds per device launch. Time a launch spends
+    # compiling is not charged to the budget (utils/compile_cache.py) and is
+    # forgiven up to watchdog_max_budget_s; the EWMA learns steady-state
+    # decode latency and tightens the budget from there.
     watchdog_base_s: float = 10.0
     watchdog_per_token_s: float = 0.5
     watchdog_multiplier: float = 8.0
@@ -261,20 +265,30 @@ class BackendConfig(BaseModel):
     jobstore_ttl_s: Optional[float] = None
 
 
-def _detect_hbm_bytes() -> Optional[int]:
-    """Per-device memory limit from the PJRT runtime, or None when the
-    platform doesn't report one (CPU, some plugins)."""
-    try:
-        import jax
+#: What the memory model plans against where there is no HBM to read (CPU
+#: test meshes with toy models): far above the scheduler's max_rows, i.e. the
+#: model imposes nothing.
+CPU_PLANNING_BYTES = 16 * (1 << 30)
 
-        stats = jax.local_devices()[0].memory_stats()
-        if stats:
-            limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-            if limit:
-                return int(limit)
-    except Exception:  # pragma: no cover - platform-dependent
-        pass
-    return None
+
+def _detect_hbm_bytes() -> int:
+    """Per-device memory limit as the PJRT runtime reports it. A TPU that
+    does not report one raises — planning KV rows against an assumed size
+    would hide the device; the CPU has no HBM and gets
+    :data:`CPU_PLANNING_BYTES`."""
+    import jax
+
+    device = jax.local_devices()[0]
+    if device.platform == "cpu":
+        return CPU_PLANNING_BYTES
+    stats = device.memory_stats() or {}
+    limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+    if not limit:
+        raise RuntimeError(
+            f"{device.device_kind} reports no memory limit (memory_stats="
+            f"{sorted(stats)}); pass BackendConfig.hbm_bytes"
+        )
+    return int(limit)
 
 
 class HbmMemoryModel:
@@ -305,11 +319,9 @@ class HbmMemoryModel:
     ):
         self.config = config
         self.param_bytes = int(param_bytes)
-        detected = hbm_bytes if hbm_bytes is not None else _detect_hbm_bytes()
-        # 16 GiB (v5e-class) fallback: on platforms with no reported limit
-        # (CPU test meshes with toy models) this yields caps far above the
-        # scheduler's max_rows, i.e. the model imposes nothing.
-        self.hbm_bytes = int(detected) if detected else 16 * (1 << 30)
+        self.hbm_bytes = int(
+            hbm_bytes if hbm_bytes is not None else _detect_hbm_bytes()
+        )
         self.headroom = float(headroom)
         self.tp = max(1, int(tp))
         self.dp = max(1, int(dp))
@@ -1235,6 +1247,7 @@ class TpuBackend(Backend):
         counters, breaker state, engine OOM stats, and the memory model's
         planning view. Cheap — no device work."""
         snap = self.scheduler.health()
+        snap["device"] = self._device_facts()
         snap["breaker"] = self.circuit_breaker.state
         snap["engine_oom"] = dict(self.engine.oom_stats)
         snap["memory_model"] = self.memory_model.describe()
@@ -1273,6 +1286,47 @@ class TpuBackend(Backend):
         grammar["enabled"] = bool(self.backend_config.constrained_decoding)
         grammar["cache"] = grammar_cache_stats()
         return snap
+
+    def _device_facts(self) -> Dict[str, Any]:
+        """What this process runs on, as JAX and the engine report it — so a
+        parent that never imports JAX can check the platform, the mesh and
+        which attention implementations the configured names resolved to."""
+        import jax
+
+        from ..native import native_status
+        from ..ops.attention import resolve_attention_impl
+        from ..ops.paged_attention import resolve_paged_attention_impl
+        from ..utils.compile_cache import compile_stats
+
+        devices = jax.devices()
+        engine = self.engine
+        config = engine.config
+        mesh = engine.mesh
+        return {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
+            "mesh": dict(mesh.shape) if mesh is not None else None,
+            "model": config.name,
+            "num_layers": config.num_layers,
+            "quantization": engine.quantized or None,
+            "attention": {
+                "prefill": resolve_attention_impl(config.attention_impl),
+                "decode": resolve_attention_impl(config.decode_attention_impl),
+                "paged": (
+                    resolve_paged_attention_impl(
+                        engine.paged_attention_impl, config=config, record=False
+                    )
+                    if engine.kv_layout == "paged"
+                    else None
+                ),
+            },
+            "bytes_in_use": [
+                (d.memory_stats() or {}).get("bytes_in_use") for d in devices
+            ],
+            "native": native_status(),
+            "compile": compile_stats(),
+        }
 
     # -- on-device consensus ----------------------------------------------
     def similarity_scorer(self, method: str):

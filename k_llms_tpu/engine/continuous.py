@@ -75,6 +75,7 @@ from ..types.wire import (
     EngineHungError,
     ServerDrainingError,
 )
+from ..utils.compile_cache import CompileTracker, wait_excluding_compile
 from ..utils.observability import (
     FAILURE_EVENTS,
     GRAMMAR_EVENTS,
@@ -221,7 +222,9 @@ class _StepDispatcher:
     """Persistent dispatch thread the loop worker hands each device step to.
 
     The worker waits on the step's completion event under the watchdog
-    budget; an overdue step is ABANDONED — its ticket is fenced, the inbox
+    budget (time the step spends compiling is not charged to it — the first
+    dispatch of a program is a compile, not a hang); an overdue step is
+    ABANDONED — its ticket is fenced, the inbox
     and thread are retired, and a fresh pair serves subsequent steps — so a
     wedged device dispatch blocks one disposable thread, never the loop.
     Hand-off uses a plain ``queue.Queue`` (no loop-ordered locks) and the
@@ -250,7 +253,8 @@ class _StepDispatcher:
                 return
             fn, ticket = item
             try:
-                ticket["result"] = fn()
+                with ticket["tracker"].active():
+                    ticket["result"] = fn()
             except BaseException as exc:
                 ticket["error"] = exc
             finally:
@@ -262,22 +266,31 @@ class _StepDispatcher:
                     )
                 ticket["done"].set()
 
-    def run(self, fn: Callable[[], Any], budget_s: float) -> Any:
-        """Run ``fn`` on the dispatch thread under a wall-clock budget.
-        Returns its result, re-raises its error, or raises :class:`_StepHung`
-        after abandoning the thread."""
+    def run(self, fn: Callable[[], Any], budget_model: Any) -> Any:
+        """Run ``fn`` on the dispatch thread under ``budget_model``'s step
+        budget. Returns ``(result, run_seconds)`` — wall time with compile
+        time taken out, the figure the budget model should learn from —
+        re-raises its error, or raises :class:`_StepHung` after abandoning
+        the thread."""
         self._ensure()
+        budget_s = budget_model.step_budget()
+        tracker = CompileTracker()
         ticket: Dict[str, Any] = {
             "done": threading.Event(),
             "result": None,
             "error": None,
             "abandoned": False,
+            "tracker": tracker,
         }
+        started = time.monotonic()
         self._inbox.put((fn, ticket))
-        if ticket["done"].wait(budget_s):
+        if wait_excluding_compile(
+            ticket["done"], budget_s, tracker, budget_model.max_budget_s
+        ):
             if ticket["error"] is not None:
                 raise ticket["error"]
-            return ticket["result"]
+            run_s = time.monotonic() - started - tracker.seconds()
+            return ticket["result"], max(0.0, run_s)
         ticket["abandoned"] = True
         # Retire the inbox+thread pair: the sentinel makes the stale thread
         # exit once the hung dispatch finally returns, and the fresh pair
@@ -501,6 +514,7 @@ class ContinuousDecodeLoop:
         with self._lock:
             out = dict(self._stats)
             out["width"] = self.width
+            out["prefill_chunk_tokens"] = self.prefill_chunk_tokens
             out["free_slots"] = len(self._free)
             active_rows = int(self._active_mask.sum())
             out["active_rows"] = active_rows
@@ -692,6 +706,7 @@ class ContinuousDecodeLoop:
 
     def _build_device_state(self) -> None:
         config = self.engine.config
+        mesh = getattr(self.engine, "mesh", None)
         W, P, G = self.width, self.max_prompt, self.max_new
         if self.paged:
             # One flat KV pool instead of dense per-slot caches; the engine
@@ -766,7 +781,8 @@ class ContinuousDecodeLoop:
             # One token for all W slots: write cur's KV at each row's own
             # offset (gen_lens), attend row-local prefix + generated KV.
             logits, gen = verify_step(
-                config, params, cur[:, None], gen_lens, prompt_lens, gen, prefix
+                config, params, cur[:, None], gen_lens, prompt_lens, gen, prefix,
+                mesh=mesh,
             )
             logits = _mask_pad(logits[:, 0, :])
             logits = jnp.where(poison[:, None], jnp.float32(jnp.nan), logits)
@@ -811,6 +827,7 @@ class ContinuousDecodeLoop:
                 KVCache(k=pool_k, v=pool_v), prefix_idx, gen_idx,
                 attn_impl=self._paged_attn_impl,
                 page_size=self._pool.page_size,
+                mesh=mesh,
             )
             pool_k = pool_k.at[:, write_idx].set(k_cols.astype(pool_k.dtype))
             pool_v = pool_v.at[:, write_idx].set(v_cols.astype(pool_v.dtype))
@@ -878,6 +895,7 @@ class ContinuousDecodeLoop:
         from .grammar import DeviceGrammar, grammar_advance, grammar_mask_logits
 
         config = self.engine.config
+        mesh = getattr(self.engine, "mesh", None)
         pad_id = config.pad_token_id
         row_keys, sample_rows, mask_pad = self._sampler_parts
         vocab_size = dg.vocab_size
@@ -908,7 +926,8 @@ class ContinuousDecodeLoop:
                     seeds, sample_idx, temps, top_ps, poison, g_states,
                     g_flags, *tabs):
             logits, gen = verify_step(
-                config, params, cur[:, None], gen_lens, prompt_lens, gen, prefix
+                config, params, cur[:, None], gen_lens, prompt_lens, gen, prefix,
+                mesh=mesh,
             )
             # Poison is injected BEFORE the grammar mask: NaNs survive the
             # mask's allowed positions, so detection sees them either way.
@@ -930,6 +949,7 @@ class ContinuousDecodeLoop:
                 KVCache(k=pool_k, v=pool_v), prefix_idx, gen_idx,
                 attn_impl=self._paged_attn_impl,
                 page_size=self._pool.page_size,
+                mesh=mesh,
             )
             pool_k = pool_k.at[:, write_idx].set(k_cols.astype(pool_k.dtype))
             pool_v = pool_v.at[:, write_idx].set(v_cols.astype(pool_v.dtype))
@@ -1598,8 +1618,8 @@ class ContinuousDecodeLoop:
         _chunk_t0 = time.perf_counter()
         if self.budget_model is not None:
             try:
-                first_logits, new_cache = self._dispatcher.run(
-                    _dispatch, self.budget_model.step_budget()
+                (first_logits, new_cache), _ = self._dispatcher.run(
+                    _dispatch, self.budget_model
                 )
             except _StepHung:
                 with self._lock:
@@ -1939,10 +1959,9 @@ class ContinuousDecodeLoop:
 
         _step_t0 = time.perf_counter()
         if self.budget_model is not None:
-            t0 = time.monotonic()
             try:
-                fetched = self._dispatcher.run(
-                    _dispatch, self.budget_model.step_budget()
+                fetched, run_s = self._dispatcher.run(
+                    _dispatch, self.budget_model
                 )
             except _StepHung:
                 with self._lock:
@@ -1953,7 +1972,7 @@ class ContinuousDecodeLoop:
                     "the dispatch thread and rebuilding"
                 )
                 raise
-            self.budget_model.observe_step(time.monotonic() - t0)
+            self.budget_model.observe_step(run_s)
         else:
             fetched = _dispatch()
         # Host wall time for the dispatched step (includes the by-design

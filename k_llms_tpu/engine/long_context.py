@@ -113,21 +113,36 @@ def forward_sequence_parallel(
             # heads' FULL sequence), attention runs locally, and the output
             # reshards back — XLA lowers the two constraint flips to
             # all-to-all collectives over the mesh axis. The attention itself
-            # is the flash kernel (VMEM-tiled online softmax — the [Sq, Sk]
-            # score matrix is never materialized), same as the dense prefill,
-            # so per-device attention memory is the K/V themselves, not S^2.
-            from ..ops.attention import flash_attention
+            # is whatever the config's attention_impl resolves to, same as
+            # the dense prefill: the flash kernel (VMEM-tiled online softmax —
+            # the [Sq, Sk] score matrix is never materialized, so per-device
+            # attention memory is the K/V themselves, not S^2), run per head
+            # shard, or the XLA reference.
+            from ..ops.attention import (
+                attention_xla,
+                flash_attention,
+                resolve_attention_impl,
+            )
 
             head_sharded = NamedSharding(mesh, P(None, seq_axis, None, None))
             qh = lax.with_sharding_constraint(q.transpose(0, 2, 1, 3), head_sharded)
             kh = lax.with_sharding_constraint(k.transpose(0, 2, 1, 3), head_sharded)
             vh = lax.with_sharding_constraint(v.transpose(0, 2, 1, 3), head_sharded)
-            attn = flash_attention(
-                qh, kh, vh,
-                causal=True,
-                sm_scale=config.query_scale,
-                interpret=jax.default_backend() != "tpu",
-            )
+            impl = resolve_attention_impl(config.attention_impl)
+            if impl == "xla":
+                attn = attention_xla(
+                    qh, kh, vh, causal=True, sm_scale=config.query_scale
+                ).astype(q.dtype)
+            else:
+                attn = flash_attention(
+                    qh, kh, vh,
+                    causal=True,
+                    sm_scale=config.query_scale,
+                    interpret=impl == "flash_interpret",
+                    mesh=mesh,
+                    head_axis=seq_axis,
+                    batch_axis=None,
+                )
             attn = lax.with_sharding_constraint(
                 attn, NamedSharding(mesh, P(None, None, seq_axis, None))
             ).transpose(0, 2, 1, 3)

@@ -34,16 +34,19 @@ class ModelConfig:
     max_seq_len: int = 8192
     dtype: str = "bfloat16"
     # Prefill attention implementation: "xla" (einsum, runs anywhere) or
-    # "flash" (Pallas TPU kernel, ops/attention.py; ~1.3x prefill attention
-    # speedup at 2k context on v5e).
+    # "flash" (Pallas TPU kernel, ops/attention.py). "flash" means the
+    # Mosaic-compiled kernel: off-TPU it resolves to "xla"
+    # (ops/attention.py::resolve_attention_impl). "flash_interpret" runs the
+    # kernel in the Pallas interpreter and is for tests only. Speed against
+    # XLA on the chip: not measured in this round.
     attention_impl: str = "xla"
     # Decode-step attention: "xla" (default) or "flash" (Pallas shared-prefix
     # kernel, ops/attention.py::decode_prefix_attention — streams each prefix
-    # KV block once per request with the whole query tile on the MXU).
-    # Measured on v5e at the 8B/int8/n=32/256-token-prefix flagship config the
-    # kernel is 0.94x of XLA: decode there is WEIGHT-streaming-bound
-    # (8.6 GB/step vs ~34 MB of prefix KV), so kernel call overhead outweighs
-    # the attention win; it's an opt-in for long-prefix regimes.
+    # KV block once per request with the whole query tile on the MXU); same
+    # resolution rule and tests-only "flash_interpret" as above. An earlier
+    # builder's note put the kernel at 0.94x of XLA at the 8B/int8/n=32/
+    # 256-token-prefix shape (decode there is weight-streaming-bound); no
+    # driver record holds that figure, so treat it as not measured.
     decode_attention_impl: str = "xla"
     # Architecture variants beyond Llama:
     # - qkv_bias: additive bias on q/k/v projections (Qwen2 family).

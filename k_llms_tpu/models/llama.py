@@ -313,6 +313,7 @@ def _block(
     prefix_lengths: Optional[jax.Array] = None,
     window_value=None,
     sp_ring_mesh=None,
+    mesh=None,
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """One transformer block over (possibly cached) keys.
 
@@ -324,10 +325,15 @@ def _block(
     enables the Pallas shared-prefix decode kernel). ``sp_ring_mesh``: a Mesh
     marking the prefix KV as SEQUENCE-SHARDED over the mesh's data axis —
     decode attends it in place via ring attention (O(S/P) per device) instead
-    of the replicated-prefix paths.
+    of the replicated-prefix paths. ``mesh``: the engine's device mesh, which
+    the Pallas kernels need to run per shard (None on one device).
     """
+    from ..ops.attention import resolve_attention_impl
+
     B, Sq, H = x.shape
     scale = config.query_scale or 1.0 / math.sqrt(config.head_dim)
+    prefill_impl = resolve_attention_impl(config.attention_impl)
+    decode_impl = resolve_attention_impl(config.decode_attention_impl)
 
     q, k, v = _attn_qkv(config, layer, x, positions)
 
@@ -362,7 +368,7 @@ def _block(
     # (Mistral "all", Gemma-2 "alternating" via a dynamic per-layer window
     # scalar) are all kernel-supported.
     if (
-        config.attention_impl == "flash"
+        prefill_impl != "xla"
         and write_index is None
         and prefix_kv is None
         and key_lengths is not None
@@ -378,7 +384,8 @@ def _block(
             sm_scale=scale,
             softcap=config.attn_softcap,
             window=window_value,
-            interpret=jax.default_backend() != "tpu",
+            interpret=prefill_impl == "flash_interpret",
+            mesh=mesh,
         ).transpose(0, 2, 1, 3)
         attn = attn.astype(x.dtype).reshape(B, Sq, config.q_dim)
         return mlp(attn_out(attn)), (cache_k, cache_v)
@@ -390,7 +397,7 @@ def _block(
     # Keys beyond the written range are zeros from the padded cache seed and
     # sit above every valid query's causal horizon.
     if (
-        config.attention_impl == "flash"
+        prefill_impl != "xla"
         and write_index is not None
         and getattr(write_index, "ndim", 0) == 0
         and Sq > 1
@@ -407,7 +414,8 @@ def _block(
             softcap=config.attn_softcap,
             window=window_value,
             q_offset=write_index,
-            interpret=jax.default_backend() != "tpu",
+            interpret=prefill_impl == "flash_interpret",
+            mesh=mesh,
         ).transpose(0, 2, 1, 3)
         attn = attn.astype(x.dtype).reshape(B, Sq, config.q_dim)
         return mlp(attn_out(attn)), (cache_k, cache_v)
@@ -462,7 +470,7 @@ def _block(
     # exact logsumexp merge stay in XLA. Gated to tile-friendly shapes
     # (query rows per request >= one sublane tile).
     if (
-        config.decode_attention_impl == "flash"
+        decode_impl != "xla"
         and config.sliding_window is None
         and config.attn_softcap is None
         and write_index is not None
@@ -480,7 +488,8 @@ def _block(
             pv,
             prefix_lengths,
             sm_scale=scale,
-            interpret=jax.default_backend() != "tpu",
+            interpret=decode_impl == "flash_interpret",
+            mesh=mesh,
         )
         return (
             mlp(attn_out(_merge_tail(out_p[:, :, None], m_p[:, :, None], l_p[:, :, None]))),
@@ -535,6 +544,7 @@ def _apply_stack(
     prefix_mask_global: Optional[jax.Array] = None,
     prefix_lengths: Optional[jax.Array] = None,
     sp_ring_mesh=None,
+    mesh=None,
 ) -> Tuple[jax.Array, KVCache]:
     """Scan the layer stack. cache k/v: [L, B, Smax, KVH, D].
 
@@ -579,6 +589,7 @@ def _apply_stack(
             prefix_lengths=prefix_lengths,
             window_value=window_value,
             sp_ring_mesh=sp_ring_mesh,
+            mesh=mesh,
         )
         return x, new_kv
 
@@ -618,12 +629,13 @@ def encode(
     params: Params,
     tokens: jax.Array,
     pad_mask: jax.Array,
+    mesh=None,
 ) -> jax.Array:
     """Final hidden states [B,S,H] — the on-device embedding provider only
     mean-pools hidden states. Under ``jax.jit`` the unused logits output (the
     lm_head projection, the single largest matmul in the network) is pruned by
     XLA dead-code elimination, so this thin wrapper costs nothing."""
-    return forward(config, params, tokens, pad_mask)[1]
+    return forward(config, params, tokens, pad_mask, mesh=mesh)[1]
 
 
 def forward(
@@ -631,6 +643,7 @@ def forward(
     params: Params,
     tokens: jax.Array,
     pad_mask: jax.Array,
+    mesh=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Full-sequence causal forward (no cache). Returns (logits f32 [B,S,V],
     final hidden states [B,S,H])."""
@@ -660,6 +673,7 @@ def forward(
         key_mask,
         key_lengths=key_lengths,
         key_mask_global=key_mask_global,
+        mesh=mesh,
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     logits = _logits(config, params, h)
@@ -671,6 +685,7 @@ def prefill(
     params: Params,
     tokens: jax.Array,
     prompt_len: jax.Array,
+    mesh=None,
 ) -> Tuple[jax.Array, KVCache]:
     """Prefill the shared prompt at batch=1. tokens: [1, S] (bucket-padded on the
     right), prompt_len: scalar valid length. Returns (last-token logits [1, V],
@@ -701,6 +716,7 @@ def prefill(
         key_mask,
         key_lengths=key_lengths,
         key_mask_global=key_mask_global,
+        mesh=mesh,
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     last = jnp.take_along_axis(h, (prompt_len - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1)
@@ -715,6 +731,7 @@ def prefill_continue(
     cache: KVCache,
     prefix_len: jax.Array,
     total_len: jax.Array,
+    mesh=None,
 ) -> Tuple[jax.Array, KVCache]:
     """Prefill a prompt SUFFIX against an already-computed prompt-prefix KV —
     the prefix-caching path (the reference has no model layer; its provider
@@ -751,6 +768,7 @@ def prefill_continue(
         prefix_len,
         causal_abs,
         key_mask_global=key_mask_global,
+        mesh=mesh,
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     last_row = (total_len - prefix_len - 1).reshape(B, 1, 1).astype(jnp.int32)
@@ -766,6 +784,7 @@ def prefill_chunk_step(
     cache: KVCache,
     cursor: jax.Array,
     valid_len: jax.Array,
+    mesh=None,
 ) -> Tuple[jax.Array, KVCache]:
     """Extend a partially-filled prompt prefix by one chunk — the unit of
     chunked prefill (Sarathi-style: prompt ingestion interleaved with decode
@@ -785,7 +804,8 @@ def prefill_chunk_step(
     cache).
     """
     return prefill_continue(
-        config, params, chunk_tokens, cache, cursor, cursor + valid_len
+        config, params, chunk_tokens, cache, cursor, cursor + valid_len,
+        mesh=mesh,
     )
 
 
@@ -796,6 +816,7 @@ def prefill_chunk_step_paged(
     cache: KVCache,
     cursor: jax.Array,
     valid_len: jax.Array,
+    mesh=None,
 ) -> Tuple[jax.Array, KVCache, jax.Array, jax.Array]:
     """Paged twin of :func:`prefill_chunk_step`: identical compute against the
     dense staging cache (byte-identity comes for free from the shared path),
@@ -805,7 +826,7 @@ def prefill_chunk_step_paged(
     v_cols [L, C, KVH, D])."""
     C = chunk_tokens.shape[1]
     logits, cache = prefill_chunk_step(
-        config, params, chunk_tokens, cache, cursor, valid_len
+        config, params, chunk_tokens, cache, cursor, valid_len, mesh=mesh
     )
     k_cols = jax.lax.dynamic_slice_in_dim(cache.k[:, 0], cursor, C, axis=1)
     v_cols = jax.lax.dynamic_slice_in_dim(cache.v[:, 0], cursor, C, axis=1)
@@ -821,6 +842,7 @@ def decode_step(
     gen_cache: KVCache,
     prefix: KVCache,
     sp_ring_mesh=None,
+    mesh=None,
 ) -> Tuple[jax.Array, KVCache]:
     """One decode step for all samples against their shared prefix(es).
 
@@ -874,6 +896,7 @@ def decode_step(
         prefix_mask_global=prefix_mask_global,
         prefix_lengths=pl,
         sp_ring_mesh=sp_ring_mesh,
+        mesh=mesh,
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     logits = _logits(config, params, h[:, 0, :])
@@ -889,6 +912,7 @@ def verify_step(
     gen_cache: KVCache,
     prefix: KVCache,
     sp_ring_mesh=None,
+    mesh=None,
 ) -> Tuple[jax.Array, KVCache]:
     """Speculative-decoding verification: score k+1 tokens per row in ONE
     forward (the draft-tree trunk of prompt-lookup decoding).
@@ -945,6 +969,7 @@ def verify_step(
         prefix_mask_global=prefix_mask_global,
         prefix_lengths=pl,
         sp_ring_mesh=sp_ring_mesh,
+        mesh=mesh,
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     logits = _logits(config, params, h)
@@ -970,6 +995,7 @@ def _block_paged(
     page_tables=None,
     page_size: Optional[int] = None,
     attn_impl: str = "xla",
+    mesh=None,
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """Paged twin of :func:`_block` for the ``Sq == 1`` decode/verify step.
 
@@ -983,6 +1009,7 @@ def _block_paged(
     the written gather transient via ``take_along_axis``; taking it straight
     from the projection is bit-identical and skips the round-trip).
     """
+    from ..ops.attention import resolve_attention_impl
     from ..ops.paged_attention import (
         paged_decode_attention_pallas,
         paged_decode_attention_xla,
@@ -1020,12 +1047,14 @@ def _block_paged(
             page_size=page_size,
             sm_scale=scale,
             interpret=attn_impl == "pallas_interpret",
+            mesh=mesh,
         )[:, None]  # [B, 1, QH, D]
     else:
         # Same gate as _block's decode_prefix_attention branch, so a config
         # running flash decode on dense caches keeps it on paged ones.
+        decode_impl = resolve_attention_impl(config.decode_attention_impl)
         flash_prefix = (
-            config.decode_attention_impl == "flash"
+            decode_impl != "xla"
             and config.sliding_window is None
             and config.attn_softcap is None
             and Sq == 1
@@ -1046,8 +1075,8 @@ def _block_paged(
             sm_scale=scale,
             softcap=config.attn_softcap,
             prefix_lengths=prefix_lengths,
-            flash_prefix=flash_prefix,
-            interpret=jax.default_backend() != "tpu",
+            flash_prefix=decode_impl if flash_prefix else None,
+            mesh=mesh,
         )
     attn = attn.astype(x.dtype).reshape(B, Sq, config.q_dim)
     x = _attn_residual(config, layer, x, attn)
@@ -1070,6 +1099,7 @@ def _apply_stack_paged(
     prefix_lengths: Optional[jax.Array] = None,
     attn_impl: str = "xla",
     page_size: Optional[int] = None,
+    mesh=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Paged twin of :func:`_apply_stack`: per-layer KV lives in a flat page
     pool addressed through block tables instead of dense caches.
@@ -1125,6 +1155,7 @@ def _apply_stack_paged(
             page_tables=page_tables,
             page_size=page_size,
             attn_impl=attn_impl,
+            mesh=mesh,
         )
         return x, cols
 
@@ -1146,6 +1177,7 @@ def paged_verify_step(
     gen_idx: jax.Array,
     attn_impl: str = "xla",
     page_size: Optional[int] = None,
+    mesh=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Paged twin of :func:`verify_step` at ``Sq == 1`` — the continuous
     decode loop's step when its slots hold block tables into a shared page
@@ -1204,6 +1236,7 @@ def paged_verify_step(
         prefix_lengths=pl,
         attn_impl=attn_impl,
         page_size=page_size,
+        mesh=mesh,
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     logits = _logits(config, params, h)

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.w4matmul import Q4Tensor, pack_int4, supports_int4, w4_matmul
+from ..ops.w4matmul import Q4Tensor, pack_int4, supports_int4, unpack_int4, w4_matmul
 
 
 class QTensor(NamedTuple):
@@ -134,6 +134,12 @@ def tree_has_q4(params: "Dict[str, Any]") -> bool:
     return any(isinstance(w, Q4Tensor) for w in _quant_leaf_nodes(params))
 
 
+def tree_fully_quantized(params: "Dict[str, Any]") -> bool:
+    """True when every quantizable matmul already holds a QTensor/Q4Tensor —
+    ``quantize_params`` would hand each one back unchanged."""
+    return all(isinstance(w, (QTensor, Q4Tensor)) for w in _quant_leaf_nodes(params))
+
+
 def stored_quant_layout(params: "Dict[str, Any]") -> "str | None":
     """The quantization a params tree actually stores — 'int4' if any leaf is
     Q4Tensor, 'int8' if any is QTensor, None for a plain bf16 tree. Lets a
@@ -207,18 +213,19 @@ def qdot(x: jax.Array, w: WeightLike) -> jax.Array:
     """``x @ w`` for a plain array, a QTensor, or a Q4Tensor. For QTensor the
     int8 payload is cast inside the matmul (HBM reads stay int8) and the
     per-channel scale is applied to the output. For Q4Tensor the Pallas w4a16
-    kernel unpacks nibbles in VMEM (HBM reads stay int4); off-TPU the kernel
-    runs in interpret mode only for realistic shapes — tiny test shapes take
-    the XLA dequant reference inside :func:`w4_matmul`."""
+    kernel unpacks nibbles in VMEM (HBM reads stay int4); the kernel is
+    Mosaic-only, so off-TPU the product is the XLA dequant reference (the
+    interpreter is something only a test asks :func:`w4_matmul` for)."""
     if isinstance(w, Q4Tensor):
         x2 = x.reshape(-1, x.shape[-1])
-        interpret = jax.default_backend() != "tpu"
-        if w.part is not None and w.mesh is not None:
+        if jax.default_backend() != "tpu":
+            out = (x2.astype(jnp.float32) @ unpack_int4(w)).astype(x.dtype)
+        elif w.part is not None and w.mesh is not None:
             from ..ops.w4matmul import w4_matmul_tp
 
-            out = w4_matmul_tp(x2, w, interpret=interpret)
+            out = w4_matmul_tp(x2, w)
         else:
-            out = w4_matmul(x2, w, interpret=interpret)
+            out = w4_matmul(x2, w)
         return out.reshape(*x.shape[:-1], w.q.shape[-1])
     if isinstance(w, QTensor):
         out = x @ w.q.astype(x.dtype)

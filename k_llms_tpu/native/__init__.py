@@ -4,9 +4,12 @@ The reference leans on two native wheels for its scalar hot loops: the
 python-Levenshtein C extension and scipy's Hungarian solver
 (`/root/reference/k_llms/utils/consensus_utils.py:15,20,372,759`). Here both are
 first-party C++ (``levenshtein.cpp``, ``hungarian.cpp``) compiled to one shared
-library and bound via ctypes — no pybind11 dependency. Pure-Python fallbacks keep
-the package importable before the library is built; ``build()`` compiles it with
-``make`` on demand (and is attempted once, silently, at import).
+library and bound via ctypes — no pybind11 dependency. The library is a build
+product, not a source file: it is git-ignored and compiled from the ``.cpp``
+files with ``make`` on first import (and again whenever a source is newer
+than it). Pure-Python fallbacks keep the package usable where there is no
+toolchain; :func:`native_status` says which one is serving, so a health check
+can tell a fast deployment from a degraded one.
 
 These stay host-side on purpose: inputs are tiny (n <= 32 samples, short strings),
 so the TPU/MXU has no role here — see SURVEY.md §2.3.
@@ -17,18 +20,36 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
-from typing import Optional, Sequence
+from typing import Any, Dict, Optional, Sequence
 
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_DIR, "libkllms_native.so")
+_SOURCES = ("levenshtein.cpp", "hungarian.cpp", "Makefile")
 
 _lib: Optional[ctypes.CDLL] = None
+_attempted = False
+_load_error: Optional[str] = None
+
+
+def _stale() -> bool:
+    """Is the library missing, or older than a file it is built from?"""
+    try:
+        built = os.path.getmtime(_LIB_PATH)
+    except OSError:
+        return True
+    return any(
+        os.path.getmtime(os.path.join(_DIR, name)) > built for name in _SOURCES
+    )
 
 
 def build(quiet: bool = True) -> bool:
-    """Compile the shared library in-place. Returns True on success."""
+    """Compile the shared library in-place (the Makefile writes a temporary
+    file and renames it, so a concurrent importer never loads half a
+    library). Returns True on success; the reason for a failure is kept for
+    :func:`native_status`."""
+    global _load_error
     try:
         subprocess.run(
             ["make", "-C", _DIR],
@@ -36,21 +57,29 @@ def build(quiet: bool = True) -> bool:
             capture_output=quiet,
             timeout=120,
         )
-        return os.path.exists(_LIB_PATH)
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", None) or b""
+        _load_error = f"build failed: {e!r} {detail[-300:]!r}"
         return False
+    return os.path.exists(_LIB_PATH)
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib
-    if _lib is not None:
+    """The library, built and loaded at most once per process: a failed
+    attempt is remembered (the fallbacks serve from then on) rather than
+    re-running ``make`` on every distance call."""
+    global _lib, _load_error, _attempted
+    if _lib is not None or _attempted:
         return _lib
+    _attempted = True
+    if _stale():
+        build(quiet=True)  # on failure an older library, if any, still serves
     if not os.path.exists(_LIB_PATH):
-        if not build(quiet=True):
-            return None
+        return None
     try:
         lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
+    except OSError as e:
+        _load_error = f"load failed: {e}"
         return None
 
     lib.kllms_levenshtein.restype = ctypes.c_int64
@@ -69,11 +98,19 @@ def _load() -> Optional[ctypes.CDLL]:
         ctypes.POINTER(ctypes.c_int64),
     ]
     _lib = lib
+    _load_error = None
     return lib
 
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def native_status() -> Dict[str, Any]:
+    """``{"loaded": bool, "error": str | None}`` — whether the C++ kernels
+    (True) or the pure-Python fallbacks (False, with why) are serving."""
+    loaded = _load() is not None
+    return {"loaded": loaded, "error": None if loaded else _load_error}
 
 
 def _to_u32(s: str) -> np.ndarray:
@@ -190,5 +227,6 @@ def _lsa_py(c: np.ndarray):
     return row, col
 
 
-# Try to have the native library ready; harmless if the toolchain is absent.
+# Build/load once at import; where the toolchain is absent the fallbacks serve
+# and native_status() records why.
 _load()

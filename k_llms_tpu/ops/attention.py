@@ -8,6 +8,14 @@ K/V BlockSpec onto its shared kv head — no head replication in memory.
 
 `attention_xla` is the always-available reference implementation (also the
 numerical oracle in tests, where the kernel runs in interpret mode on CPU).
+
+Two rules hold for every Pallas call in the package. (1) Mosaic kernels cannot
+be partitioned by GSPMD, so under a multi-device mesh the call runs per shard
+inside ``shard_map`` (:func:`shard_kernel`): heads over the model axis, batch
+rows over the data axis when they divide. (2) Interpret mode is never chosen
+from the platform: ``"flash"`` means the compiled kernel and resolves to the
+XLA reference off-TPU (:func:`resolve_attention_impl`); only the explicit
+``"flash_interpret"`` name — which tests use — runs the interpreter.
 """
 
 from __future__ import annotations
@@ -20,8 +28,51 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from ..parallel.mesh import DATA_AXIS, MODEL_AXIS
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
+
+#: Values of ``ModelConfig.attention_impl`` / ``decode_attention_impl``.
+ATTENTION_IMPLS = ("xla", "flash", "flash_interpret")
+
+
+def resolve_attention_impl(requested: str) -> str:
+    """The implementation a config's ``"xla" | "flash" | "flash_interpret"``
+    runs in this process: ``"flash"`` is the Mosaic-compiled kernel, which
+    exists on TPU only — anywhere else the served path takes the XLA
+    reference (the same posture as paged ``"auto"``). ``"flash_interpret"``
+    runs the kernel body in the Pallas interpreter on any backend; tests ask
+    for it by name, nothing selects it from the platform."""
+    if requested not in ATTENTION_IMPLS:
+        raise ValueError(
+            f"attention impl must be one of {ATTENTION_IMPLS}, got {requested!r}"
+        )
+    if requested == "flash" and jax.default_backend() != "tpu":
+        return "xla"
+    return requested
+
+
+def mesh_axis(mesh, axis: str, size: int) -> Optional[str]:
+    """``axis`` when ``size`` elements split evenly over it, else None (the
+    dimension is then replicated over that axis inside the shard_map)."""
+    return axis if size % mesh.shape[axis] == 0 else None
+
+
+def multi_device(mesh) -> bool:
+    """Does a Pallas call under ``mesh`` need :func:`shard_kernel`? (One
+    device compiles the call directly.)"""
+    return mesh is not None and mesh.size > 1
+
+
+def shard_kernel(fn, mesh, in_specs, out_specs):
+    """``fn`` run per shard of a multi-device ``mesh`` (Mosaic refuses
+    automatic partitioning). ``check_vma`` is off because ``pallas_call``
+    outputs carry no varying-axes annotation."""
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def attention_xla(
@@ -214,6 +265,7 @@ def decode_prefix_attention(
     sm_scale: Optional[float] = None,
     block_k: int = 128,
     interpret: bool = False,
+    mesh=None,
 ):
     """Decode-step attention over the SHARED-PREFIX KV, as a Pallas kernel.
 
@@ -229,21 +281,37 @@ def decode_prefix_attention(
     q: [B, QH, D] (rows request-major, B % R == 0); prefix_k/v:
     [R, P, KVH, D]; prompt_lens: [R] valid key counts. Returns
     (out [B, QH, D] f32 — normalized within the prefix phase, m [B, QH],
-    l [B, QH]) for the caller's merge.
+    l [B, QH]) for the caller's merge. Under a multi-device ``mesh`` heads
+    shard over the model axis and rows replicate over data (a request's rows
+    are one query tile; splitting them would split the tile).
     """
+    if multi_device(mesh):
+        h_ax = mesh_axis(mesh, MODEL_AXIS, prefix_k.shape[2])
+        local = functools.partial(
+            decode_prefix_attention, sm_scale=sm_scale, block_k=block_k,
+            interpret=interpret,
+        )
+        return shard_kernel(
+            local, mesh,
+            in_specs=(
+                P(None, h_ax, None), P(None, None, h_ax, None),
+                P(None, None, h_ax, None), P(),
+            ),
+            out_specs=(P(None, h_ax, None), P(None, h_ax), P(None, h_ax)),
+        )(q, prefix_k, prefix_v, prompt_lens)
     B, QH, D = q.shape
-    R, P, KVH, _ = prefix_k.shape
+    R, P_len, KVH, _ = prefix_k.shape
     G = QH // KVH
     n_per = B // R
     QR = n_per * G
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
-    block_k = min(block_k, P)
+    block_k = min(block_k, P_len)
 
     # Request-major query tile per kv head: [R, KVH, n_per*G, D]. Row (r, h,
     # i*G + g) is batch row r*n_per + i, query head h*G + g.
     q4 = q.reshape(R, n_per, KVH, G, D).transpose(0, 2, 1, 3, 4).reshape(R, KVH, QR, D)
 
-    grid = (R, pl.cdiv(P, block_k))
+    grid = (R, pl.cdiv(P_len, block_k))
     kernel = functools.partial(
         _decode_prefix_kernel, sm_scale=scale, block_k=block_k, kv_heads=KVH
     )
@@ -300,6 +368,9 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool = False,
+    mesh=None,
+    head_axis: str = MODEL_AXIS,
+    batch_axis: Optional[str] = DATA_AXIS,
 ) -> jax.Array:
     """Pallas flash attention. q: [B, QH, Sq, D]; k/v: [B, KVH, Sk, D];
     key_lengths: [B] int32 — keys at positions >= length are masked (the
@@ -314,8 +385,42 @@ def flash_attention(
     evaluated at row + q_offset. Returns [B, QH, Sq, D].
 
     Sq/Sk pad to block multiples internally; GQA maps query head h onto kv head
-    h // (QH // KVH) via the BlockSpec index maps.
+    h // (QH // KVH) via the BlockSpec index maps. Under a multi-device
+    ``mesh`` the kernel runs per shard: heads over ``head_axis`` (contiguous
+    head blocks keep the q-head -> kv-head grouping), batch rows over
+    ``batch_axis`` — model and data by default; Ulysses context parallelism
+    puts the heads on its sequence axis instead.
     """
+    B, QH, Sq, D = q.shape
+    KVH, Sk = k.shape[1], k.shape[2]
+    if key_lengths is None:
+        key_lengths = jnp.full((B,), Sk, jnp.int32)
+    key_lengths = key_lengths.astype(jnp.int32).reshape(B, 1)
+    if window is None:
+        window = NO_WINDOW
+    window_arr = jnp.asarray(window, jnp.int32).reshape(1, 1)
+    qoff_arr = jnp.asarray(0 if q_offset is None else q_offset, jnp.int32).reshape(1, 1)
+    local = functools.partial(
+        _flash_attention_local, causal=causal, sm_scale=sm_scale,
+        softcap=softcap, block_q=block_q, block_k=block_k, interpret=interpret,
+    )
+    if multi_device(mesh):
+        b_ax = mesh_axis(mesh, batch_axis, B) if batch_axis else None
+        h_ax = mesh_axis(mesh, head_axis, KVH)
+        heads = P(b_ax, h_ax, None, None)
+        local = shard_kernel(
+            local, mesh,
+            in_specs=(heads, heads, heads, P(b_ax, None), P(), P()),
+            out_specs=heads,
+        )
+    return local(q, k, v, key_lengths, window_arr, qoff_arr)
+
+
+def _flash_attention_local(
+    q, k, v, key_lengths, window_arr, qoff_arr, *,
+    causal, sm_scale, softcap, block_q, block_k, interpret,
+):
+    """One shard's flash attention (the whole call on a single device)."""
     B, QH, Sq, D = q.shape
     KVH, Sk = k.shape[1], k.shape[2]
     G = QH // KVH
@@ -325,14 +430,6 @@ def flash_attention(
     block_k = max(8, min(block_k, Sk))
     Sq_pad = pl.cdiv(Sq, block_q) * block_q
     Sk_pad = pl.cdiv(Sk, block_k) * block_k
-
-    if key_lengths is None:
-        key_lengths = jnp.full((B,), Sk, jnp.int32)
-    key_lengths = key_lengths.astype(jnp.int32).reshape(B, 1)
-    if window is None:
-        window = NO_WINDOW
-    window_arr = jnp.asarray(window, jnp.int32).reshape(1, 1)
-    qoff_arr = jnp.asarray(0 if q_offset is None else q_offset, jnp.int32).reshape(1, 1)
     if Sk_pad != Sk:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, Sk_pad - Sk), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, Sk_pad - Sk), (0, 0)))

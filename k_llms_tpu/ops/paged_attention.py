@@ -48,9 +48,19 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from jax.sharding import PartitionSpec as P
+
+from ..parallel.mesh import DATA_AXIS, MODEL_AXIS
 from ..reliability import failpoints as _failpoints
 from ..utils.observability import KERNEL_EVENTS
-from .attention import NEG_INF, decode_prefix_attention, gather_kv_pages
+from .attention import (
+    NEG_INF,
+    decode_prefix_attention,
+    gather_kv_pages,
+    mesh_axis,
+    multi_device,
+    shard_kernel,
+)
 
 #: Values accepted by ``BackendConfig.paged_attention_impl`` /
 #: ``LocalEngine(paged_attention_impl=...)``. "pallas_interpret" is a
@@ -59,7 +69,9 @@ from .attention import NEG_INF, decode_prefix_attention, gather_kv_pages
 PAGED_ATTENTION_IMPLS = ("auto", "pallas", "xla")
 
 
-def resolve_paged_attention_impl(requested: str, *, config=None) -> str:
+def resolve_paged_attention_impl(
+    requested: str, *, config=None, record: bool = True
+) -> str:
     """Pick the paged-attention implementation for the current process.
 
     requested: "auto" | "pallas" | "xla"; config: optional ModelConfig — a
@@ -73,13 +85,15 @@ def resolve_paged_attention_impl(requested: str, *, config=None) -> str:
     (``failpoint``); "auto" picking XLA off-TPU is the expected CPU posture
     and is NOT counted. The ``ops.paged_attn`` failpoint (action
     ``fallback``) forces the counted fallback for observability drills.
+    ``record=False`` asks what WOULD resolve without firing the failpoint or
+    counting anything (``health()`` reports it).
     """
     if requested not in PAGED_ATTENTION_IMPLS:
         raise ValueError(
             f"paged_attention_impl must be one of {PAGED_ATTENTION_IMPLS}, "
             f"got {requested!r}"
         )
-    spec = _failpoints.fire("ops.paged_attn")
+    spec = _failpoints.fire("ops.paged_attn") if record else None
     if spec is not None and spec.action == "fallback":
         KERNEL_EVENTS.record("kernel.paged_attn_fallback.failpoint")
         return "xla"
@@ -93,7 +107,7 @@ def resolve_paged_attention_impl(requested: str, *, config=None) -> str:
         blocked = None
     if jax.default_backend() == "tpu" and blocked is None:
         return "pallas"
-    if requested == "pallas":
+    if requested == "pallas" and record:
         KERNEL_EVENTS.record(f"kernel.paged_attn_fallback.{blocked or 'platform'}")
     return "xla"
 
@@ -128,8 +142,8 @@ def paged_decode_attention_xla(
     sm_scale: float,
     softcap: Optional[float] = None,
     prefix_lengths: Optional[jax.Array] = None,
-    flash_prefix: bool = False,
-    interpret: bool = False,
+    flash_prefix: Optional[str] = None,
+    mesh=None,
 ) -> jax.Array:
     """Reference paged decode attention, byte-identical to the dense path.
 
@@ -144,7 +158,8 @@ def paged_decode_attention_xla(
 
     The op order — gather, per-row fresh-column insert, masked scores,
     concatenated softmax (or the flash-prefix logsumexp merge when
-    ``flash_prefix``) — replicates `models/llama.py::_block`'s decode branch
+    ``flash_prefix`` names the resolved decode kernel, "flash" or the
+    tests-only "flash_interpret") — replicates `models/llama.py::_block`'s decode branch
     operation for operation, so outputs are bit-identical to dense attention
     on equal inputs. Returns attn ``[B, Sq, QH, D]`` f32.
     """
@@ -174,7 +189,8 @@ def paged_decode_attention_xla(
             pv,
             prefix_lengths,
             sm_scale=sm_scale,
-            interpret=interpret,
+            interpret=flash_prefix == "flash_interpret",
+            mesh=mesh,
         )
         return _merge_prefix_tail(
             q,
@@ -198,9 +214,9 @@ def paged_decode_attention_xla(
     p_scores = jnp.where(prefix_mask[:, None, :, :], p_scores, neg)
     all_scores = jnp.concatenate([p_scores, scores], axis=-1)
     weights = jax.nn.softmax(all_scores, axis=-1)
-    P = pk.shape[1]
-    return _gqa_values_shared(weights[..., :P], pv) + _gqa_values(
-        weights[..., P:], gv
+    plen = pk.shape[1]
+    return _gqa_values_shared(weights[..., :plen], pv) + _gqa_values(
+        weights[..., plen:], gv
     )
 
 
@@ -357,6 +373,7 @@ def paged_decode_attention_pallas(
     page_size: int,
     sm_scale: float,
     interpret: bool = False,
+    mesh=None,
 ) -> jax.Array:
     """Fused paged decode attention (``Sq == 1``).
 
@@ -367,18 +384,49 @@ def paged_decode_attention_pallas(
     gen_lens [B]: per-row valid counts. Returns [B, QH, D] f32 — the same
     normalized output the XLA reference produces (up to online-softmax
     float ordering; token-exact under greedy, pinned by the differential
-    tests).
+    tests). Under a multi-device ``mesh`` the kernel runs per shard: kv heads
+    (and the pool, which is sharded the same way) over the model axis, rows
+    and their tables over the data axis when they divide; the pool itself is
+    replicated over data.
     """
-    B, QH, D = q.shape
-    KVH = pool_k.shape[1]
-    G = QH // KVH
-    ps = page_size
-    npages = pool_k.shape[0] // ps
+    B = q.shape[0]
     if prefix_pages.shape[0] != B:  # [R, NP] shared prefix -> per-row table
         prefix_pages = jnp.repeat(
             prefix_pages, B // prefix_pages.shape[0], axis=0,
             total_repeat_length=B,
         )
+    local = functools.partial(
+        _paged_decode_local, page_size=page_size, sm_scale=sm_scale,
+        interpret=interpret,
+    )
+    if multi_device(mesh):
+        b_ax = mesh_axis(mesh, DATA_AXIS, B)
+        h_ax = mesh_axis(mesh, MODEL_AXIS, pool_k.shape[1])
+        rows, pool = P(b_ax, h_ax, None), P(None, h_ax, None)
+        local = shard_kernel(
+            local, mesh,
+            in_specs=(
+                rows, pool, pool, P(b_ax, None), P(b_ax, None), P(b_ax),
+                rows, rows, P(b_ax), P(b_ax),
+            ),
+            out_specs=rows,
+        )
+    return local(
+        q, pool_k, pool_v, prefix_pages, gen_pages, gen_phase, new_k, new_v,
+        prompt_lens, gen_lens,
+    )
+
+
+def _paged_decode_local(
+    q, pool_k, pool_v, prefix_pages, gen_pages, gen_phase, new_k, new_v,
+    prompt_lens, gen_lens, *, page_size, sm_scale, interpret,
+):
+    """One shard's fused paged decode (the whole call on a single device)."""
+    B, QH, D = q.shape
+    KVH = pool_k.shape[1]
+    G = QH // KVH
+    ps = page_size
+    npages = pool_k.shape[0] // ps
     NP = prefix_pages.shape[1]
     NG = gen_pages.shape[1]
     tables = jnp.concatenate([prefix_pages, gen_pages], axis=1).astype(jnp.int32)

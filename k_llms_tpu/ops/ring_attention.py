@@ -26,19 +26,11 @@ from jax.sharding import PartitionSpec as P
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
-# jax moved shard_map out of experimental (and introduced explicit
-# varying-axis typing via lax.pvary) after 0.4.x; support both so the ring
-# paths run on the 0.4-series CPU image as well as current TPU toolchains.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # 0.4.x: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-if hasattr(lax, "pvary"):
-    _pvary = lax.pvary
-else:  # 0.4.x infers replication instead of explicit varying-axis marks
-    def _pvary(x, axes):
-        return x
+def _pvary(x, axes):
+    """Mark a replicated value as varying over ``axes`` (shard_map's
+    varying-axes typing needs loop carries marked up front)."""
+    return lax.pcast(x, axes, to="varying")
 
 
 def _chunk_attention_update(q, k, v, q_pos, k_pos, causal, scale, acc, m, l):
@@ -195,7 +187,7 @@ def ring_decode_prefix(
     q_spec = P(seq_axis, model_axis, None)
     kv_spec = P(None, seq_axis, model_axis, None)
     out_spec = (q_spec, P(seq_axis, model_axis), P(seq_axis, model_axis))
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, P()),
@@ -286,7 +278,7 @@ def ring_verify_prefix(
     q_spec = P(seq_axis, model_axis, None, None)
     kv_spec = P(None, seq_axis, model_axis, None)
     out_spec = (q_spec, P(seq_axis, model_axis, None), P(seq_axis, model_axis, None))
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, P()),
@@ -311,7 +303,7 @@ def ring_attention(
     fn = functools.partial(
         ring_attention_local, axis_name=seq_axis, causal=causal, sm_scale=sm_scale
     )
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         lambda q, k, v: fn(q, k, v),
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -385,7 +377,7 @@ def suffix_prefix_attention(
 
     q_spec = P(None, model_axis, None, None)
     kv_spec = P(None, seq_axis, model_axis, None)
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, P()),
@@ -420,7 +412,7 @@ def scatter_into_ring(
 
     spec = P(None, seq_axis, model_axis, None)
     rep = P(None, None, model_axis, None)
-    return _shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, rep, P(), P()),

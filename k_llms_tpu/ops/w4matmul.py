@@ -33,7 +33,6 @@ bf16, so no precision is lost to the weight cast.
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Optional
 
 import jax
@@ -41,14 +40,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # 0.4.x: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# 0.4.x names it TPUCompilerParams; same kwargs for the fields we use.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 GROUP = 128  # contraction rows per quantization group (one scale each)
 _HALF = GROUP // 2
@@ -212,7 +203,7 @@ def w4_matmul(
         ],
         out_specs=pl.BlockSpec((rp, block_n), lambda rb, nb, kb: (rb, nb)),
         scratch_shapes=[pltpu.VMEM((rp, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -266,15 +257,8 @@ def w4_matmul_tp(x: jax.Array, w: Q4Tensor, *, interpret: bool = False) -> jax.A
     else:  # pragma: no cover - callers gate on part
         raise ValueError(f"unknown partition kind {w.part!r}")
 
-    # Disable the replication/varying-axes checker: pallas_call's out_shape
-    # carries no varying-mesh-axes annotation, which it would otherwise
-    # reject inside shard_map. The flag is check_vma on current jax and
-    # check_rep on 0.4.x.
-    check_kw = (
-        {"check_vma": False}
-        if "check_vma" in inspect.signature(_shard_map).parameters
-        else {"check_rep": False}
-    )
-    return _shard_map(
-        local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **check_kw
+    # check_vma off: pallas_call's out_shape carries no varying-mesh-axes
+    # annotation, which shard_map would otherwise reject.
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )(x, w.q, w.scale)

@@ -2,12 +2,10 @@
 breaker.
 
 The reference gets retries from the OpenAI client (2 retries, exponential
-backoff); locally the same shape already proved itself in ``bench.py``'s
-relay-flap survival (bounded probe attempts + backoff + structured error on
-final failure). This module is that shape as a reusable policy, plus the
-circuit breaker that turns a flapping backend (relay death, OOM loop, compile
-failure storm) into fast typed errors instead of every caller queueing behind
-a hang.
+backoff). This module is that shape as a reusable policy (bounded attempts +
+backoff + structured error on final failure), plus the circuit breaker that
+turns a flapping backend (OOM loop, compile failure storm) into fast typed
+errors instead of every caller queueing behind a hang.
 
 Determinism: jitter derives from ``random.Random(seed)`` so failure tests can
 pin exact backoff schedules; production constructs without a seed.

@@ -40,6 +40,7 @@ from typing import Any, Callable, Deque, Dict, Optional, Tuple
 
 from ..analysis.lockcheck import make_lock
 from ..types.wire import CheckpointCorruptError, EngineHungError
+from ..utils.compile_cache import CompileTracker, wait_excluding_compile
 from ..utils.observability import RECOVERY_EVENTS
 
 logger = logging.getLogger(__name__)
@@ -53,9 +54,12 @@ class LaunchBudgetModel:
     ``per_token_ewma`` is learned from completed launches (elapsed divided by
     the batch's max_new_tokens — decode steps dominate, and step latency is
     nearly row-count independent at serving widths, so tokens are the right
-    unit). The generous ``min_budget`` floor absorbs first-launch compile
-    time, which the EWMA then decays away from; ``multiplier`` is the slack
-    between "slow" and "hung".
+    unit). Compile time is NOT part of the budget: both watchdogs wait with
+    ``wait_excluding_compile``, which forgives up to ``max_budget`` seconds the
+    launch thread spends tracing/lowering/compiling, and observe only the
+    remainder — so the EWMA learns run time and the first launch of a shape
+    is not declared hung for compiling. ``multiplier`` is the slack between
+    "slow" and "hung".
     """
 
     def __init__(
@@ -97,8 +101,7 @@ class LaunchBudgetModel:
     # The continuous loop's unit of dispatch is one STEP — a single token
     # across every active slot row — so its watchdog budget is the
     # max_new_tokens=1 specialization of the launch budget: the same EWMA,
-    # the same clamp, learned one step at a time. The floor still absorbs
-    # first-step compile (a new batch shape recompiles mid-loop).
+    # the same clamp, learned one step at a time.
 
     def step_budget(self) -> float:
         return self.budget(1, 1)
@@ -213,10 +216,17 @@ class EngineSupervisor:
             start_epoch = self.epoch
             done = threading.Event()
             box: Dict[str, Any] = {}
+            tracker = CompileTracker()
 
-            def _run(_epoch: int = start_epoch, _box: Dict[str, Any] = box, _done: threading.Event = done) -> None:
+            def _run(
+                _epoch: int = start_epoch,
+                _box: Dict[str, Any] = box,
+                _done: threading.Event = done,
+                _tracker: CompileTracker = tracker,
+            ) -> None:
                 try:
-                    _box["result"] = launch_fn()
+                    with _tracker.active():
+                        _box["result"] = launch_fn()
                 except BaseException as exc:  # delivered to the caller below
                     _box["error"] = exc
                 finally:
@@ -238,11 +248,13 @@ class EngineSupervisor:
                 target=_run, name="kllms-supervised-launch", daemon=True
             )
             thread.start()
-            if done.wait(budget):
-                elapsed = time.monotonic() - started
+            if wait_excluding_compile(
+                done, budget, tracker, self.budget_model.max_budget_s
+            ):
+                elapsed = time.monotonic() - started - tracker.seconds()
                 if "error" in box:
                     raise box["error"]
-                self.budget_model.observe(rows, max_new_tokens, elapsed)
+                self.budget_model.observe(rows, max_new_tokens, max(0.0, elapsed))
                 with self._lock:
                     self._consecutive_rebuilds = 0
                 if replay:

@@ -5,6 +5,12 @@ Example::
     python -m k_llms_tpu.serving --backend tpu --model tiny --port 8000 \
         --continuous-batching
 
+    # a 7-8B model on one 16 GB chip needs int8 weights; on a four-chip host
+    # the default mesh is all-data (every chip holds the weights), and
+    # --model-parallel 2 makes it data=2 x model=2
+    python -m k_llms_tpu.serving --backend tpu --model qwen2-7b \
+        --quantization int8 --continuous-batching
+
 SIGINT/SIGTERM trigger graceful shutdown: the socket closes, the backend
 drains (in-flight decodes finish; late arrivals get typed 503s), then exit.
 """
@@ -31,6 +37,15 @@ def _parse_args(argv=None) -> argparse.Namespace:
     p.add_argument("--tokenizer-path", default=None)
     p.add_argument("--max-new-tokens", type=int, default=None)
     p.add_argument(
+        "--quantization", default=None, choices=["int8", "int4"],
+        help="weight-only quantization (BackendConfig.quantization)",
+    )
+    p.add_argument(
+        "--model-parallel", type=int, default=None,
+        help="tensor-parallel degree: the mesh's model axis "
+             "(BackendConfig.model_parallel); the rest is data parallel",
+    )
+    p.add_argument(
         "--continuous-batching", action="store_true",
         help="serve decodes through the in-flight slot loop (streaming-"
              "friendly admission; see engine/continuous.py)",
@@ -48,13 +63,11 @@ def _parse_args(argv=None) -> argparse.Namespace:
 
 async def _amain(args: argparse.Namespace) -> None:
     kwargs = {"backend": args.backend, "model": args.model}
-    for flag, key in (
-        ("checkpoint_path", "checkpoint_path"),
-        ("tokenizer_path", "tokenizer_path"),
-        ("max_new_tokens", "max_new_tokens"),
-        ("continuous_width", "continuous_width"),
+    for key in (
+        "checkpoint_path", "tokenizer_path", "max_new_tokens", "quantization",
+        "model_parallel", "continuous_width",
     ):
-        val = getattr(args, flag)
+        val = getattr(args, key)
         if val is not None:
             kwargs[key] = val
     if args.continuous_batching:

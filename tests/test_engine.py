@@ -200,7 +200,7 @@ def test_flash_decode_matches_xla(tok):
         cfg.with_(decode_attention_impl="xla"), params=params, use_mesh=False
     )
     e_flash = LocalEngine(
-        cfg.with_(decode_attention_impl="flash"), params=params, use_mesh=False
+        cfg.with_(decode_attention_impl="flash_interpret"), params=params, use_mesh=False
     )
     ids = tok.encode("hello flash decode path")
     a = e_xla.generate(ids, n=8, max_new_tokens=8, temperature=0.0)

@@ -229,7 +229,7 @@ def test_gemma_flash_forward_matches_xla():
     import numpy as np
 
     cfg_xla = TINY_GEMMA.with_(attention_impl="xla")
-    cfg_flash = TINY_GEMMA.with_(attention_impl="flash")
+    cfg_flash = TINY_GEMMA.with_(attention_impl="flash_interpret")
     params = init_params(cfg_xla, jax.random.key(2))
     S = 24
     tokens = jax.random.randint(jax.random.key(3), (2, S), 0, cfg_xla.vocab_size)

@@ -50,10 +50,7 @@ assert t == 0 + 1 + 10 + 11, t
 
 # A sharded matmul with a psum over the data axis (the coalesced-decode
 # collective pattern).
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # 0.4.x: experimental module
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 @jax.jit
 def dotsum(x):

@@ -102,9 +102,9 @@ def test_prompt_that_is_prefix_of_cached_prompt():
     dict(sliding_window=16, sliding_window_layers="all"),
     dict(sliding_window=16, sliding_window_layers="alternating"),
     dict(attn_softcap=50.0, query_scale=0.125),
-    dict(attention_impl="flash", sliding_window=16, sliding_window_layers="all"),
-    dict(attention_impl="flash", sliding_window=16, sliding_window_layers="alternating"),
-    dict(attention_impl="flash", attn_softcap=50.0, query_scale=0.125),
+    dict(attention_impl="flash_interpret", sliding_window=16, sliding_window_layers="all"),
+    dict(attention_impl="flash_interpret", sliding_window=16, sliding_window_layers="alternating"),
+    dict(attention_impl="flash_interpret", attn_softcap=50.0, query_scale=0.125),
 ])
 def test_continuation_matches_dense_on_windowed_and_softcap_configs(overrides):
     """The continuation path builds masks over absolute positions, so sliding
@@ -178,7 +178,7 @@ def test_flash_continuation_matches_dense():
     """attention_impl="flash": the continuation prefill runs the flash kernel
     in q_offset mode — output must still be bit-equal to the uncached dense
     engine (VERDICT r2 #5)."""
-    plain, cached = _engines(cfg_overrides={"attention_impl": "flash"})
+    plain, cached = _engines(cfg_overrides={"attention_impl": "flash_interpret"})
     cached.generate(SYSTEM + DOC_A, n=2, max_new_tokens=4, temperature=0.7, seed=7)
     r_cached = cached.generate(SYSTEM + DOC_B, n=2, max_new_tokens=4, temperature=0.7, seed=8)
     assert cached.prefix_cache_stats["partial_hits"] == 1
@@ -191,7 +191,7 @@ def test_flash_continuation_ignores_score_cap():
     """The 1 GB masked-XLA score cap does not apply to the flash path: even
     with the cap forced to 1 byte, the partial hit still takes continuation
     instead of falling back to full prefill."""
-    plain, cached = _engines(cfg_overrides={"attention_impl": "flash"})
+    plain, cached = _engines(cfg_overrides={"attention_impl": "flash_interpret"})
     cached.MAX_CONT_SCORE_BYTES = 1
     cached.generate(SYSTEM + DOC_A, n=1, max_new_tokens=3, temperature=0.6, seed=50)
     r = cached.generate(SYSTEM + DOC_B, n=1, max_new_tokens=3, temperature=0.6, seed=51)

@@ -142,3 +142,17 @@ def test_prequantized_checkpoint_with_quantize_unset_on_mesh():
     explicit = LocalEngine(config, params=qparams, mesh=mesh, quantize="int8")
     r2 = explicit.generate(ids, n=4, max_new_tokens=4, temperature=0.5, seed=2)
     np.testing.assert_array_equal(result.tokens, r2.tokens)
+
+
+def test_engine_aliases_an_already_quantized_tree():
+    """A second engine handed a fully quantized tree (bench.py's speculative
+    and prefix-cache twins share the flagship's int8 params) must alias the
+    device buffers: a jitted identity 'quantize' copied all 8 GB and ran a
+    16 GB v5e out of memory (PR 21 chip run)."""
+    config = get_config("tiny")
+    qparams = quantize_params(init_params(config, jax.random.key(0)))
+    twin = LocalEngine(config, params=qparams, use_mesh=False, quantize="int8")
+    assert twin.quantized == "int8"
+    for key in ("wq", "w_down"):
+        mine, theirs = twin.params["layers"][key].q, qparams["layers"][key].q
+        assert mine.unsafe_buffer_pointer() == theirs.unsafe_buffer_pointer()

@@ -10,6 +10,8 @@ import asyncio
 import json
 import time
 
+import jax
+
 import httpx
 import pytest
 
@@ -388,6 +390,17 @@ def test_tpu_healthz_lifecycle(tpu_app):
     r = _run(go())
     assert r.status_code == 200
     assert r.json()["state"] == "ready"
+    # The device facts a JAX-free parent (chip_smoke.py) reads: what the
+    # process runs on and what the configured attention names resolved to.
+    device = r.json()["device"]
+    assert device["platform"] == "cpu" and device["device_count"] == len(jax.devices())
+    assert device["device_kind"] == jax.devices()[0].device_kind
+    # Off-TPU every configured name resolves to the XLA reference ("paged" is
+    # None on the shared dense-layout engine the mesh run injects).
+    assert device["attention"]["prefill"] == device["attention"]["decode"] == "xla"
+    assert device["attention"]["paged"] in ("xla", None)
+    assert device["native"]["loaded"] is True
+    assert "mesh" in device and device["compile"]["programs"] >= 0
     client.backend.drain(timeout=30)
     r = _run(go())
     assert r.status_code == 503

@@ -229,6 +229,24 @@ def test_int4_on_mesh_bitcompares_single_chip():
 
 
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs the 8-device CPU mesh")
+@pytest.mark.parametrize("part", ["col", "row"])
+def test_w4_matmul_tp_interpret_matches_dequant_reference(part):
+    """The shard_mapped kernel itself (off-TPU the engine takes the XLA
+    dequant reference, so the per-shard wiring is pinned here, with the
+    interpreter asked for by name): column- and row-parallel layouts on a
+    data=4 x model=2 mesh against the dense dequantized product."""
+    from k_llms_tpu.ops.w4matmul import unpack_int4, w4_matmul_tp
+    from k_llms_tpu.parallel.mesh import make_mesh
+
+    mesh = make_mesh(4, 2)
+    w = pack_int4(jax.random.normal(jax.random.key(3), (512, 256), jnp.float32))
+    x = jax.random.normal(jax.random.key(4), (8, 512), jnp.float32)
+    out = w4_matmul_tp(x, Q4Tensor(w.q, w.scale, part=part, mesh=mesh), interpret=True)
+    ref = x @ unpack_int4(w)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.skipif(len(jax.devices()) < 8, reason="needs the 8-device CPU mesh")
 def test_int4_downgrades_when_groups_would_split():
     """tp=4 over a K=256 row-parallel weight would split a quantization group
     (needs K % (128*4) == 0) — the engine must fall back to int8, loudly."""
