@@ -12,8 +12,9 @@ any single file:
   shape) is covered by its group's ``declared=`` patterns; every declared
   non-wildcard counter is actually recorded somewhere; every group is
   surfaced by the ``/metrics`` endpoint. The same contract covers
-  ``LatencyHistograms``: every ``observe(...)`` against a declared histogram
-  group uses a declared family, every declared family is observed somewhere,
+  ``LatencyHistograms``: every ``observe(...)`` or ``span(...)`` against a
+  declared histogram group uses a declared family, every declared family is
+  observed (or spanned) somewhere,
   and the group is surfaced on ``/metrics``.
 - **wire-error-contract** — every direct ``KLLMsError`` subclass pins
   ``type`` and ``status_code`` in its class body, and every ``as_wire``
@@ -213,11 +214,11 @@ class CounterHygieneRule(Rule):
         "each *_EVENTS.record(name) literal (or f-string shape) matches a "
         "pattern in that group's declared= tuple; each declared non-wildcard "
         "counter is recorded somewhere; each group is surfaced on /metrics; "
-        "the same holds for LatencyHistograms families via observe()"
+        "the same holds for LatencyHistograms families via observe() and span()"
     )
     subsystem = (
-        "utils/observability.py + observability/ + all record()/observe() "
-        "call sites + serving/app.py"
+        "utils/observability.py + observability/ + all record()/observe()/"
+        "span() call sites + serving/app.py"
     )
 
     def _declared_groups(
@@ -352,7 +353,7 @@ class CounterHygieneRule(Rule):
         assignments anywhere in the package (the canonical ``LATENCY`` lives
         in ``observability/histograms.py``; ``utils/observability.py`` only
         re-exports it, which is an ImportFrom, not an Assign). ``observe()``
-        receivers are matched by the group's name normalised for private
+        and ``span()`` receivers are matched by the group's name normalised for private
         aliases (``self._latency.observe`` attributes to ``LATENCY``)."""
         hist_groups: Dict[str, Tuple[List[str], int, ProjectFile]] = {}
         for pf in project.files:
@@ -398,7 +399,8 @@ class CounterHygieneRule(Rule):
                 if d is None:
                     continue
                 parts = d.split(".")
-                if parts[-1] != "observe" or len(parts) < 2:
+                # span("...") observes its family when the block ends.
+                if parts[-1] not in ("observe", "span") or len(parts) < 2:
                     continue
                 group = norm_groups.get(parts[-2].lstrip("_").upper())
                 if group is None:

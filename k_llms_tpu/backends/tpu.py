@@ -1086,28 +1086,26 @@ class TpuBackend(Backend):
             # The lambda re-resolves self.engine at call time, so when the
             # supervisor rebuilds mid-launch the replay lands on the NEW
             # engine — that is the whole recovery contract.
-            t0 = time.perf_counter()
-            out = self.supervisor.supervised_launch(
-                lambda: self.engine.generate_many(
-                    specs,
-                    max_new_tokens=max_new,
-                    temperature=temperature,
-                    top_p=top_p,
-                    eos_ids=eos_ids,
-                    constraint=constraint,
-                    top_logprobs=top_logprobs,
-                    frequency_penalty=frequency_penalty,
-                    presence_penalty=presence_penalty,
-                    logit_bias=logit_bias,
-                    stop_sequences=stop_sequences,
-                ),
-                rows=launch_rows,
-                max_new_tokens=max_new,
-            )
             # Per-launch decode wall time (host clock around the whole
             # supervised launch — includes the fused paged-attention path).
-            LATENCY.observe("engine.decode_launch", time.perf_counter() - t0)
-            return out
+            with LATENCY.span("engine.decode_launch"):
+                return self.supervisor.supervised_launch(
+                    lambda: self.engine.generate_many(
+                        specs,
+                        max_new_tokens=max_new,
+                        temperature=temperature,
+                        top_p=top_p,
+                        eos_ids=eos_ids,
+                        constraint=constraint,
+                        top_logprobs=top_logprobs,
+                        frequency_penalty=frequency_penalty,
+                        presence_penalty=presence_penalty,
+                        logit_bias=logit_bias,
+                        stop_sequences=stop_sequences,
+                    ),
+                    rows=launch_rows,
+                    max_new_tokens=max_new,
+                )
 
         # max_rows = the HBM memory model's row cap for THIS request's KV
         # length — any group this item joins is clipped to the tightest

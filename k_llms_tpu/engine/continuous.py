@@ -153,6 +153,10 @@ class _SlotRequest:
     # queue-wait span/histogram. Both are host-side observability only.
     trace: Optional[Any] = None
     enqueued_at: float = 0.0
+    # Host clock at the request's last dequeue and at the installation of its
+    # rows: the ends of its ``prefill_wall`` and ``decode_wall`` phases.
+    dequeued_at: float = 0.0
+    installed_at: float = 0.0
     # Resolved TenantContext (or None for the implicit default tenant):
     # drives WFQ slot selection and per-tenant queue-wait attribution.
     tenant: Optional[Any] = None
@@ -254,10 +258,12 @@ class _StepDispatcher:
             fn, ticket = item
             try:
                 with ticket["tracker"].active():
+                    ticket["started"] = time.perf_counter()
                     ticket["result"] = fn()
             except BaseException as exc:
                 ticket["error"] = exc
             finally:
+                ticket["ended"] = time.perf_counter()
                 if ticket["abandoned"]:
                     RECOVERY_EVENTS.record("continuous.stale_steps_discarded")
                     logger.warning(
@@ -271,7 +277,9 @@ class _StepDispatcher:
         budget. Returns ``(result, run_seconds)`` — wall time with compile
         time taken out, the figure the budget model should learn from —
         re-raises its error, or raises :class:`_StepHung` after abandoning
-        the thread."""
+        the thread. A step that came back observes ``continuous.handoff``: the
+        host clock from the ``put`` to ``fn``'s first line on the step thread,
+        plus that from ``fn``'s end to this thread running again."""
         self._ensure()
         budget_s = budget_model.step_budget()
         tracker = CompileTracker()
@@ -283,12 +291,18 @@ class _StepDispatcher:
             "tracker": tracker,
         }
         started = time.monotonic()
+        put_at = time.perf_counter()
         self._inbox.put((fn, ticket))
         if wait_excluding_compile(
             ticket["done"], budget_s, tracker, budget_model.max_budget_s
         ):
+            back_at = time.perf_counter()
             if ticket["error"] is not None:
                 raise ticket["error"]
+            LATENCY.observe(
+                "continuous.handoff",
+                (ticket["started"] - put_at) + (back_at - ticket["ended"]),
+            )
             run_s = time.monotonic() - started - tracker.seconds()
             return ticket["result"], max(0.0, run_s)
         ticket["abandoned"] = True
@@ -353,6 +367,8 @@ class ContinuousDecodeLoop:
             "_sample_rows_fn",
             "_paged_attn_impl",
             "_pool",
+            "_results_at",
+            "_host_annotation",
         )
         self.width = int(width)
         self.max_prompt = int(max_prompt)
@@ -389,6 +405,14 @@ class ContinuousDecodeLoop:
         self.on_rebuilt = on_rebuilt
         self.on_rebuild_failed = on_rebuild_failed
         self._dispatcher = _StepDispatcher()
+        # Host clock at which the last device program's results reached the
+        # host, for ``continuous.gap``: written where the readback ends (the
+        # step thread under a watchdog), taken by the next program's first
+        # line, cleared by the worker where the loop pauses.
+        # kllms: unguarded — the hand-off orders the step thread's write before the worker's accesses
+        self._results_at: Optional[float] = None
+        # kllms: unguarded — the open ``continuous.host`` annotation; worker thread only
+        self._host_annotation: Optional[Any] = None
         # Epoch fence: bumped on every recovery; an abandoned step thread
         # waking into a newer epoch discards its work instead of committing
         # device state that belongs to a torn-down engine.
@@ -490,6 +514,11 @@ class ContinuousDecodeLoop:
             # with decode rows in flight (the interleaving the feature buys).
             "prefill_chunks": 0,
             "prefill_interleaved": 0,
+            # Times _admit_locked left a non-empty queue's head behind, by
+            # what it lacked: free slots, the one PREFILLING lane, pool pages.
+            "blocked_slots": 0,
+            "blocked_lane": 0,
+            "blocked_pages": 0,
         }
         self._thread: Optional[threading.Thread] = None
 
@@ -740,6 +769,9 @@ class ContinuousDecodeLoop:
                 )
             )(seeds, steps, sample_idx)
 
+        # Scope names (here and in models/llama.py) are metadata on the step
+        # programs' ops: a profiler capture reads them, no result changes.
+        @jax.named_scope("sampler")
         def _sample_rows(logits, keys, temps, top_ps):
             # Per-row temperature/top_p (the whole point of the shared loop);
             # same sanitization + untempered-logprob contract as sample_logits.
@@ -829,8 +861,9 @@ class ContinuousDecodeLoop:
                 page_size=self._pool.page_size,
                 mesh=mesh,
             )
-            pool_k = pool_k.at[:, write_idx].set(k_cols.astype(pool_k.dtype))
-            pool_v = pool_v.at[:, write_idx].set(v_cols.astype(pool_v.dtype))
+            with jax.named_scope("kv_write"):
+                pool_k = pool_k.at[:, write_idx].set(k_cols.astype(pool_k.dtype))
+                pool_v = pool_v.at[:, write_idx].set(v_cols.astype(pool_v.dtype))
             logits = _mask_pad(logits[:, 0, :])
             logits = jnp.where(poison[:, None], jnp.float32(jnp.nan), logits)
             keys = _row_keys(seeds, gen_lens + 1, sample_idx)
@@ -907,10 +940,12 @@ class ContinuousDecodeLoop:
                 masks, trans, terminal, token_bytes, token_len, 0, vocab_size
             )
 
+        @jax.named_scope("grammar_mask")
         def _apply_mask(logits, g_states, g_flags, tabs):
             masked = grammar_mask_logits(_as_grammar(tabs), logits, g_states, eos_arr)
             return jnp.where(g_flags[:, None], masked, logits)
 
+        @jax.named_scope("grammar_advance")
         def _advance(tok, g_states, g_flags, tabs):
             nxt = grammar_advance(_as_grammar(tabs), tok, g_states)
             return jnp.where(g_flags, nxt, g_states)
@@ -951,8 +986,9 @@ class ContinuousDecodeLoop:
                 page_size=self._pool.page_size,
                 mesh=mesh,
             )
-            pool_k = pool_k.at[:, write_idx].set(k_cols.astype(pool_k.dtype))
-            pool_v = pool_v.at[:, write_idx].set(v_cols.astype(pool_v.dtype))
+            with jax.named_scope("kv_write"):
+                pool_k = pool_k.at[:, write_idx].set(k_cols.astype(pool_k.dtype))
+                pool_v = pool_v.at[:, write_idx].set(v_cols.astype(pool_v.dtype))
             logits = jnp.where(
                 poison[:, None], jnp.float32(jnp.nan), logits[:, 0, :]
             )
@@ -1008,6 +1044,10 @@ class ContinuousDecodeLoop:
                 RECOVERY_EVENTS.record("continuous.worker_crashes")
                 if not self._recover("worker_crash"):
                     return
+            finally:
+                # A recovery is no host overhead of the loop, and neither is
+                # the time after its end.
+                self._pause_host_clock()
 
     def _worker_loop(self) -> None:
         while True:
@@ -1023,7 +1063,9 @@ class ContinuousDecodeLoop:
                     raise _AdoptEngine(eng)
                 if self._pool_fault is not None:
                     raise _PoolFault(self._pool_fault)
-                self._admit_locked()
+                if self._queue:
+                    with LATENCY.span("continuous.admit"):
+                        self._admit_locked()
                 has_decode = bool(self._active_mask.any())
                 prefilling = self._prefilling is not None
                 if not has_decode and not prefilling:
@@ -1032,7 +1074,9 @@ class ContinuousDecodeLoop:
                         return
                     # Wake for new arrivals; re-check queued budgets at a
                     # coarse interval so expired deadlines shed.
-                    self._lock.wait(timeout=0.05)
+                    self._pause_host_clock()
+                    with LATENCY.span("continuous.idle"):
+                        self._lock.wait(timeout=0.05)
                     self._shed_expired_locked()
                     continue
             # The interleave: one decode step for the active batch, then one
@@ -1043,6 +1087,56 @@ class ContinuousDecodeLoop:
                 self._step_once()
             if prefilling:
                 self._prefill_chunk_once()
+
+    # -- the host's share of the loop, on both clocks ----------------------
+
+    def _open_host(self) -> None:
+        """Open ``continuous.host`` as a program's hand-off returns (worker
+        thread). It stays open across ``_step_once`` -> ``_worker_loop`` ->
+        ``_admit_locked`` -> the next program's preparation, so it is opened
+        and closed by hand; ``bookkeep``, ``admit`` and ``prepare`` lie inside
+        it, and a device gap that none of them covers half of is still the
+        loop's on the trace."""
+        self._host_annotation = jax.profiler.TraceAnnotation("continuous.host")
+        self._host_annotation.__enter__()
+
+    def _close_host(self) -> None:
+        annotation, self._host_annotation = self._host_annotation, None
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+
+    def _pause_host_clock(self) -> None:
+        """Before an idle wait and around a recovery: what follows is not the
+        host keeping the device waiting, so neither clock charges it."""
+        self._close_host()
+        self._results_at = None
+
+    def _observe_gap(self) -> None:
+        """First line of a step's or chunk's dispatch: ``continuous.gap`` is
+        the host clock since the previous program's results reached the host."""
+        results_at, self._results_at = self._results_at, None
+        if results_at is not None:
+            LATENCY.observe("continuous.gap", time.perf_counter() - results_at)
+
+    def _hand_off(self, dispatch: Callable[[], Any], what: str) -> Any:
+        """Run one device program's ``dispatch`` closure: on the disposable
+        step thread under the watchdog budget where the loop has a budget
+        model, else inline. Returns ``(result, run_seconds)``, the latter None
+        inline."""
+        if self.budget_model is None:
+            LATENCY.observe("continuous.handoff", 0.0)
+            return dispatch(), None
+        try:
+            return self._dispatcher.run(dispatch, self.budget_model)
+        except _StepHung:
+            with self._lock:
+                self._loop_epoch += 1
+            RECOVERY_EVENTS.record("continuous.step_hangs")
+            logger.error(
+                "continuous %s overran its watchdog budget; abandoning the "
+                "dispatch thread and rebuilding", what,
+            )
+            raise
 
     # -- recovery ----------------------------------------------------------
 
@@ -1268,7 +1362,10 @@ class ContinuousDecodeLoop:
         admitted request's prefill."""
         while self._queue:
             idx = self._select_locked()
-            if idx is None or len(self._free) < self._queue[idx].n:
+            if idx is None:
+                break
+            if len(self._free) < self._queue[idx].n:
+                self._stats["blocked_slots"] += 1
                 break
             req = self._queue[idx]
             chunked = self._chunk_eligible(req)
@@ -1276,6 +1373,7 @@ class ContinuousDecodeLoop:
                 # One chunked admission at a time: the head waits for the
                 # in-flight PREFILLING to finish (no skipping past it — the
                 # same no-starvation rule as the slot-shortage break above).
+                self._stats["blocked_lane"] += 1
                 break
             del self._queue[idx]
             if req.budget is not None and req.budget.should_abort():
@@ -1291,6 +1389,7 @@ class ContinuousDecodeLoop:
                     )
                 if req.trace is not None:
                     req.trace.add_phase("queue_wait", wait_s)
+            req.dequeued_at = time.perf_counter()
             if not self._built:
                 self._build_device_state()
             in_flight = self._active_mask.any()
@@ -1321,6 +1420,7 @@ class ContinuousDecodeLoop:
                     self._free.append(r)
                 req.slots = []
                 if in_flight:
+                    self._stats["blocked_pages"] += 1
                     self._queue.appendleft(req)
                     break
                 req.future.set_exception(BackendUnavailableError(
@@ -1456,6 +1556,14 @@ class ContinuousDecodeLoop:
             note = getattr(self.engine, "_note_quarantine", None)
             if note is not None:
                 note(quarantined, n)
+        # The rows are installed: the request's prefill_wall (everything since
+        # its dequeue, other requests' steps and chunks included) ends here
+        # and its decode_wall begins.
+        req.installed_at = time.perf_counter()
+        prefill_wall_s = req.installed_at - req.dequeued_at
+        LATENCY.observe("continuous.prefill_wall", prefill_wall_s)
+        if req.trace is not None:
+            req.trace.add_phase("prefill_wall", prefill_wall_s)
         self._deliver_sink(req)
         self._retire_finished_rows(req)
         self._resolve_if_done(req)
@@ -1552,40 +1660,44 @@ class ContinuousDecodeLoop:
         admission from cursor 0. The final chunk's logits feed the shared
         first-token admission tail, so the sampled stream is byte-identical
         to whole-prompt prefill."""
-        with self._lock:
-            pf = self._prefilling
-            if pf is None:
-                return
-            req = pf.req
-            if req.budget is not None and req.budget.should_abort():
-                # Budget abort retires the PREFILLING row through the same
-                # fault counters as a decoding abort.
-                self._retire_prefilling_locked(
-                    req.budget.error("engine prefill"), abort=True
-                )
-                return
-            epoch = self._loop_epoch
-            C = self.prefill_chunk_tokens
-            start = pf.cursor
-            end = min(start + C, pf.plen)
-            valid = end - start
-            final = end >= pf.plen
-            pad_id = self.engine.config.pad_token_id
-            chunk = np.full((1, C), pad_id, np.int32)
-            chunk[0, :valid] = pf.ids[start:end]
-            cache, bucket = pf.cache, pf.bucket
-            pool = slot_idx = None
-            if self.paged:
-                pool = self._pool
-                ps = pool.page_size
-                # The chunk's KV columns land in the row's reserved page run
-                # at its current offset; pad positions retarget to trash.
-                slot_idx = flat_slots(pf.run_pages, start + np.arange(C), ps)
-                trash = (np.arange(C) % ps + TRASH_PAGE * ps).astype(np.int32)
-                slot_idx[valid:] = trash[valid:]
-        fn = self.engine._get_prefill_chunk(C, bucket, self.paged)
+        with LATENCY.span("continuous.admit"):
+            with self._lock:
+                pf = self._prefilling
+                if pf is None:
+                    return
+                req = pf.req
+                if req.budget is not None and req.budget.should_abort():
+                    # Budget abort retires the PREFILLING row through the same
+                    # fault counters as a decoding abort.
+                    self._retire_prefilling_locked(
+                        req.budget.error("engine prefill"), abort=True
+                    )
+                    return
+                epoch = self._loop_epoch
+                chunk_no = self._stats["prefill_chunks"]
+                C = self.prefill_chunk_tokens
+                start = pf.cursor
+                end = min(start + C, pf.plen)
+                valid = end - start
+                final = end >= pf.plen
+                pad_id = self.engine.config.pad_token_id
+                chunk = np.full((1, C), pad_id, np.int32)
+                chunk[0, :valid] = pf.ids[start:end]
+                cache, bucket = pf.cache, pf.bucket
+                pool = slot_idx = None
+                if self.paged:
+                    pool = self._pool
+                    ps = pool.page_size
+                    # The chunk's KV columns land in the row's reserved page
+                    # run at its current offset; pad positions retarget to
+                    # trash.
+                    slot_idx = flat_slots(pf.run_pages, start + np.arange(C), ps)
+                    trash = (np.arange(C) % ps + TRASH_PAGE * ps).astype(np.int32)
+                    slot_idx[valid:] = trash[valid:]
+            fn = self.engine._get_prefill_chunk(C, bucket, self.paged)
 
         def _dispatch():
+            self._observe_gap()
             # Hang-injection point for the chunk itself
             # (``continuous.prefill``): fire() sleeps inline, so a ``hang``
             # spec wedges THIS disposable thread under the watchdog budget —
@@ -1594,67 +1706,56 @@ class ContinuousDecodeLoop:
             if self._loop_epoch != epoch:
                 raise _StaleStep("prefill chunk fenced before dispatch")
             note_device_dispatch("continuous prefill chunk")
-            if self.paged:
-                logits, new_cache, k_cols, v_cols = fn(
-                    self.engine.params, jnp.asarray(chunk), cache,
-                    jnp.int32(start), jnp.int32(valid),
-                )
-                if self._loop_epoch != epoch:
-                    raise _StaleStep("prefill chunk fenced post-dispatch")
-                pool.scatter_tokens(k_cols, v_cols, slot_idx)
-            else:
-                logits, new_cache = fn(
-                    self.engine.params, jnp.asarray(chunk), cache,
-                    jnp.int32(start), jnp.int32(valid),
-                )
-                if self._loop_epoch != epoch:
-                    raise _StaleStep("prefill chunk fenced post-dispatch")
+            with LATENCY.span("continuous.dispatch", chunk=chunk_no):
+                if self.paged:
+                    logits, new_cache, k_cols, v_cols = fn(
+                        self.engine.params, jnp.asarray(chunk), cache,
+                        jnp.int32(start), jnp.int32(valid),
+                    )
+                    if self._loop_epoch != epoch:
+                        raise _StaleStep("prefill chunk fenced post-dispatch")
+                    pool.scatter_tokens(k_cols, v_cols, slot_idx)
+                else:
+                    logits, new_cache = fn(
+                        self.engine.params, jnp.asarray(chunk), cache,
+                        jnp.int32(start), jnp.int32(valid),
+                    )
+                    if self._loop_epoch != epoch:
+                        raise _StaleStep("prefill chunk fenced post-dispatch")
             # Synchronize on the (tiny) logits readback so the watchdog
             # budget covers the device work, like the step's readback.
-            # kllms: ignore[host-sync-hot-path] — the per-chunk completion sync; the cache stays on device
-            jax.device_get(logits)
+            with LATENCY.span("continuous.readback"):
+                # kllms: ignore[host-sync-hot-path] — the per-chunk completion sync; the cache stays on device
+                jax.device_get(logits)
+            self._results_at = time.perf_counter()
             return logits, new_cache
 
-        _chunk_t0 = time.perf_counter()
-        if self.budget_model is not None:
-            try:
-                (first_logits, new_cache), _ = self._dispatcher.run(
-                    _dispatch, self.budget_model
-                )
-            except _StepHung:
-                with self._lock:
-                    self._loop_epoch += 1
-                RECOVERY_EVENTS.record("continuous.step_hangs")
-                logger.error(
-                    "continuous prefill chunk overran its watchdog budget; "
-                    "abandoning the dispatch thread and rebuilding"
-                )
-                raise
-            # Deliberately NOT fed to observe_step: a C-token chunk would
-            # pollute the decode loop's per-step EWMA.
-        else:
-            first_logits, new_cache = _dispatch()
-        chunk_s = time.perf_counter() - _chunk_t0
-        LATENCY.observe("continuous.prefill_chunk", chunk_s)
-        with self._lock:
-            if self._loop_epoch != epoch or self._prefilling is not pf:
-                return
-            pf.cache = new_cache
-            pf.cursor = end
-            req.chunk_cursor = end
-            self._stats["prefill_chunks"] += 1
-            if self._active_mask.any():
-                self._stats["prefill_interleaved"] += 1
-            # A completed chunk is proof of life, like a completed step.
-            self._consecutive_faults = 0
-            if req.trace is not None:
-                # One add_phase per chunk: the prefill phase accumulates the
-                # total AND records a per-chunk span.
-                req.trace.add_phase("prefill", chunk_s)
-            if final:
-                self._prefilling = None
-                self._finish_prefilling_locked(pf, first_logits)
-                self._lock.notify_all()
+        # Deliberately NOT fed to observe_step: a C-token chunk would pollute
+        # the decode loop's per-step EWMA.
+        self._close_host()
+        with LATENCY.span("continuous.prefill_chunk") as chunk_span:
+            (first_logits, new_cache), _ = self._hand_off(
+                _dispatch, "prefill chunk"
+            )
+        self._open_host()
+        with LATENCY.span("continuous.admit"):
+            with self._lock:
+                if self._loop_epoch != epoch or self._prefilling is not pf:
+                    return
+                pf.cache = new_cache
+                pf.cursor = end
+                req.chunk_cursor = end
+                self._stats["prefill_chunks"] += 1
+                if self._active_mask.any():
+                    self._stats["prefill_interleaved"] += 1
+                # A completed chunk is proof of life, like a completed step.
+                self._consecutive_faults = 0
+                if req.trace is not None:
+                    req.trace.add_phase("prefill", chunk_span.seconds)
+                if final:
+                    self._prefilling = None
+                    self._finish_prefilling_locked(pf, first_logits)
+                    self._lock.notify_all()
 
     def _finish_prefilling_locked(self, pf: _Prefilling, first_logits) -> None:
         """Transition PREFILLING -> DECODING (lock held): install the fully
@@ -1868,40 +1969,44 @@ class ContinuousDecodeLoop:
         self._refresh_row_idx(slot, 0)
 
     def _step_once(self) -> None:
-        with self._lock:
-            epoch = self._loop_epoch
-            cur = jnp.asarray(self._cur)
-            gen_lens = jnp.asarray(self._gen_lens)
-            prompt_lens = jnp.asarray(self._prompt_lens)
-            active = jnp.asarray(self._active_mask)
-            seeds = jnp.asarray(self._seeds)
-            sidx = jnp.asarray(self._sample_idx)
-            temps = jnp.asarray(self._temps)
-            tps = jnp.asarray(self._top_ps)
-            live_rows = np.flatnonzero(self._active_mask)
-            # Grammar twins run only when a constrained row is live: steps
-            # with no grammar work dispatch the ORIGINAL programs, so the
-            # unconstrained loop stays byte-identical (and program-identical).
-            n_masked = int((self._g_flags & self._active_mask).sum())
-            g_states = g_flags = g_fns = g_tabs = None
-            if n_masked:
-                g_states = jnp.asarray(self._g_states)
-                g_flags = jnp.asarray(self._g_flags)
-                g_fns = self._grammar_programs()
-                g_tabs = self._g_tabs()
-            if self.paged:
-                write_idx = jnp.asarray(self._prepare_step_pages())
-                pidx = jnp.asarray(self._prefix_idx)
-                gidx = jnp.asarray(self._gen_idx)
-        # All-False in production; with an active ``engine.logits`` nan
-        # failpoint, a seeded subset of the LIVE rows is poisoned — the
-        # loop-scoped twin of the batch path's first-step injection.
-        poison = self.engine._poison0_array(
-            # kllms: ignore[host-sync-hot-path] — live_rows is np.flatnonzero output (already host memory); this tolist is pure host bookkeeping, not a device readback
-            self.width, live_rows=live_rows.tolist()
-        )
+        with LATENCY.span("continuous.prepare"):
+            with self._lock:
+                epoch = self._loop_epoch
+                step_no = self._stats["steps"]
+                cur = jnp.asarray(self._cur)
+                gen_lens = jnp.asarray(self._gen_lens)
+                prompt_lens = jnp.asarray(self._prompt_lens)
+                active = jnp.asarray(self._active_mask)
+                seeds = jnp.asarray(self._seeds)
+                sidx = jnp.asarray(self._sample_idx)
+                temps = jnp.asarray(self._temps)
+                tps = jnp.asarray(self._top_ps)
+                live_rows = np.flatnonzero(self._active_mask)
+                # Grammar twins run only when a constrained row is live: steps
+                # with no grammar work dispatch the ORIGINAL programs, so the
+                # unconstrained loop stays byte-identical (and
+                # program-identical).
+                n_masked = int((self._g_flags & self._active_mask).sum())
+                g_states = g_flags = g_fns = g_tabs = None
+                if n_masked:
+                    g_states = jnp.asarray(self._g_states)
+                    g_flags = jnp.asarray(self._g_flags)
+                    g_fns = self._grammar_programs()
+                    g_tabs = self._g_tabs()
+                if self.paged:
+                    write_idx = jnp.asarray(self._prepare_step_pages())
+                    pidx = jnp.asarray(self._prefix_idx)
+                    gidx = jnp.asarray(self._gen_idx)
+            # All-False in production; with an active ``engine.logits`` nan
+            # failpoint, a seeded subset of the LIVE rows is poisoned — the
+            # loop-scoped twin of the batch path's first-step injection.
+            poison = self.engine._poison0_array(
+                # kllms: ignore[host-sync-hot-path] — live_rows is np.flatnonzero output (already host memory); this tolist is pure host bookkeeping, not a device readback
+                self.width, live_rows=live_rows.tolist()
+            )
 
         def _dispatch():
+            self._observe_gap()
             # Hang-injection point for the step itself (``continuous.step``):
             # fire() sleeps inline, so a ``hang`` spec wedges THIS disposable
             # thread under the watchdog budget, exactly like a stuck device.
@@ -1913,38 +2018,40 @@ class ContinuousDecodeLoop:
                 note_paged_attn_dispatch(self._paged_attn_impl)
                 with pool.lock:
                     note_device_dispatch("continuous paged step")
-                    if n_masked:
-                        tok, lp, bad, new_k, new_v, new_g = g_fns["step_paged"](
-                            self.engine.params, pool.kv.k, pool.kv.v, cur,
-                            gen_lens, prompt_lens, active, seeds, sidx, temps,
-                            tps, pidx, gidx, write_idx, poison, g_states,
-                            g_flags, *g_tabs,
-                        )
-                    else:
-                        tok, lp, bad, new_k, new_v = self._step_paged_fn(
-                            self.engine.params, pool.kv.k, pool.kv.v, cur,
-                            gen_lens, prompt_lens, active, seeds, sidx, temps,
-                            tps, pidx, gidx, write_idx, poison,
-                        )
-                        new_g = None
+                    with LATENCY.span("continuous.dispatch", step=step_no):
+                        if n_masked:
+                            tok, lp, bad, new_k, new_v, new_g = g_fns["step_paged"](
+                                self.engine.params, pool.kv.k, pool.kv.v, cur,
+                                gen_lens, prompt_lens, active, seeds, sidx,
+                                temps, tps, pidx, gidx, write_idx, poison,
+                                g_states, g_flags, *g_tabs,
+                            )
+                        else:
+                            tok, lp, bad, new_k, new_v = self._step_paged_fn(
+                                self.engine.params, pool.kv.k, pool.kv.v, cur,
+                                gen_lens, prompt_lens, active, seeds, sidx,
+                                temps, tps, pidx, gidx, write_idx, poison,
+                            )
+                            new_g = None
                     if self._loop_epoch != epoch:
                         raise _StaleStep("continuous step fenced post-dispatch")
                     pool.kv = KVCache(k=new_k, v=new_v)
             else:
                 note_device_dispatch("continuous dense step")
-                if n_masked:
-                    tok, lp, bad, gen, new_g = g_fns["step"](
-                        self.engine.params, self._prefix, self._gen, cur,
-                        gen_lens, prompt_lens, active, seeds, sidx, temps,
-                        tps, poison, g_states, g_flags, *g_tabs,
-                    )
-                else:
-                    tok, lp, bad, gen = self._step_fn(
-                        self.engine.params, self._prefix, self._gen, cur,
-                        gen_lens, prompt_lens, active, seeds, sidx, temps,
-                        tps, poison,
-                    )
-                    new_g = None
+                with LATENCY.span("continuous.dispatch", step=step_no):
+                    if n_masked:
+                        tok, lp, bad, gen, new_g = g_fns["step"](
+                            self.engine.params, self._prefix, self._gen, cur,
+                            gen_lens, prompt_lens, active, seeds, sidx, temps,
+                            tps, poison, g_states, g_flags, *g_tabs,
+                        )
+                    else:
+                        tok, lp, bad, gen = self._step_fn(
+                            self.engine.params, self._prefix, self._gen, cur,
+                            gen_lens, prompt_lens, active, seeds, sidx, temps,
+                            tps, poison,
+                        )
+                        new_g = None
                 # An abandoned thread waking into a rebuilt loop must not
                 # clobber the new generation cache with the old epoch's.
                 if self._loop_epoch != epoch:
@@ -1953,94 +2060,85 @@ class ContinuousDecodeLoop:
             # The one by-design sync per step: slot bookkeeping below needs
             # the sampled token ids on the host, and it runs outside both
             # locks (advanced grammar states ride the same fetch).
-            # kllms: ignore[host-sync-hot-path] — the per-step result readback; everything after it is host-side bookkeeping
             outs = (tok, lp, bad) if new_g is None else (tok, lp, bad, new_g)
-            return list(map(np.asarray, jax.device_get(outs)))
+            with LATENCY.span("continuous.readback"):
+                # kllms: ignore[host-sync-hot-path] — the per-step result readback; everything after it is host-side bookkeeping
+                fetched = jax.device_get(outs)
+            self._results_at = time.perf_counter()
+            return list(map(np.asarray, fetched))
 
-        _step_t0 = time.perf_counter()
-        if self.budget_model is not None:
-            try:
-                fetched, run_s = self._dispatcher.run(
-                    _dispatch, self.budget_model
-                )
-            except _StepHung:
-                with self._lock:
-                    self._loop_epoch += 1
-                RECOVERY_EVENTS.record("continuous.step_hangs")
-                logger.error(
-                    "continuous step overran its watchdog budget; abandoning "
-                    "the dispatch thread and rebuilding"
-                )
-                raise
-            self.budget_model.observe_step(run_s)
-        else:
-            fetched = _dispatch()
         # Host wall time for the dispatched step (includes the by-design
         # result readback); pure host-side observability, no extra syncs.
-        step_s = time.perf_counter() - _step_t0
-        LATENCY.observe("continuous.step", step_s)
-        tok_np, lp_np, bad_np = fetched[0], fetched[1], fetched[2]
-        quarantined = 0
-        with self._lock:
-            if n_masked:
-                # .copy(): device_get may hand back a read-only view, and the
-                # mirror is written per-slot at admission/retirement.
-                self._g_states = fetched[3].copy()
-                GRAMMAR_EVENTS.record("grammar.masked_steps", n_masked)
-            self._stats["steps"] += 1
-            self._stats["row_steps"] += int(self._active_mask.sum())
-            self._stats["max_active_rows"] = max(
-                self._stats["max_active_rows"], int(self._active_mask.sum())
-            )
-            # A completed step is proof of life: recovery credits refill so
-            # intermittent faults don't accumulate toward terminal.
-            self._consecutive_faults = 0
-            touched = set()
-            for slot in range(self.width):
-                req = self._active[slot]
-                if req is None:
-                    continue
-                j = req.slots.index(slot)
-                if req.done[j]:
-                    continue
-                self._gen_lens[slot] += 1  # cur's KV is now written
-                if bad_np[slot]:
-                    # Numeric poison: freeze + retire this row only; its
-                    # garbage token never reaches the accumulators or sinks.
-                    self._quarantine_row(req, j)
-                    quarantined += 1
-                    touched.add(id(req))
-                    continue
-                t = int(tok_np[slot])
-                self._cur[slot] = t
-                req.tokens[j].append(t)
-                req.logprobs[j].append(float(lp_np[slot]))
-                if t in self.eos_ids:
-                    req.done[j] = True
-                    req.finish[j] = "stop"
-                elif len(req.tokens[j]) >= req.max_new:
-                    req.done[j] = True
-                    req.finish[j] = "length"
-                touched.add(id(req))
-            for rid in touched:
-                req = next(
-                    r for r in self._active if r is not None and id(r) == rid
+        self._close_host()
+        with LATENCY.span("continuous.step") as step_span:
+            fetched, run_s = self._hand_off(_dispatch, "step")
+        self._open_host()
+        if run_s is not None:
+            self.budget_model.observe_step(run_s)
+        step_s = step_span.seconds
+        with LATENCY.span("continuous.bookkeep"):
+            tok_np, lp_np, bad_np = fetched[0], fetched[1], fetched[2]
+            quarantined = 0
+            with self._lock:
+                if n_masked:
+                    # .copy(): device_get may hand back a read-only view, and the
+                    # mirror is written per-slot at admission/retirement.
+                    self._g_states = fetched[3].copy()
+                    GRAMMAR_EVENTS.record("grammar.masked_steps", n_masked)
+                self._stats["steps"] += 1
+                self._stats["row_steps"] += int(self._active_mask.sum())
+                self._stats["max_active_rows"] = max(
+                    self._stats["max_active_rows"], int(self._active_mask.sum())
                 )
-                if req.trace is not None:
-                    req.trace.add_phase("decode", step_s)
-                if req.budget is not None and req.budget.should_abort():
-                    self._abort_request(req)
-                    continue
-                self._deliver_sink(req)
-                self._retire_finished_rows(req)
-                self._resolve_if_done(req)
-            self._lock.notify_all()
-        # Quarantine accounting + supervisor hook OUTSIDE the loop lock (it
-        # fans out to scheduler/supervisor locks); clean steps report 0 so
-        # the escalation window decays, same contract as the batch path.
-        note = getattr(self.engine, "_note_quarantine", None)
-        if note is not None:
-            note(quarantined, int(live_rows.size))
+                # A completed step is proof of life: recovery credits refill so
+                # intermittent faults don't accumulate toward terminal.
+                self._consecutive_faults = 0
+                touched = set()
+                for slot in range(self.width):
+                    req = self._active[slot]
+                    if req is None:
+                        continue
+                    j = req.slots.index(slot)
+                    if req.done[j]:
+                        continue
+                    self._gen_lens[slot] += 1  # cur's KV is now written
+                    if bad_np[slot]:
+                        # Numeric poison: freeze + retire this row only; its
+                        # garbage token never reaches the accumulators or sinks.
+                        self._quarantine_row(req, j)
+                        quarantined += 1
+                        touched.add(id(req))
+                        continue
+                    t = int(tok_np[slot])
+                    self._cur[slot] = t
+                    req.tokens[j].append(t)
+                    req.logprobs[j].append(float(lp_np[slot]))
+                    if t in self.eos_ids:
+                        req.done[j] = True
+                        req.finish[j] = "stop"
+                    elif len(req.tokens[j]) >= req.max_new:
+                        req.done[j] = True
+                        req.finish[j] = "length"
+                    touched.add(id(req))
+                for rid in touched:
+                    req = next(
+                        r for r in self._active if r is not None and id(r) == rid
+                    )
+                    if req.trace is not None:
+                        req.trace.add_phase("decode", step_s)
+                    if req.budget is not None and req.budget.should_abort():
+                        self._abort_request(req)
+                        continue
+                    self._deliver_sink(req)
+                    self._retire_finished_rows(req)
+                    self._resolve_if_done(req)
+                self._lock.notify_all()
+            # Quarantine accounting + supervisor hook OUTSIDE the loop lock (it
+            # fans out to scheduler/supervisor locks); clean steps report 0 so
+            # the escalation window decays, same contract as the batch path.
+            note = getattr(self.engine, "_note_quarantine", None)
+            if note is not None:
+                note(quarantined, int(live_rows.size))
 
     # -- retirement --------------------------------------------------------
 
@@ -2059,18 +2157,19 @@ class ContinuousDecodeLoop:
         # (rows of one request march in lockstep until they finish; finished
         # rows report pad thereafter, which the sink's detokenizer skips).
         pad = self.engine.config.pad_token_id
-        row = np.array(
-            [
-                s[step] if step < len(s) else pad
-                for s in req.tokens
-            ],
-            np.int32,
-        )
-        try:
-            req.token_sink(step, row)
-        except Exception:
-            logger.exception("continuous token sink failed; dropping tap")
-            req.token_sink = None
+        with LATENCY.span("continuous.emit"):
+            row = np.array(
+                [
+                    s[step] if step < len(s) else pad
+                    for s in req.tokens
+                ],
+                np.int32,
+            )
+            try:
+                req.token_sink(step, row)
+            except Exception:
+                logger.exception("continuous token sink failed; dropping tap")
+                req.token_sink = None
 
     def _retire_finished_rows(self, req: _SlotRequest) -> None:
         for j, slot in enumerate(list(req.slots)):
@@ -2121,6 +2220,10 @@ class ContinuousDecodeLoop:
             sample_errors=errs if any(e is not None for e in errs) else None,
         )
         self._stats["completed"] += 1
+        decode_wall_s = time.perf_counter() - req.installed_at
+        LATENCY.observe("continuous.decode_wall", decode_wall_s)
+        if req.trace is not None:
+            req.trace.add_phase("decode_wall", decode_wall_s)
         if not req.future.done():
             req.future.set_result(result)
 

@@ -231,6 +231,7 @@ def _gqa_values_shared(weights: jax.Array, v: jax.Array) -> jax.Array:
     return out.reshape(B, Sq, QH, v.shape[3])
 
 
+@jax.named_scope("attn_qkv")
 def _attn_qkv(
     config: ModelConfig, layer: Params, x: jax.Array, positions: jax.Array
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -252,6 +253,7 @@ def _attn_qkv(
     return q, k, v
 
 
+@jax.named_scope("mlp")
 def _mlp_sublayer(config: ModelConfig, layer: Params, x: jax.Array) -> jax.Array:
     """Post-attention MLP sublayer with its residual (dense MLP or MoE)."""
     offset = config.norm_offset
@@ -267,6 +269,7 @@ def _mlp_sublayer(config: ModelConfig, layer: Params, x: jax.Array) -> jax.Array
     return x + out
 
 
+@jax.named_scope("attn_out")
 def _attn_residual(
     config: ModelConfig, layer: Params, x: jax.Array, attn: jax.Array
 ) -> jax.Array:
@@ -338,24 +341,25 @@ def _block(
     q, k, v = _attn_qkv(config, layer, x, positions)
 
     cache_k, cache_v = kv
-    if write_index is None:
-        cache_k = lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype), 0, axis=1)
-        cache_v = lax.dynamic_update_slice_in_dim(cache_v, v.astype(cache_v.dtype), 0, axis=1)
-    elif getattr(write_index, "ndim", 0) == 1:
-        # Per-ROW write offsets (speculative verify: rows have different
-        # generated lengths) — a vmapped dynamic_update_slice per batch row.
-        row_update = jax.vmap(
-            lambda c, kk, off: lax.dynamic_update_slice_in_dim(c, kk, off, axis=0)
-        )
-        cache_k = row_update(cache_k, k.astype(cache_k.dtype), write_index)
-        cache_v = row_update(cache_v, v.astype(cache_v.dtype), write_index)
-    else:
-        cache_k = lax.dynamic_update_slice_in_dim(
-            cache_k, k.astype(cache_k.dtype), write_index, axis=1
-        )
-        cache_v = lax.dynamic_update_slice_in_dim(
-            cache_v, v.astype(cache_v.dtype), write_index, axis=1
-        )
+    with jax.named_scope("kv_write"):
+        if write_index is None:
+            cache_k = lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype), 0, axis=1)
+            cache_v = lax.dynamic_update_slice_in_dim(cache_v, v.astype(cache_v.dtype), 0, axis=1)
+        elif getattr(write_index, "ndim", 0) == 1:
+            # Per-ROW write offsets (speculative verify: rows have different
+            # generated lengths) — a vmapped dynamic_update_slice per batch row.
+            row_update = jax.vmap(
+                lambda c, kk, off: lax.dynamic_update_slice_in_dim(c, kk, off, axis=0)
+            )
+            cache_k = row_update(cache_k, k.astype(cache_k.dtype), write_index)
+            cache_v = row_update(cache_v, v.astype(cache_v.dtype), write_index)
+        else:
+            cache_k = lax.dynamic_update_slice_in_dim(
+                cache_k, k.astype(cache_k.dtype), write_index, axis=1
+            )
+            cache_v = lax.dynamic_update_slice_in_dim(
+                cache_v, v.astype(cache_v.dtype), write_index, axis=1
+            )
 
     def mlp(y: jax.Array) -> jax.Array:
         return _mlp_sublayer(config, layer, y)
@@ -610,6 +614,7 @@ def _apply_stack(
 # Entry points
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("embed")
 def _embed(config: ModelConfig, params: Params, tokens: jax.Array) -> jax.Array:
     x = jnp.take(params["embed"], tokens, axis=0)
     if config.embed_scale:  # Gemma: normalize embedding magnitude
@@ -617,6 +622,7 @@ def _embed(config: ModelConfig, params: Params, tokens: jax.Array) -> jax.Array:
     return x
 
 
+@jax.named_scope("lm_head")
 def _logits(config: ModelConfig, params: Params, h: jax.Array) -> jax.Array:
     logits = qdot(h, params["lm_head"]).astype(jnp.float32)
     if config.logit_softcap is not None:
@@ -1022,62 +1028,63 @@ def _block_paged(
     k_col = k[:, 0].astype(pool_k_l.dtype)
     v_col = v[:, 0].astype(pool_v_l.dtype)
 
-    if (
-        attn_impl in ("pallas", "pallas_interpret")
-        and Sq == 1
-        and page_tables is not None
-        and prefix_lengths is not None
-        and config.attn_softcap is None
-        and config.sliding_window is None
-    ):
-        prefix_pages, gen_pages, gen_phase = page_tables
-        plen = jnp.asarray(prefix_lengths, jnp.int32).reshape(-1)
-        pl_row = jnp.repeat(plen, B // plen.shape[0], total_repeat_length=B)
-        attn = paged_decode_attention_pallas(
-            q[:, 0],
-            pool_k_l,
-            pool_v_l,
-            prefix_pages,
-            gen_pages,
-            gen_phase,
-            k_col,
-            v_col,
-            pl_row,
-            write_index.astype(jnp.int32),
-            page_size=page_size,
-            sm_scale=scale,
-            interpret=attn_impl == "pallas_interpret",
-            mesh=mesh,
-        )[:, None]  # [B, 1, QH, D]
-    else:
-        # Same gate as _block's decode_prefix_attention branch, so a config
-        # running flash decode on dense caches keeps it on paged ones.
-        decode_impl = resolve_attention_impl(config.decode_attention_impl)
-        flash_prefix = (
-            decode_impl != "xla"
-            and config.sliding_window is None
-            and config.attn_softcap is None
+    with jax.named_scope("paged_attn"):
+        if (
+            attn_impl in ("pallas", "pallas_interpret")
             and Sq == 1
+            and page_tables is not None
             and prefix_lengths is not None
-            and (B // prefix_idx.shape[0]) * (config.num_heads // config.num_kv_heads) >= 8
-        )
-        attn = paged_decode_attention_xla(
-            q,
-            pool_k_l,
-            pool_v_l,
-            prefix_idx,
-            gen_idx,
-            k,
-            v,
-            write_index,
-            key_mask,
-            prefix_mask,
-            sm_scale=scale,
-            softcap=config.attn_softcap,
-            prefix_lengths=prefix_lengths,
-            flash_prefix=decode_impl if flash_prefix else None,
-            mesh=mesh,
-        )
+            and config.attn_softcap is None
+            and config.sliding_window is None
+        ):
+            prefix_pages, gen_pages, gen_phase = page_tables
+            plen = jnp.asarray(prefix_lengths, jnp.int32).reshape(-1)
+            pl_row = jnp.repeat(plen, B // plen.shape[0], total_repeat_length=B)
+            attn = paged_decode_attention_pallas(
+                q[:, 0],
+                pool_k_l,
+                pool_v_l,
+                prefix_pages,
+                gen_pages,
+                gen_phase,
+                k_col,
+                v_col,
+                pl_row,
+                write_index.astype(jnp.int32),
+                page_size=page_size,
+                sm_scale=scale,
+                interpret=attn_impl == "pallas_interpret",
+                mesh=mesh,
+            )[:, None]  # [B, 1, QH, D]
+        else:
+            # Same gate as _block's decode_prefix_attention branch, so a config
+            # running flash decode on dense caches keeps it on paged ones.
+            decode_impl = resolve_attention_impl(config.decode_attention_impl)
+            flash_prefix = (
+                decode_impl != "xla"
+                and config.sliding_window is None
+                and config.attn_softcap is None
+                and Sq == 1
+                and prefix_lengths is not None
+                and (B // prefix_idx.shape[0]) * (config.num_heads // config.num_kv_heads) >= 8
+            )
+            attn = paged_decode_attention_xla(
+                q,
+                pool_k_l,
+                pool_v_l,
+                prefix_idx,
+                gen_idx,
+                k,
+                v,
+                write_index,
+                key_mask,
+                prefix_mask,
+                sm_scale=scale,
+                softcap=config.attn_softcap,
+                prefix_lengths=prefix_lengths,
+                flash_prefix=decode_impl if flash_prefix else None,
+                mesh=mesh,
+            )
     attn = attn.astype(x.dtype).reshape(B, Sq, config.q_dim)
     x = _attn_residual(config, layer, x, attn)
     return _mlp_sublayer(config, layer, x), (k_col, v_col)
@@ -1140,12 +1147,20 @@ def _apply_stack_paged(
         else:
             km = jnp.where(flag, key_mask, key_mask_global)
             pm = jnp.where(flag, prefix_mask, prefix_mask_global)
+        # One layer's pool, indexed by hand where the scan would slice it as
+        # a scanned operand (the same op), so that the copy ahead of the
+        # attention carries a name on a capture.
+        with jax.named_scope("kv_slice"):
+            pool_l = tuple(
+                lax.dynamic_index_in_dim(pool, scanned["layer"], 0, keepdims=False)
+                for pool in (pool_kv.k, pool_kv.v)
+            )
         x, cols = _block_paged(
             config,
             scanned["layers"],
             x,
             positions,
-            scanned["pool"],
+            pool_l,
             prefix_idx,
             gen_idx,
             write_index,
@@ -1159,7 +1174,10 @@ def _apply_stack_paged(
         )
         return x, cols
 
-    xs = {"layers": params["layers"], "pool": (pool_kv.k, pool_kv.v)}
+    xs = {
+        "layers": params["layers"],
+        "layer": jnp.arange(pool_kv.k.shape[0], dtype=jnp.int32),
+    }
     if local_flags is not None:
         xs["flag"] = local_flags
     x, cols = lax.scan(body, x, xs)

@@ -3,7 +3,7 @@
 Three pieces, re-exported through ``utils/observability.py`` for the rest of
 the package:
 
-- :mod:`.trace` — ``Tracer``/``Span`` request tracing with W3C
+- :mod:`.trace` — ``Tracer``/``RequestTrace`` request tracing with W3C
   ``traceparent`` ingestion and contextvar propagation;
 - :mod:`.histograms` — declared-vocabulary log-bucketed latency histograms
   (``EventCounters`` hygiene contract, ``counter-hygiene`` lint enforced);
@@ -24,11 +24,9 @@ from .prometheus import (
     render_families,
 )
 from .trace import (
-    MAX_SPANS,
     NOOP_TRACE,
     NoopTrace,
     RequestTrace,
-    Span,
     TRACER,
     Tracer,
     current_trace,
@@ -44,11 +42,9 @@ __all__ = [
     "FlightRecorder",
     "LATENCY",
     "LatencyHistograms",
-    "MAX_SPANS",
     "NOOP_TRACE",
     "NoopTrace",
     "RequestTrace",
-    "Span",
     "TRACER",
     "Tracer",
     "counter_family",

@@ -17,9 +17,10 @@ from __future__ import annotations
 
 import bisect
 import fnmatch
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-from ..analysis.lockcheck import make_lock
+from ..analysis.lockcheck import make_lock, race_exempt
 
 #: Log-spaced bucket upper bounds in seconds (1-2.5-5 decades, 1ms → 60s).
 #: The +Inf bucket is implicit: its cumulative count is the sample count.
@@ -27,6 +28,36 @@ DEFAULT_BUCKETS: Tuple[float, ...] = (
     0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
     0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
 )
+
+
+class _Span:
+    """One timed region of :meth:`LatencyHistograms.span`: a
+    ``jax.profiler.TraceAnnotation`` held open for the block and one
+    ``observe()`` of its ``perf_counter`` duration. ``seconds`` holds that
+    duration once the block has ended, for callers that also add it to a
+    request's phases."""
+
+    __slots__ = ("_hist", "_name", "_annotation", "_t0", "seconds")
+
+    def __init__(self, hist: "LatencyHistograms", name: str, annotation: Any) -> None:
+        self._hist = hist
+        self._name = name
+        self._annotation = annotation
+        self._t0 = 0.0
+        self.seconds = 0.0
+
+    def __enter__(self) -> "_Span":
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
+        # Like the hand-written ``t0 ... observe`` pairs this replaces: a
+        # block that raised is not a sample of the region's latency.
+        if exc_type is None:
+            self._hist.observe(self._name, self.seconds)
 
 
 class LatencyHistograms:
@@ -56,6 +87,11 @@ class LatencyHistograms:
         }
         self._sums: Dict[str, float] = {}
         self._totals: Dict[str, int] = {}
+        # jax.profiler.TraceAnnotation, resolved by the first span(): importing
+        # this module (and the package) stays free of jax.
+        # kllms: unguarded — idempotent cache of one imported class; a lost race imports it twice
+        self._annotation: Any = None
+        race_exempt(self, "_annotation")
 
     def _check_declared(self, name: str) -> None:
         if not self.declared or name in self._exact:
@@ -79,6 +115,22 @@ class LatencyHistograms:
                 counts[i] += 1
             self._sums[name] = self._sums.get(name, 0.0) + v
             self._totals[name] = self._totals.get(name, 0) + 1
+
+    def span(self, name: str, **args: Any) -> _Span:
+        """Context manager: time the block on the host clock and ``observe()``
+        it under ``name`` when it ends cleanly, and hold a
+        ``jax.profiler.TraceAnnotation(name, **args)`` open for it, so the
+        same region lies on the profiler's clock whenever a capture is
+        running (with none running the annotation is a fraction of a
+        microsecond). Same declared-vocabulary contract as ``observe()``,
+        checked before the block runs."""
+        self._check_declared(name)
+        annotation = self._annotation
+        if annotation is None:
+            from jax.profiler import TraceAnnotation
+
+            annotation = self._annotation = TraceAnnotation
+        return _Span(self, name, annotation(name, **args))
 
     def count(self, name: str) -> int:
         with self._lock:
@@ -142,12 +194,39 @@ class LatencyHistograms:
 #: scatter + sync), observed per chunk by the continuous loop; compare its
 #: max against ``continuous.step`` p50 to verify long admissions no longer
 #: stall in-flight decode rows.
+#: The continuous loop's own work (ISSUE 25), each a ``span()`` — so also an
+#: event of the same name on a profiler capture — unless said. Per decode
+#: step or chunk: ``continuous.gap`` (observe only) — host clock from one
+#: program's results reaching the host to the next program's dispatch, not
+#: across an idle wait; its parts ``continuous.prepare`` (staging a step's
+#: arguments), ``continuous.handoff`` (observe only: the two thread hand-offs
+#: through the watchdog's step thread), ``continuous.bookkeep`` (tokens,
+#: sinks, retirement after a step's readback; ``continuous.emit``, one
+#: request's token sink, lies inside it) and ``continuous.admit`` (dequeue
+#: and admission, the host sides of a chunk); ``continuous.dispatch`` (the
+#: jitted call until it returns) and ``continuous.readback`` (the
+#: ``device_get``: host blocked, device busy); ``continuous.idle`` — the
+#: worker's wait when it has neither a live row nor a PREFILLING admission.
+#: Per request: ``continuous.prefill_wall`` (dequeue -> its rows installed)
+#: and ``continuous.decode_wall`` (rows installed -> future resolved), wall
+#: clock, other requests' steps and chunks included; also phases of its trace.
 LATENCY = LatencyHistograms(declared=(
     "request.e2e",
     "request.ttft",
     "scheduler.queue_wait",
     "continuous.step",
     "continuous.prefill_chunk",
+    "continuous.gap",
+    "continuous.prepare",
+    "continuous.handoff",
+    "continuous.dispatch",
+    "continuous.readback",
+    "continuous.bookkeep",
+    "continuous.emit",
+    "continuous.admit",
+    "continuous.idle",
+    "continuous.prefill_wall",
+    "continuous.decode_wall",
     "engine.decode_launch",
     "consensus.consolidate",
     "batch.item",

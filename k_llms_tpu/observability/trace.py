@@ -17,7 +17,7 @@ host-side wall clock: no device syncs, nothing inside jitted step programs.
 
 Tracing must never fail a request: the ``serving.trace`` failpoint's
 ``drop`` action (and any unexpected error while starting a trace) degrades
-the tracer to :data:`NOOP_TRACE`, whose spans are free and which is never
+the tracer to :data:`NOOP_TRACE`, whose phases are free and which is never
 flight-recorded.
 """
 
@@ -28,7 +28,7 @@ import contextvars
 import os
 import re
 import time
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, Iterator, Optional, Tuple
 
 from ..analysis.lockcheck import make_lock
 from ..reliability import failpoints as _failpoints
@@ -39,10 +39,6 @@ _TRACEPARENT_RE = re.compile(
     r"^(?P<version>[0-9a-f]{2})-(?P<trace_id>[0-9a-f]{32})-"
     r"(?P<span_id>[0-9a-f]{16})-(?P<flags>[0-9a-f]{2})$"
 )
-
-#: Per-trace span cap: a pathological request (thousands of coalesced decode
-#: launches) keeps its aggregate durations but stops growing the span list.
-MAX_SPANS = 128
 
 
 def _new_trace_id() -> str:
@@ -77,35 +73,13 @@ def format_traceparent(trace_id: str, span_id: str, flags: str = "01") -> str:
     return f"00-{trace_id}-{span_id}-{flags}"
 
 
-class Span:
-    """One recorded phase occurrence: name + offset from trace start +
-    duration, with its own span_id parented on the trace's root span."""
-
-    __slots__ = ("name", "span_id", "parent_id", "start_s", "duration_s")
-
-    def __init__(
-        self, name: str, span_id: str, parent_id: str, start_s: float, duration_s: float
-    ) -> None:
-        self.name = name
-        self.span_id = span_id
-        self.parent_id = parent_id
-        self.start_s = start_s
-        self.duration_s = duration_s
-
-    def as_dict(self) -> Dict[str, Any]:
-        return {
-            "name": self.name,
-            "span_id": self.span_id,
-            "parent_id": self.parent_id,
-            "start_s": round(self.start_s, 6),
-            "duration_s": round(self.duration_s, 6),
-        }
-
-
 class RequestTrace:
     """Thread-safe per-request trace: aggregated phase durations (the
-    ``KLLMS_TRACE=1`` ``timings`` payload), a bounded span list, and
-    free-form annotations (``replayed``, ``quarantined_rows``...).
+    ``KLLMS_TRACE=1`` ``timings`` payload and the flight record's ``phases``)
+    and free-form annotations (``replayed``, ``quarantined_rows``...). One
+    ``span_id`` names the request's root span for ``traceparent``; single
+    phase occurrences are not kept — where a region matters on a timeline it
+    is a ``LATENCY.span`` and lies on the profiler's capture.
 
     ``phase()`` keeps the old two-phase ``Trace`` API so existing call sites
     and tests hold; mutation is guarded by a lockcheck leaf lock because the
@@ -125,7 +99,6 @@ class RequestTrace:
         self.started_at = time.time()
         self._t0 = time.monotonic()
         self.durations: Dict[str, float] = {}
-        self.spans: List[Span] = []
         self.annotations: Dict[str, Any] = {}
         self._finished = False
 
@@ -139,28 +112,19 @@ class RequestTrace:
 
     @contextlib.contextmanager
     def phase(self, name: str) -> Iterator[None]:
-        start = time.monotonic() - self._t0
         t0 = time.perf_counter()
         try:
             yield
         finally:
-            self.add_phase(name, time.perf_counter() - t0, start_offset_s=start)
+            self.add_phase(name, time.perf_counter() - t0)
 
-    def add_phase(
-        self, name: str, duration_s: float, start_offset_s: Optional[float] = None
-    ) -> None:
-        """Accumulate a phase duration (and one span) measured externally —
-        the thread-boundary form of ``phase()`` for the scheduler worker and
-        the continuous loop, where the timed region isn't a ``with`` block
-        on the trace owner's thread."""
-        if start_offset_s is None:
-            start_offset_s = max(0.0, time.monotonic() - self._t0 - duration_s)
+    def add_phase(self, name: str, duration_s: float) -> None:
+        """Accumulate a phase duration measured externally — the
+        thread-boundary form of ``phase()`` for the scheduler worker and the
+        continuous loop, where the timed region isn't a ``with`` block on the
+        trace owner's thread."""
         with self._lock:
             self.durations[name] = self.durations.get(name, 0.0) + duration_s
-            if len(self.spans) < MAX_SPANS:
-                self.spans.append(
-                    Span(name, _new_span_id(), self.span_id, start_offset_s, duration_s)
-                )
 
     def annotate(self, key: str, value: Any = True) -> None:
         with self._lock:
@@ -190,10 +154,6 @@ class RequestTrace:
         with self._lock:
             return {k: round(v, 6) for k, v in self.durations.items()}
 
-    def spans_as_dicts(self) -> List[Dict[str, Any]]:
-        with self._lock:
-            return [s.as_dict() for s in self.spans]
-
     def annotations_snapshot(self) -> Dict[str, Any]:
         with self._lock:
             return dict(self.annotations)
@@ -210,7 +170,6 @@ class NoopTrace:
     flags = "00"
     started_at = 0.0
     durations: Dict[str, float] = {}
-    spans: List[Span] = []
     annotations: Dict[str, Any] = {}
 
     @property
@@ -224,7 +183,7 @@ class NoopTrace:
     def phase(self, name: str) -> Iterator[None]:
         yield
 
-    def add_phase(self, name: str, duration_s: float, start_offset_s: Optional[float] = None) -> None:
+    def add_phase(self, name: str, duration_s: float) -> None:
         pass
 
     def annotate(self, key: str, value: Any = True) -> None:
@@ -241,9 +200,6 @@ class NoopTrace:
 
     def as_dict(self) -> Dict[str, float]:
         return {}
-
-    def spans_as_dicts(self) -> List[Dict[str, Any]]:
-        return []
 
     def annotations_snapshot(self) -> Dict[str, Any]:
         return {}

@@ -341,6 +341,7 @@ def decode_prefix_attention(
             pltpu.VMEM((KVH, QR), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_prefix_attention",
     )(prompt_lens.astype(jnp.int32).reshape(R, 1), q4, prefix_k, prefix_v)
 
     def back(x):  # [R, KVH, QR, ...] -> [B, QH, ...]
@@ -403,6 +404,9 @@ def flash_attention(
     local = functools.partial(
         _flash_attention_local, causal=causal, sm_scale=sm_scale,
         softcap=softcap, block_q=block_q, block_k=block_k, interpret=interpret,
+        # One kernel, two uses: a capture tells whole-prompt prefill from the
+        # q_offset continuation by the kernel's name.
+        name="flash_prefill" if q_offset is None else "flash_continue",
     )
     if multi_device(mesh):
         b_ax = mesh_axis(mesh, batch_axis, B) if batch_axis else None
@@ -418,7 +422,7 @@ def flash_attention(
 
 def _flash_attention_local(
     q, k, v, key_lengths, window_arr, qoff_arr, *,
-    causal, sm_scale, softcap, block_q, block_k, interpret,
+    causal, sm_scale, softcap, block_q, block_k, interpret, name,
 ):
     """One shard's flash attention (the whole call on a single device)."""
     B, QH, Sq, D = q.shape
@@ -466,6 +470,7 @@ def _flash_attention_local(
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=name,
     )(key_lengths, window_arr, qoff_arr, q, k, v)
 
     return out[:, :, :Sq, :]

@@ -468,6 +468,7 @@ def _paged_decode_local(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, G, D), jnp.float32),
         interpret=interpret,
+        name="paged_attention_decode",
     )(
         tables,
         prompt_lens.astype(jnp.int32),

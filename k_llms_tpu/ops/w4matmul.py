@@ -207,6 +207,7 @@ def w4_matmul(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="w4_matmul",
     )(x, w.q, w.scale)
     return out[:rows]
 

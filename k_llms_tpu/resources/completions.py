@@ -241,8 +241,7 @@ class ChatCompletionStream:
                 # Finish chunks can go out while consolidation is still
                 # running.
                 self._events.put(("sampled", completion))
-                t0 = time.perf_counter()
-                with self.trace.phase("consolidate"):
+                with LATENCY.span("consensus.consolidate"), self.trace.phase("consolidate"):
                     result = consolidate_chat_completions(
                         completion,
                         self._scorer,
@@ -250,9 +249,6 @@ class ChatCompletionStream:
                         llm_consensus_fn=self._llm_consensus_fn,
                         budget=self._request.budget,
                     )
-                LATENCY.observe(
-                    "consensus.consolidate", time.perf_counter() - t0
-                )
             self._events.put(("final", result))
         except BaseException as e:  # surfaced on the consumer side
             if self._owns_trace:
@@ -481,8 +477,7 @@ class Completions:
                     completion = self._wrapper.backend.dispatch_chat_completion(
                         request
                     )
-                t0 = time.perf_counter()
-                with trace.phase("consolidate"):
+                with LATENCY.span("consensus.consolidate"), trace.phase("consolidate"):
                     result = consolidate_chat_completions(
                         completion,
                         self._scorer(settings),
@@ -490,9 +485,6 @@ class Completions:
                         llm_consensus_fn=self._wrapper.backend.llm_consensus,
                         budget=request.budget,
                     )
-                LATENCY.observe(
-                    "consensus.consolidate", time.perf_counter() - t0
-                )
         except BaseException as e:
             if owned:
                 TRACER.finish(
@@ -552,8 +544,7 @@ class Completions:
                     completion = self._wrapper.backend.dispatch_chat_completion(
                         request
                     )
-                t0 = time.perf_counter()
-                with trace.phase("consolidate"):
+                with LATENCY.span("consensus.consolidate"), trace.phase("consolidate"):
                     result = consolidate_parsed_chat_completions(
                         completion,
                         self._scorer(settings),
@@ -562,9 +553,6 @@ class Completions:
                         llm_consensus_fn=self._wrapper.backend.llm_consensus,
                         budget=request.budget,
                     )
-                LATENCY.observe(
-                    "consensus.consolidate", time.perf_counter() - t0
-                )
         except BaseException as e:
             if owned:
                 TRACER.finish(

@@ -37,7 +37,9 @@ Routes:
                                 (trace_id, phases, status, annotations).
                                 404 unless BackendConfig.debug_endpoints.
     POST /debug/profile         on-demand jax.profiler capture (bounded
-                                duration). 404 unless debug_endpoints.
+                                ``duration_s``, ``log_dir``; ``python_tracer``
+                                default false, ``host_tracer_level`` default
+                                2). 404 unless debug_endpoints.
 
 Request tracing: a W3C ``traceparent`` header on POST /v1/chat/completions is
 ingested at this front door (one is generated when absent) and bound to the
@@ -554,7 +556,11 @@ class ServingApp:
             if not isinstance(payload, dict):
                 raise ValueError("payload must be a JSON object")
             duration = float(payload.get("duration_s", 1.0))
-        except ValueError as e:
+            python_tracer = payload.get("python_tracer", False)
+            if not isinstance(python_tracer, bool):
+                raise ValueError("'python_tracer' must be a boolean")
+            host_tracer_level = int(payload.get("host_tracer_level", 2))
+        except (TypeError, ValueError) as e:
             _obs.SERVE_EVENTS.record("request.debug.400")
             await _send_json(
                 send, 400,
@@ -573,13 +579,23 @@ class ServingApp:
         )
 
         def _capture() -> None:
-            with _obs.device_profiler(log_dir):
+            with _obs.device_profiler(
+                log_dir,
+                python_tracer=python_tracer,
+                host_tracer_level=host_tracer_level,
+            ):
                 time.sleep(duration)
 
         await asyncio.to_thread(_capture)
         _obs.SERVE_EVENTS.record("request.debug.200")
         await _send_json(
-            send, 200, {"log_dir": log_dir, "duration_s": duration}
+            send, 200,
+            {
+                "log_dir": log_dir,
+                "duration_s": duration,
+                "python_tracer": python_tracer,
+                "host_tracer_level": host_tracer_level,
+            },
         )
 
     # -- POST /v1/chat/completions ----------------------------------------

@@ -28,7 +28,6 @@ from ..observability import (  # noqa: F401  (re-exported surface)
     NOOP_TRACE,
     NoopTrace,
     RequestTrace,
-    Span,
     TRACER,
     Tracer,
     current_trace,
@@ -53,16 +52,31 @@ def configure_logging() -> logging.Logger:
 
 
 @contextlib.contextmanager
-def device_profiler(log_dir: Optional[str] = None) -> Iterator[None]:
+def device_profiler(
+    log_dir: Optional[str] = None,
+    python_tracer: bool = False,
+    host_tracer_level: int = 2,
+) -> Iterator[None]:
     """jax.profiler trace around a block (view with TensorBoard/Perfetto).
-    No-ops when log_dir is None and KLLMS_PROFILE_DIR is unset."""
+    No-ops when log_dir is None and KLLMS_PROFILE_DIR is unset.
+
+    With the Python tracer off (the default) the host planes hold the
+    program's own ``TraceAnnotation`` events — every ``LATENCY.span`` and the
+    loop's ``continuous.host`` — under their plain names, and the host runs at
+    its own speed inside the capture; ``python_tracer=True`` adds one event per
+    Python call and slows the traced threads enough to misread the device's
+    idle share (PERF.md §6, PR 25). Either way the call returns only once the
+    device plane is serialized, which takes far longer than the capture."""
     import jax
 
     log_dir = log_dir or os.getenv("KLLMS_PROFILE_DIR")
     if not log_dir:
         yield
         return
-    with jax.profiler.trace(log_dir):
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 1 if python_tracer else 0
+    options.host_tracer_level = int(host_tracer_level)
+    with jax.profiler.trace(log_dir, profiler_options=options):
         yield
 
 

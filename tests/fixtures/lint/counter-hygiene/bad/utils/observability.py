@@ -16,6 +16,9 @@ class LatencyHistograms:
     def observe(self, name, seconds):
         pass
 
+    def span(self, name, **args):
+        pass
+
 
 ALPHA_EVENTS = EventCounters()  # no declared= vocabulary
 

@@ -8,3 +8,5 @@ def work(route):
     EVENTS.record(f"keyed.{route}")
     HIST.observe("h.a", 0.1)
     HIST.observe(f"hkeyed.{route}", 0.1)
+    with HIST.span("h.spanned"):  # a span observes its family
+        pass

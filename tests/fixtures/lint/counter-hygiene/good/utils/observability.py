@@ -16,6 +16,9 @@ class LatencyHistograms:
     def observe(self, name, seconds):
         pass
 
+    def span(self, name, **args):
+        pass
+
 
 EVENTS = EventCounters(declared=(
     "a.b",
@@ -24,5 +27,6 @@ EVENTS = EventCounters(declared=(
 
 HIST = LatencyHistograms(declared=(
     "h.a",
+    "h.spanned",  # observed through span() only
     "hkeyed.*",  # f-string family: hkeyed.<route>
 ))

@@ -241,7 +241,7 @@ def test_failpoint_coverage_good_fixture_is_clean():
 
 def test_counter_hygiene_bad_fixture():
     msgs = messages(run_fixture("counter-hygiene", "counter-hygiene/bad"))
-    assert len(msgs) == 8
+    assert len(msgs) == 9
     # Counter group findings.
     assert sum("counter group" in m and "without declared=" in m for m in msgs) == 1
     assert sum("'a.typo'" in m for m in msgs) == 1
@@ -250,6 +250,7 @@ def test_counter_hygiene_bad_fixture():
     # Histogram group findings mirror the counter contract.
     assert sum("histogram group" in m and "without declared=" in m for m in msgs) == 1
     assert sum("'h.typo'" in m for m in msgs) == 1
+    assert sum("'h.span_typo'" in m for m in msgs) == 1  # span() literals too
     assert sum("'stale.hist'" in m and "never observed" in m for m in msgs) == 1
     assert sum("not surfaced" in m and "GAMMA_HIST" in m for m in msgs) == 1
 

@@ -17,13 +17,25 @@ The load-bearing pins:
 - A request healed through watchdog rebuild + journal replay produces
   EXACTLY ONE flight record, annotated ``replayed`` — not one per epoch.
 - Differential: generated bytes are identical with tracing on, dropped, and
-  absent (observability is host-side bookkeeping, never part of the math).
+  absent, and with a profiler capture running (observability is host-side
+  bookkeeping, never part of the math).
+- The continuous loop names its own work (PR 25): ``LATENCY.span`` regions
+  land on ``/metrics`` histograms AND, as ``TraceAnnotation`` events of the
+  same name, on a ``/debug/profile`` capture; the parts of ``continuous.gap``
+  add up to it; the step programs carry scope and kernel names.
 """
 
 import asyncio
+import glob
+import os
+import re
+import threading
+import time
 from types import SimpleNamespace
 
 import httpx
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -411,15 +423,20 @@ def test_rebuild_replay_yields_one_flight_record_annotated(eng):
         assert phase in rec["phases"], rec["phases"]
 
 
-def test_tracing_differential_byte_identity(eng):
+def test_tracing_differential_byte_identity(eng, tmp_path):
     """Observability never touches the math: the same seeded request decodes
     to identical tokens with a live trace bound, with the tracer dropped by
-    the ``serving.trace`` failpoint, and with no trace at all."""
+    the ``serving.trace`` failpoint, with no trace at all, and with a
+    profiler capture running."""
     outs = []
-    for mode in ("traced", "dropped", "untraced"):
+    for mode in ("traced", "dropped", "untraced", "captured"):
         loop = ContinuousDecodeLoop(eng, width=4, max_prompt=64, max_new=32)
         try:
-            if mode == "traced":
+            if mode == "captured":
+                # Every span's TraceAnnotation is live while a capture runs.
+                jax.profiler.start_trace(str(tmp_path))
+                trace = TRACER.start()
+            elif mode == "traced":
                 trace = TRACER.start()
             elif mode == "dropped":
                 with fp.failpoints(
@@ -437,8 +454,298 @@ def test_tracing_differential_byte_identity(eng):
             outs.append(fut.result(timeout=120))
         finally:
             loop.stop()
+            if mode == "captured":
+                jax.profiler.stop_trace()
     base = outs[0]
     for other in outs[1:]:
         assert np.array_equal(base.tokens, other.tokens)
         assert np.allclose(base.logprobs, other.logprobs, atol=1e-6)
         assert list(base.lengths) == list(other.lengths)
+
+
+# -- the loop names its own work (PR 25) -----------------------------------
+
+
+def test_latency_span_observes_once_and_refuses_undeclared():
+    h = LatencyHistograms(declared=("a.b",), buckets=(0.1, 1.0))
+    with h.span("a.b", step=3) as span:
+        time.sleep(0.002)
+    assert h.count("a.b") == 1
+    assert span.seconds >= 0.002
+    assert h.snapshot()["a.b"]["sum"] == pytest.approx(span.seconds)
+    # The vocabulary is checked before the block runs, like observe().
+    with pytest.raises(ValueError, match="not declared"):
+        h.span("a.typo")
+    # A block that raises is not a sample of the region's latency.
+    with pytest.raises(RuntimeError):
+        with h.span("a.b"):
+            raise RuntimeError("boom")
+    assert h.count("a.b") == 1
+
+
+LOOP_FAMILIES = (
+    "gap", "prepare", "handoff", "bookkeep", "admit", "emit", "idle",
+    "dispatch", "readback", "step", "prefill_chunk", "prefill_wall",
+    "decode_wall",
+)
+
+
+def _loop_totals():
+    snap = LATENCY.snapshot()
+    return {
+        name: (snap[f"continuous.{name}"]["count"], snap[f"continuous.{name}"]["sum"])
+        for name in LOOP_FAMILIES
+    }
+
+
+def _submit_mix(loop, sink):
+    """Two streamed and two unstreamed requests of two rows each into a loop
+    four rows wide: the later ones wait for slots, and of the two prompts
+    long enough to chunk the second waits for the PREFILLING lane."""
+    long_a, long_b = list(range(5, 45)), list(range(45, 85))
+    futs = [
+        loop.submit(ids, n=2, max_new=12, temperature=0.8, top_p=0.9,
+                    seed=seed, token_sink=tap)
+        for seed, (ids, tap) in enumerate(
+            [(long_a, sink), (long_b, None), ([5, 6, 7], sink), ([8, 9], None)]
+        )
+    ]
+    return [f.result(timeout=120) for f in futs]
+
+
+def test_loop_spans_split_the_gap(eng):
+    """A run of streamed and unstreamed requests through the watchdogged
+    loop: every step leaves one prepare, one bookkeep and one hand-off, every
+    program but the first after a pause leaves a gap, and the four parts of
+    the gap add up to it."""
+    loop = ContinuousDecodeLoop(
+        eng, width=4, max_prompt=64, max_new=32, prefill_chunk_tokens=32,
+        budget_model=_step_budget(60.0),
+    )
+    sunk = []
+    sink = lambda step, row: sunk.append(step)  # noqa: E731
+    try:
+        _submit_mix(loop, sink)  # compiles every program the mix uses
+        assert loop.drain(timeout=60)
+        with loop._lock:
+            loop._closing = False  # drain() closes admission; reopen it
+        stats0, before, sunk0 = loop.stats, _loop_totals(), len(sunk)
+        results = _submit_mix(loop, sink)
+        assert loop.drain(timeout=60)
+        stats1, after = loop.stats, _loop_totals()
+    finally:
+        loop.stop()
+    assert all(int(r.lengths[0]) > 0 for r in results)
+    count = {n: after[n][0] - before[n][0] for n in LOOP_FAMILIES}
+    total = {n: after[n][1] - before[n][1] for n in LOOP_FAMILIES}
+    steps = stats1["steps"] - stats0["steps"]
+    chunks = stats1["prefill_chunks"] - stats0["prefill_chunks"]
+    assert steps >= 12 and chunks >= 4
+    # One per step, exactly; a hand-off, a dispatch and a readback per program.
+    assert count["prepare"] == count["bookkeep"] == count["step"] == steps
+    assert count["prefill_chunk"] == chunks
+    assert count["handoff"] == count["dispatch"] == count["readback"] == steps + chunks
+    # A gap per program, except the first after each pause of the loop.
+    assert steps + chunks - count["idle"] - 1 <= count["gap"] <= steps + chunks
+    assert count["admit"] >= 4 + 2 * chunks
+    assert count["prefill_wall"] == count["decode_wall"] == 4
+    # The parts add up to the gap. What they leave out is a few lines between
+    # spans; what they add is the admit and the prepare ahead of the first
+    # program after a pause, which no gap covers. Stated tolerance: 35 %.
+    parts = sum(total[n] for n in ("prepare", "handoff", "bookkeep", "admit"))
+    assert total["gap"] > 0
+    assert abs(parts - total["gap"]) <= 0.35 * total["gap"], (total, count)
+    # The sinks run inside the bookkeeping (the first delta's inside admit).
+    assert count["emit"] >= len(sunk) - sunk0 > 0
+    assert total["emit"] <= total["bookkeep"]
+    # The queue's head was left behind for both reasons the mix provokes.
+    assert stats1["blocked_slots"] > stats0["blocked_slots"]
+    assert stats1["blocked_lane"] > stats0["blocked_lane"]
+    assert stats1["blocked_pages"] == 0
+
+
+def test_request_wall_phases_reach_the_flight_record(eng):
+    """``prefill_wall`` (dequeue -> rows installed) and ``decode_wall`` (rows
+    installed -> future resolved) are phases of the request's trace, and with
+    ``queue_wait`` they fit inside the request's duration."""
+    loop = ContinuousDecodeLoop(eng, width=4, max_prompt=64, max_new=32)
+    trace = TRACER.start()
+    try:
+        with use_trace(trace):
+            fut = loop.submit(
+                [5, 6, 7, 8], n=2, max_new=8, temperature=0.8, top_p=0.9, seed=3,
+            )
+        fut.result(timeout=120)
+    finally:
+        loop.stop()
+    rec = TRACER.finish(trace, route="continuous", status="ok", n=2)
+    phases = rec["phases"]
+    for phase in ("queue_wait", "prefill_wall", "decode_wall", "prefill", "decode"):
+        assert phase in phases, phases
+    assert phases["prefill_wall"] >= phases["prefill"] > 0
+    assert phases["decode_wall"] >= phases["decode"] > 0
+    walls = phases["queue_wait"] + phases["prefill_wall"] + phases["decode_wall"]
+    assert walls <= rec["duration_s"] + 1e-3, (phases, rec["duration_s"])
+
+
+def _host_event_names(log_dir):
+    """Names of the events on the ``/host:`` planes of the capture in
+    ``log_dir``, read back with ``jax.profiler.ProfileData``."""
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True)
+    names = set()
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(ev.name for ev in line.events)
+    return names
+
+
+@pytest.mark.parametrize("python_tracer", [False, True])
+def test_debug_profile_capture_holds_the_loops_spans(eng, tmp_path, python_tracer):
+    """A ``/debug/profile`` capture taken while the loop decodes holds the
+    loop's spans under their plain names on a host plane. The Python tracer
+    is off unless the payload asks for it: its one-event-per-call frames
+    (``$file.py:line function``) slow the traced threads enough to misread the
+    device's idle share."""
+    client = _fake_client()
+    client.backend.backend_config = SimpleNamespace(debug_endpoints=True)
+    app = ServingApp(client)
+    loop = ContinuousDecodeLoop(
+        eng, width=4, max_prompt=64, max_new=32, budget_model=_step_budget(60.0),
+    )
+    stop = threading.Event()
+
+    def feed():
+        while not stop.is_set():
+            loop.submit(
+                [5, 6, 7, 8], n=2, max_new=16, temperature=0.8, top_p=0.9,
+                seed=5, token_sink=lambda step, row: None,
+            ).result(timeout=120)
+
+    feeder = threading.Thread(target=feed, daemon=True)
+    payload = {"duration_s": 0.4, "log_dir": str(tmp_path)}
+    if python_tracer:
+        payload["python_tracer"] = True
+
+    async def go():
+        async with _asgi(app) as c:
+            return await c.post("/debug/profile", json=payload)
+
+    try:
+        loop.submit(  # warm: the capture must see steps, not compiles
+            [5, 6, 7, 8], n=2, max_new=4, temperature=0.8, top_p=0.9, seed=5,
+        ).result(timeout=120)
+        feeder.start()
+        resp = _run(go())
+    finally:
+        stop.set()
+        feeder.join(timeout=120)
+        loop.stop()
+    assert not feeder.is_alive()
+    assert resp.status_code == 200
+    assert resp.json()["python_tracer"] is python_tracer
+    assert resp.json()["host_tracer_level"] == 2
+    names = _host_event_names(str(tmp_path))
+    for span in ("step", "prepare", "dispatch", "readback", "bookkeep", "emit", "host"):
+        assert f"continuous.{span}" in names, sorted(n for n in names if "continuous" in n)
+    python_frames = {n for n in names if n.startswith("$")}
+    assert bool(python_frames) is python_tracer, sorted(python_frames)[:5]
+
+
+def test_debug_profile_rejects_bad_options():
+    client = _fake_client()
+    client.backend.backend_config = SimpleNamespace(debug_endpoints=True)
+    app = ServingApp(client)
+
+    async def go():
+        async with _asgi(app) as c:
+            return [
+                await c.post("/debug/profile", json=bad)
+                for bad in ({"python_tracer": "yes"}, {"host_tracer_level": "deep"})
+            ]
+
+    assert [r.status_code for r in _run(go())] == [400, 400]
+
+
+def test_paged_step_program_carries_scope_names():
+    """Names on the device side are metadata of the step programs: the paged
+    step's lowered text carries every scope (the chip's profiler writes them
+    into the device plane's event metadata)."""
+    from conftest import shared_engine
+
+    paged = shared_engine(model="tiny", kv_layout="paged", kv_page_size=16)
+    loop = ContinuousDecodeLoop(paged, width=4, max_prompt=64, max_new=32)
+    try:
+        loop.submit(
+            [5, 6, 7, 8], n=2, max_new=2, temperature=0.8, top_p=0.9, seed=1,
+        ).result(timeout=120)
+        W = loop.width
+        i32, f32 = (lambda: jnp.zeros((W,), jnp.int32)), (lambda: jnp.ones((W,), jnp.float32))
+        lowered = loop._step_paged_fn.lower(
+            paged.params, loop._pool.kv.k, loop._pool.kv.v, i32(), i32(),
+            jnp.ones((W,), jnp.int32), jnp.ones((W,), bool),
+            jnp.zeros((W,), jnp.uint32), i32(), f32(), f32(),
+            jnp.asarray(loop._prefix_idx), jnp.asarray(loop._gen_idx), i32(),
+            jnp.zeros((W,), bool),
+        ).as_text(debug_info=True)
+    finally:
+        loop.stop()
+    # Ops of the layer scan's body are located relative to it ("mlp/..."),
+    # the others from the program's root ("jit(_step_paged)/sampler/...").
+    located = set(re.findall(r'loc\("([^"]+)"', lowered))
+    for scope in (
+        "embed", "kv_slice", "attn_qkv", "paged_attn", "attn_out", "mlp",
+        "lm_head", "kv_write", "sampler",
+    ):
+        assert any(
+            name.startswith(scope + "/") or f"/{scope}/" in name for name in located
+        ), scope
+    assert "kv_slice/dynamic_slice" in located
+
+
+def _kernel_call(kernel):
+    """A traceable call of one Pallas kernel at a toy shape."""
+    from k_llms_tpu.ops.attention import decode_prefix_attention, flash_attention
+    from k_llms_tpu.ops.paged_attention import (
+        paged_attention_page_tables,
+        paged_decode_attention_pallas,
+    )
+    from k_llms_tpu.ops.w4matmul import pack_int4, w4_matmul
+
+    f32 = lambda *shape: jnp.ones(shape, jnp.float32)  # noqa: E731
+    if kernel == "paged_attention_decode":
+        tables = paged_attention_page_tables(
+            jnp.zeros((2, 16), jnp.int32), jnp.zeros((2, 8), jnp.int32), 8
+        )
+        return lambda: paged_decode_attention_pallas(
+            f32(2, 4, 16), f32(32, 2, 16), f32(32, 2, 16), *tables,
+            f32(2, 2, 16), f32(2, 2, 16), jnp.ones((2,), jnp.int32),
+            jnp.zeros((2,), jnp.int32), page_size=8, sm_scale=0.25, interpret=True,
+        )
+    if kernel in ("flash_prefill", "flash_continue"):
+        offset = {"q_offset": 8} if kernel == "flash_continue" else {}
+        return lambda: flash_attention(
+            f32(1, 4, 16, 16), f32(1, 2, 32, 16), f32(1, 2, 32, 16),
+            causal=True, interpret=True, **offset,
+        )
+    if kernel == "decode_prefix_attention":
+        return lambda: decode_prefix_attention(
+            f32(8, 4, 16), f32(1, 32, 2, 16), f32(1, 32, 2, 16),
+            jnp.full((1,), 32, jnp.int32), interpret=True,
+        )
+    assert kernel == "w4_matmul"
+    return lambda: w4_matmul(f32(16, 256), pack_int4(f32(256, 256)), interpret=True)
+
+
+@pytest.mark.parametrize(
+    "kernel",
+    ["paged_attention_decode", "flash_prefill", "flash_continue",
+     "decode_prefix_attention", "w4_matmul"],
+)
+def test_pallas_kernels_carry_names(kernel):
+    """Each ``pl.pallas_call`` site names its kernel; a CPU lowering shows the
+    name on the ``pallas_call`` equation of the jaxpr (the chip's compiler
+    writes it into the custom call, where a capture reads it)."""
+    jaxpr = str(jax.make_jaxpr(_kernel_call(kernel))())
+    assert "pallas_call" in jaxpr
+    assert re.search(rf"name={kernel}\b", jaxpr), re.findall(r"name=\S+", jaxpr)
