@@ -543,14 +543,14 @@ def bench_paged_attention() -> dict:
     rng = np.random.default_rng(17)
 
     def materializing(
-        q, pool_k, pool_v, prefix_idx, gen_idx, new_k, new_v, write_index,
+        q, pool_k, pool_v, layer, prefix_idx, gen_idx, new_k, new_v, write_index,
         key_mask, prefix_mask,
     ):
         # The PR 7 step, operation for operation: gather both regions to a
         # dense copy, run the dense attention over the copy, re-extract the
         # written column from the copy for the pool scatter.
-        pk, pv = gather_kv_pages(pool_k, pool_v, prefix_idx)
-        gk, gv = gather_kv_pages(pool_k, pool_v, gen_idx)
+        pk, pv = gather_kv_pages(pool_k, pool_v, prefix_idx, layer)
+        gk, gv = gather_kv_pages(pool_k, pool_v, gen_idx, layer)
         row_update = jax.vmap(
             lambda c, kk, off: jax.lax.dynamic_update_slice_in_dim(
                 c, kk, off, axis=0
@@ -578,8 +578,8 @@ def bench_paged_attention() -> dict:
         B = n
         npages = P // ps + B * (G // ps) + 1
         flat = npages * ps
-        pool_k = jnp.asarray(rng.standard_normal((flat, KVH, D)), jnp.float32)
-        pool_v = jnp.asarray(rng.standard_normal((flat, KVH, D)), jnp.float32)
+        pool_k = jnp.asarray(rng.standard_normal((1, flat, KVH, D)), jnp.float32)
+        pool_v = jnp.asarray(rng.standard_normal((1, flat, KVH, D)), jnp.float32)
         # One request, n rows sharing its prefix (the consensus fan-out
         # shape): request-level [1, P] prefix table, per-row gen slots.
         prefix_idx = jnp.asarray(
@@ -599,7 +599,7 @@ def bench_paged_attention() -> dict:
         key_mask = jnp.broadcast_to(jnp.arange(G) <= glen, (B, 1, G))
         prefix_mask = jnp.broadcast_to(jnp.arange(P) < plen, (B, 1, P))
         args = (
-            q, pool_k, pool_v, prefix_idx, gen_idx, new_k, new_v,
+            q, pool_k, pool_v, jnp.int32(0), prefix_idx, gen_idx, new_k, new_v,
             write_index, key_mask, prefix_mask,
         )
         fused = jax.jit(
@@ -641,16 +641,17 @@ def bench_paged_attention() -> dict:
     ps8 = BackendConfig.model_fields["kv_page_size"].default
     prompt_len, gen_bucket = 1408, MAX_NEW
     pool_shape = jax.ShapeDtypeStruct(
-        (64 * ps8, cfg8.num_kv_heads, cfg8.head_dim), cfg8.jax_dtype
+        (1, 64 * ps8, cfg8.num_kv_heads, cfg8.head_dim), cfg8.jax_dtype
     )
+    layer0 = jax.ShapeDtypeStruct((), np.int32)
 
     def gather_bytes(n: int) -> int:
         outs = jax.eval_shape(
             gather_kv_pages, pool_shape, pool_shape,
-            jax.ShapeDtypeStruct((1, prompt_len), np.int32),
+            jax.ShapeDtypeStruct((1, prompt_len), np.int32), layer0,
         ) + jax.eval_shape(
             gather_kv_pages, pool_shape, pool_shape,
-            jax.ShapeDtypeStruct((n, gen_bucket), np.int32),
+            jax.ShapeDtypeStruct((n, gen_bucket), np.int32), layer0,
         )
         per_layer = sum(
             int(np.prod(o.shape)) * np.dtype(o.dtype).itemsize for o in outs

@@ -991,7 +991,8 @@ def _block_paged(
     layer: Params,
     x: jax.Array,
     positions: jax.Array,
-    pool_kv_l: Tuple[jax.Array, jax.Array],
+    pool_kv: KVCache,
+    layer_idx: jax.Array,
     prefix_idx: jax.Array,
     gen_idx: jax.Array,
     write_index: jax.Array,
@@ -1005,11 +1006,14 @@ def _block_paged(
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """Paged twin of :func:`_block` for the ``Sq == 1`` decode/verify step.
 
-    KV comes from ONE layer's flat page pool (``pool_kv_l``) through block
-    tables; attention runs in ``ops/paged_attention.py`` — the fused Pallas
-    kernel when ``attn_impl`` selects it (block-table gather folded into the
-    K/V load, no materialized copy) or the byte-identical XLA reference
-    otherwise. Returns ``(x, (k_col, v_col))`` where the cols ``[B, KVH, D]``
+    KV comes from the whole flat page pool (``pool_kv``,
+    ``[L, flat, KVH, D]``) through block tables and this layer's number
+    (``layer_idx``, int32 scalar): the pool is never sliced, the attention op
+    addresses (layer, slot) itself. Attention runs in
+    ``ops/paged_attention.py`` — the fused Pallas kernel when ``attn_impl``
+    selects it (block-table gather folded into the K/V load, no materialized
+    copy) or the byte-identical XLA reference otherwise. Returns
+    ``(x, (k_col, v_col))`` where the cols ``[B, KVH, D]``
     are this step's freshly computed column in pool dtype — the caller
     scatters them into the pool (the old path extracted the same column from
     the written gather transient via ``take_along_axis``; taking it straight
@@ -1024,9 +1028,8 @@ def _block_paged(
     B, Sq, H = x.shape
     scale = config.query_scale or 1.0 / math.sqrt(config.head_dim)
     q, k, v = _attn_qkv(config, layer, x, positions)
-    pool_k_l, pool_v_l = pool_kv_l
-    k_col = k[:, 0].astype(pool_k_l.dtype)
-    v_col = v[:, 0].astype(pool_v_l.dtype)
+    k_col = k[:, 0].astype(pool_kv.k.dtype)
+    v_col = v[:, 0].astype(pool_kv.v.dtype)
 
     with jax.named_scope("paged_attn"):
         if (
@@ -1042,8 +1045,9 @@ def _block_paged(
             pl_row = jnp.repeat(plen, B // plen.shape[0], total_repeat_length=B)
             attn = paged_decode_attention_pallas(
                 q[:, 0],
-                pool_k_l,
-                pool_v_l,
+                pool_kv.k,
+                pool_kv.v,
+                layer_idx,
                 prefix_pages,
                 gen_pages,
                 gen_phase,
@@ -1070,8 +1074,9 @@ def _block_paged(
             )
             attn = paged_decode_attention_xla(
                 q,
-                pool_k_l,
-                pool_v_l,
+                pool_kv.k,
+                pool_kv.v,
+                layer_idx,
                 prefix_idx,
                 gen_idx,
                 k,
@@ -1116,10 +1121,14 @@ def _apply_stack_paged(
     prompt and generated positions (an ``[R, P]`` prefix table is shared
     request-major like the dense shared-prefix cache; out-of-table positions
     map into the trash page and are masked). Each layer runs
-    :func:`_block_paged`, which fuses the block-table gather into attention —
-    on the Pallas path nothing dense is ever materialized; on the XLA
-    reference the gather happens INSIDE the layer scan so the transient is
-    one layer's worth, 1/L of a dense cache.
+    :func:`_block_paged` on the WHOLE pool plus its own layer number (the scan
+    body closes over the pool; only the number is scanned), which fuses the
+    block-table gather into attention — on the Pallas path nothing dense is
+    ever materialized; on the XLA reference the gather happens INSIDE the
+    layer scan so the transient is the gathered rows of one layer, 1/L of a
+    dense cache. No layer's pool is sliced out on either path: a slice ahead
+    of the attention op is a copy of ``flat * KVH * D`` elements per layer
+    per step, whatever the pages hold.
 
     Unmasked pool values are bit-identical to dense cache contents and masked
     slots contribute an exact 0.0 through the softmax (scores forced to
@@ -1147,20 +1156,13 @@ def _apply_stack_paged(
         else:
             km = jnp.where(flag, key_mask, key_mask_global)
             pm = jnp.where(flag, prefix_mask, prefix_mask_global)
-        # One layer's pool, indexed by hand where the scan would slice it as
-        # a scanned operand (the same op), so that the copy ahead of the
-        # attention carries a name on a capture.
-        with jax.named_scope("kv_slice"):
-            pool_l = tuple(
-                lax.dynamic_index_in_dim(pool, scanned["layer"], 0, keepdims=False)
-                for pool in (pool_kv.k, pool_kv.v)
-            )
         x, cols = _block_paged(
             config,
             scanned["layers"],
             x,
             positions,
-            pool_l,
+            pool_kv,
+            scanned["layer"],
             prefix_idx,
             gen_idx,
             write_index,

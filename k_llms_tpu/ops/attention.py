@@ -480,13 +480,20 @@ def gather_kv_pages(
     pool_k: jax.Array,
     pool_v: jax.Array,
     slot_idx: jax.Array,
+    layer: jax.Array,
 ) -> tuple[jax.Array, jax.Array]:
     """Block-table gather: materialize logical KV rows from a flat page pool.
 
-    pool_k/pool_v: one layer's pool, ``[total_pages * page_size, KVH, D]``;
+    pool_k/pool_v: the whole pool, ``[L, total_pages * page_size, KVH, D]``;
     slot_idx: int32 flat slot indices of any shape (typically ``[B, S]`` —
-    each row's block table expanded to per-position slots). Returns
-    ``(k, v)`` shaped ``slot_idx.shape + (KVH, D)``.
+    each row's block table expanded to per-position slots); layer: int32
+    scalar, which layer's slots to read. Returns ``(k, v)`` shaped
+    ``slot_idx.shape + (KVH, D)``.
+
+    One gather from the pool seen as ``[L * flat, KVH, D]`` (a free reshape)
+    at ``slot_idx + layer * flat``: the layer number is part of the address,
+    so no layer's pool is ever sliced out ahead of the gather (``pool[layer]``
+    first would copy ``flat * KVH * D`` elements per layer per step).
 
     Out-of-table positions point into the trash page (page 0) by convention;
     their values are arbitrary-but-finite and every consumer masks their
@@ -494,4 +501,8 @@ def gather_kv_pages(
     0.0 to the output — which is what keeps the paged attention path
     byte-identical to the dense one.
     """
-    return jnp.take(pool_k, slot_idx, axis=0), jnp.take(pool_v, slot_idx, axis=0)
+    idx = slot_idx + layer * pool_k.shape[1]
+    return tuple(
+        jnp.take(pool.reshape(-1, *pool.shape[2:]), idx, axis=0)
+        for pool in (pool_k, pool_v)
+    )

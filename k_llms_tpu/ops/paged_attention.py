@@ -14,7 +14,8 @@ column (not yet scattered into the pool) folded in at finalize.
 Two implementations, one contract:
 
 - ``paged_decode_attention_pallas``: the fused kernel. Uses scalar prefetch
-  (page tables + per-row lengths/phase) to drive the data BlockSpecs. TPU
+  (page tables, per-row lengths/phase and the layer number) to drive the
+  data BlockSpecs over the whole ``[L, ...]`` pool, read in place. TPU
   only in production; ``interpret=True`` exists for the differential tests.
 - ``paged_decode_attention_xla``: jittable pure-XLA reference with identical
   semantics — and byte-identical to the dense `_block` decode math (same op
@@ -131,6 +132,7 @@ def paged_decode_attention_xla(
     q: jax.Array,
     pool_k: jax.Array,
     pool_v: jax.Array,
+    layer: jax.Array,
     prefix_idx: jax.Array,
     gen_idx: jax.Array,
     new_k: jax.Array,
@@ -148,8 +150,10 @@ def paged_decode_attention_xla(
     """Reference paged decode attention, byte-identical to the dense path.
 
     q/new_k/new_v: this step's post-RoPE projections, ``[B, Sq, QH|KVH, D]``
-    (``Sq == 1`` on the decode hot path); pool_k/pool_v: ONE layer's flat
-    page pool ``[total_pages * page_size, KVH, D]``; prefix_idx
+    (``Sq == 1`` on the decode hot path); pool_k/pool_v: the whole flat
+    page pool ``[L, total_pages * page_size, KVH, D]`` and ``layer``, the
+    int32 scalar that says which layer's slots to read (the pool is never
+    sliced: see :func:`gather_kv_pages`); prefix_idx
     ``[B|R, P]`` / gen_idx ``[B, G]``: flat pool slots per logical position
     (an ``[R, P]`` prefix is shared request-major, exactly like the dense
     shared-prefix cache); write_index ``[B]``: each row's write offset into
@@ -172,8 +176,8 @@ def paged_decode_attention_xla(
         _softcap,
     )
 
-    pk, pv = gather_kv_pages(pool_k, pool_v, prefix_idx)  # [B|R, P, KVH, D]
-    gk, gv = gather_kv_pages(pool_k, pool_v, gen_idx)  # [B, G, KVH, D]
+    pk, pv = gather_kv_pages(pool_k, pool_v, prefix_idx, layer)  # [B|R, P, KVH, D]
+    gk, gv = gather_kv_pages(pool_k, pool_v, gen_idx, layer)  # [B, G, KVH, D]
     # The dense path's per-row cache write: the freshly computed column lands
     # at each row's own offset before attention reads it.
     row_update = jax.vmap(
@@ -270,9 +274,10 @@ def _paged_decode_kernel(
     plen_ref,  # [B] int32: valid prefix length per row
     glen_ref,  # [B] int32: generated count per row (current token excluded)
     phase_ref,  # [B] int32: in-page offset of gen position 0
+    layer_ref,  # [1] int32: the layer whose pages are read (index maps only)
     # data -------------------------------------------------------------------
     q_ref,  # [1, KVH, G, D] — one row's queries, grouped per kv head
-    k_ref,  # [1, page_size, KVH, D] — pool page tables_ref[b, j]
+    k_ref,  # [1, page_size, KVH, D] — page tables_ref[b, j] of that layer
     v_ref,  # [1, page_size, KVH, D]
     nk_ref,  # [1, KVH, D] — this step's fresh key column (not yet in pool)
     nv_ref,  # [1, KVH, D]
@@ -289,9 +294,9 @@ def _paged_decode_kernel(
 ):
     # Grid (row, page block): pages run prefix-first then gen; TPU grids
     # execute sequentially so the online-softmax scratch persists across the
-    # page axis. The block-table indirection already happened in the
-    # BlockSpec index_map — by the time this body runs, k_ref/v_ref ARE the
-    # right page.
+    # page axis. The block-table indirection, layer number included, already
+    # happened in the BlockSpec index_map — by the time this body runs,
+    # k_ref/v_ref ARE the right page.
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -362,6 +367,7 @@ def paged_decode_attention_pallas(
     q: jax.Array,
     pool_k: jax.Array,
     pool_v: jax.Array,
+    layer: jax.Array,
     prefix_pages: jax.Array,
     gen_pages: jax.Array,
     gen_phase: jax.Array,
@@ -377,9 +383,12 @@ def paged_decode_attention_pallas(
 ) -> jax.Array:
     """Fused paged decode attention (``Sq == 1``).
 
-    q: [B, QH, D]; pool_k/pool_v: one layer's flat pool
-    [total_pages * page_size, KVH, D]; prefix_pages [B|R, NP] / gen_pages
-    [B, NG] / gen_phase [B]: from :func:`paged_attention_page_tables`;
+    q: [B, QH, D]; pool_k/pool_v: the whole flat pool
+    [L, total_pages * page_size, KVH, D], read in place; layer: int32 scalar,
+    the layer whose pages the index maps address (a fifth scalar-prefetch
+    operand, so no layer's pool is sliced out for the custom call);
+    prefix_pages [B|R, NP] / gen_pages [B, NG] / gen_phase [B]: from
+    :func:`paged_attention_page_tables`;
     new_k/new_v [B, KVH, D]: this step's fresh column; prompt_lens /
     gen_lens [B]: per-row valid counts. Returns [B, QH, D] f32 — the same
     normalized output the XLA reference produces (up to online-softmax
@@ -387,7 +396,7 @@ def paged_decode_attention_pallas(
     tests). Under a multi-device ``mesh`` the kernel runs per shard: kv heads
     (and the pool, which is sharded the same way) over the model axis, rows
     and their tables over the data axis when they divide; the pool itself is
-    replicated over data.
+    replicated over data, and so is the layer number.
     """
     B = q.shape[0]
     if prefix_pages.shape[0] != B:  # [R, NP] shared prefix -> per-row table
@@ -401,39 +410,44 @@ def paged_decode_attention_pallas(
     )
     if multi_device(mesh):
         b_ax = mesh_axis(mesh, DATA_AXIS, B)
-        h_ax = mesh_axis(mesh, MODEL_AXIS, pool_k.shape[1])
-        rows, pool = P(b_ax, h_ax, None), P(None, h_ax, None)
+        h_ax = mesh_axis(mesh, MODEL_AXIS, pool_k.shape[2])
+        rows, pool = P(b_ax, h_ax, None), P(None, None, h_ax, None)
         local = shard_kernel(
             local, mesh,
             in_specs=(
-                rows, pool, pool, P(b_ax, None), P(b_ax, None), P(b_ax),
+                rows, pool, pool, P(), P(b_ax, None), P(b_ax, None), P(b_ax),
                 rows, rows, P(b_ax), P(b_ax),
             ),
             out_specs=rows,
         )
     return local(
-        q, pool_k, pool_v, prefix_pages, gen_pages, gen_phase, new_k, new_v,
-        prompt_lens, gen_lens,
+        q, pool_k, pool_v, jnp.asarray(layer, jnp.int32).reshape(1),
+        prefix_pages, gen_pages, gen_phase, new_k, new_v, prompt_lens, gen_lens,
     )
 
 
 def _paged_decode_local(
-    q, pool_k, pool_v, prefix_pages, gen_pages, gen_phase, new_k, new_v,
+    q, pool_k, pool_v, layer, prefix_pages, gen_pages, gen_phase, new_k, new_v,
     prompt_lens, gen_lens, *, page_size, sm_scale, interpret,
 ):
     """One shard's fused paged decode (the whole call on a single device)."""
     B, QH, D = q.shape
-    KVH = pool_k.shape[1]
+    L, flat, KVH = pool_k.shape[:3]
     G = QH // KVH
     ps = page_size
-    npages = pool_k.shape[0] // ps
+    npages = flat // ps
     NP = prefix_pages.shape[1]
     NG = gen_pages.shape[1]
     tables = jnp.concatenate([prefix_pages, gen_pages], axis=1).astype(jnp.int32)
 
     q4 = q.reshape(B, KVH, G, D)  # query head h*G+g shares kv head h
-    pk4 = pool_k.reshape(npages, ps, KVH, D)
-    pv4 = pool_v.reshape(npages, ps, KVH, D)
+    # Every layer's pages in one page axis (a free reshape): page p of layer
+    # l is block l * npages + p, so the custom call's operand is the pool.
+    pk4 = pool_k.reshape(L * npages, ps, KVH, D)
+    pv4 = pool_v.reshape(L * npages, ps, KVH, D)
+
+    def page_map(b, j, tables, plen, glen, phase, layer):
+        return (layer[0] * npages + tables[b, j], 0, 0, 0)
 
     kernel = functools.partial(
         _paged_decode_kernel,
@@ -443,16 +457,12 @@ def _paged_decode_local(
         kv_heads=KVH,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(B, NP + NG),
         in_specs=[
             pl.BlockSpec((1, KVH, G, D), lambda b, j, *_: (b, 0, 0, 0)),
-            pl.BlockSpec(
-                (1, ps, KVH, D), lambda b, j, tables, *_: (tables[b, j], 0, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, ps, KVH, D), lambda b, j, tables, *_: (tables[b, j], 0, 0, 0)
-            ),
+            pl.BlockSpec((1, ps, KVH, D), page_map),
+            pl.BlockSpec((1, ps, KVH, D), page_map),
             pl.BlockSpec((1, KVH, D), lambda b, j, *_: (b, 0, 0)),
             pl.BlockSpec((1, KVH, D), lambda b, j, *_: (b, 0, 0)),
         ],
@@ -474,6 +484,7 @@ def _paged_decode_local(
         prompt_lens.astype(jnp.int32),
         gen_lens.astype(jnp.int32),
         gen_phase.astype(jnp.int32),
+        layer,
         q4,
         pk4,
         pv4,

@@ -61,14 +61,17 @@ def report(name, got, ref, dtype):
         failures.append(name)
 
 
-def paged_case(dtype, continuous, mesh):
+def paged_case(dtype, continuous, mesh, layers=1, layer=0):
+    """The kernel reading ``layer`` out of a pool of ``layers`` against the XLA
+    op on that layer's slice alone."""
     plens = np.array([1, PS, 400, 1408, 131, 511, 512, 77], np.int32)
     B, G = len(plens), 256
     wis = np.array([0, 3, G - 1, 63, 64, 65, 200, 7], np.int32)
     prefix_idx, gen_idx, npages = _build_tables(plens, G, PS, continuous=continuous)
     keys = jax.random.split(jax.random.key(int(continuous)), 5)
-    pool_k = jax.random.normal(keys[0], (npages * PS, KVH, D), jnp.float32).astype(dtype)
-    pool_v = jax.random.normal(keys[1], (npages * PS, KVH, D), jnp.float32).astype(dtype)
+    pool_shape = (layers, npages * PS, KVH, D)
+    pool_k = jax.random.normal(keys[0], pool_shape, jnp.float32).astype(dtype)
+    pool_v = jax.random.normal(keys[1], pool_shape, jnp.float32).astype(dtype)
     q = jax.random.normal(keys[2], (B, 1, QH, D), jnp.float32).astype(dtype)
     nk = jax.random.normal(keys[3], (B, 1, KVH, D), jnp.float32).astype(dtype)
     nv = jax.random.normal(keys[4], (B, 1, KVH, D), jnp.float32).astype(dtype)
@@ -79,21 +82,23 @@ def paged_case(dtype, continuous, mesh):
     pidx, gidx = jnp.asarray(prefix_idx), jnp.asarray(gen_idx)
     ref = jax.jit(
         lambda *a: paged_decode_attention_xla(*a, sm_scale=SCALE)
-    )(q, pool_k, pool_v, pidx, gidx, nk, nv, jnp.asarray(wis), key_mask, prefix_mask)
+    )(q, pool_k[layer][None], pool_v[layer][None], jnp.int32(0), pidx, gidx, nk, nv,
+      jnp.asarray(wis), key_mask, prefix_mask)
 
-    def kernel(q, pool_k, pool_v, pidx, gidx, nk, nv, plens, wis):
+    def kernel(q, pool_k, pool_v, layer, pidx, gidx, nk, nv, plens, wis):
         tables = paged_attention_page_tables(pidx, gidx, PS)
         return paged_decode_attention_pallas(
-            q[:, 0], pool_k, pool_v, *tables, nk[:, 0], nv[:, 0], plens, wis,
+            q[:, 0], pool_k, pool_v, layer, *tables, nk[:, 0], nv[:, 0], plens, wis,
             page_size=PS, sm_scale=SCALE, mesh=mesh,
         )
 
     got = jax.jit(kernel)(
-        q, pool_k, pool_v, pidx, gidx, nk, nv, jnp.asarray(plens), jnp.asarray(wis)
+        q, pool_k, pool_v, jnp.int32(layer), pidx, gidx, nk, nv,
+        jnp.asarray(plens), jnp.asarray(wis),
     )
     layout = "continuous" if continuous else "coalesced"
-    report(f"paged decode {layout} {jnp.dtype(dtype).name} {mesh_name(mesh)}",
-           got, ref[:, 0], dtype)
+    report(f"paged decode {layout} layer {layer} of {layers} "
+           f"{jnp.dtype(dtype).name} {mesh_name(mesh)}", got, ref[:, 0], dtype)
 
 
 def flash_case(dtype, mesh):
@@ -201,6 +206,7 @@ def main():
             with jax.default_matmul_precision("highest" if dtype == jnp.float32 else "default"):
                 for continuous in (False, True):
                     paged_case(dtype, continuous, mesh)
+                paged_case(dtype, True, mesh, layers=2, layer=1)
                 flash_case(dtype, mesh)
     if "--skip-model" not in sys.argv[1:]:
         greedy_model_case()

@@ -100,13 +100,22 @@ def _build_tables(plens, G, ps, *, continuous):
     return prefix_idx, gen_idx, next_page
 
 
+# Both ops take the whole [L, flat, KVH, D] pool and a layer number. The op
+# tests build L = 3 layers of different contents and read the first and the
+# last, against the XLA op on that layer's slice alone.
+POOL_LAYERS = 3
+LAYERS = [0, 2]
+
+
 @pytest.mark.parametrize("page_size", PAGE_SIZES)
 @pytest.mark.parametrize("continuous", [False, True])
-def test_op_pallas_interpret_matches_xla(page_size, continuous):
+@pytest.mark.parametrize("layer", LAYERS)
+def test_op_pallas_interpret_matches_xla(page_size, continuous, layer):
     """Ragged prompt/gen lengths, every page-boundary alignment class
     (mid-page, exact multiple, single-slot), trash garbage in the pool:
     the fused kernel must agree with the reference on both the coalesced
-    (phase 0) and continuous (phase-shifted) gen layouts."""
+    (phase 0) and continuous (phase-shifted) gen layouts — reading its layer
+    out of the whole pool, as the reference does bit for bit."""
     ps = page_size
     B, G = 4, 12
     QH, KVH, D = 4, 2, 16
@@ -124,8 +133,9 @@ def test_op_pallas_interpret_matches_xla(page_size, continuous):
         np.testing.assert_array_equal(np.asarray(phase), expect_phase)
 
     keys = jax.random.split(jax.random.key(ps + int(continuous)), 5)
-    pool_k = jax.random.normal(keys[0], (npages * ps, KVH, D), jnp.float32)
-    pool_v = jax.random.normal(keys[1], (npages * ps, KVH, D), jnp.float32)
+    pool_shape = (POOL_LAYERS, npages * ps, KVH, D)
+    pool_k = jax.random.normal(keys[0], pool_shape, jnp.float32)
+    pool_v = jax.random.normal(keys[1], pool_shape, jnp.float32)
     q = jax.random.normal(keys[2], (B, 1, QH, D), jnp.float32)
     nk = jax.random.normal(keys[3], (B, 1, KVH, D), jnp.float32)
     nv = jax.random.normal(keys[4], (B, 1, KVH, D), jnp.float32)
@@ -136,17 +146,23 @@ def test_op_pallas_interpret_matches_xla(page_size, continuous):
     c = np.arange(prefix_idx.shape[1])[None, None, :]
     prefix_mask = jnp.asarray(c < plens[:, None, None])
 
-    out_x = paged_decode_attention_xla(
-        q, pool_k, pool_v,
-        jnp.asarray(prefix_idx), jnp.asarray(gen_idx),
-        nk, nv, jnp.asarray(wis), key_mask, prefix_mask,
-        sm_scale=sm_scale,
+    def xla_op(pk, pv, l):
+        return paged_decode_attention_xla(
+            q, pk, pv, jnp.int32(l),
+            jnp.asarray(prefix_idx), jnp.asarray(gen_idx),
+            nk, nv, jnp.asarray(wis), key_mask, prefix_mask,
+            sm_scale=sm_scale,
+        )
+
+    out_x = xla_op(pool_k[layer][None], pool_v[layer][None], 0)
+    np.testing.assert_array_equal(
+        np.asarray(xla_op(pool_k, pool_v, layer)), np.asarray(out_x)
     )
     tables = paged_attention_page_tables(
         jnp.asarray(prefix_idx), jnp.asarray(gen_idx), ps
     )
     out_p = paged_decode_attention_pallas(
-        q[:, 0], pool_k, pool_v, *tables, nk[:, 0], nv[:, 0],
+        q[:, 0], pool_k, pool_v, jnp.int32(layer), *tables, nk[:, 0], nv[:, 0],
         jnp.asarray(plens), jnp.asarray(wis),
         page_size=ps, sm_scale=sm_scale, interpret=True,
     )
@@ -155,19 +171,16 @@ def test_op_pallas_interpret_matches_xla(page_size, continuous):
     )
 
 
-def test_op_shared_prefix_table_broadcasts():
-    """An [R, P] request-major prefix table (the engine's shared-prefix
-    layout) must produce the same kernel output as the explicitly repeated
-    [B, P] per-row table."""
+def _shared_prefix_case():
+    """B = 4 rows of R = 2 requests over a 3-layer pool: request-major
+    ``[R, P]`` and repeated ``[B, P]`` prefix tables, fresh gen pages per row."""
     ps = 8
     B, R, G = 4, 2, 8
     QH, KVH, D = 4, 2, 16
     plens_req = np.array([ps + 3, 2 * ps], np.int32)
-    plens_row = np.repeat(plens_req, B // R)
     wis = np.array([0, 2, 5, 7], np.int32)
 
     prefix_req, _, npages0 = _build_tables(plens_req, 1, ps, continuous=False)
-    prefix_row = np.repeat(prefix_req, B // R, axis=0)
     # Fresh gen pages per row, past the prompt pages.
     gen_idx = np.empty((B, G), np.int32)
     next_page = npages0
@@ -178,28 +191,78 @@ def test_op_shared_prefix_table_broadcasts():
             gen_idx[b, g] = gpages[g // ps] * ps + g % ps
 
     keys = jax.random.split(jax.random.key(42), 5)
-    pool_k = jax.random.normal(keys[0], (next_page * ps, KVH, D), jnp.float32)
-    pool_v = jax.random.normal(keys[1], (next_page * ps, KVH, D), jnp.float32)
-    q = jax.random.normal(keys[2], (B, QH, D), jnp.float32)
-    nk = jax.random.normal(keys[3], (B, KVH, D), jnp.float32)
-    nv = jax.random.normal(keys[4], (B, KVH, D), jnp.float32)
-    sm_scale = 1.0 / math.sqrt(D)
+    pool_shape = (POOL_LAYERS, next_page * ps, KVH, D)
+    return dict(
+        ps=ps,
+        prefix_req=prefix_req,
+        prefix_row=np.repeat(prefix_req, B // R, axis=0),
+        gen_idx=gen_idx,
+        plens_row=np.repeat(plens_req, B // R),
+        wis=wis,
+        pool_k=jax.random.normal(keys[0], pool_shape, jnp.float32),
+        pool_v=jax.random.normal(keys[1], pool_shape, jnp.float32),
+        q=jax.random.normal(keys[2], (B, QH, D), jnp.float32),
+        nk=jax.random.normal(keys[3], (B, KVH, D), jnp.float32),
+        nv=jax.random.normal(keys[4], (B, KVH, D), jnp.float32),
+        sm_scale=1.0 / math.sqrt(D),
+    )
 
-    outs = []
-    for table in (prefix_req, prefix_row):
-        tables = paged_attention_page_tables(
-            jnp.asarray(table), jnp.asarray(gen_idx), ps
+
+def _kernel_on(case, table, layer, mesh=None):
+    tables = paged_attention_page_tables(
+        jnp.asarray(table), jnp.asarray(case["gen_idx"]), case["ps"]
+    )
+    return np.asarray(
+        paged_decode_attention_pallas(
+            case["q"], case["pool_k"], case["pool_v"], jnp.int32(layer), *tables,
+            case["nk"], case["nv"],
+            jnp.asarray(case["plens_row"]), jnp.asarray(case["wis"]),
+            page_size=case["ps"], sm_scale=case["sm_scale"], interpret=True,
+            mesh=mesh,
         )
-        outs.append(
-            np.asarray(
-                paged_decode_attention_pallas(
-                    q, pool_k, pool_v, *tables, nk, nv,
-                    jnp.asarray(plens_row), jnp.asarray(wis),
-                    page_size=ps, sm_scale=sm_scale, interpret=True,
-                )
-            )
-        )
-    np.testing.assert_array_equal(outs[0], outs[1])
+    )
+
+
+@pytest.mark.parametrize("layer", LAYERS)
+def test_op_shared_prefix_table_broadcasts(layer):
+    """An [R, P] request-major prefix table (the engine's shared-prefix
+    layout) must produce the same kernel output as the explicitly repeated
+    [B, P] per-row table — and that output is the XLA op's on the layer's
+    slice alone."""
+    case = _shared_prefix_case()
+    out = _kernel_on(case, case["prefix_req"], layer)
+    np.testing.assert_array_equal(
+        out, _kernel_on(case, case["prefix_row"], layer)
+    )
+
+    wis, plens_row = case["wis"], case["plens_row"]
+    s = np.arange(case["gen_idx"].shape[1])[None, None, :]
+    c = np.arange(case["prefix_req"].shape[1])[None, None, :]
+    out_x = paged_decode_attention_xla(
+        case["q"][:, None],
+        case["pool_k"][layer][None], case["pool_v"][layer][None], jnp.int32(0),
+        jnp.asarray(case["prefix_req"]), jnp.asarray(case["gen_idx"]),
+        case["nk"][:, None], case["nv"][:, None], jnp.asarray(wis),
+        jnp.asarray(s <= wis[:, None, None]),
+        jnp.asarray(c < plens_row[:, None, None]),
+        sm_scale=case["sm_scale"],
+    )
+    np.testing.assert_allclose(out, np.asarray(out_x[:, 0]), rtol=2e-5, atol=2e-6)
+
+
+def test_op_under_mesh_reads_its_layer_per_shard():
+    """Under a data x model mesh the kernel runs per shard: rows over data,
+    kv heads (of the pool too) over model, the pool's layer axis whole and the
+    layer number replicated. Every (row, kv head) is computed alone, so the
+    result is the single-device one bit for bit."""
+    from k_llms_tpu.parallel.mesh import make_mesh
+
+    case = _shared_prefix_case()
+    layer = LAYERS[-1]
+    np.testing.assert_array_equal(
+        _kernel_on(case, case["prefix_req"], layer, mesh=make_mesh(2, 2)),
+        _kernel_on(case, case["prefix_req"], layer),
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -364,6 +427,48 @@ def test_step_cow_forked_table_is_invisible():
         )
         outs.append(np.asarray(logits_p))
     np.testing.assert_array_equal(outs[0], outs[1])
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (scan and kernel bodies) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas_interpret"])
+def test_step_never_slices_one_layers_pool(attn_impl):
+    """The pool is addressed by (layer, slot) where it is read: no equation of
+    the step, the layer scan's body included, may produce one layer's pool
+    (``[flat, KVH, D]`` or ``[1, flat, KVH, D]``). On the chip such a slice
+    ahead of the attention op is a copy of the layer's whole pool, every
+    layer, every decode step."""
+    ps = 8
+    params = _params()
+    tokens, lengths, plens, _, paged = _step_case(ps)
+    layer_pool = paged["pool_kv"].k.shape[1:]
+
+    def step(pool_kv):
+        return paged_verify_step(
+            CONFIG, params, tokens, lengths, plens,
+            pool_kv, paged["prefix_idx"], paged["gen_idx"],
+            attn_impl=attn_impl, page_size=ps,
+        )
+
+    closed = jax.make_jaxpr(step)(paged["pool_kv"])
+    equations = list(_equations(closed.jaxpr))
+    assert any(eqn.primitive.name == "scan" for eqn in equations)
+    slices = [
+        f"{eqn.primitive.name} -> {var.aval.str_short()}"
+        for eqn in equations
+        for var in eqn.outvars
+        if getattr(var.aval, "shape", None) in (layer_pool, (1, *layer_pool))
+    ]
+    assert not slices, slices
 
 
 # ---------------------------------------------------------------------------
