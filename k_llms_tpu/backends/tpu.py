@@ -298,7 +298,7 @@ class HbmMemoryModel:
     Per-device footprint of an R-row decode at sequence length S:
 
         params / tp                               (weights, sharded over TP)
-      + (R / dp) * S * kv_bytes_per_token / tp    (KV cache; heads shard TP,
+      + (R / dp) * S * kv_bytes_per_token / tp    (cache rows; heads shard TP,
                                                    rows shard DP)
       + (R / dp) * row_margin                     (logits f32 + sampling state)
 
@@ -306,7 +306,13 @@ class HbmMemoryModel:
     scheduler may coalesce to for a given request shape. Deliberately
     conservative and static — it exists to keep the FIRST launch from
     exceeding HBM; the engine's OOM guard (split-and-requeue) catches what
-    the model underestimates."""
+    the model underestimates.
+
+    ``kv_bytes_per_token`` is the model's own (``ModelConfig``): K and V over
+    every layer's KV heads for the GQA block, one ``kv_lora_rank +
+    qk_rope_head_dim`` latent row a layer and no V for a latent (MLA) model
+    (8,064 B a token for the 7-layer Xing4.0 cut against qwen2-7b's
+    57,344)."""
 
     def __init__(
         self,
@@ -325,10 +331,9 @@ class HbmMemoryModel:
         self.headroom = float(headroom)
         self.tp = max(1, int(tp))
         self.dp = max(1, int(dp))
-        itemsize = np.dtype(config.jax_dtype).itemsize
-        # K and V, every layer, kv_dim features per token; KV heads shard
-        # over the model axis with the attention that consumes them.
-        self.kv_bytes_per_token = 2 * config.num_layers * config.kv_dim * itemsize
+        # Every layer's cache row for one token; KV heads shard over the
+        # model axis with the attention that consumes them.
+        self.kv_bytes_per_token = config.kv_bytes_per_token
         # Per-row non-KV working set: the decode loop materializes f32 logits
         # and sampling buffers per row; 4 bytes * vocab is the dominant term.
         self.row_margin_bytes = 4 * config.vocab_size + (64 << 10)

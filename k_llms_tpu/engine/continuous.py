@@ -82,6 +82,7 @@ from ..utils.observability import (
     LATENCY,
     RECOVERY_EVENTS,
     current_trace,
+    note_model_aux,
 )
 from .engine import (
     GenerationResult,
@@ -812,9 +813,13 @@ class ContinuousDecodeLoop:
                   seeds, sample_idx, temps, top_ps, poison):
             # One token for all W slots: write cur's KV at each row's own
             # offset (gen_lens), attend row-local prefix + generated KV.
+            # ``aux``: what the model's stack counts (router loads, cache
+            # rows read: utils/observability.py::note_model_aux adds them at
+            # readback); empty for a model that counts nothing.
+            aux: Dict[str, Any] = {}
             logits, gen = verify_step(
                 config, params, cur[:, None], gen_lens, prompt_lens, gen, prefix,
-                mesh=mesh,
+                mesh=mesh, aux=aux,
             )
             logits = _mask_pad(logits[:, 0, :])
             logits = jnp.where(poison[:, None], jnp.float32(jnp.nan), logits)
@@ -822,7 +827,7 @@ class ContinuousDecodeLoop:
             tok, lp, bad = _sample_rows(logits, keys, temps, top_ps)
             tok = jnp.where(active, tok, jnp.int32(pad_id))
             lp = jnp.where(active, lp, 0.0)
-            return tok, lp, bad & active, gen
+            return tok, lp, bad & active, gen, aux
 
         # gen KV is donated: the loop is its only owner and it is re-passed
         # every step, so the update happens in place on device.
@@ -854,12 +859,13 @@ class ContinuousDecodeLoop:
             # gathers into the shared pool and write cur's column back at a
             # host-computed flat slot. Same masks, same sampler, same key
             # schedule — byte-identical tokens to the dense loop.
+            aux: Dict[str, Any] = {}
             logits, k_cols, v_cols = paged_verify_step(
                 config, params, cur[:, None], gen_lens, prompt_lens,
                 KVCache(k=pool_k, v=pool_v), prefix_idx, gen_idx,
                 attn_impl=self._paged_attn_impl,
                 page_size=self._pool.page_size,
-                mesh=mesh,
+                mesh=mesh, aux=aux,
             )
             with jax.named_scope("kv_write"):
                 pool_k = pool_k.at[:, write_idx].set(k_cols.astype(pool_k.dtype))
@@ -870,7 +876,7 @@ class ContinuousDecodeLoop:
             tok, lp, bad = _sample_rows(logits, keys, temps, top_ps)
             tok = jnp.where(active, tok, jnp.int32(pad_id))
             lp = jnp.where(active, lp, 0.0)
-            return tok, lp, bad & active, pool_k, pool_v
+            return tok, lp, bad & active, pool_k, pool_v, aux
 
         self._step_paged_fn = jax.jit(_step_paged, donate_argnums=(1, 2))
         # Raw sampler pieces, reused by the grammar-twin programs so masked
@@ -960,9 +966,10 @@ class ContinuousDecodeLoop:
         def _step_g(params, prefix, gen, cur, gen_lens, prompt_lens, active,
                     seeds, sample_idx, temps, top_ps, poison, g_states,
                     g_flags, *tabs):
+            aux: Dict[str, Any] = {}
             logits, gen = verify_step(
                 config, params, cur[:, None], gen_lens, prompt_lens, gen, prefix,
-                mesh=mesh,
+                mesh=mesh, aux=aux,
             )
             # Poison is injected BEFORE the grammar mask: NaNs survive the
             # mask's allowed positions, so detection sees them either way.
@@ -974,17 +981,18 @@ class ContinuousDecodeLoop:
             tok, lp, bad = sample_rows(logits, keys, temps, top_ps)
             tok = jnp.where(active, tok, jnp.int32(pad_id))
             lp = jnp.where(active, lp, 0.0)
-            return tok, lp, bad & active, gen, _advance(tok, g_states, g_flags, tabs)
+            return tok, lp, bad & active, gen, _advance(tok, g_states, g_flags, tabs), aux
 
         def _step_paged_g(params, pool_k, pool_v, cur, gen_lens, prompt_lens,
                           active, seeds, sample_idx, temps, top_ps, prefix_idx,
                           gen_idx, write_idx, poison, g_states, g_flags, *tabs):
+            aux: Dict[str, Any] = {}
             logits, k_cols, v_cols = paged_verify_step(
                 config, params, cur[:, None], gen_lens, prompt_lens,
                 KVCache(k=pool_k, v=pool_v), prefix_idx, gen_idx,
                 attn_impl=self._paged_attn_impl,
                 page_size=self._pool.page_size,
-                mesh=mesh,
+                mesh=mesh, aux=aux,
             )
             with jax.named_scope("kv_write"):
                 pool_k = pool_k.at[:, write_idx].set(k_cols.astype(pool_k.dtype))
@@ -999,7 +1007,7 @@ class ContinuousDecodeLoop:
             lp = jnp.where(active, lp, 0.0)
             return tok, lp, bad & active, pool_k, pool_v, _advance(
                 tok, g_states, g_flags, tabs
-            )
+            ), aux
 
         fns = {
             "admit": jax.jit(_admit_g),
@@ -1708,7 +1716,7 @@ class ContinuousDecodeLoop:
             note_device_dispatch("continuous prefill chunk")
             with LATENCY.span("continuous.dispatch", chunk=chunk_no):
                 if self.paged:
-                    logits, new_cache, k_cols, v_cols = fn(
+                    logits, new_cache, k_cols, v_cols, aux = fn(
                         self.engine.params, jnp.asarray(chunk), cache,
                         jnp.int32(start), jnp.int32(valid),
                     )
@@ -1716,7 +1724,7 @@ class ContinuousDecodeLoop:
                         raise _StaleStep("prefill chunk fenced post-dispatch")
                     pool.scatter_tokens(k_cols, v_cols, slot_idx)
                 else:
-                    logits, new_cache = fn(
+                    logits, new_cache, aux = fn(
                         self.engine.params, jnp.asarray(chunk), cache,
                         jnp.int32(start), jnp.int32(valid),
                     )
@@ -1726,8 +1734,9 @@ class ContinuousDecodeLoop:
             # budget covers the device work, like the step's readback.
             with LATENCY.span("continuous.readback"):
                 # kllms: ignore[host-sync-hot-path] — the per-chunk completion sync; the cache stays on device
-                jax.device_get(logits)
+                _, aux = jax.device_get((logits, aux))
             self._results_at = time.perf_counter()
+            note_model_aux(aux)
             return logits, new_cache
 
         # Deliberately NOT fed to observe_step: a C-token chunk would pollute
@@ -2020,14 +2029,14 @@ class ContinuousDecodeLoop:
                     note_device_dispatch("continuous paged step")
                     with LATENCY.span("continuous.dispatch", step=step_no):
                         if n_masked:
-                            tok, lp, bad, new_k, new_v, new_g = g_fns["step_paged"](
+                            tok, lp, bad, new_k, new_v, new_g, aux = g_fns["step_paged"](
                                 self.engine.params, pool.kv.k, pool.kv.v, cur,
                                 gen_lens, prompt_lens, active, seeds, sidx,
                                 temps, tps, pidx, gidx, write_idx, poison,
                                 g_states, g_flags, *g_tabs,
                             )
                         else:
-                            tok, lp, bad, new_k, new_v = self._step_paged_fn(
+                            tok, lp, bad, new_k, new_v, aux = self._step_paged_fn(
                                 self.engine.params, pool.kv.k, pool.kv.v, cur,
                                 gen_lens, prompt_lens, active, seeds, sidx,
                                 temps, tps, pidx, gidx, write_idx, poison,
@@ -2040,13 +2049,13 @@ class ContinuousDecodeLoop:
                 note_device_dispatch("continuous dense step")
                 with LATENCY.span("continuous.dispatch", step=step_no):
                     if n_masked:
-                        tok, lp, bad, gen, new_g = g_fns["step"](
+                        tok, lp, bad, gen, new_g, aux = g_fns["step"](
                             self.engine.params, self._prefix, self._gen, cur,
                             gen_lens, prompt_lens, active, seeds, sidx, temps,
                             tps, poison, g_states, g_flags, *g_tabs,
                         )
                     else:
-                        tok, lp, bad, gen = self._step_fn(
+                        tok, lp, bad, gen, aux = self._step_fn(
                             self.engine.params, self._prefix, self._gen, cur,
                             gen_lens, prompt_lens, active, seeds, sidx, temps,
                             tps, poison,
@@ -2063,8 +2072,9 @@ class ContinuousDecodeLoop:
             outs = (tok, lp, bad) if new_g is None else (tok, lp, bad, new_g)
             with LATENCY.span("continuous.readback"):
                 # kllms: ignore[host-sync-hot-path] — the per-step result readback; everything after it is host-side bookkeeping
-                fetched = jax.device_get(outs)
+                fetched, aux = jax.device_get((outs, aux))
             self._results_at = time.perf_counter()
+            note_model_aux(aux)
             return list(map(np.asarray, fetched))
 
         # Host wall time for the dispatched step (includes the by-design

@@ -306,6 +306,26 @@ class LocalEngine:
         self.mesh = mesh
         if quantize is True:
             quantize = "int8"
+        if self.config.is_latent:
+            # What the latent block (models/latent.py) cannot do yet fails
+            # here, by name, before anything is built; nothing falls back.
+            refused = [
+                what for what, asked in (
+                    (f"a device mesh ({len(jax.devices())} devices; build the "
+                     "engine with use_mesh=False or give the process one device)",
+                     mesh is not None),
+                    (f"quantize={quantize!r} (int8/int4 expert stacks)", bool(quantize)),
+                    ("sp_prefill_min_tokens (sequence-parallel prefill)",
+                     sp_prefill_min_tokens is not None),
+                    (f"speculative={speculative!r} (the dense-cache speculation path)",
+                     speculative is not None),
+                ) if asked
+            ]
+            if refused:
+                raise NotImplementedError(
+                    f"{self.config.name}: the latent block is not implemented for "
+                    + "; ".join(refused)
+                )
         if params is not None and not quantize:
             # A PRE-quantized checkpoint passed with quantize unset must still
             # route through the quantized spec/partitioning machinery: the
@@ -359,7 +379,9 @@ class LocalEngine:
         self.quantized = quantize
         bits = 4 if quantize == "int4" else 8
 
-        pspecs = param_specs(self.config)
+        # Specs are needed to place or quantize a tree; a latent model has none
+        # yet (parallel/sharding.py refuses) and was held to neither above.
+        pspecs = param_specs(self.config) if (mesh is not None or quantize) else None
         if quantize:
             from ..models.quant import quantize_params, quantized_param_specs
 
@@ -811,10 +833,13 @@ class LocalEngine:
             step = prefill_chunk_step_paged if paged else prefill_chunk_step
 
             def _chunk(params, chunk_tokens, cache, cursor, valid_len):
+                # Last output: what the model's stack counted (see the loop's
+                # step programs); an empty dict for most models.
+                aux: Dict[str, Any] = {}
                 return step(
                     self.config, params, chunk_tokens, cache, cursor, valid_len,
-                    mesh=self.mesh,
-                )
+                    mesh=self.mesh, aux=aux,
+                ) + (aux,)
 
             if self.mesh is not None:
                 kv_sh = KVCache(
@@ -826,9 +851,9 @@ class LocalEngine:
                     # Chunk KV columns [L, C, KVH, D]: heads shard tp, like
                     # the pool they are scattered into.
                     cols_sh = NamedSharding(self.mesh, P(None, None, MODEL_AXIS, None))
-                    out_shardings = (logits_sh, kv_sh, cols_sh, cols_sh)
+                    out_shardings = (logits_sh, kv_sh, cols_sh, cols_sh, {})
                 else:
-                    out_shardings = (logits_sh, kv_sh)
+                    out_shardings = (logits_sh, kv_sh, {})
                 fn = jax.jit(_chunk, out_shardings=out_shardings, donate_argnums=(2,))
             else:
                 fn = jax.jit(_chunk, donate_argnums=(2,))

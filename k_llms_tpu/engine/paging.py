@@ -12,7 +12,12 @@ portion of the sequence at the same HBM budget.
 
 Layout. The device pool is one flat pair of arrays ``[L, pages * page_size,
 kv_heads, head_dim]`` (kv-head axis sharded over the existing tp mesh axis,
-like every other KV buffer here). A *block table* is a host-side list of page
+like every other KV buffer here). What a page row holds is the model's
+(``ModelConfig.cache_widths``): K and V per KV head for the GQA block; for a
+latent (MLA) model one ``[c_kv | k_rope]`` row in ``k``
+(``[L, flat, 1, kv_lora_rank + qk_rope_head_dim]``) and a ``v`` of width 0,
+so the movers below move the pair as always and no V bytes exist. A *block
+table* is a host-side list of page
 ids per logical row; attention consumes it as flat slot indices
 ``page_id * page_size + offset`` through a plain gather
 (``ops/attention.gather_kv_pages``). Gathered garbage in masked slots is
@@ -273,7 +278,9 @@ def flat_slots(pages: Sequence[int], positions: np.ndarray, page_size: int) -> n
 class PagedKVPool:
     """The device-side page pool plus its jitted data movers.
 
-    ``kv.k`` / ``kv.v``: ``[L, total_pages * page_size, kv_heads, head_dim]``.
+    ``kv.k`` / ``kv.v``: ``[L, total_pages * page_size, heads, width]`` with
+    (heads, k width, v width) from ``config.cache_widths`` — K and V per KV
+    head, or one latent row and an empty V.
     All device ops that consume-and-replace the pool buffers (scatter, copy)
     dispatch under ``self.lock`` and swap ``self.kv`` atomically, so the
     continuous-loop worker and the scheduler threads never race a donated
@@ -293,9 +300,12 @@ class PagedKVPool:
         # self.kv swaps atomically with the donated buffers it replaces.
         self.lock = make_rlock("engine.kv_pool", allow_dispatch=True)
         flat = int(total_pages) * int(page_size)
-        shape = (config.num_layers, flat, config.num_kv_heads, config.head_dim)
+        heads, k_width, v_width = config.cache_widths
+        shape = (config.num_layers, flat, heads)
         dtype = dtype or config.jax_dtype
-        self.kv = KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+        self.kv = KVCache(
+            k=jnp.zeros(shape + (k_width,), dtype), v=jnp.zeros(shape + (v_width,), dtype)
+        )
         self._scatter_cache: Dict[Any, Any] = {}
         self._gather_cache: Dict[Any, Any] = {}
         self._copy_cache: Dict[Any, Any] = {}
@@ -306,7 +316,7 @@ class PagedKVPool:
 
     def pool_bytes(self) -> int:
         with self.lock:
-            return 2 * int(np.prod(self.kv.k.shape)) * self.kv.k.dtype.itemsize
+            return int(self.kv.k.nbytes) + int(self.kv.v.nbytes)
 
     # -- jitted movers -----------------------------------------------------
 

@@ -7,6 +7,7 @@ single-v5e-chip bench model (8B bf16 weights alone exceed one chip's 16 GB HBM â
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 from typing import Dict
 
@@ -29,7 +30,12 @@ class ModelConfig:
     # None = vanilla RoPE. Long wavelengths (past original_max/low_freq)
     # divide by factor, short ones keep, the band between interpolates â€”
     # matching HF's rope_type="llama3".
-    rope_scaling: "tuple[float, float, float, int] | None" = None
+    # A tuple that starts with "yarn" is YaRN instead (DeepSeek-V3 form):
+    # ("yarn", factor, original_max_position, beta_fast, beta_slow,
+    # mscale_all_dim) â€” per-pair frequencies blend plain and factor-divided
+    # ones over the betas' correction range; the attention scale gains
+    # mscale(factor, mscale_all_dim)^2 (see :attr:`attn_scale`).
+    rope_scaling: "tuple | None" = None
     rms_eps: float = 1e-5
     max_seq_len: int = 8192
     dtype: str = "bfloat16"
@@ -77,6 +83,30 @@ class ModelConfig:
     # experts with top-k token-choice routing. 0 = dense MLP.
     num_experts: int = 0
     num_experts_per_tok: int = 2
+    # Latent attention (MLA; models/latent.py): kv_lora_rank > 0 switches the
+    # whole block. Queries go through a q_lora_rank bottleneck to num_heads
+    # heads of [qk_nope_head_dim | qk_rope_head_dim]; keys and values are one
+    # kv_lora_rank latent plus one shared rope key a token â€” the only thing
+    # the cache holds (num_kv_heads and head_dim are unused). The same block
+    # carries the routed-expert layer (sigmoid "noaux_tc" router: top-k of
+    # score + bias, weights from the scores, times routed_scaling_factor; the
+    # first first_k_dense layers keep a dense MLP of intermediate_size, the
+    # rest run num_experts experts of moe_intermediate_size plus
+    # n_shared_experts always-on ones) and hyper-connections (hc_mult residual
+    # streams mixed per sublayer by a Sinkhorn-normalised matrix).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    first_k_dense: int = 0
+    routed_scaling_factor: float = 1.0
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
     # byte tokenizer vocab fits any vocab_size >= 260; HF tokenizers use the full space
     bos_token_id: int = 256
     eos_token_id: int = 257
@@ -93,6 +123,41 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def cache_widths(self) -> "tuple[int, int, int]":
+        """(heads, k width, v width) of one token's cache row in one layer.
+        A latent model caches one [c_kv | k_rope] row and no V: its ``v``
+        arrays exist with width 0, so every mover of a (k, v) pair still
+        works and moves no bytes for it."""
+        if self.is_latent:
+            return 1, self.kv_lora_rank + self.qk_rope_head_dim, 0
+        return self.num_kv_heads, self.head_dim, self.head_dim
+
+    @property
+    def kv_bytes_per_token(self) -> int:
+        """Cache bytes one token holds over all layers, in the model dtype."""
+        heads, k_width, v_width = self.cache_widths
+        return self.num_layers * heads * (k_width + v_width) * self.jax_dtype.itemsize
+
+    @property
+    def attn_scale(self) -> float:
+        """Attention score scale: ``query_scale`` if set, else 1/sqrt(qk
+        width), times YaRN's mscale(factor, mscale_all_dim)^2."""
+        if self.query_scale is not None:
+            return self.query_scale
+        if not self.is_latent:
+            return 1.0 / math.sqrt(self.head_dim)
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        if self.rope_scaling is not None and self.rope_scaling[0] == "yarn":
+            _, factor, _, _, _, all_dim = self.rope_scaling
+            if factor > 1 and all_dim:
+                scale *= (0.1 * all_dim * math.log(factor) + 1.0) ** 2
+        return scale
 
     def with_(self, **kw) -> "ModelConfig":
         return replace(self, **kw)
@@ -307,6 +372,74 @@ register_config(
         bos_token_id=1,
         eos_token_id=2,
         pad_token_id=2,
+    )
+)
+
+# Xing4.0-29B-A4B (https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B):
+# MLA + 64 routed experts (top-4, sigmoid noaux_tc) with one shared expert +
+# 4 hyper-connected residual streams + YaRN. Published in bfloat16; served so
+# (int8/int4, a mesh, sequence-parallel prefill and speculation are refused at
+# build time by name). The published preset is for shape arithmetic and the
+# loader's refusal: 29.5 B parameters do not fit a chip. ``-cut7`` is the
+# one-chip cut the benchmark serves: depth only â€” 1 leading dense layer and 6
+# of the 38 expert layers, every width, all 64 experts and the whole
+# vocabulary as published (benchmark/configs/xing4-29b-a4b.json has the
+# arithmetic and what is assumed). The next-token module is not built.
+_XING4 = ModelConfig(
+    name="xing4-29b-a4b",
+    vocab_size=131072,
+    hidden_size=3584,
+    intermediate_size=9216,
+    num_layers=40,
+    num_heads=32,
+    num_kv_heads=32,
+    head_dim=192,  # unused by the latent block; the qk width, for readers
+    rope_theta=10000.0,
+    rope_scaling=("yarn", 64.0, 4096, 32.0, 1.0, 1.0),
+    rms_eps=1e-6,
+    max_seq_len=8192,  # served context; the config declares 262,144
+    num_experts=64,
+    num_experts_per_tok=4,
+    q_lora_rank=768,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    moe_intermediate_size=1024,
+    n_shared_experts=1,
+    first_k_dense=2,
+    routed_scaling_factor=2.0,
+    hc_mult=4,
+    hc_sinkhorn_iters=20,
+    hc_eps=1e-6,
+    hc_res_clamp=30.0,
+)
+register_config(_XING4)
+register_config(_XING4.with_(name="xing4-29b-a4b-cut7", num_layers=7, first_k_dense=1))
+# CPU test size of the same block: 1 dense + 2 expert layers, 8 experts top-2,
+# the published ratios of the head widths (nope : rope : v = 2 : 1 : 2).
+register_config(
+    _XING4.with_(
+        name="xing4-tiny",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=160,
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=24,
+        rope_scaling=("yarn", 64.0, 64, 32.0, 1.0, 1.0),
+        max_seq_len=4096,
+        dtype="float32",
+        num_experts=8,
+        num_experts_per_tok=2,
+        q_lora_rank=24,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        moe_intermediate_size=32,
+        first_k_dense=1,
     )
 )
 

@@ -47,8 +47,9 @@ class KVCache(NamedTuple):
 
 def init_cache(config: ModelConfig, batch: int, max_len: int, dtype=None) -> KVCache:
     dtype = dtype or config.jax_dtype
-    shape = (config.num_layers, batch, max_len, config.num_kv_heads, config.head_dim)
-    return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+    heads, k_width, v_width = config.cache_widths
+    shape = (config.num_layers, batch, max_len, heads)
+    return KVCache(k=jnp.zeros(shape + (k_width,), dtype), v=jnp.zeros(shape + (v_width,), dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -58,6 +59,10 @@ def init_cache(config: ModelConfig, batch: int, max_len: int, dtype=None) -> KVC
 def init_params(config: ModelConfig, key: jax.Array, dtype=None) -> Params:
     """Random (scaled-normal) initialization; real checkpoints come from
     k_llms_tpu.models.loader."""
+    if config.is_latent:
+        from . import latent
+
+        return latent.init_params(config, key, dtype)
     dtype = dtype or config.jax_dtype
     H, I, V = config.hidden_size, config.intermediate_size, config.vocab_size
     L, Q, KV = config.num_layers, config.q_dim, config.kv_dim
@@ -154,6 +159,24 @@ def _rope_inv_freq(d: int, theta: float, scaling) -> jax.Array:
     inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     if scaling is None:
         return inv_freq
+    if scaling[0] == "yarn":
+        # YaRN (DeepSeek-V3 form): pairs that turn more than beta_fast times
+        # within the original context keep their frequency, those that turn
+        # fewer than beta_slow times divide it by ``factor``, a linear ramp
+        # over the pair index between. cos/sin carry mscale/mscale_all_dim,
+        # which is 1 for every registered model; the score scale carries
+        # mscale^2 (ModelConfig.attn_scale).
+        _, factor, orig_ctx, beta_fast, beta_slow, _ = scaling
+
+        def correction_dim(rotations):
+            return d * math.log(orig_ctx / (rotations * 2.0 * math.pi)) / (2.0 * math.log(theta))
+
+        low = max(math.floor(correction_dim(beta_fast)), 0)
+        high = min(math.ceil(correction_dim(beta_slow)), d - 1)
+        ramp = jnp.clip(
+            (jnp.arange(d // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0
+        )
+        return inv_freq * (1.0 - ramp) + (inv_freq / factor) * ramp
     factor, low_freq_factor, high_freq_factor, orig_ctx = scaling
     wavelen = 2.0 * math.pi / inv_freq
     low_wavelen = orig_ctx / low_freq_factor
@@ -334,7 +357,7 @@ def _block(
     from ..ops.attention import resolve_attention_impl
 
     B, Sq, H = x.shape
-    scale = config.query_scale or 1.0 / math.sqrt(config.head_dim)
+    scale = config.attn_scale
     prefill_impl = resolve_attention_impl(config.attention_impl)
     decode_impl = resolve_attention_impl(config.decode_attention_impl)
 
@@ -549,13 +572,26 @@ def _apply_stack(
     prefix_lengths: Optional[jax.Array] = None,
     sp_ring_mesh=None,
     mesh=None,
+    aux: Optional[dict] = None,
 ) -> Tuple[jax.Array, KVCache]:
     """Scan the layer stack. cache k/v: [L, B, Smax, KVH, D].
 
     When layers alternate local/global attention (Gemma-2), ``key_mask`` /
     ``prefix_mask`` hold the WINDOWED masks, the ``*_global`` twins hold the
     full-causal ones, and a scanned per-layer flag picks between them.
+
+    ``aux``: a dict the caller wants the stack's own counts in (what a model
+    counts is its own affair: see models/latent.py; the dense block counts
+    nothing and leaves it empty). A latent model's stack is models/latent.py's.
     """
+    if config.is_latent:
+        from . import latent
+
+        return latent.apply_stack(
+            config, params, x, positions, cache, write_index, key_mask,
+            prefix=prefix, prefix_mask=prefix_mask, aux=aux,
+            sp_ring_mesh=sp_ring_mesh, mesh=mesh,
+        )
     local_flags = _local_layer_flags(config) if key_mask_global is not None else None
 
     def body(carry, scanned):
@@ -738,6 +774,7 @@ def prefill_continue(
     prefix_len: jax.Array,
     total_len: jax.Array,
     mesh=None,
+    aux: Optional[dict] = None,
 ) -> Tuple[jax.Array, KVCache]:
     """Prefill a prompt SUFFIX against an already-computed prompt-prefix KV —
     the prefix-caching path (the reference has no model layer; its provider
@@ -775,6 +812,7 @@ def prefill_continue(
         causal_abs,
         key_mask_global=key_mask_global,
         mesh=mesh,
+        aux=aux,
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     last_row = (total_len - prefix_len - 1).reshape(B, 1, 1).astype(jnp.int32)
@@ -791,6 +829,7 @@ def prefill_chunk_step(
     cursor: jax.Array,
     valid_len: jax.Array,
     mesh=None,
+    aux: Optional[dict] = None,
 ) -> Tuple[jax.Array, KVCache]:
     """Extend a partially-filled prompt prefix by one chunk — the unit of
     chunked prefill (Sarathi-style: prompt ingestion interleaved with decode
@@ -811,7 +850,7 @@ def prefill_chunk_step(
     """
     return prefill_continue(
         config, params, chunk_tokens, cache, cursor, cursor + valid_len,
-        mesh=mesh,
+        mesh=mesh, aux=aux,
     )
 
 
@@ -823,6 +862,7 @@ def prefill_chunk_step_paged(
     cursor: jax.Array,
     valid_len: jax.Array,
     mesh=None,
+    aux: Optional[dict] = None,
 ) -> Tuple[jax.Array, KVCache, jax.Array, jax.Array]:
     """Paged twin of :func:`prefill_chunk_step`: identical compute against the
     dense staging cache (byte-identity comes for free from the shared path),
@@ -832,7 +872,7 @@ def prefill_chunk_step_paged(
     v_cols [L, C, KVH, D])."""
     C = chunk_tokens.shape[1]
     logits, cache = prefill_chunk_step(
-        config, params, chunk_tokens, cache, cursor, valid_len, mesh=mesh
+        config, params, chunk_tokens, cache, cursor, valid_len, mesh=mesh, aux=aux
     )
     k_cols = jax.lax.dynamic_slice_in_dim(cache.k[:, 0], cursor, C, axis=1)
     v_cols = jax.lax.dynamic_slice_in_dim(cache.v[:, 0], cursor, C, axis=1)
@@ -919,6 +959,7 @@ def verify_step(
     prefix: KVCache,
     sp_ring_mesh=None,
     mesh=None,
+    aux: Optional[dict] = None,
 ) -> Tuple[jax.Array, KVCache]:
     """Speculative-decoding verification: score k+1 tokens per row in ONE
     forward (the draft-tree trunk of prompt-lookup decoding).
@@ -976,6 +1017,7 @@ def verify_step(
         prefix_lengths=pl,
         sp_ring_mesh=sp_ring_mesh,
         mesh=mesh,
+        aux=aux,
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     logits = _logits(config, params, h)
@@ -1026,7 +1068,7 @@ def _block_paged(
     )
 
     B, Sq, H = x.shape
-    scale = config.query_scale or 1.0 / math.sqrt(config.head_dim)
+    scale = config.attn_scale
     q, k, v = _attn_qkv(config, layer, x, positions)
     k_col = k[:, 0].astype(pool_kv.k.dtype)
     v_col = v[:, 0].astype(pool_kv.v.dtype)
@@ -1112,6 +1154,7 @@ def _apply_stack_paged(
     attn_impl: str = "xla",
     page_size: Optional[int] = None,
     mesh=None,
+    aux: Optional[dict] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Paged twin of :func:`_apply_stack`: per-layer KV lives in a flat page
     pool addressed through block tables instead of dense caches.
@@ -1138,6 +1181,13 @@ def _apply_stack_paged(
     ``[L, B, KVH, D]`` — each row's freshly written KV column for the
     caller's pool scatter.
     """
+    if config.is_latent:
+        from . import latent
+
+        return latent.apply_stack_paged(
+            config, params, x, positions, pool_kv, prefix_idx, gen_idx,
+            write_index, key_mask, prefix_mask, aux=aux, mesh=mesh,
+        )
     local_flags = _local_layer_flags(config) if key_mask_global is not None else None
 
     page_tables = None
@@ -1198,6 +1248,7 @@ def paged_verify_step(
     attn_impl: str = "xla",
     page_size: Optional[int] = None,
     mesh=None,
+    aux: Optional[dict] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Paged twin of :func:`verify_step` at ``Sq == 1`` — the continuous
     decode loop's step when its slots hold block tables into a shared page
@@ -1257,6 +1308,7 @@ def paged_verify_step(
         attn_impl=attn_impl,
         page_size=page_size,
         mesh=mesh,
+        aux=aux,
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     logits = _logits(config, params, h)

@@ -295,6 +295,11 @@ def load_checkpoint(path: str, config: ModelConfig, dtype=None) -> Dict[str, Any
     integrity verification (finite floats + manifest checksum when one was
     written at save time) and fails fast with a typed
     :class:`CheckpointCorruptError` rather than serving garbage weights."""
+    if config.is_latent:
+        raise NotImplementedError(
+            f"{config.name}: no checkpoint mapping for the latent block (MLA projections, "
+            "expert stacks, hyper-connection mixers); it runs on seeded weights"
+        )
     if os.path.isdir(path) and any(f.endswith(".safetensors") for f in os.listdir(path)):
         params = load_safetensors(path, config, dtype)
     else:
@@ -342,6 +347,11 @@ def config_from_hf(path: str) -> Optional[ModelConfig]:
         return None
     with open(cfg_path) as f:
         hf = json.load(f)
+    if "kv_lora_rank" in hf:
+        raise NotImplementedError(
+            f"config.json of model_type {hf.get('model_type')!r} describes latent attention; "
+            "use the registered preset (models/config.py), no checkpoint mapping exists"
+        )
     hidden = hf["hidden_size"]
     heads = hf["num_attention_heads"]
     model_type = hf.get("model_type", "llama")

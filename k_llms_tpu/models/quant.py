@@ -275,6 +275,11 @@ def quantize_params(params: Dict[str, Any], bits: int = 8) -> Dict[str, Any]:
     ``bits=4`` packs eligible weights group-wise int4 (:mod:`ops.w4matmul`);
     ineligible ones (MoE expert stacks, non-divisible shapes) fall back int8.
     """
+    if "dense_layers" in params:
+        raise NotImplementedError(
+            "quantize_params: the latent block's tree (models/latent.py) is served in "
+            "its own dtype; int8/int4 expert stacks under the grouped products are not written"
+        )
     layers = dict(params["layers"])
     for key in _QUANT_LAYER_KEYS:
         layers[key] = quantize_weight_bits(layers[key], bits)
@@ -309,6 +314,11 @@ def init_params_quantized(
 
     if dist not in ("random", "cheap"):
         raise ValueError(f"Unknown dist {dist!r}; use 'random' or 'cheap'")
+    if config.is_latent:
+        raise NotImplementedError(
+            f"{config.name}: no quantized init for the latent block; it is served in "
+            f"{config.dtype} (int8/int4 expert stacks are not written)"
+        )
     cheap = dist == "cheap"
     dtype = dtype or config.jax_dtype
     H, I, V = config.hidden_size, config.intermediate_size, config.vocab_size

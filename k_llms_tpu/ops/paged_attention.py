@@ -27,7 +27,8 @@ Selection is ``resolve_paged_attention_impl`` (backed by
 ``BackendConfig.paged_attention_impl``): "xla" | "pallas" | "auto", with an
 automatic COUNTED fallback (``kernel.paged_attn_fallback.<reason>``, where
 the suffix names what blocked the kernel: failpoint / softcap /
-sliding_window / platform) when "pallas" is requested but can't run; "auto"
+sliding_window / mla / platform) when "pallas" is requested but can't run, or
+when "auto" on a TPU meets a model outside the kernel's support; "auto"
 choosing XLA off-TPU is the documented CPU posture, not a fallback, so it is
 not counted. The ``ops.paged_attn`` failpoint forces the fallback branch for
 drills.
@@ -76,12 +77,13 @@ def resolve_paged_attention_impl(
     """Pick the paged-attention implementation for the current process.
 
     requested: "auto" | "pallas" | "xla"; config: optional ModelConfig — a
-    model using attention softcap or sliding windows is outside the kernel's
-    support and resolves to "xla". Resolution is host-side and happens once
+    model using attention softcap, sliding windows or a latent (MLA) cache is
+    outside the kernel's support and resolves to "xla". Resolution is host-side and happens once
     per loop/launch build, not per step. An explicit "pallas" request that
     cannot be honored records ``kernel.paged_attn_fallback.<reason>``, where
     the reason distinguishes config-driven fallbacks (``softcap``,
-    ``sliding_window`` — the model is outside the kernel's support) from
+    ``sliding_window``, ``mla`` — the model is outside the kernel's support;
+    on a TPU these are counted under "auto" too) from
     environment-driven ones (``platform`` — no TPU) and drills
     (``failpoint``); "auto" picking XLA off-TPU is the expected CPU posture
     and is NOT counted. The ``ops.paged_attn`` failpoint (action
@@ -100,15 +102,20 @@ def resolve_paged_attention_impl(
         return "xla"
     if requested == "xla":
         return "xla"
-    if config is not None and config.attn_softcap is not None:
-        blocked: Optional[str] = "softcap"
+    if config is not None and config.is_latent:
+        blocked: Optional[str] = "mla"  # a latent page is no (KVH, D) tile
+    elif config is not None and config.attn_softcap is not None:
+        blocked = "softcap"
     elif config is not None and config.sliding_window is not None:
         blocked = "sliding_window"
     else:
         blocked = None
-    if jax.default_backend() == "tpu" and blocked is None:
+    on_tpu = jax.default_backend() == "tpu"
+    if on_tpu and blocked is None:
         return "pallas"
-    if requested == "pallas" and record:
+    # Counted when the kernel was asked for by name, or when "auto" would
+    # have taken it here and the model is what stands in the way.
+    if record and (requested == "pallas" or (on_tpu and blocked is not None)):
         KERNEL_EVENTS.record(f"kernel.paged_attn_fallback.{blocked or 'platform'}")
     return "xla"
 

@@ -20,6 +20,12 @@ from .mesh import DATA_AXIS, MODEL_AXIS
 
 def param_specs(config: ModelConfig) -> Dict[str, Any]:
     """Pytree of PartitionSpec matching models.llama.init_params."""
+    if config.is_latent:
+        raise NotImplementedError(
+            f"{config.name}: no partition specs for the latent block's tree yet "
+            "(dense_layers/layers groups, latent projections, expert stacks, mixers): "
+            "it runs on one device"
+        )
     layers = {
         "attn_norm": P(None, None),
         "wq": P(None, None, MODEL_AXIS),

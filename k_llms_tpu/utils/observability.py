@@ -304,6 +304,40 @@ GRAMMAR_EVENTS = EventCounters(declared=(
     "grammar.masked_steps",
 ))
 
+#: What a model's own stack counts inside the loop's programs, added up at
+#: readback by :func:`note_model_aux` and exported unlabeled as
+#: ``kllms_<name>`` on ``/metrics``. ``moe_layer_calls`` — expert layers run
+#: (layers x program calls); ``moe_pairs`` — token-expert pairs computed;
+#: ``moe_experts_touched`` — experts with at least one token, summed over
+#: layers and calls (times the bytes of one expert: what the grouped products
+#: streamed); ``moe_max_load`` — the busiest expert's token count, summed
+#: likewise; ``mla_latent_rows_read`` — latent cache rows the paged decode
+#: steps attended, summed over rows and layers. A model with no routed
+#: experts or no latent cache leaves its counters at zero.
+MODEL_COUNTERS = EventCounters(declared=(
+    "moe_layer_calls",
+    "moe_pairs",
+    "moe_experts_touched",
+    "moe_max_load",
+    "mla_latent_rows_read",
+))
+
+
+def note_model_aux(aux: Dict[str, Any]) -> None:
+    """Add one program call's ``aux`` (host arrays: see models/latent.py) to
+    :data:`MODEL_COUNTERS`. ``moe_counts`` is ``[expert layers, experts]``
+    tokens per expert over the rows the call computed."""
+    counts = aux.get("moe_counts")
+    if counts is not None:
+        MODEL_COUNTERS.record("moe_layer_calls", int(counts.shape[0]))
+        MODEL_COUNTERS.record("moe_pairs", int(counts.sum()))
+        MODEL_COUNTERS.record("moe_experts_touched", int((counts > 0).sum()))
+        MODEL_COUNTERS.record("moe_max_load", int(counts.max(axis=-1).sum()))
+    rows = aux.get("mla_latent_rows_read")
+    if rows is not None:
+        MODEL_COUNTERS.record("mla_latent_rows_read", int(rows))
+
+
 #: Process-wide SSE-streaming counters (streams.opened, streams.completed,
 #: streams.aborted — closed before the final consensus event, whether by
 #: client disconnect or a mid-stream error — and tokens.streamed, the count

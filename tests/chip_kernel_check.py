@@ -20,6 +20,11 @@ with the paged Pallas kernel, again with the paged XLA reference, and through
 the dense ``generate`` — the three must emit the same tokens in every row
 (``--skip-model`` leaves it out; it builds two 8 GB engines one after the
 other). Prints one line per comparison and exits non-zero if any failed.
+
+The latent model (``xing4-29b-a4b``: MLA pages, routed experts,
+hyper-connections) runs no Pallas kernel of this repo and is not checked here:
+its on-chip comparison, the loop's programs at full width against the plain
+float32 reference, is ``python benchmark/check_xing4.py``.
 """
 
 import math

@@ -106,3 +106,69 @@ def test_logits_match_transformers_with_scaling(tmp_path):
     cfg_off = cfg.with_(rope_scaling=None)
     off, _ = forward(cfg_off, params, jnp.asarray(ids), jnp.ones((1, 100), jnp.int32))
     assert not np.allclose(np.asarray(off), ref, rtol=2e-3, atol=2e-3)
+
+
+# -- YaRN (DeepSeek-V3 form; models/latent.py's rope dims) ----------------------------
+
+XING_YARN = ("yarn", 64.0, 4096, 32.0, 1.0, 1.0)
+
+
+def hand_yarn(d, theta, factor, orig, beta_fast, beta_slow):
+    """The frequencies by hand: pair i keeps 1/theta^(2i/d) below the fast
+    correction dim, takes a 1/factor of it above the slow one, a linear blend
+    between."""
+    import math
+
+    def dim(rot):
+        return d * math.log(orig / (rot * 2 * math.pi)) / (2 * math.log(theta))
+
+    low, high = max(math.floor(dim(beta_fast)), 0), min(math.ceil(dim(beta_slow)), d - 1)
+    out = []
+    for i in range(d // 2):
+        keep = 1.0 - min(max((i - low) / (high - low), 0.0), 1.0)
+        extra = theta ** (-2 * i / d)
+        out.append(extra * keep + extra / factor * (1.0 - keep))
+    return low, high, np.asarray(out)
+
+
+@pytest.mark.parametrize("d,theta,scaling", [
+    (64, 10000.0, XING_YARN),
+    (8, 10000.0, ("yarn", 64.0, 64, 32.0, 1.0, 1.0)),
+    (64, 10000.0, ("yarn", 40.0, 4096, 32.0, 1.0, 0.707)),
+])
+def test_yarn_inv_freq_matches_hand_computed(d, theta, scaling):
+    _, factor, orig, beta_fast, beta_slow, _ = scaling
+    low, high, want = hand_yarn(d, theta, factor, orig, beta_fast, beta_slow)
+    ours = np.asarray(_rope_inv_freq(d, theta, scaling))
+    np.testing.assert_allclose(ours, want, rtol=1e-6)
+    # Fast pairs are untouched, slow pairs divided by the factor, exactly.
+    np.testing.assert_allclose(ours[: low + 1], theta ** (-2 * np.arange(low + 1) / d), rtol=1e-6)
+    np.testing.assert_allclose(
+        ours[high:], theta ** (-2 * np.arange(high, d // 2) / d) / factor, rtol=1e-6)
+    if scaling == XING_YARN:
+        assert (low, high) == (10, 23)  # 64 ln(4096 / 64 pi) / (2 ln 1e4) = 10.47; ... / 2 pi = 22.51
+
+
+def test_yarn_inv_freq_matches_transformers():
+    from transformers import PretrainedConfig
+    from transformers.modeling_rope_utils import ROPE_INIT_FUNCTIONS
+
+    hf_cfg = PretrainedConfig(
+        hidden_size=64 * 32, num_attention_heads=32, head_dim=64, rope_theta=10000.0,
+        max_position_embeddings=262144, qk_rope_head_dim=64,
+        rope_scaling={"rope_type": "yarn", "factor": 64.0, "beta_fast": 32, "beta_slow": 1,
+                      "mscale": 1, "mscale_all_dim": 1, "original_max_position_embeddings": 4096},
+    )
+    ref_inv_freq, attention_factor = ROPE_INIT_FUNCTIONS["yarn"](hf_cfg, device="cpu")
+    np.testing.assert_allclose(
+        np.asarray(_rope_inv_freq(64, 10000.0, XING_YARN)), ref_inv_freq.numpy(), rtol=1e-5)
+    assert attention_factor == pytest.approx(1.0)  # mscale / mscale_all_dim on cos and sin
+
+
+def test_registered_xing4_config_carries_yarn_and_its_score_scale():
+    cfg = get_config("xing4-29b-a4b")
+    assert cfg.rope_scaling == XING_YARN
+    mscale = 0.1 * np.log(64.0) + 1.0
+    assert cfg.attn_scale == pytest.approx(192 ** -0.5 * mscale ** 2)
+    assert get_config("qwen2-7b").attn_scale == 1.0 / np.sqrt(128)
+    assert get_config("gemma-2-2b").attn_scale == 256.0 ** -0.5
