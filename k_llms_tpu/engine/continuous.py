@@ -57,7 +57,7 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +66,7 @@ from concurrent.futures import Future
 
 from ..analysis.lockcheck import make_condition, note_device_dispatch, race_exempt
 from ..models.llama import KVCache, init_cache, paged_verify_step, verify_step
-from ..ops.paged_attention import note_paged_attn_dispatch
+from ..ops.paged_attention import live_pages, note_paged_attn_dispatch, table_pages
 from ..reliability import failpoints as _failpoints
 from ..reliability.deadline import RequestBudget
 from ..types.wire import (
@@ -80,6 +80,7 @@ from ..utils.observability import (
     FAILURE_EVENTS,
     GRAMMAR_EVENTS,
     LATENCY,
+    PAGED_ATTN_PAGES,
     RECOVERY_EVENTS,
     current_trace,
     note_model_aux,
@@ -860,8 +861,12 @@ class ContinuousDecodeLoop:
             # host-computed flat slot. Same masks, same sampler, same key
             # schedule — byte-identical tokens to the dense loop.
             aux: Dict[str, Any] = {}
+            # A retired slot keeps its last tenant's lengths on the host; the
+            # model is given none for it, so the paged kernel walks no page
+            # of an idle row (its output is discarded below either way).
             logits, k_cols, v_cols = paged_verify_step(
-                config, params, cur[:, None], gen_lens, prompt_lens,
+                config, params, cur[:, None],
+                jnp.where(active, gen_lens, 0), jnp.where(active, prompt_lens, 0),
                 KVCache(k=pool_k, v=pool_v), prefix_idx, gen_idx,
                 attn_impl=self._paged_attn_impl,
                 page_size=self._pool.page_size,
@@ -988,7 +993,8 @@ class ContinuousDecodeLoop:
                           gen_idx, write_idx, poison, g_states, g_flags, *tabs):
             aux: Dict[str, Any] = {}
             logits, k_cols, v_cols = paged_verify_step(
-                config, params, cur[:, None], gen_lens, prompt_lens,
+                config, params, cur[:, None],
+                jnp.where(active, gen_lens, 0), jnp.where(active, prompt_lens, 0),
                 KVCache(k=pool_k, v=pool_v), prefix_idx, gen_idx,
                 attn_impl=self._paged_attn_impl,
                 page_size=self._pool.page_size,
@@ -1911,6 +1917,21 @@ class ContinuousDecodeLoop:
         self._prefix_idx[slot] = pidx
         self._gen_idx[slot] = flat_slots(table, plen + np.arange(G), ps)
 
+    def _step_page_counts(self) -> Tuple[int, int]:
+        """(pages the live rows' walks hold, pages the step's tables hold) for
+        the upcoming decode step, from what the step program hands the paged
+        kernel: the lengths with idle slots zeroed, the phase out of the gen
+        slot map. Lock held."""
+        ps = self._pool.page_size
+        n_prefix, n_gen = live_pages(
+            np.where(self._active_mask, self._prompt_lens, 0),
+            np.where(self._active_mask, self._gen_lens, 0),
+            self._gen_idx[:, 0] % ps,
+            ps,
+        )
+        tabled = self.width * sum(table_pages(self.max_prompt, self.max_new, ps))
+        return int(n_prefix.sum() + n_gen.sum()), tabled
+
     def _prepare_step_pages(self) -> np.ndarray:
         """Resolve each row's write slot for the upcoming step, performing
         page-table maintenance on the way: append a reserved page when the
@@ -2006,6 +2027,12 @@ class ContinuousDecodeLoop:
                     write_idx = jnp.asarray(self._prepare_step_pages())
                     pidx = jnp.asarray(self._prefix_idx)
                     gidx = jnp.asarray(self._gen_idx)
+                    # The fused kernel's walk, counted here where the
+                    # lengths are coherent (the XLA path gathers whole tables).
+                    pages = (
+                        self._step_page_counts()
+                        if self._paged_attn_impl != "xla" else None
+                    )
             # All-False in production; with an active ``engine.logits`` nan
             # failpoint, a seeded subset of the LIVE rows is poisoned — the
             # loop-scoped twin of the batch path's first-step injection.
@@ -2025,6 +2052,9 @@ class ContinuousDecodeLoop:
             if self.paged:
                 pool = self._pool
                 note_paged_attn_dispatch(self._paged_attn_impl)
+                if pages is not None:
+                    PAGED_ATTN_PAGES.record("paged_attn_pages_walked", pages[0])
+                    PAGED_ATTN_PAGES.record("paged_attn_pages_tabled", pages[1])
                 with pool.lock:
                     note_device_dispatch("continuous paged step")
                     with LATENCY.span("continuous.dispatch", step=step_no):

@@ -5,17 +5,20 @@ pool pages addressed through per-row block tables. Before this op existed the
 layer scan materialized the gathered K/V (`take_along_axis` twice per layer)
 and then ran dense attention over the copy — the gather bandwidth alone is
 2 * bytes(KV) per decode step per layer at 8B widths. The Pallas kernel here
-does what vLLM's PagedAttention does on GPU: the grid walks (row, page) and
-each page block's HBM read is indexed *through the block table* by the
-BlockSpec index_map, so the gather IS the attention's K/V load — no
-materialized copy, one online-softmax pass, and the current step's fresh
-column (not yet scattered into the pool) folded in at finalize.
+does what vLLM's PagedAttention does on GPU: the grid walks rows, each row
+walks the pages it has (:func:`live_pages` — a trip count from its own
+lengths, zero for an idle slot), and each page is a DMA out of the pool in
+HBM addressed *through the block table*, K pages a block into a double
+buffer, so the gather IS the attention's K/V load — no materialized copy, one
+online-softmax pass over blocks scored whole (all query heads against the
+block's ``K * page_size * KVH`` pool rows), and the current step's fresh
+column (not yet scattered into the pool) folded in at the end.
 
 Two implementations, one contract:
 
 - ``paged_decode_attention_pallas``: the fused kernel. Uses scalar prefetch
-  (page tables, per-row lengths/phase and the layer number) to drive the
-  data BlockSpecs over the whole ``[L, ...]`` pool, read in place. TPU
+  (page tables, per-row lengths/phase and the layer number) to drive its own
+  page copies out of the whole ``[L, ...]`` pool, read in place. TPU
   only in production; ``interpret=True`` exists for the differential tests.
 - ``paged_decode_attention_xla``: jittable pure-XLA reference with identical
   semantics — and byte-identical to the dense `_block` decode math (same op
@@ -236,6 +239,13 @@ def paged_decode_attention_xla(
 # ---------------------------------------------------------------------------
 
 
+def table_pages(prefix_slots: int, gen_slots: int, page_size: int) -> Tuple[int, int]:
+    """Widths of the page tables :func:`paged_attention_page_tables` derives
+    from ``[.., P]`` and ``[.., G]`` slot maps: ``(NP, NG)``. The +1 gen page
+    absorbs the phase shift's worst case."""
+    return -(-prefix_slots // page_size), -(-gen_slots // page_size) + 1
+
+
 def paged_attention_page_tables(
     prefix_idx: jax.Array, gen_idx: jax.Array, page_size: int
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -247,10 +257,10 @@ def paged_attention_page_tables(
     [B, ceil(G/ps) + 1]`` and ``gen_phase [B]`` — the in-page offset of gen
     position 0 (``plen % ps`` for the continuous layout where generated
     tokens continue the prompt's last partial page; 0 for the coalesced
-    fresh-page layout). The +1 gen page absorbs the phase shift's worst
-    case. Pages for fully-masked table regions are whatever slot the map
-    pointed at (typically trash) — the kernel's validity predicate masks
-    every position they cover, so their contents are don't-care.
+    fresh-page layout). Pages for fully-masked table regions are whatever
+    slot the map pointed at (typically trash) — the kernel's validity
+    predicate masks every position they cover, so their contents are
+    don't-care.
 
     Traceable (pure jnp); layer-invariant, so callers hoist it outside the
     layer scan.
@@ -258,7 +268,7 @@ def paged_attention_page_tables(
     ps = page_size
     prefix_pages = prefix_idx[..., ::ps] // ps  # [B|R, ceil(P/ps)]
     G = gen_idx.shape[-1]
-    NG = -(-G // ps) + 1
+    _, NG = table_pages(prefix_idx.shape[-1], G, ps)
     phase = gen_idx[:, :1] % ps  # [B, 1]
     starts = jnp.arange(NG, dtype=jnp.int32)[None, :] * ps - phase  # [B, NG]
     src = jnp.clip(starts, 0, G - 1)
@@ -274,100 +284,183 @@ def paged_attention_page_tables(
 # Fused Pallas kernel
 # ---------------------------------------------------------------------------
 
+#: VMEM the kernel's page buffers may take: two slots (one scored while the
+#: other fills) of K pages of keys and K of values. K follows from this and
+#: the page's own bytes (:func:`pages_per_block`).
+PAGE_BUFFER_BYTES = 2 * 1024 * 1024
+
+
+def pages_per_block(page_bytes: int, table_pages: int) -> int:
+    """K, the pages the kernel fetches and scores together: what fits the
+    double-buffered K and V page buffers, at most the whole table."""
+    return max(1, min(PAGE_BUFFER_BYTES // (4 * page_bytes), table_pages))
+
+
+def live_pages(prompt_lens, gen_lens, gen_phase, page_size: int):
+    """The pages of a row's table that hold a position it attends to:
+    ``(prefix pages, generated pages)``, the first ones of each table.
+
+    prompt_lens / gen_lens: valid prompt positions and generated tokens in the
+    pool (the current token's fresh column is not in it); gen_phase: the
+    in-page offset of generated position 0. A row with no generated token has
+    no generated page whatever its phase, so a row with nothing has none at
+    all. Plain integer arithmetic: the kernel calls it on its SMEM scalars,
+    the loop's page counter on numpy vectors.
+    """
+    ps = page_size
+    n_prefix = (prompt_lens + (ps - 1)) // ps
+    n_gen = (gen_phase + gen_lens + (ps - 1)) // ps * (gen_lens > 0)
+    return n_prefix, n_gen
+
 
 def _paged_decode_kernel(
     # scalar prefetch (SMEM) -------------------------------------------------
-    tables_ref,  # [B, NP + NG] int32: pool page per (row, page block)
+    tables_ref,  # [B, NP + NG] int32: pool page per (row, table page)
     plen_ref,  # [B] int32: valid prefix length per row
     glen_ref,  # [B] int32: generated count per row (current token excluded)
     phase_ref,  # [B] int32: in-page offset of gen position 0
-    layer_ref,  # [1] int32: the layer whose pages are read (index maps only)
+    layer_ref,  # [1] int32: the layer whose pages are read
     # data -------------------------------------------------------------------
-    q_ref,  # [1, KVH, G, D] — one row's queries, grouped per kv head
-    k_ref,  # [1, page_size, KVH, D] — page tables_ref[b, j] of that layer
-    v_ref,  # [1, page_size, KVH, D]
-    nk_ref,  # [1, KVH, D] — this step's fresh key column (not yet in pool)
-    nv_ref,  # [1, KVH, D]
-    o_ref,  # [1, KVH, G, D] f32
-    # VMEM scratch -----------------------------------------------------------
-    acc_ref,  # [KVH, G, D] f32
-    m_ref,  # [KVH, G] f32 running max
-    l_ref,  # [KVH, G] f32 running denominator
+    q_ref,  # [1, QH, D] — one row's queries; query head h*G+g shares kv head h
+    k_hbm,  # [L * npages, page_size * KVH, D] — the whole pool, left in HBM;
+    v_hbm,  # a page's row r is (position r // KVH, kv head r % KVH)
+    nk_ref,  # [1, QH, D] — this step's fresh key column (not yet in pool),
+    nv_ref,  # each kv head's repeated for its G query heads
+    o_ref,  # [1, QH, D] f32
+    # scratch ----------------------------------------------------------------
+    k_buf,  # VMEM [2, K, page_size * KVH, D]: two slots of K pages
+    v_buf,  # VMEM [2, K, page_size * KVH, D]
+    sems,  # DMA semaphores [2, 2]: (slot, keys | values)
     *,
     sm_scale: float,
     page_size: int,
     num_prefix_pages: int,
+    num_gen_pages: int,
+    pages_per_layer: int,
     kv_heads: int,
+    block_pages: int,
 ):
-    # Grid (row, page block): pages run prefix-first then gen; TPU grids
-    # execute sequentially so the online-softmax scratch persists across the
-    # page axis. The block-table indirection, layer number included, already
-    # happened in the BlockSpec index_map — by the time this body runs,
-    # k_ref/v_ref ARE the right page.
+    # Grid (row,). A row walks its own live pages and no others: the first
+    # n_prefix columns of its prefix table, then the first n_gen of its gen
+    # table, K pages a block. Each page is one DMA out of the pool in HBM,
+    # addressed through the block table and the layer number here in the
+    # body. Two slots: while a block is scored out of one, the other fills
+    # with the row's next. The trip count is read from SMEM, so a row with
+    # nothing fetches nothing and runs the fresh-column fold alone.
+    #
+    # A block is scored whole, every kv head at once: its K * ps * KVH pool
+    # rows against all QH queries in one product, a query head's weights
+    # kept on its own kv head's rows (the others score NEG_INF like any
+    # masked slot). That computes KVH times the scores it keeps, and takes
+    # the pages exactly as the pool lays them out: cutting one head's rows
+    # out of the (position, head) interleave cost more than the scores.
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    ps, K, KVH = page_size, block_pages, kv_heads
+    plen, glen, phase = plen_ref[b], glen_ref[b], phase_ref[b]
+    n_prefix, n_gen = live_pages(plen, glen, phase, ps)
+    n_prefix = jnp.minimum(n_prefix, num_prefix_pages)
+    live = n_prefix + jnp.minimum(n_gen, num_gen_pages)
+    n_blocks = (live + (K - 1)) // K
+    base = layer_ref[0] * pages_per_layer
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def page_copies(page, slot, t):  # a page of keys and of values into a slot
+        return [
+            pltpu.make_async_copy(hbm.at[page], buf.at[slot, t], sems.at[slot, kv])
+            for kv, (hbm, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf)))
+        ]
 
-    Gq = q_ref.shape[2]
-    offs = lax.broadcasted_iota(jnp.int32, (Gq, page_size), 1)
-    is_prefix = j < num_prefix_pages
-    # Logical position of each in-page slot: prefix pages count from 0;
-    # gen pages are phase-shifted (gen position g lives at in-page offset
-    # (phase + g) % ps of gen page (phase + g) // ps).
-    pos = jnp.where(
-        is_prefix,
-        j * page_size + offs,
-        (j - num_prefix_pages) * page_size + offs - phase_ref[b],
-    )
-    limit = jnp.where(is_prefix, plen_ref[b], glen_ref[b])
-    # TRASH_PAGE safety: any slot outside [0, limit) — padding, the phase
-    # shift's dead lead-in, trash-retargeted table tails — scores NEG_INF
-    # and contributes an exact 0.
-    valid = (pos >= 0) & (pos < limit)
+    def start_block(blk, slot):
+        for t in range(K):  # static unroll
+            i = blk * K + t
 
-    for h in range(kv_heads):  # static unroll
-        q = q_ref[0, h].astype(jnp.float32)  # [Gq, D]
-        k = k_ref[0, :, h, :].astype(jnp.float32)  # [page_size, D]
+            @pl.when(i < live)
+            def _start():
+                col = jnp.where(i < n_prefix, i, num_prefix_pages + i - n_prefix)
+                for copy in page_copies(base + tables_ref[b, col], slot, t):
+                    copy.start()
+
+    def wait_block(blk, slot):
+        for t in range(K):
+
+            @pl.when(blk * K + t < live)
+            def _wait():
+                for copy in page_copies(0, slot, t):  # the shape is what is waited on
+                    copy.wait()
+
+    @pl.when(b == 0)
+    def _first_row():
+        # A block's tail past the row's last page is not fetched: what the
+        # slot holds there is scored NEG_INF, and its weight, an exact 0,
+        # multiplies whatever V the slot last held. Pool values are finite;
+        # uninitialised VMEM need not be.
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    start_block(0, 0)
+
+    QH, D = q_ref.shape[1], q_ref.shape[2]
+    T = K * ps * KVH  # pool rows of a block, in (page, position, head) order
+    row = lax.broadcasted_iota(jnp.int32, (QH, T), 1)
+    tok = row // KVH  # which of the block's K * ps token slots
+    own = row % KVH == lax.broadcasted_iota(jnp.int32, (QH, T), 0) // (QH // KVH)
+    q = q_ref[0].astype(jnp.float32)  # [QH, D]
+
+    def block(blk, carry):
+        m, l, acc = carry
+        slot = lax.rem(blk, 2)
+
+        @pl.when(blk + 1 < n_blocks)
+        def _next():
+            start_block(blk + 1, 1 - slot)
+
+        # Logical position of each of the block's token slots: prefix pages
+        # count from 0; gen pages are phase-shifted (gen position g lives at
+        # in-page offset (phase + g) % ps of gen page (phase + g) // ps).
+        # Anything outside [0, limit) — padding, the phase shift's dead
+        # lead-in, the block's unfetched tail — scores NEG_INF and
+        # contributes an exact 0 (the TRASH_PAGE contract).
+        prefix_toks = (n_prefix - blk * K) * ps
+        is_prefix = tok < prefix_toks
+        pos = jnp.where(is_prefix, tok + blk * (K * ps), tok - prefix_toks - phase)
+        limit = jnp.where(is_prefix, plen, glen)
+        valid = own & (pos >= 0) & (pos < limit)
+
+        wait_block(blk, slot)
         s = lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q, k_buf[slot].reshape(T, D).astype(jnp.float32),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
         )
-        s = jnp.where(valid, s * sm_scale, NEG_INF)  # [Gq, page_size]
-
-        m_prev = m_ref[h][:, None]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
+        s = jnp.where(valid, s * sm_scale, NEG_INF)  # [QH, T]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
-        l_ref[h] = l_ref[h] * alpha[:, 0] + jnp.sum(p, axis=1)
-        acc_ref[h] = acc_ref[h] * alpha + lax.dot_general(
-            p,
-            v_ref[0, :, h, :].astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_new = acc * alpha + lax.dot_general(
+            p, v_buf[slot].reshape(T, D).astype(jnp.float32),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
         )
-        m_ref[h] = m_new[:, 0]
+        return m_new, l_new, acc_new
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _finalize():
-        # Fold in the CURRENT token's fresh K/V column — the caller hasn't
-        # scattered it into the pool yet (the dense twin writes it into the
-        # cache before attending; same visibility, no pool round-trip).
-        for h in range(kv_heads):
-            q = q_ref[0, h].astype(jnp.float32)  # [Gq, D]
-            nk = nk_ref[0, h].astype(jnp.float32)  # [D]
-            s = jnp.sum(q * nk[None, :], axis=1, keepdims=True) * sm_scale
-            m_prev = m_ref[h][:, None]
-            m_new = jnp.maximum(m_prev, s)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)  # [Gq, 1]
-            l = l_ref[h] * alpha[:, 0] + p[:, 0]
-            acc = acc_ref[h] * alpha + p * nv_ref[0, h].astype(jnp.float32)[None, :]
-            safe_l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, h] = acc / safe_l[:, None]
+    m, l, acc = lax.fori_loop(
+        0,
+        n_blocks,
+        block,
+        (
+            jnp.full((QH, 1), NEG_INF, jnp.float32),
+            jnp.zeros((QH, 1), jnp.float32),
+            jnp.zeros((QH, D), jnp.float32),
+        ),
+    )
+
+    # Fold in the CURRENT token's fresh K/V column — the caller hasn't
+    # scattered it into the pool yet (the dense twin writes it into the
+    # cache before attending; same visibility, no pool round-trip).
+    s = jnp.sum(q * nk_ref[0].astype(jnp.float32), axis=1, keepdims=True) * sm_scale
+    m_new = jnp.maximum(m, s)
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)  # [QH, 1]
+    l = l * alpha + p
+    acc = acc * alpha + p * nv_ref[0].astype(jnp.float32)
+    o_ref[0] = acc / jnp.where(l == 0.0, 1.0, l)
 
 
 def paged_decode_attention_pallas(
@@ -392,18 +485,29 @@ def paged_decode_attention_pallas(
 
     q: [B, QH, D]; pool_k/pool_v: the whole flat pool
     [L, total_pages * page_size, KVH, D], read in place; layer: int32 scalar,
-    the layer whose pages the index maps address (a fifth scalar-prefetch
-    operand, so no layer's pool is sliced out for the custom call);
-    prefix_pages [B|R, NP] / gen_pages [B, NG] / gen_phase [B]: from
+    the layer whose pages the kernel's copies address (a fifth
+    scalar-prefetch operand, so no layer's pool is sliced out for the custom
+    call); prefix_pages [B|R, NP] / gen_pages [B, NG] / gen_phase [B]: from
     :func:`paged_attention_page_tables`;
     new_k/new_v [B, KVH, D]: this step's fresh column; prompt_lens /
-    gen_lens [B]: per-row valid counts. Returns [B, QH, D] f32 — the same
-    normalized output the XLA reference produces (up to online-softmax
-    float ordering; token-exact under greedy, pinned by the differential
-    tests). Under a multi-device ``mesh`` the kernel runs per shard: kv heads
-    (and the pool, which is sharded the same way) over the model axis, rows
-    and their tables over the data axis when they divide; the pool itself is
-    replicated over data, and so is the layer number.
+    gen_lens [B]: per-row valid counts — they are the walk's trip count too
+    (:func:`live_pages`), so a row whose lengths are zero reads no page.
+    Returns [B, QH, D] f32 — the normalized output the XLA reference
+    produces, up to the float ordering of an online softmax over blocks
+    (f32 accumulation: 2e-5 beside the reference over an f32 pool, bf16's own
+    resolution over a bf16 pool). Not bit-exact, so greedy tokens equal the
+    reference's wherever its top two logits lie further apart than that
+    noise, and may swap across a nearer tie; the differential tests pin
+    both sides of that (tokens on decisive prompts, logits at every step
+    teacher-forced: ``tests/chip_kernel_check.py``).
+
+    Under a multi-device ``mesh`` the kernel runs per shard: kv heads (and
+    the pool, which is sharded the same way) over the model axis, rows and
+    their tables over the data axis when they divide; the pool itself is
+    replicated over data, and so is the layer number. A shard scores a block
+    against its own kv heads' rows alone, so its sums run in another order
+    than one device's: sharded and unsharded agree to the same tolerance,
+    not bit for bit.
     """
     B = q.shape[0]
     if prefix_pages.shape[0] != B:  # [R, NP] shared prefix -> per-row table
@@ -440,50 +544,55 @@ def _paged_decode_local(
     """One shard's fused paged decode (the whole call on a single device)."""
     B, QH, D = q.shape
     L, flat, KVH = pool_k.shape[:3]
-    G = QH // KVH
+    G = QH // KVH  # query head h*G+g shares kv head h
     ps = page_size
     npages = flat // ps
     NP = prefix_pages.shape[1]
     NG = gen_pages.shape[1]
     tables = jnp.concatenate([prefix_pages, gen_pages], axis=1).astype(jnp.int32)
+    K = pages_per_block(ps * KVH * D * pool_k.dtype.itemsize, NP + NG)
 
-    q4 = q.reshape(B, KVH, G, D)  # query head h*G+g shares kv head h
-    # Every layer's pages in one page axis (a free reshape): page p of layer
-    # l is block l * npages + p, so the custom call's operand is the pool.
-    pk4 = pool_k.reshape(L * npages, ps, KVH, D)
-    pv4 = pool_v.reshape(L * npages, ps, KVH, D)
-
-    def page_map(b, j, tables, plen, glen, phase, layer):
-        return (layer[0] * npages + tables[b, j], 0, 0, 0)
+    # Every layer's pages in one page axis, a page's (position, head) rows in
+    # one row axis: page p of layer l is page l * npages + p. Both are free
+    # reshapes of the pool as the device lays it out (rows of D, position
+    # major), so the custom call's operand is the pool itself.
+    pk = pool_k.reshape(L * npages, ps * KVH, D)
+    pv = pool_v.reshape(L * npages, ps * KVH, D)
 
     kernel = functools.partial(
         _paged_decode_kernel,
         sm_scale=sm_scale,
         page_size=ps,
         num_prefix_pages=NP,
+        num_gen_pages=NG,
+        pages_per_layer=npages,
         kv_heads=KVH,
+        block_pages=K,
     )
+    row = pl.BlockSpec((1, QH, D), lambda b, *_: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(B, NP + NG),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, KVH, G, D), lambda b, j, *_: (b, 0, 0, 0)),
-            pl.BlockSpec((1, ps, KVH, D), page_map),
-            pl.BlockSpec((1, ps, KVH, D), page_map),
-            pl.BlockSpec((1, KVH, D), lambda b, j, *_: (b, 0, 0)),
-            pl.BlockSpec((1, KVH, D), lambda b, j, *_: (b, 0, 0)),
+            row,
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            row,
+            row,
         ],
-        out_specs=pl.BlockSpec((1, KVH, G, D), lambda b, j, *_: (b, 0, 0, 0)),
+        out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((KVH, G, D), jnp.float32),
-            pltpu.VMEM((KVH, G), jnp.float32),
-            pltpu.VMEM((KVH, G), jnp.float32),
+            pltpu.VMEM((2, K, ps * KVH, D), pool_k.dtype),
+            pltpu.VMEM((2, K, ps * KVH, D), pool_v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KVH, G, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, QH, D), jnp.float32),
+        # Rows run in order on one core: the first clears the page buffers.
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention_decode",
     )(
@@ -492,10 +601,9 @@ def _paged_decode_local(
         gen_lens.astype(jnp.int32),
         gen_phase.astype(jnp.int32),
         layer,
-        q4,
-        pk4,
-        pv4,
-        new_k,
-        new_v,
+        q,
+        pk,
+        pv,
+        jnp.repeat(new_k, G, axis=1),
+        jnp.repeat(new_v, G, axis=1),
     )
-    return out.reshape(B, QH, D)

@@ -427,14 +427,15 @@ class ServingApp:
         # What the model's own stack counted in the loop's programs: one
         # unlabeled cumulative sample each (a gauge family, like the loop's
         # own ``kllms_continuous_steps``), present at zero for every model.
-        model_counts = _obs.MODEL_COUNTERS.snapshot()
-        for name in _obs.MODEL_COUNTERS.declared:
-            families.append(_prom.gauge_family(
-                f"kllms_{name}",
-                f"model stack count {name!r}, cumulative "
-                "(utils/observability.py::MODEL_COUNTERS)",
-                model_counts.get(name, 0),
-            ))
+        # The paged kernel's page counts (PAGED_ATTN_PAGES) go out the same way.
+        for counters in (_obs.MODEL_COUNTERS, _obs.PAGED_ATTN_PAGES):
+            counts = counters.snapshot()
+            for name in counters.declared:
+                families.append(_prom.gauge_family(
+                    f"kllms_{name}",
+                    f"count {name!r}, cumulative (utils/observability.py)",
+                    counts.get(name, 0),
+                ))
         # Latency histograms (LATENCY): exactly-declared families export even
         # at zero samples, so the scrape surface is stable from first poll.
         # Per-tenant fan-outs (``request.e2e.<tenant>``...) fold into ONE

@@ -323,6 +323,19 @@ MODEL_COUNTERS = EventCounters(declared=(
 ))
 
 
+#: How much of its block tables the fused paged-decode kernel walks, added up
+#: a continuous decode step on the host (``engine/continuous.py::_step_once``,
+#: beside the dispatch counter) and exported unlabeled as ``kllms_<name>`` on
+#: ``/metrics``. ``paged_attn_pages_walked`` — pages holding a position some
+#: live row attends to (``live_pages``, summed over rows: what the kernel
+#: fetches a layer); ``paged_attn_pages_tabled`` — rows x table pages, what a
+#: walk of whole tables would fetch. Zero where the XLA path serves.
+PAGED_ATTN_PAGES = EventCounters(declared=(
+    "paged_attn_pages_walked",
+    "paged_attn_pages_tabled",
+))
+
+
 def note_model_aux(aux: Dict[str, Any]) -> None:
     """Add one program call's ``aux`` (host arrays: see models/latent.py) to
     :data:`MODEL_COUNTERS`. ``moe_counts`` is ``[expert layers, experts]``

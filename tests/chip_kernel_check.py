@@ -12,14 +12,24 @@ where the interpret-mode CPU differential's tolerance applies unchanged
 served dtype — where kernel and reference round differently (the kernel
 accumulates f32 over f32-cast K/V, XLA feeds bf16 to the MXU) and the bound is
 bf16's own resolution. On several chips every comparison is repeated under the
-data x model meshes the engine builds, the kernel running per shard.
+data x model meshes the engine builds, the kernel running per shard. The paged
+kernel is also given the loop's own shapes: 32 ragged rows (nothing to a full
+2,048-token table) over qwen2-7b's whole ``[28, 79936, 4, 128]`` pool.
 
 The last stage is the model-level differential at full width (qwen2-7b, all 28
-layers, int8 from the seed): a greedy n=8 request through the continuous loop
-with the paged Pallas kernel, again with the paged XLA reference, and through
-the dense ``generate`` — the three must emit the same tokens in every row
-(``--skip-model`` leaves it out; it builds two 8 GB engines one after the
-other). Prints one line per comparison and exits non-zero if any failed.
+layers, int8 from the seed; ``--skip-model`` leaves it out, it builds two 8 GB
+engines one after the other): four greedy n=8 requests through the continuous
+loop with the paged Pallas kernel, again with the paged XLA reference, and
+through the dense ``generate``. Then (``TeacherForced``) ``paged_verify_step``
+with the kernel and with the XLA reference over one and the same pool, fed each
+path's tokens: the kernel's whole-vocabulary logits against the reference's at
+every step, and every token a served path emitted, with the logprob it
+reported, against the reference's logits at that step. A path's token must be
+the reference's wherever the reference's top two logits lie ``GREEDY_MARGIN``
+apart or more, at all 12 steps, whether or not a nearer tie came before.
+Prints one line per comparison and exits non-zero if any failed.
+``--rehearse`` runs that stage alone at toy size with the interpreted kernel,
+on any platform, to debug this script before a chip call.
 
 The latent model (``xing4-29b-a4b``: MLA pages, routed experts,
 hyper-connections) runs no Pallas kernel of this repo and is not checked here:
@@ -66,13 +76,36 @@ def report(name, got, ref, dtype):
         failures.append(name)
 
 
-def paged_case(dtype, continuous, mesh, layers=1, layer=0):
+# The loop's own shapes (benchmark cells: width 32, 2,048-token prompt table,
+# 256 generated): 32 rows from nothing to the whole table — idle slots, one
+# partial page, chat-short and extract-long rows, lengths on and off page and
+# block boundaries — in the cells' pool of 1,249 pages.
+RAGGED_PLENS = np.array(
+    [0, 2048, 1, 63, 64, 65, 0, 120, 32, 480, 257, 1152, 1924, 1424, 1424, 1424,
+     511, 512, 513, 0, 2047, 1025, 96, 200, 300, 0, 1600, 1601, 77, 1999, 640, 8],
+    np.int32,
+)
+RAGGED_WIS = np.array(
+    [0, 255, 0, 1, 63, 64, 0, 95, 3, 17, 255, 63, 0, 20, 41, 62,
+     1, 0, 2, 0, 130, 64, 96, 7, 128, 0, 191, 192, 5, 254, 33, 250],
+    np.int32,
+)
+RAGGED_POOL_PAGES = 1249
+
+
+def paged_case(dtype, continuous, mesh, layers=1, layer=0, ragged=False):
     """The kernel reading ``layer`` out of a pool of ``layers`` against the XLA
-    op on that layer's slice alone."""
-    plens = np.array([1, PS, 400, 1408, 131, 511, 512, 77], np.int32)
+    op on that layer's slice alone. ``ragged``: the 32-row case above."""
+    if ragged:
+        plens, wis = RAGGED_PLENS, RAGGED_WIS
+    else:
+        plens = np.array([1, PS, 400, 1408, 131, 511, 512, 77], np.int32)
+        wis = np.array([0, 3, 255, 63, 64, 65, 200, 7], np.int32)
     B, G = len(plens), 256
-    wis = np.array([0, 3, G - 1, 63, 64, 65, 200, 7], np.int32)
     prefix_idx, gen_idx, npages = _build_tables(plens, G, PS, continuous=continuous)
+    if ragged:
+        assert npages <= RAGGED_POOL_PAGES, npages
+        npages = RAGGED_POOL_PAGES
     keys = jax.random.split(jax.random.key(int(continuous)), 5)
     pool_shape = (layers, npages * PS, KVH, D)
     pool_k = jax.random.normal(keys[0], pool_shape, jnp.float32).astype(dtype)
@@ -102,7 +135,8 @@ def paged_case(dtype, continuous, mesh, layers=1, layer=0):
         jnp.asarray(plens), jnp.asarray(wis),
     )
     layout = "continuous" if continuous else "coalesced"
-    report(f"paged decode {layout} layer {layer} of {layers} "
+    rows = f"ragged {B} rows " if ragged else ""
+    report(f"paged decode {rows}{layout} layer {layer} of {layers} "
            f"{jnp.dtype(dtype).name} {mesh_name(mesh)}", got, ref[:, 0], dtype)
 
 
@@ -141,7 +175,118 @@ def flash_case(dtype, mesh):
            got, ref, dtype)
 
 
-def greedy_model_case():
+# The model stage's limits. Random weights put the whole 152k vocabulary within
+# a few nats (a greedy token's logprob reads about -8, the reference's top two
+# logits lie under 0.1 apart at 4 to 10 of a prompt's 12 steps), so two correct
+# paths, whose logits differ by rounding alone, need not pick the same token
+# across a near tie. Every comparison below is therefore made against the
+# reference's own logits, at every step, and a token may differ from the
+# reference's only where those logits do not decide.
+#
+# Kernel against XLA on one pool, |dlogit| over the whole vocabulary and all 12
+# steps of a prompt, as read on the v5e (PR 29; bf16 pages, int8 weights, 28
+# layers — the kernel-level TOL above does not survive 28 layers, for either
+# kernel): this kernel rms 0.0140-0.0144, max 0.069-0.076; the parent's (row,
+# page) grid kernel 0.0136-0.0142, 0.072-0.076; with the last prompt page not
+# walked rms >= 0.033, max >= 0.20; with the newest pooled token masked — one
+# token of 330 on the long prompts, the smallest fault there is — rms >= 0.0175,
+# max >= 0.094. The limits lie between the two readings.
+FORCED_RMS = 0.016
+FORCED_MAX = 0.10
+# |dlogprob| on one token between two correct paths (the parent's bound; read up
+# to 0.045 between a served path and the forced reference, 0.034 between the
+# kernel and XLA on one pool).
+PATH_TOL = 0.05
+GREEDY_MARGIN = 2 * PATH_TOL  # a greedy token lies less than this under the reference's best
+GREEDY_NEW = 12
+GREEDY_PROMPTS = (
+    "[greedy] You are an extraction engine. Read the doc",
+    "[greedy] Summarise the following invoice and return the total amount due, "
+    "the vendor and the date. " * 3,
+    "hello",
+    "[greedy] " + "The quick brown fox jumps over the lazy dog. " * 8,
+)
+
+
+class TeacherForced:
+    """Both paged implementations of ``paged_verify_step`` over ONE pool, fed a
+    given token sequence: the prompts are prefilled once into their own pages
+    (continuous layout, the generated tokens continuing the last prompt page),
+    one row a prompt in a batch of 8 whose other rows are idle slots, and each
+    step's XLA columns are what the pool receives, so at every step the kernel
+    and the reference read the same bytes. ``run(tokens)`` returns both paths'
+    logits ``[rows, new, V]``: index t is the distribution token t is drawn
+    from (t = 0 comes from the prefill and is the same array in both)."""
+
+    def __init__(self, eng, prompts, kernel="pallas", page_size=PS):
+        from k_llms_tpu.models.llama import KVCache, paged_verify_step, prefill
+
+        config, self.params = eng.config, eng.params
+        self.rows, self.pad, B, G = len(prompts), config.pad_token_id, 8, 256
+        plens = np.array([len(ids) for ids in prompts] + [0] * (B - self.rows), np.int32)
+        prefix_idx, self.gen_idx, npages = _build_tables(plens, G, page_size, continuous=True)
+        S = max(32, 1 << (int(plens.max()) - 1).bit_length())
+        prefill_fn = jax.jit(lambda p, t, n: prefill(config, p, t, n))
+        pool, first = None, []
+        for r, ids in enumerate(prompts):
+            tokens = np.full((1, S), self.pad, np.int32)
+            tokens[0, :len(ids)] = ids
+            logits, cache = prefill_fn(self.params, jnp.asarray(tokens), jnp.int32(len(ids)))
+            if pool is None:  # garbage everywhere a prompt does not write
+                shape = (cache.k.shape[0], npages * page_size) + cache.k.shape[3:]
+                pool = [
+                    jax.random.normal(key, shape, jnp.float32).astype(cache.k.dtype)
+                    for key in jax.random.split(jax.random.key(7), 2)
+                ]
+            slots = jnp.asarray(prefix_idx[r, :len(ids)])
+            pool = [p.at[:, slots].set(c[:, 0, :len(ids)]) for p, c in zip(pool, cache)]
+            first.append(np.asarray(logits[0], np.float32))
+        self.pool0, self.first = pool, np.stack(first)
+        self.live = np.arange(B) < self.rows
+        self.idle = np.arange(B) % page_size  # idle rows write into the trash page
+
+        def step(impl):
+            return jax.jit(lambda p, pk, pv, cur, glen: paged_verify_step(
+                config, p, cur[:, None], glen, jnp.asarray(plens), KVCache(k=pk, v=pv),
+                jnp.asarray(prefix_idx), jnp.asarray(self.gen_idx),
+                attn_impl=impl, page_size=page_size,
+            ))
+
+        self.steps = {"kernel": step(kernel), "xla": step("xla")}
+
+    def run(self, tokens):
+        rows, new = tokens.shape
+        logits = {name: [self.first] for name in self.steps}
+        pool = self.pool0
+        for t in range(new - 1):
+            cur = np.full(self.live.shape, self.pad, np.int32)
+            cur[:rows] = tokens[:, t]
+            glen = np.where(self.live, t, 0).astype(np.int32)
+            out = {
+                name: fn(self.params, *pool, jnp.asarray(cur), jnp.asarray(glen))
+                for name, fn in self.steps.items()
+            }
+            for name, (step_logits, _, _) in out.items():
+                logits[name].append(np.asarray(step_logits[:rows, 0], np.float32))
+            write = jnp.asarray(np.where(self.live, self.gen_idx[:, t], self.idle))
+            pool = [
+                p.at[:, write].set(c.astype(p.dtype)) for p, c in zip(pool, out["xla"][1:])
+            ]
+        return {name: np.stack(steps, axis=1) for name, steps in logits.items()}
+
+
+def check(ok, line, failure):
+    print(f"{'ok  ' if ok else 'FAIL'} {line}", flush=True)
+    if not ok:
+        failures.append(failure)
+
+
+def log_softmax(x):
+    x = x - x.max(axis=-1, keepdims=True)
+    return x - np.log(np.exp(x).sum(axis=-1, keepdims=True))
+
+
+def greedy_model_case(model="qwen2-7b", quantize="int8", kernel="pallas", page_size=PS):
     import gc
 
     from k_llms_tpu.engine.continuous import ContinuousDecodeLoop
@@ -149,45 +294,110 @@ def greedy_model_case():
     from k_llms_tpu.engine.tokenizer import ByteTokenizer
 
     tok = ByteTokenizer()
-    ids = tok.apply_chat_template(
-        [{"role": "user", "content": "[greedy] You are an extraction engine. Read the doc"}]
-    )
+    prompts = [
+        tok.apply_chat_template([{"role": "user", "content": text}])
+        for text in GREEDY_PROMPTS
+    ]
+    new = GREEDY_NEW
     runs = {}
-    for impl in ("pallas", "xla"):
+    for impl in ("xla", "pallas"):  # the kernel's engine last: it stays for the forced passes
         eng = LocalEngine(
-            "qwen2-7b", quantize="int8", kv_layout="paged",
+            model, quantize=quantize, kv_layout="paged", kv_page_size=page_size,
             paged_attention_impl=impl, use_mesh=False,
         )
         if impl == "pallas":
-            dense = eng.generate(
-                ids, n=8, max_new_tokens=12, temperature=0.0, seed=5, eos_ids=tok.stop_ids
-            )
-            runs["dense generate"] = (dense.tokens, dense.logprobs)
+            runs["dense generate"] = [
+                eng.generate(
+                    ids, n=8, max_new_tokens=new, temperature=0.0, seed=5,
+                    eos_ids=tok.stop_ids,
+                )
+                for ids in prompts
+            ]
         loop = ContinuousDecodeLoop(
             eng, width=32, max_prompt=512, max_new=256, eos_ids=tok.stop_ids
         )
         try:
-            r = loop.submit(
-                ids, n=8, max_new=12, temperature=0.0, top_p=None, seed=5
-            ).result(timeout=900)
+            runs[f"loop paged {impl}"] = [
+                loop.submit(
+                    ids, n=8, max_new=new, temperature=0.0, top_p=None, seed=5
+                ).result(timeout=900)
+                for ids in prompts
+            ]
         finally:
             loop.stop()
-        runs[f"loop paged {impl}"] = (r.tokens, r.logprobs)
         # The worker's frame holds the engine until the thread has exited;
         # the next 8 GB engine only fits once this one is really gone.
         if loop._thread is not None:
             loop._thread.join(timeout=30)
-        del loop, eng, r
+        del loop
+        if impl == "pallas":
+            # Every path's own tokens through both implementations on one
+            # pool; paths that emitted the same tokens share a pass.
+            forced, eng_pad = TeacherForced(eng, prompts, kernel, page_size), eng.config.pad_token_id
+            passes, by_tokens = {}, {}
+            for name, results in runs.items():
+                tokens = np.stack([np.asarray(r.tokens)[0, :new] for r in results])
+                if tokens.tobytes() not in by_tokens:
+                    passes[name] = (tokens, forced.run(tokens))
+                    by_tokens[tokens.tobytes()] = passes[name][1]["xla"]
+            del forced
+        del eng
         gc.collect()
-    ref_tokens, ref_lps = runs["dense generate"]
-    for name, (tokens, lps) in runs.items():
-        same = bool((tokens == ref_tokens[0]).all())
-        err = float(np.max(np.abs(lps - ref_lps)))
-        ok = same and err < 0.05
-        print(f"{'ok  ' if ok else 'FAIL'} greedy n=8 x12 tokens, {name:<18} rows and paths "
-              f"identical={same} max|dlogprob|={err:.4f} tokens={tokens[0].tolist()}", flush=True)
-        if not ok:
-            failures.append(f"greedy {name}")
+    reference = np.stack([np.asarray(r.tokens)[0, :new] for r in runs["dense generate"]])
+
+    # How decisive the reference is: its top two (sampleable) logits, a step.
+    for i, ref in enumerate(by_tokens[reference.tobytes()]):
+        top2 = np.sort(np.delete(ref, eng_pad, axis=-1), axis=-1)[:, -2:]
+        gaps = top2[:, 1] - top2[:, 0]
+        print(f"note prompt {i} ({len(prompts[i])} tokens): the reference's top two logits lie "
+              f"{gaps.min():.4f} apart at step {gaps.argmin()}, under {GREEDY_MARGIN} at "
+              f"{int((gaps < GREEDY_MARGIN).sum())} of {new} steps", flush=True)
+
+    # 1. The kernel against the XLA reference on the same pool, every step of
+    # every distinct token sequence: whole-vocabulary logits, and the logprob
+    # of the token the path emitted.
+    for name, (tokens, logits) in passes.items():
+        for i in range(len(prompts)):
+            got, ref = logits["kernel"][i], logits["xla"][i]
+            diff, steps = np.abs(got - ref), np.arange(new)
+            rms = float(np.sqrt((diff ** 2).mean()))
+            dlp = float(np.abs(
+                log_softmax(got)[steps, tokens[i]] - log_softmax(ref)[steps, tokens[i]]).max())
+            swaps = int((got.argmax(-1) != ref.argmax(-1)).sum())
+            ok = bool(np.isfinite(got).all() and rms < FORCED_RMS and diff.max() < FORCED_MAX
+                      and dlp < PATH_TOL)
+            check(ok, f"teacher-forced x{new} along {name}, prompt {i} ({len(prompts[i])} tokens), "
+                  f"kernel vs XLA-paged on one pool: |dlogit| rms={rms:.4f} (<{FORCED_RMS}) "
+                  f"max={diff.max():.4f} (<{FORCED_MAX}), emitted token's max|dlogprob|={dlp:.4f} "
+                  f"(<{PATH_TOL}), argmax differs at {swaps} steps",
+                  f"teacher-forced {name} prompt {i}")
+
+    # 2. Each served path against the reference's logits along its OWN tokens,
+    # at every step: its rows are identical, each token it emitted lies less
+    # than GREEDY_MARGIN under the reference's best there (so it IS the
+    # reference's token wherever the reference decides, before and after any
+    # tie), and the logprob it reported is the reference's up to PATH_TOL.
+    for name, results in runs.items():
+        own = np.stack([np.asarray(r.tokens)[0, :new] for r in results])
+        for i, r in enumerate(results):
+            tokens, lps = np.asarray(r.tokens)[:, :new], np.asarray(r.logprobs)[:, :new]
+            ref = by_tokens[own.tobytes()][i].copy()
+            if eng_pad not in tok.stop_ids:
+                ref[:, eng_pad] = -np.inf  # as every served path masks it before sampling
+            steps = np.arange(new)
+            behind = float((ref.max(-1) - ref[steps, tokens[0]]).max())
+            err = float(np.abs(lps - log_softmax(ref)[steps, tokens[0]]).max())
+            rows_same = bool((tokens == tokens[0]).all())
+            left = np.flatnonzero(tokens[0] != reference[i])
+            top2 = np.sort(ref, axis=-1)[:, -2:]
+            note = "the reference's tokens" if not left.size else (
+                f"leaves the reference's tokens at step {left[0]}, where its top two "
+                f"logits lie {top2[left[0], 1] - top2[left[0], 0]:.4f} apart")
+            ok = rows_same and behind < GREEDY_MARGIN and err < PATH_TOL
+            check(ok, f"greedy n=8 x{new} tokens, prompt {i}, {name:<18} rows identical={rows_same}, "
+                  f"{note}; furthest under the reference's best={behind:.4f} (<{GREEDY_MARGIN}) "
+                  f"max|dlogprob|={err:.4f} (<{PATH_TOL}) tokens={tokens[0].tolist()}",
+                  f"greedy {name} prompt {i}")
 
 
 def mesh_name(mesh):
@@ -196,6 +406,12 @@ def mesh_name(mesh):
 
 def main():
     device = jax.devices()[0]
+    if "--rehearse" in sys.argv[1:]:
+        # The model stage's own mechanics at toy size, anywhere: the tiny model
+        # in f32, the kernel in the interpreter (the loops run what the
+        # platform resolves). Debugs this script without a chip; proves no kernel.
+        greedy_model_case("tiny", False, "pallas_interpret", 8)
+        sys.exit(f"rehearsal FAILED: {failures}" if failures else 0)
     if device.platform == "cpu":
         sys.exit("chip_kernel_check: needs the accelerator (the CPU only has the interpreter)")
     n = len(jax.devices())
@@ -212,6 +428,10 @@ def main():
                 for continuous in (False, True):
                     paged_case(dtype, continuous, mesh)
                 paged_case(dtype, True, mesh, layers=2, layer=1)
+                # qwen2-7b's whole pool in bf16 ([28, 79936, 4, 128], 2.3 GB
+                # each of K and V); four of its layers in f32 at the same size.
+                deep = 28 if dtype == jnp.bfloat16 else 4
+                paged_case(dtype, True, mesh, layers=deep, layer=deep - 1, ragged=True)
                 flash_case(dtype, mesh)
     if "--skip-model" not in sys.argv[1:]:
         greedy_model_case()
