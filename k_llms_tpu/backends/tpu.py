@@ -21,6 +21,7 @@ from pydantic import BaseModel
 
 from ..consensus.prompts import SYSTEM_PROMPT_STRING_CONSENSUS_LLM
 from ..engine.engine import LocalEngine
+from ..engine.paging import row_reserve_pages
 from ..engine.tokenizer import get_tokenizer
 from ..models.config import get_config
 from ..types import ChatCompletion
@@ -368,7 +369,7 @@ class HbmMemoryModel:
         max_new = max(1, int(max_new))
         page_bytes = ps * self.kv_bytes_per_token // self.tp
         prompt_pages = -(-prompt_len // ps)
-        reserve = (prompt_len + max_new - 1) // ps - prompt_len // ps + 1
+        reserve = row_reserve_pages(prompt_len, max_new, ps)
         per_row = (
             reserve * page_bytes
             + -(-prompt_pages * page_bytes // fanout)
@@ -382,7 +383,7 @@ class HbmMemoryModel:
         one token-row per active slot (<= ``width``); a C-token chunk costs
         ~C token-rows of the same per-layer work, so C ~= 4*width keeps the
         chunk's step-budget share within a small multiple of a decode step
-        (the <= 3x steady-state stall bound bench_chunked_prefill pins).
+        (a stall of at most ~3x a steady-state step).
         Power of two, floored at 32, capped at max_prompt // 2 so chunking
         actually splits any prompt it engages on; 0 (off) when the prompt
         bound is too small for chunking to ever help."""

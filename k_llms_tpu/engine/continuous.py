@@ -11,13 +11,12 @@ requests instead of behind them.
 
 Design:
 
-- Device state is a per-slot prompt-prefix KV ``[L, W, P, kvh, d]`` plus a
-  per-slot generation KV ``[L, W, G, kvh, d]``; ONE jitted step function
-  (``verify_step`` with Sq=1 — its per-row ``lengths`` write offsets are
-  exactly the mid-flight join primitive) advances all W slots regardless of
-  which request each row belongs to. Freed slots need no cache clearing: the
-  self-attention mask only exposes slots ``<= lengths``, and a new occupant's
-  first step overwrites offset 0 before attending it.
+- Device state follows the engine's KV layout: the engine's page pool,
+  addressed through per-slot block tables (``engine/paging.py::SlotPages``:
+  what every benchmarked cell runs), or dense per-slot caches
+  (``_DenseSlots``). ONE step body (``_build_step``) advances all W slots
+  regardless of which request each row belongs to; its per-row ``lengths``
+  write offsets are exactly the mid-flight join primitive.
 - Sampling is a per-ROW array sampler (temperature[W] / top_p[W]) so requests
   with different sampling configs share the batch — the coalescing scheduler's
   batch_key compatibility restriction disappears. temperature 0 is greedy per
@@ -56,8 +55,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from functools import partial
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +64,7 @@ from concurrent.futures import Future
 
 from ..analysis.lockcheck import make_condition, note_device_dispatch, race_exempt
 from ..models.llama import KVCache, init_cache, paged_verify_step, verify_step
-from ..ops.paged_attention import live_pages, note_paged_attn_dispatch, table_pages
+from ..ops.paged_attention import note_paged_attn_dispatch
 from ..reliability import failpoints as _failpoints
 from ..reliability.deadline import RequestBudget
 from ..types.wire import (
@@ -91,13 +89,7 @@ from .engine import (
     _quarantine_error,
     is_resource_exhausted,
 )
-from .paging import (
-    TRASH_PAGE,
-    PageAccountingError,
-    PagePoolExhausted,
-    flat_slots,
-    pages_for,
-)
+from .paging import PageAccountingError, PagePoolExhausted, SlotPages
 
 logger = logging.getLogger(__name__)
 
@@ -164,6 +156,7 @@ class _SlotRequest:
     tenant: Optional[Any] = None
 
 
+@dataclass(eq=False)
 class _Prefilling:
     """The loop's single PREFILLING admission: a request whose prompt is
     being ingested chunk by chunk between decode steps instead of in one
@@ -174,22 +167,15 @@ class _Prefilling:
     All fields are guarded by the loop lock; the dispatch closure only reads
     snapshots taken under it."""
 
-    __slots__ = ("req", "rows", "ids", "cache", "cursor", "plen", "bucket",
-                 "run_pages", "reserved")
-
-    def __init__(self, req: "_SlotRequest", rows: List[int], ids: List[int],
-                 cache: Any, plen: int, bucket: int,
-                 run_pages: Optional[List[int]],
-                 reserved: List[List[int]]) -> None:
-        self.req = req
-        self.rows = rows
-        self.ids = ids
-        self.cache = cache
-        self.cursor = 0
-        self.plen = plen
-        self.bucket = bucket
-        self.run_pages = run_pages
-        self.reserved = reserved
+    req: "_SlotRequest"
+    rows: List[int]
+    ids: List[int]
+    cache: Any
+    plen: int
+    bucket: int
+    run_pages: Optional[List[int]]
+    reserved: List[List[int]]
+    cursor: int = 0
 
 
 def _req_tenant_name(req: "_SlotRequest") -> str:
@@ -202,6 +188,41 @@ def _req_interactive(req: "_SlotRequest") -> bool:
 
 def _req_tenant_weight(req: "_SlotRequest") -> float:
     return max(req.tenant.weight, 1e-9) if req.tenant is not None else 1.0
+
+
+class _DenseSlots:
+    """The dense layout's slot KV: a per-slot prompt prefix ``[L, W, P, kvh,
+    d]`` and a per-slot generation cache ``[L, W, G, kvh, d]``. No cell runs
+    it; it is the reference the paged differentials compare against. Freed
+    slots need no clearing: the self-attention mask only exposes positions
+    ``<= lengths``, and a new occupant's first step overwrites offset 0
+    before attending it."""
+
+    def __init__(self, config: Any, width: int, max_prompt: int, max_new: int) -> None:
+        self.max_prompt = max_prompt
+        self.prefix = init_cache(config, width, max_prompt)
+        self.gen = init_cache(config, width, max_new)
+
+        def _write_prefix(prefix, new_k, new_v, rows):
+            k = prefix.k.at[:, rows].set(new_k)
+            v = prefix.v.at[:, rows].set(new_v)
+            return KVCache(k=k, v=v)
+
+        self._write_prefix_fn = jax.jit(_write_prefix, donate_argnums=(0,))
+
+    def install(self, rows: List[int], cache: KVCache, bucket: int) -> None:
+        """Admission: replicate one request's prefill KV ``[L, 1, bucket,
+        ...]`` into its n slots."""
+        pk, pv = cache.k, cache.v
+        n = len(rows)
+        if bucket < self.max_prompt:
+            pad = [(0, 0)] * 5
+            pad[2] = (0, self.max_prompt - bucket)
+            pk, pv = jnp.pad(pk, pad), jnp.pad(pv, pad)
+        rows_arr = jnp.asarray(np.asarray(rows, np.int32))
+        rep_k = jnp.broadcast_to(pk[:, 0:1], (pk.shape[0], n) + pk.shape[2:])
+        rep_v = jnp.broadcast_to(pv[:, 0:1], (pv.shape[0], n) + pv.shape[2:])
+        self.prefix = self._write_prefix_fn(self.prefix, rep_k, rep_v, rows_arr)
 
 
 class _StepHung(RuntimeError):
@@ -352,21 +373,16 @@ class ContinuousDecodeLoop:
         self.engine = engine
         # Runtime twin of the annotations in this __init__ plus the
         # qualifies() inline suppression: the lockset sanitizer skips what the
-        # static rule skips. The device-state family (_prefix/_gen/_step_fn,
-        # the paged twins, and the resolved _paged_attn_impl) is handed to
-        # the disposable dispatch thread under the epoch fence rather than
-        # the loop lock.
+        # static rule skips. The device-state family (the layout's KV in
+        # _dense or _pool, _step_fn, and the resolved _paged_attn_impl) is
+        # handed to the disposable dispatch thread under the epoch fence
+        # rather than the loop lock.
         race_exempt(
             self,
             "engine",
-            "_pool_pages_planned",
             "_loop_epoch",
-            "_prefix",
-            "_gen",
+            "_dense",
             "_step_fn",
-            "_step_paged_fn",
-            "_write_prefix_fn",
-            "_sample_rows_fn",
             "_paged_attn_impl",
             "_pool",
             "_results_at",
@@ -462,44 +478,37 @@ class ContinuousDecodeLoop:
         self._grammar: Optional[Any] = None
         self._dgrammar: Optional[Any] = None
         self._g_programs: Optional[tuple] = None
-        self._sampler_parts: Optional[tuple] = None
-        # Device KV state, built lazily on first admission (compile + HBM cost
+        # Device state, built lazily on first admission (compile + HBM cost
         # only when the feature is actually used). The worker thread mutates
-        # these between steps; the disposable dispatch thread reads (and
-        # commits _gen) mid-step with no lock held — the epoch fence, not the
-        # loop lock, keeps abandoned threads from clobbering a rebuilt loop.
-        # kllms: unguarded — epoch-fenced handoff to the step dispatch thread
-        self._prefix: Optional[KVCache] = None
-        # kllms: unguarded — epoch-fenced handoff to the step dispatch thread
-        self._gen: Optional[KVCache] = None
+        # it between steps; the disposable dispatch thread reads it (and
+        # commits the step's new KV) mid-step with no lock held — the epoch
+        # fence, not the loop lock, keeps abandoned threads from clobbering a
+        # rebuilt loop.
         # kllms: unguarded — epoch-fenced handoff to the step dispatch thread
         self._step_fn = None
-        self._write_prefix_fn = None
-        self._sample_rows_fn = None
+        self._admit_sample_fn = None
         self._built = False
-        # PAGED slot state: the loop follows the engine's KV layout. Instead
-        # of dense per-slot caches, each slot holds a block TABLE of pool page
-        # ids (prompt pages shared across a request's n rows, refcounted;
-        # generation pages private, pre-reserved at admission so a mid-flight
-        # step can never fail on allocation) plus host index mirrors the
-        # jitted paged step consumes.
+        # The loop follows the engine's KV layout, and this is where the two
+        # part. PAGED: the engine's page pool holds the KV and ``_pages``
+        # keeps each slot's block table, reserve and index mirrors (the books
+        # exist from here on, for qualifies(); the pool at the first
+        # admission). DENSE: per-slot caches of the loop's own.
         self.paged = getattr(engine, "kv_layout", "dense") == "paged"
+        self._pages: Optional[SlotPages] = None
+        # kllms: unguarded — epoch-fenced handoff to the step dispatch thread
         self._pool = None
-        self._tables: List[List[int]] = [[] for _ in range(self.width)]
-        self._reserved: List[List[int]] = [[] for _ in range(self.width)]
-        self._prefix_idx = np.zeros((self.width, self.max_prompt), np.int32)
-        self._gen_idx = np.zeros((self.width, self.max_new), np.int32)
-        self._step_paged_fn = None
         self._paged_attn_impl = "xla"
+        # kllms: unguarded — epoch-fenced handoff to the step dispatch thread
+        self._dense: Optional[_DenseSlots] = None
         if self.paged:
             pool = getattr(engine, "_kv_pool", None)
-            self._pool_pages_planned = (
-                pool.allocator.total_pages
-                if pool is not None
-                else int(engine.kv_pool_pages or self._default_pool_pages())
+            self._pages = SlotPages(
+                engine.kv_page_size, self.width, self.max_prompt, self.max_new,
+                pool_pages=(
+                    pool.allocator.total_pages if pool is not None
+                    else engine.kv_pool_pages
+                ),
             )
-        else:
-            self._pool_pages_planned = 0
         # Stats (reported via backend health() and the bench workload).
         self._stats: Dict[str, Any] = {
             "steps": 0,
@@ -524,15 +533,6 @@ class ContinuousDecodeLoop:
         }
         self._thread: Optional[threading.Thread] = None
 
-    def _default_pool_pages(self) -> int:
-        """Pool sizing when neither the engine nor the backend pinned one:
-        every slot decoding a DISTINCT max-shape prompt (the no-sharing worst
-        case), plus one reserve page per slot for CoW, a couple of prompt-size
-        runs of prefix-cache slack, and the trash page."""
-        ps = self.engine.kv_page_size
-        per_slot = pages_for(self.max_prompt + self.max_new, ps) + 1
-        return self.width * per_slot + 2 * pages_for(self.max_prompt, ps) + 1
-
     @property
     def stats(self) -> Dict[str, Any]:
         """Loop counters — and, in paged mode, the page-pool snapshot behind a
@@ -552,16 +552,13 @@ class ContinuousDecodeLoop:
             out["occupancy"] = active_rows / self.width if self.width else 0.0
             out["queue_depth"] = len(self._queue)
             out["last_recovery_reason"] = self._last_recovery_reason
-            if self.paged and self._pool is not None:
+            if self._pool is not None:  # a paged loop, once built
                 if self._pool_fault is None:
                     fault = self._pool.allocator.check()
                     if fault is None:
-                        held = sum(len(t) for t in self._tables) + sum(
-                            len(r) for r in self._reserved
-                        )
                         out["pages"] = {
                             **self._pool.allocator.snapshot(),
-                            "loop_refs": held,
+                            "loop_refs": self._pages.held(),
                         }
                     else:
                         self._quarantine_pool_locked(fault)
@@ -595,17 +592,11 @@ class ContinuousDecodeLoop:
             and prompt_len <= self.max_prompt
             and max_new <= self.max_new
         )
-        if ok and self.paged:
-            # Peak page demand for this request alone must fit the pool even
-            # with the prefix cache fully evicted: one shared prompt run plus
-            # n private generation reserves (minus the trash page).
-            ps = self.engine.kv_page_size
-            reserve = (prompt_len + max_new - 1) // ps - prompt_len // ps + 1
-            need = pages_for(prompt_len, ps) + max(1, n) * reserve
+        if ok and self._pages is not None:
             # Admission revalidates page supply under the loop lock before
-            # placement, so a stale planned-pages read only skews this hint.
-            # kllms: ignore[guarded-by] — lock-free capacity pre-check hint
-            ok = need <= self._pool_pages_planned - 1
+            # placement, so a stale read of the pool's size only skews this
+            # hint.
+            ok = self._pages.fits(prompt_len, n, max_new)
         return ok
 
     def submit(
@@ -737,15 +728,14 @@ class ContinuousDecodeLoop:
 
     def _build_device_state(self) -> None:
         config = self.engine.config
-        mesh = getattr(self.engine, "mesh", None)
         W, P, G = self.width, self.max_prompt, self.max_new
         if self.paged:
             # One flat KV pool instead of dense per-slot caches; the engine
             # owns it so prefix-cache page runs and loop rows share pages.
             self._pool = self.engine._ensure_kv_pool(
-                min_pages=self._pool_pages_planned
+                min_pages=self._pages.planned_pages
             )
-            self._pool_pages_planned = self._pool.allocator.total_pages
+            self._pages.attach(self._pool)
             # Resolve the paged-attention implementation ONCE per loop build
             # (failpoint-aware, counted fallback) — never per step.
             from ..ops.paged_attention import resolve_paged_attention_impl
@@ -755,10 +745,17 @@ class ContinuousDecodeLoop:
                 config=config,
             )
         else:
-            self._prefix = init_cache(config, W, P)
-            self._gen = init_cache(config, W, G)
+            self._dense = _DenseSlots(config, W, P, G)
+        self._step_fn = self._build_step(grammar=False)
+        self._admit_sample_fn = self._build_first_token(grammar=False)
+        self._built = True
 
-        pad_id = config.pad_token_id
+    def _sampler(self) -> tuple:
+        """``(row_keys, sample_rows, mask_pad)``: the key schedule, the
+        per-row sampler and the pad mask every program of the loop shares, so
+        rows a grammar mask does not touch sample byte-identically with and
+        without one."""
+        pad_id = self.engine.config.pad_token_id
         # pad must stay unsampleable on live rows unless the tokenizer maps
         # pad onto eos (then it IS the stop token) — same rule as the batch
         # decode loop.
@@ -781,7 +778,6 @@ class ContinuousDecodeLoop:
             # logits BEFORE sanitization: a poisoned row still samples (the
             # sanitized path keeps the batch marching) but the host freezes
             # and retires it with sample_error code "numeric_poison".
-            V = logits.shape[-1]
             bad = _poisoned_logits(logits)
             finite = jnp.isfinite(logits)
             row_ok = jnp.any(finite, axis=-1, keepdims=True)
@@ -810,102 +806,157 @@ class ContinuousDecodeLoop:
                 return logits
             return logits.at[:, pad_id].set(-jnp.inf)
 
-        def _step(params, prefix, gen, cur, gen_lens, prompt_lens, active,
-                  seeds, sample_idx, temps, top_ps, poison):
-            # One token for all W slots: write cur's KV at each row's own
-            # offset (gen_lens), attend row-local prefix + generated KV.
+        return _row_keys, _sample_rows, _mask_pad
+
+    def _grammar_ops(self) -> tuple:
+        """``(apply_mask, advance)`` over the resident grammar's tables, which
+        the programs take as ARGUMENTS (only the vocab size is static)."""
+        from .grammar import DeviceGrammar, grammar_advance, grammar_mask_logits
+
+        vocab_size = self._dgrammar.vocab_size
+        eos_arr = jnp.asarray(self.eos_ids, jnp.int32)
+
+        def _as_grammar(tabs):
+            masks, trans, terminal, token_bytes, token_len = tabs
+            return DeviceGrammar(
+                masks, trans, terminal, token_bytes, token_len, 0, vocab_size
+            )
+
+        @jax.named_scope("grammar_mask")
+        def _apply_mask(logits, g_states, g_flags, tabs):
+            masked = grammar_mask_logits(_as_grammar(tabs), logits, g_states, eos_arr)
+            return jnp.where(g_flags[:, None], masked, logits)
+
+        @jax.named_scope("grammar_advance")
+        def _advance(tok, g_states, g_flags, tabs):
+            nxt = grammar_advance(_as_grammar(tabs), tok, g_states)
+            return jnp.where(g_flags, nxt, g_states)
+
+        return _apply_mask, _advance
+
+    def _build_step(self, grammar: bool):
+        """The decode step, written once over two static choices: the KV
+        layout and whether a grammar mask rides along. One token for all W
+        slots; the four programs that come out keep the names every profile
+        and the ledger's breakdown know them by: ``_step``, ``_step_paged``,
+        ``_step_g``, ``_step_paged_g``. Arguments: ``(params, *layout_state,
+        *row_args, *layout_idx, poison, *grammar_args)``; results ``(tok, lp,
+        bad, *new_kv[, g_states], aux)``."""
+        config = self.engine.config
+        mesh = getattr(self.engine, "mesh", None)
+        paged = self.paged
+        pad_id = config.pad_token_id
+        row_keys, sample_rows, mask_pad = self._sampler()
+        if grammar:
+            apply_mask, advance = self._grammar_ops()
+        if paged:
+            attn_impl, page_size = self._paged_attn_impl, self._pool.page_size
+        n_idx = 3 if paged else 0
+
+        def _body(params, kv_a, kv_b, cur, gen_lens, prompt_lens, active,
+                  seeds, sample_idx, temps, top_ps, *rest):
+            layout_idx, (poison, *g_args) = rest[:n_idx], rest[n_idx:]
             # ``aux``: what the model's stack counts (router loads, cache
             # rows read: utils/observability.py::note_model_aux adds them at
             # readback); empty for a model that counts nothing.
             aux: Dict[str, Any] = {}
-            logits, gen = verify_step(
-                config, params, cur[:, None], gen_lens, prompt_lens, gen, prefix,
-                mesh=mesh, aux=aux,
+            if paged:
+                # Rows read their KV through block-table gathers into the
+                # shared pool and write cur's column back at a host-computed
+                # flat slot. Same masks, same sampler, same key schedule —
+                # byte-identical tokens to the dense layout.
+                pool_k, pool_v = kv_a, kv_b
+                prefix_idx, gen_idx, write_idx = layout_idx
+                # A retired slot keeps its last tenant's lengths on the host;
+                # the model is given none for it, so the paged kernel walks
+                # no page of an idle row (its output is discarded below
+                # either way).
+                logits, k_cols, v_cols = paged_verify_step(
+                    config, params, cur[:, None],
+                    jnp.where(active, gen_lens, 0),
+                    jnp.where(active, prompt_lens, 0),
+                    KVCache(k=pool_k, v=pool_v), prefix_idx, gen_idx,
+                    attn_impl=attn_impl, page_size=page_size,
+                    mesh=mesh, aux=aux,
+                )
+                with jax.named_scope("kv_write"):
+                    pool_k = pool_k.at[:, write_idx].set(k_cols.astype(pool_k.dtype))
+                    pool_v = pool_v.at[:, write_idx].set(v_cols.astype(pool_v.dtype))
+                new_kv = (pool_k, pool_v)
+            else:
+                # Write cur's KV at each row's own offset (gen_lens), attend
+                # row-local prefix + generated KV (``verify_step`` with Sq=1:
+                # its per-row write offsets are the mid-flight join).
+                prefix, gen = kv_a, kv_b
+                logits, gen = verify_step(
+                    config, params, cur[:, None], gen_lens, prompt_lens,
+                    gen, prefix, mesh=mesh, aux=aux,
+                )
+                new_kv = (gen,)
+            # Poison is injected BEFORE the pad and grammar masks: NaNs
+            # survive the masks' allowed positions, so detection sees them
+            # either way.
+            logits = jnp.where(
+                poison[:, None], jnp.float32(jnp.nan), logits[:, 0, :]
             )
-            logits = _mask_pad(logits[:, 0, :])
-            logits = jnp.where(poison[:, None], jnp.float32(jnp.nan), logits)
-            keys = _row_keys(seeds, gen_lens + 1, sample_idx)
-            tok, lp, bad = _sample_rows(logits, keys, temps, top_ps)
+            logits = mask_pad(logits)
+            if grammar:
+                g_states, g_flags, *tabs = g_args
+                logits = apply_mask(logits, g_states, g_flags, tabs)
+            keys = row_keys(seeds, gen_lens + 1, sample_idx)
+            tok, lp, bad = sample_rows(logits, keys, temps, top_ps)
             tok = jnp.where(active, tok, jnp.int32(pad_id))
             lp = jnp.where(active, lp, 0.0)
-            return tok, lp, bad & active, gen, aux
+            out = (tok, lp, bad & active) + new_kv
+            if grammar:
+                out += (advance(tok, g_states, g_flags, tabs),)
+            return out + (aux,)
 
-        # gen KV is donated: the loop is its only owner and it is re-passed
-        # every step, so the update happens in place on device.
-        self._step_fn = jax.jit(_step, donate_argnums=(2,))
+        _body.__name__ = (
+            "_step" + ("_paged" if paged else "") + ("_g" if grammar else "")
+        )
+        # The step's KV is donated (the pool's pair; the dense generation
+        # cache): its only owner re-passes it every step, so the update
+        # happens in place on device.
+        return jax.jit(_body, donate_argnums=(1, 2) if paged else (2,))
 
-        def _write_prefix(prefix, new_k, new_v, rows):
-            # Admission: replicate one request's prefill KV into its n slots.
-            k = prefix.k.at[:, rows].set(new_k)
-            v = prefix.v.at[:, rows].set(new_v)
-            return KVCache(k=k, v=v)
+    def _build_first_token(self, grammar: bool):
+        """The first token, sampled at admission from the prefill logits at
+        step 0 — padded to W rows so every admission shares one program.
+        Detection-only quarantine here (no injection arg: the
+        ``engine.logits`` failpoint targets decode steps); genuinely poisoned
+        prefill logits still freeze the row at step 0. Under a grammar the
+        sample is masked from the start state and each row's automaton
+        advanced on the device."""
+        row_keys, sample_rows, mask_pad = self._sampler()
+        if grammar:
+            apply_mask, advance = self._grammar_ops()
 
-        self._write_prefix_fn = jax.jit(_write_prefix, donate_argnums=(0,))
+        def _body(first_logits, seeds, sample_idx, temps, top_ps, *g_args):
+            logits = mask_pad(first_logits)
+            if grammar:
+                g_states, g_flags, *tabs = g_args
+                logits = apply_mask(logits, g_states, g_flags, tabs)
+            keys = row_keys(seeds, jnp.zeros_like(sample_idx), sample_idx)
+            out = sample_rows(logits, keys, temps, top_ps)
+            if grammar:
+                out += (advance(out[0], g_states, g_flags, tabs),)
+            return out
 
-        def _admit_sample(first_logits, seeds, sample_idx, temps, top_ps):
-            # First token, sampled at admission from the prefill logits at
-            # step 0 — padded to W rows so every admission shares one program.
-            # Detection-only quarantine here (no injection arg: the
-            # ``engine.logits`` failpoint targets decode steps); genuinely
-            # poisoned prefill logits still freeze the row at step 0.
-            keys = _row_keys(seeds, jnp.zeros_like(sample_idx), sample_idx)
-            return _sample_rows(_mask_pad(first_logits), keys, temps, top_ps)
-
-        self._admit_sample_fn = jax.jit(_admit_sample)
-
-        def _step_paged(params, pool_k, pool_v, cur, gen_lens, prompt_lens,
-                        active, seeds, sample_idx, temps, top_ps, prefix_idx,
-                        gen_idx, write_idx, poison):
-            # Paged twin of _step: rows read their KV through block-table
-            # gathers into the shared pool and write cur's column back at a
-            # host-computed flat slot. Same masks, same sampler, same key
-            # schedule — byte-identical tokens to the dense loop.
-            aux: Dict[str, Any] = {}
-            # A retired slot keeps its last tenant's lengths on the host; the
-            # model is given none for it, so the paged kernel walks no page
-            # of an idle row (its output is discarded below either way).
-            logits, k_cols, v_cols = paged_verify_step(
-                config, params, cur[:, None],
-                jnp.where(active, gen_lens, 0), jnp.where(active, prompt_lens, 0),
-                KVCache(k=pool_k, v=pool_v), prefix_idx, gen_idx,
-                attn_impl=self._paged_attn_impl,
-                page_size=self._pool.page_size,
-                mesh=mesh, aux=aux,
-            )
-            with jax.named_scope("kv_write"):
-                pool_k = pool_k.at[:, write_idx].set(k_cols.astype(pool_k.dtype))
-                pool_v = pool_v.at[:, write_idx].set(v_cols.astype(pool_v.dtype))
-            logits = _mask_pad(logits[:, 0, :])
-            logits = jnp.where(poison[:, None], jnp.float32(jnp.nan), logits)
-            keys = _row_keys(seeds, gen_lens + 1, sample_idx)
-            tok, lp, bad = _sample_rows(logits, keys, temps, top_ps)
-            tok = jnp.where(active, tok, jnp.int32(pad_id))
-            lp = jnp.where(active, lp, 0.0)
-            return tok, lp, bad & active, pool_k, pool_v, aux
-
-        self._step_paged_fn = jax.jit(_step_paged, donate_argnums=(1, 2))
-        # Raw sampler pieces, reused by the grammar-twin programs so masked
-        # rows share the exact key schedule and sampler math (byte-identical
-        # tokens for rows the mask does not touch).
-        self._sampler_parts = (_row_keys, _sample_rows, _mask_pad)
-        self._built = True
+        _body.__name__ = "_admit_g" if grammar else "_admit_sample"
+        return jax.jit(_body)
 
     # -- grammar-constrained programs --------------------------------------
 
     def _grammar_busy_locked(self, grammar: Any) -> bool:
         """Is constrained work under a *different* schema queued or active?
         (Same digest shares the resident tables.) Lock held by the caller."""
-        for r in self._active:
-            if r is not None and r.grammar is not None \
-                    and r.grammar.digest != grammar.digest:
-                return True
-        pf = self._prefilling
-        if pf is not None and pf.req.grammar is not None \
-                and pf.req.grammar.digest != grammar.digest:
-            return True
+        holders = [r for r in self._active if r is not None] + list(self._queue)
+        if self._prefilling is not None:
+            holders.append(self._prefilling.req)
         return any(
             r.grammar is not None and r.grammar.digest != grammar.digest
-            for r in self._queue
+            for r in holders
         )
 
     def _install_grammar(self, grammar: Any) -> None:
@@ -936,89 +987,9 @@ class ContinuousDecodeLoop:
         )
         if self._g_programs is not None and self._g_programs[0] == shape_key:
             return self._g_programs[1]
-        from .grammar import DeviceGrammar, grammar_advance, grammar_mask_logits
-
-        config = self.engine.config
-        mesh = getattr(self.engine, "mesh", None)
-        pad_id = config.pad_token_id
-        row_keys, sample_rows, mask_pad = self._sampler_parts
-        vocab_size = dg.vocab_size
-        eos_arr = jnp.asarray(self.eos_ids, jnp.int32)
-
-        def _as_grammar(tabs):
-            masks, trans, terminal, token_bytes, token_len = tabs
-            return DeviceGrammar(
-                masks, trans, terminal, token_bytes, token_len, 0, vocab_size
-            )
-
-        @jax.named_scope("grammar_mask")
-        def _apply_mask(logits, g_states, g_flags, tabs):
-            masked = grammar_mask_logits(_as_grammar(tabs), logits, g_states, eos_arr)
-            return jnp.where(g_flags[:, None], masked, logits)
-
-        @jax.named_scope("grammar_advance")
-        def _advance(tok, g_states, g_flags, tabs):
-            nxt = grammar_advance(_as_grammar(tabs), tok, g_states)
-            return jnp.where(g_flags, nxt, g_states)
-
-        def _admit_g(first_logits, seeds, sample_idx, temps, top_ps,
-                     g_states, g_flags, *tabs):
-            logits = _apply_mask(mask_pad(first_logits), g_states, g_flags, tabs)
-            keys = row_keys(seeds, jnp.zeros_like(sample_idx), sample_idx)
-            tok, lp, bad = sample_rows(logits, keys, temps, top_ps)
-            return tok, lp, bad, _advance(tok, g_states, g_flags, tabs)
-
-        def _step_g(params, prefix, gen, cur, gen_lens, prompt_lens, active,
-                    seeds, sample_idx, temps, top_ps, poison, g_states,
-                    g_flags, *tabs):
-            aux: Dict[str, Any] = {}
-            logits, gen = verify_step(
-                config, params, cur[:, None], gen_lens, prompt_lens, gen, prefix,
-                mesh=mesh, aux=aux,
-            )
-            # Poison is injected BEFORE the grammar mask: NaNs survive the
-            # mask's allowed positions, so detection sees them either way.
-            logits = jnp.where(
-                poison[:, None], jnp.float32(jnp.nan), logits[:, 0, :]
-            )
-            logits = _apply_mask(mask_pad(logits), g_states, g_flags, tabs)
-            keys = row_keys(seeds, gen_lens + 1, sample_idx)
-            tok, lp, bad = sample_rows(logits, keys, temps, top_ps)
-            tok = jnp.where(active, tok, jnp.int32(pad_id))
-            lp = jnp.where(active, lp, 0.0)
-            return tok, lp, bad & active, gen, _advance(tok, g_states, g_flags, tabs), aux
-
-        def _step_paged_g(params, pool_k, pool_v, cur, gen_lens, prompt_lens,
-                          active, seeds, sample_idx, temps, top_ps, prefix_idx,
-                          gen_idx, write_idx, poison, g_states, g_flags, *tabs):
-            aux: Dict[str, Any] = {}
-            logits, k_cols, v_cols = paged_verify_step(
-                config, params, cur[:, None],
-                jnp.where(active, gen_lens, 0), jnp.where(active, prompt_lens, 0),
-                KVCache(k=pool_k, v=pool_v), prefix_idx, gen_idx,
-                attn_impl=self._paged_attn_impl,
-                page_size=self._pool.page_size,
-                mesh=mesh, aux=aux,
-            )
-            with jax.named_scope("kv_write"):
-                pool_k = pool_k.at[:, write_idx].set(k_cols.astype(pool_k.dtype))
-                pool_v = pool_v.at[:, write_idx].set(v_cols.astype(pool_v.dtype))
-            logits = jnp.where(
-                poison[:, None], jnp.float32(jnp.nan), logits[:, 0, :]
-            )
-            logits = _apply_mask(mask_pad(logits), g_states, g_flags, tabs)
-            keys = row_keys(seeds, gen_lens + 1, sample_idx)
-            tok, lp, bad = sample_rows(logits, keys, temps, top_ps)
-            tok = jnp.where(active, tok, jnp.int32(pad_id))
-            lp = jnp.where(active, lp, 0.0)
-            return tok, lp, bad & active, pool_k, pool_v, _advance(
-                tok, g_states, g_flags, tabs
-            ), aux
-
         fns = {
-            "admit": jax.jit(_admit_g),
-            "step": jax.jit(_step_g, donate_argnums=(2,)),
-            "step_paged": jax.jit(_step_paged_g, donate_argnums=(1, 2)),
+            "admit": self._build_first_token(grammar=True),
+            "step": self._build_step(grammar=True),
         }
         self._g_programs = (shape_key, fns)
         return fns
@@ -1296,20 +1267,14 @@ class ContinuousDecodeLoop:
         self._grammar = None
         self._dgrammar = None
         self._g_programs = None
-        self._sampler_parts = None
-        self._prefix = None
-        self._gen = None
         self._step_fn = None
-        self._write_prefix_fn = None
         self._admit_sample_fn = None
-        self._step_paged_fn = None
+        self._dense = None
         self._pool = None
-        self._tables = [[] for _ in range(self.width)]
-        self._reserved = [[] for _ in range(self.width)]
-        self._prefix_idx[:] = 0
-        self._gen_idx[:] = 0
+        if self._pages is not None:
+            self._pages.reset()
         self._pool_fault = None
-        # Like the tables above: the holder's page references die with the
+        # Like the slots' tables: the holder's page references die with the
         # pool, no decref against a replaced allocator.
         self._prefilling = None
         self._built = False
@@ -1472,22 +1437,28 @@ class ContinuousDecodeLoop:
     def _admit_device(self, req, rows) -> None:
         engine = self.engine
         _ids, _plen, bucket = engine._prep_prompt(req.ids)
-        n = len(rows)
         if self.paged:
-            first_logits = self._admit_paged_kv(req, rows, _ids, _plen, bucket)
+            # The prompt KV as shared, refcounted pool pages: the prefill's
+            # (or the cache entry's) page run, a reference for each row, and
+            # each row's generation reserve; PagePoolExhausted leaves here
+            # with everything rolled back.
+            first_logits, run, transient = engine.paged_admit_prefix(
+                _ids, _plen, bucket
+            )
+            try:
+                with engine._paged_mutex:
+                    self._pages.admit(
+                        rows, run.pages, _plen, req.max_new,
+                        engine._alloc_pages_with_evict,
+                    )
+            finally:
+                if transient:
+                    # Uncached prefill: the run was a scratch owner of the
+                    # prompt pages; the rows' references now keep them alive.
+                    run.release()
         else:
             first_logits, prefix = engine._prefill_routed(_ids, _plen, bucket)
-            pk, pv = prefix.k, prefix.v
-            if bucket < self.max_prompt:
-                pad = [(0, 0)] * 5
-                pad[2] = (0, self.max_prompt - bucket)
-                pk, pv = jnp.pad(pk, pad), jnp.pad(pv, pad)
-            rows_arr = jnp.asarray(np.asarray(rows, np.int32))
-            rep_k = jnp.broadcast_to(pk[:, 0:1], (pk.shape[0], n) + pk.shape[2:])
-            rep_v = jnp.broadcast_to(pv[:, 0:1], (pv.shape[0], n) + pv.shape[2:])
-            self._prefix = self._write_prefix_fn(
-                self._prefix, rep_k, rep_v, rows_arr
-            )
+            self._dense.install(rows, prefix, bucket)
         self._admit_rows(req, rows, first_logits)
 
     def _admit_rows(self, req, rows, first_logits) -> None:
@@ -1511,35 +1482,28 @@ class ContinuousDecodeLoop:
         temps[:n] = temperature
         tps = np.full((W,), 1.0, np.float32)
         tps[:n] = top_p
+        first_fn, grammar_args = self._admit_sample_fn, ()
         if req.grammar is not None:
             # Constrained admission: mask the first sample from the start
             # state and advance each row's automaton on device; the states
             # ride the same readback as tok0/lp0 (admission is not the hot
             # loop, but there is still only one sync here).
             self._install_grammar(req.grammar)
-            fns = self._grammar_programs()
-            g_states = np.full((W,), self._dgrammar.start, np.int32)
+            first_fn = self._grammar_programs()["admit"]
             g_flags = np.zeros((W,), bool)
             g_flags[:n] = True
-            tok0, lp0, bad0, st0 = fns["admit"](
-                fl, jnp.asarray(seeds), jnp.asarray(sidx), jnp.asarray(temps),
-                jnp.asarray(tps), jnp.asarray(g_states), jnp.asarray(g_flags),
-                *self._g_tabs(),
+            grammar_args = (
+                jnp.full((W,), self._dgrammar.start, jnp.int32),
+                jnp.asarray(g_flags), *self._g_tabs(),
             )
-            tok0, lp0, bad0, st0 = map(
-                np.asarray, jax.device_get((tok0, lp0, bad0, st0))
-            )
-            tok0, lp0, bad0, st0 = tok0[:n], lp0[:n], bad0[:n], st0[:n]
+        outs = first_fn(
+            fl, jnp.asarray(seeds), jnp.asarray(sidx), jnp.asarray(temps),
+            jnp.asarray(tps), *grammar_args,
+        )
+        tok0, lp0, bad0, *st0 = (np.asarray(a)[:n] for a in jax.device_get(outs))
+        if st0:
             GRAMMAR_EVENTS.record("grammar.masked_steps", n)
-        else:
-            tok0, lp0, bad0 = self._admit_sample_fn(
-                fl, jnp.asarray(seeds), jnp.asarray(sidx), jnp.asarray(temps),
-                jnp.asarray(tps),
-            )
-            tok0 = np.asarray(jax.device_get(tok0))[:n]
-            lp0 = np.asarray(jax.device_get(lp0))[:n]
-            bad0 = np.asarray(jax.device_get(bad0))[:n]
-            st0 = np.zeros((n,), np.int32)
+        st0 = st0[0] if st0 else np.zeros((n,), np.int32)
 
         quarantined = 0
         for j, slot in enumerate(rows):
@@ -1614,38 +1578,20 @@ class ContinuousDecodeLoop:
         return probe is None or probe(req.ids) == 0
 
     def _begin_prefilling_locked(self, req: _SlotRequest, rows: List[int]) -> None:
-        """Enter the PREFILLING state: allocate the prompt's page run and
-        every row's generation reserve UP FRONT (chunk-aware reservation —
-        the same worst-case demand qualifies() checked, so a half-prefilled
-        admission can never strand mid-prompt on allocation), build the
-        1-row staging KV the chunks extend, and hand the request to the
-        worker's chunk phase. Raises :class:`PagePoolExhausted` with
-        everything rolled back, exactly like whole-prompt admission."""
+        """Enter the PREFILLING state: take the prompt's page run and every
+        row's generation reserve UP FRONT (:meth:`SlotPages.reserve_chunked`;
+        :class:`PagePoolExhausted` leaves with everything rolled back, exactly
+        like whole-prompt admission), build the 1-row staging KV the chunks
+        extend, and hand the request to the worker's chunk phase."""
         engine = self.engine
         _ids, _plen, bucket = engine._prep_prompt(req.ids)
         run_pages: Optional[List[int]] = None
         reserved: List[List[int]] = []
-        if self.paged:
-            alloc = self._pool.allocator
-            ps = self._pool.page_size
-            reserve = (_plen + req.max_new - 1) // ps - _plen // ps + 1
+        if self._pages is not None:
             with engine._paged_mutex:
-                run_pages = engine._alloc_pages_with_evict(pages_for(_plen, ps))
-                extra_refs = 0
-                try:
-                    # One prompt-run reference per row (the n-way fan-out
-                    # shares one copy, like _admit_paged_kv).
-                    for _ in range(len(rows) - 1):
-                        alloc.incref(run_pages)
-                        extra_refs += 1
-                    for _ in rows:
-                        reserved.append(engine._alloc_pages_with_evict(reserve))
-                except BaseException:
-                    for lst in reserved:
-                        alloc.decref(lst)
-                    for _ in range(extra_refs + 1):
-                        alloc.decref(run_pages)
-                    raise
+                run_pages, reserved = self._pages.reserve_chunked(
+                    len(rows), _plen, req.max_new, engine._alloc_pages_with_evict
+                )
         cache = init_cache(engine.config, 1, bucket)
         mesh = getattr(engine, "mesh", None)
         if mesh is not None:
@@ -1698,17 +1644,14 @@ class ContinuousDecodeLoop:
                 chunk = np.full((1, C), pad_id, np.int32)
                 chunk[0, :valid] = pf.ids[start:end]
                 cache, bucket = pf.cache, pf.bucket
-                pool = slot_idx = None
-                if self.paged:
-                    pool = self._pool
-                    ps = pool.page_size
-                    # The chunk's KV columns land in the row's reserved page
-                    # run at its current offset; pad positions retarget to
-                    # trash.
-                    slot_idx = flat_slots(pf.run_pages, start + np.arange(C), ps)
-                    trash = (np.arange(C) % ps + TRASH_PAGE * ps).astype(np.int32)
-                    slot_idx[valid:] = trash[valid:]
-            fn = self.engine._get_prefill_chunk(C, bucket, self.paged)
+                # Paged: the chunk's KV columns land in the admission's page
+                # run at its current offset.
+                pool = self._pool
+                slot_idx = (
+                    self._pages.chunk_slots(pf.run_pages, start, C, valid)
+                    if pool is not None else None
+                )
+            fn = self.engine._get_prefill_chunk(C, bucket, pool is not None)
 
         def _dispatch():
             self._observe_gap()
@@ -1721,21 +1664,15 @@ class ContinuousDecodeLoop:
                 raise _StaleStep("prefill chunk fenced before dispatch")
             note_device_dispatch("continuous prefill chunk")
             with LATENCY.span("continuous.dispatch", chunk=chunk_no):
-                if self.paged:
-                    logits, new_cache, k_cols, v_cols, aux = fn(
-                        self.engine.params, jnp.asarray(chunk), cache,
-                        jnp.int32(start), jnp.int32(valid),
-                    )
-                    if self._loop_epoch != epoch:
-                        raise _StaleStep("prefill chunk fenced post-dispatch")
-                    pool.scatter_tokens(k_cols, v_cols, slot_idx)
-                else:
-                    logits, new_cache, aux = fn(
-                        self.engine.params, jnp.asarray(chunk), cache,
-                        jnp.int32(start), jnp.int32(valid),
-                    )
-                    if self._loop_epoch != epoch:
-                        raise _StaleStep("prefill chunk fenced post-dispatch")
+                # (logits, staging cache[, the chunk's k and v columns], aux)
+                logits, new_cache, *cols, aux = fn(
+                    self.engine.params, jnp.asarray(chunk), cache,
+                    jnp.int32(start), jnp.int32(valid),
+                )
+                if self._loop_epoch != epoch:
+                    raise _StaleStep("prefill chunk fenced post-dispatch")
+                if pool is not None:
+                    pool.scatter_tokens(*cols, slot_idx)
             # Synchronize on the (tiny) logits readback so the watchdog
             # budget covers the device work, like the step's readback.
             with LATENCY.span("continuous.readback"):
@@ -1781,36 +1718,18 @@ class ContinuousDecodeLoop:
         with the submission-pinned seed."""
         engine = self.engine
         req, rows = pf.req, pf.rows
+        cached = getattr(engine, "prefix_cache_size", 0) > 0
         if self.paged:
-            for j, slot in enumerate(rows):
-                self._tables[slot] = list(pf.run_pages)
-                self._reserved[slot] = pf.reserved[j]
-                self._refresh_row_idx(slot, pf.plen)
-            if getattr(engine, "prefix_cache_size", 0) > 0:
-                from .paging import PagedPrefixRun
-
-                # One extra reference transfers to the cache entry; the
-                # run is already scattered, so the store is pure accounting.
-                self._pool.allocator.incref(pf.run_pages)
+            self._pages.install(rows, pf.run_pages, pf.reserved, pf.plen)
+            if cached:
+                # The entry's reference is one more on the run.
                 engine._prefix_store_paged_run(
                     pf.ids, first_logits,
-                    PagedPrefixRun(self._pool, list(pf.run_pages),
-                                   pf.plen, pf.bucket),
+                    self._pages.prefix_run(pf.run_pages, pf.plen, pf.bucket),
                 )
         else:
-            pk, pv = pf.cache.k, pf.cache.v
-            n = len(rows)
-            if pf.bucket < self.max_prompt:
-                pad = [(0, 0)] * 5
-                pad[2] = (0, self.max_prompt - pf.bucket)
-                pk, pv = jnp.pad(pk, pad), jnp.pad(pv, pad)
-            rows_arr = jnp.asarray(np.asarray(rows, np.int32))
-            rep_k = jnp.broadcast_to(pk[:, 0:1], (pk.shape[0], n) + pk.shape[2:])
-            rep_v = jnp.broadcast_to(pv[:, 0:1], (pv.shape[0], n) + pv.shape[2:])
-            self._prefix = self._write_prefix_fn(
-                self._prefix, rep_k, rep_v, rows_arr
-            )
-            if getattr(engine, "prefix_cache_size", 0) > 0:
+            self._dense.install(rows, pf.cache, pf.bucket)
+            if cached:
                 engine._prefix_store(pf.ids, first_logits, pf.cache)
         self._admit_rows(req, rows, first_logits)
 
@@ -1827,20 +1746,8 @@ class ContinuousDecodeLoop:
             return
         self._prefilling = None
         req = pf.req
-        if self.paged and self._pool is not None and pf.run_pages is not None:
-            alloc = self._pool.allocator
-            try:
-                for _ in pf.rows:
-                    alloc.decref(pf.run_pages)
-                for lst in pf.reserved:
-                    alloc.decref(lst)
-            except PageAccountingError:
-                # Containment over a corrupt allocator: drop the references
-                # (the pool audit quarantines it) so the future still fails
-                # typed instead of wedging retirement.
-                logger.exception(
-                    "page release failed retiring a PREFILLING admission"
-                )
+        if pf.run_pages is not None:
+            self._pages.drop(len(pf.rows), pf.run_pages, pf.reserved)
         for slot in pf.rows:
             self._free.append(slot)
         req.slots = []
@@ -1851,188 +1758,44 @@ class ContinuousDecodeLoop:
             req.future.set_exception(exc)
         self._lock.notify_all()
 
-    # -- paged slot management --------------------------------------------
-
-    def _admit_paged_kv(self, req, rows, _ids, _plen, bucket):
-        """Install one request's prompt KV as shared, refcounted pool pages.
-
-        The prefill's page run is incref'd once per row (the n-way fan-out
-        shares ONE copy of the prompt KV), and each row pre-reserves its
-        private generation pages up front so a mid-flight decode step can
-        never fail on allocation. Copy-on-write of the partially-filled last
-        prompt page happens lazily at each row's first divergent write
-        (:meth:`_prepare_step_pages`). Raises :class:`PagePoolExhausted` with
-        everything rolled back if the reserves don't fit."""
-        engine = self.engine
-        alloc = self._pool.allocator
-        ps = self._pool.page_size
-        first_logits, run, transient = engine.paged_admit_prefix(
-            _ids, _plen, bucket
-        )
-        # Pages the row's writes can touch: gen positions occupy pages
-        # plen//ps .. (plen+max_new-1)//ps; the first of those is the prompt's
-        # partial page (CoW target) when plen % ps != 0, fresh otherwise —
-        # the +1 covers both cases.
-        reserve = (_plen + req.max_new - 1) // ps - _plen // ps + 1
-        new_reserved: List[List[int]] = []
-        try:
-            with engine._paged_mutex:
-                for _ in rows:
-                    alloc.incref(run.pages)
-                try:
-                    for _ in rows:
-                        new_reserved.append(
-                            engine._alloc_pages_with_evict(reserve)
-                        )
-                except BaseException:
-                    for lst in new_reserved:
-                        alloc.decref(lst)
-                    for _ in rows:
-                        alloc.decref(run.pages)
-                    raise
-        finally:
-            if transient:
-                # Uncached prefill: the run was a scratch owner of the prompt
-                # pages; the rows' increfs above now keep them alive.
-                run.release()
-        for j, slot in enumerate(rows):
-            self._tables[slot] = list(run.pages)
-            self._reserved[slot] = new_reserved[j]
-            self._refresh_row_idx(slot, _plen)
-        return first_logits
-
-    def _refresh_row_idx(self, slot: int, plen: Optional[int] = None) -> None:
-        """Rebuild one slot's flat gather indices from its block table. Must
-        run after ANY table change (admit, extension, CoW, release): a stale
-        index could keep gathering a page that was freed and reused."""
-        ps = self._pool.page_size
-        table = self._tables[slot]
-        P, G = self.max_prompt, self.max_new
-        if plen is None:
-            plen = int(self._prompt_lens[slot])
-        pidx = flat_slots(table, np.arange(P), ps)
-        # Positions at/after the prompt end read through gen_idx instead;
-        # point them into the trash page (masked, but must stay in bounds).
-        pidx[plen:] = (np.arange(P - plen) % ps).astype(np.int32)
-        self._prefix_idx[slot] = pidx
-        self._gen_idx[slot] = flat_slots(table, plen + np.arange(G), ps)
-
-    def _step_page_counts(self) -> Tuple[int, int]:
-        """(pages the live rows' walks hold, pages the step's tables hold) for
-        the upcoming decode step, from what the step program hands the paged
-        kernel: the lengths with idle slots zeroed, the phase out of the gen
-        slot map. Lock held."""
-        ps = self._pool.page_size
-        n_prefix, n_gen = live_pages(
-            np.where(self._active_mask, self._prompt_lens, 0),
-            np.where(self._active_mask, self._gen_lens, 0),
-            self._gen_idx[:, 0] % ps,
-            ps,
-        )
-        tabled = self.width * sum(table_pages(self.max_prompt, self.max_new, ps))
-        return int(n_prefix.sum() + n_gen.sum()), tabled
-
-    def _prepare_step_pages(self) -> np.ndarray:
-        """Resolve each row's write slot for the upcoming step, performing
-        page-table maintenance on the way: append a reserved page when the
-        write crosses a page boundary, copy-on-write when the target page is
-        still shared with other readers. Returns the [W] flat write indices
-        (inactive rows write into the trash page). Called with the lock held;
-        never allocates — admission reserved every page this can pop."""
-        pool = self._pool
-        ps = pool.page_size
-        alloc = pool.allocator
-        W = self.width
-        write_idx = np.empty((W,), np.int32)
-        cow_src: List[int] = []
-        cow_dst: List[int] = []
-        for slot in range(W):
-            if not self._active_mask[slot]:
-                write_idx[slot] = TRASH_PAGE * ps + slot % ps
-                continue
-            pos = int(self._prompt_lens[slot]) + int(self._gen_lens[slot])
-            page_i = pos // ps
-            table = self._tables[slot]
-            if page_i == len(table):
-                table.append(self._reserved[slot].pop())
-                self._refresh_row_idx(slot)
-            elif alloc.refcount(table[page_i]) > 1:
-                # First divergent write into the shared partial prompt page:
-                # give this row a private copy, then retarget its table.
-                new_page = self._reserved[slot].pop()
-                cow_src.append(table[page_i])
-                cow_dst.append(new_page)
-                table[page_i] = new_page
-                alloc.note_cow()
-                self._refresh_row_idx(slot)
-            write_idx[slot] = table[page_i] * ps + pos % ps
-        if cow_src:
-            # Pad with trash->trash no-ops so every CoW batch shares one
-            # compiled copy program regardless of how many rows diverged.
-            src = list(cow_src)
-            dst = list(cow_dst)
-            while len(src) < W:
-                src.append(TRASH_PAGE)
-                dst.append(TRASH_PAGE)
-            pool.copy_pages(src, dst)
-            # Our reference on each source page must outlive the device copy
-            # that reads it — decref only after the copy is enqueued (the
-            # pool swap orders it before the next step's gathers).
-            alloc.decref(cow_src)
-        return write_idx
-
-    def _release_slot_pages(self, slot: int) -> None:
-        """Drop a retired slot's page references (shared prompt pages survive
-        while the prefix cache or sibling rows still hold them)."""
-        if not self.paged or self._pool is None:
-            return
-        spec = _failpoints.fire("engine.pages")
-        if spec is not None and spec.action == "leak":
-            self._pool.allocator.leak(max(1, int(spec.kill)))
-        alloc = self._pool.allocator
-        table, self._tables[slot] = self._tables[slot], []
-        reserved, self._reserved[slot] = self._reserved[slot], []
-        if table:
-            alloc.decref(table)
-        if reserved:
-            alloc.decref(reserved)
-        self._refresh_row_idx(slot, 0)
-
     def _step_once(self) -> None:
         with LATENCY.span("continuous.prepare"):
             with self._lock:
                 epoch = self._loop_epoch
                 step_no = self._stats["steps"]
-                cur = jnp.asarray(self._cur)
-                gen_lens = jnp.asarray(self._gen_lens)
-                prompt_lens = jnp.asarray(self._prompt_lens)
-                active = jnp.asarray(self._active_mask)
-                seeds = jnp.asarray(self._seeds)
-                sidx = jnp.asarray(self._sample_idx)
-                temps = jnp.asarray(self._temps)
-                tps = jnp.asarray(self._top_ps)
+                row_args = tuple(map(jnp.asarray, (
+                    self._cur, self._gen_lens, self._prompt_lens,
+                    self._active_mask, self._seeds, self._sample_idx,
+                    self._temps, self._top_ps,
+                )))
                 live_rows = np.flatnonzero(self._active_mask)
                 # Grammar twins run only when a constrained row is live: steps
                 # with no grammar work dispatch the ORIGINAL programs, so the
                 # unconstrained loop stays byte-identical (and
                 # program-identical).
                 n_masked = int((self._g_flags & self._active_mask).sum())
-                g_states = g_flags = g_fns = g_tabs = None
+                step_fn, grammar_args = self._step_fn, ()
                 if n_masked:
-                    g_states = jnp.asarray(self._g_states)
-                    g_flags = jnp.asarray(self._g_flags)
-                    g_fns = self._grammar_programs()
-                    g_tabs = self._g_tabs()
-                if self.paged:
-                    write_idx = jnp.asarray(self._prepare_step_pages())
-                    pidx = jnp.asarray(self._prefix_idx)
-                    gidx = jnp.asarray(self._gen_idx)
+                    step_fn = self._grammar_programs()["step"]
+                    grammar_args = (
+                        jnp.asarray(self._g_states), jnp.asarray(self._g_flags),
+                        *self._g_tabs(),
+                    )
+                # A loop with page books keeps them here: table growth and
+                # copy-on-write for the rows' next write, which yield the
+                # step's index arguments.
+                pool, dense = self._pool, self._dense
+                layout_idx: tuple = ()
+                pages = None
+                if self._pages is not None:
+                    lens = (self._active_mask, self._prompt_lens, self._gen_lens)
+                    layout_idx = tuple(
+                        map(jnp.asarray, self._pages.prepare_step(*lens))
+                    )
                     # The fused kernel's walk, counted here where the
                     # lengths are coherent (the XLA path gathers whole tables).
-                    pages = (
-                        self._step_page_counts()
-                        if self._paged_attn_impl != "xla" else None
-                    )
+                    if self._paged_attn_impl != "xla":
+                        pages = self._pages.walk_counts(*lens)
             # All-False in production; with an active ``engine.logits`` nan
             # failpoint, a seeded subset of the LIVE rows is poisoned — the
             # loop-scoped twin of the batch path's first-step injection.
@@ -2049,57 +1812,42 @@ class ContinuousDecodeLoop:
             _failpoints.fire("continuous.step")
             if self._loop_epoch != epoch:
                 raise _StaleStep("continuous step fenced before dispatch")
-            if self.paged:
-                pool = self._pool
+
+            def _launch(what, *layout_state):
+                note_device_dispatch(what)
+                with LATENCY.span("continuous.dispatch", step=step_no):
+                    out = step_fn(
+                        self.engine.params, *layout_state, *row_args,
+                        *layout_idx, poison, *grammar_args,
+                    )
+                # An abandoned thread waking into a rebuilt loop must not
+                # clobber the new KV with the old epoch's.
+                if self._loop_epoch != epoch:
+                    raise _StaleStep("continuous step fenced post-dispatch")
+                return out
+
+            if pool is not None:
                 note_paged_attn_dispatch(self._paged_attn_impl)
                 if pages is not None:
                     PAGED_ATTN_PAGES.record("paged_attn_pages_walked", pages[0])
                     PAGED_ATTN_PAGES.record("paged_attn_pages_tabled", pages[1])
+                # The pool's buffers are donated to the step, so ``pool.kv``
+                # must point at the returned ones before anyone else can
+                # dispatch: dispatch-and-swap under the pool lock.
                 with pool.lock:
-                    note_device_dispatch("continuous paged step")
-                    with LATENCY.span("continuous.dispatch", step=step_no):
-                        if n_masked:
-                            tok, lp, bad, new_k, new_v, new_g, aux = g_fns["step_paged"](
-                                self.engine.params, pool.kv.k, pool.kv.v, cur,
-                                gen_lens, prompt_lens, active, seeds, sidx,
-                                temps, tps, pidx, gidx, write_idx, poison,
-                                g_states, g_flags, *g_tabs,
-                            )
-                        else:
-                            tok, lp, bad, new_k, new_v, aux = self._step_paged_fn(
-                                self.engine.params, pool.kv.k, pool.kv.v, cur,
-                                gen_lens, prompt_lens, active, seeds, sidx,
-                                temps, tps, pidx, gidx, write_idx, poison,
-                            )
-                            new_g = None
-                    if self._loop_epoch != epoch:
-                        raise _StaleStep("continuous step fenced post-dispatch")
+                    tok, lp, bad, new_k, new_v, *new_g, aux = _launch(
+                        "continuous paged step", pool.kv.k, pool.kv.v
+                    )
                     pool.kv = KVCache(k=new_k, v=new_v)
             else:
-                note_device_dispatch("continuous dense step")
-                with LATENCY.span("continuous.dispatch", step=step_no):
-                    if n_masked:
-                        tok, lp, bad, gen, new_g, aux = g_fns["step"](
-                            self.engine.params, self._prefix, self._gen, cur,
-                            gen_lens, prompt_lens, active, seeds, sidx, temps,
-                            tps, poison, g_states, g_flags, *g_tabs,
-                        )
-                    else:
-                        tok, lp, bad, gen, aux = self._step_fn(
-                            self.engine.params, self._prefix, self._gen, cur,
-                            gen_lens, prompt_lens, active, seeds, sidx, temps,
-                            tps, poison,
-                        )
-                        new_g = None
-                # An abandoned thread waking into a rebuilt loop must not
-                # clobber the new generation cache with the old epoch's.
-                if self._loop_epoch != epoch:
-                    raise _StaleStep("continuous step fenced post-dispatch")
-                self._gen = gen
+                tok, lp, bad, gen, *new_g, aux = _launch(
+                    "continuous dense step", dense.prefix, dense.gen
+                )
+                dense.gen = gen
             # The one by-design sync per step: slot bookkeeping below needs
             # the sampled token ids on the host, and it runs outside both
             # locks (advanced grammar states ride the same fetch).
-            outs = (tok, lp, bad) if new_g is None else (tok, lp, bad, new_g)
+            outs = (tok, lp, bad, *new_g)
             with LATENCY.span("continuous.readback"):
                 # kllms: ignore[host-sync-hot-path] — the per-step result readback; everything after it is host-side bookkeeping
                 fetched, aux = jax.device_get((outs, aux))
@@ -2219,7 +1967,8 @@ class ContinuousDecodeLoop:
                 self._active[slot] = None
                 self._g_flags[slot] = False
                 self._g_states[slot] = 0
-                self._release_slot_pages(slot)
+                if self._pages is not None:
+                    self._pages.release(slot)
                 self._free.append(slot)
 
     def _resolve_if_done(self, req: _SlotRequest) -> None:
@@ -2295,8 +2044,7 @@ class ContinuousDecodeLoop:
                         if self._active[slot] is req:
                             self._active[slot] = None
                             self._active_mask[slot] = False
-                            self._tables[slot] = []
-                            self._reserved[slot] = []
+                            self._pages.forget(slot)
                             self._free.append(slot)
                 if not req.future.done():
                     req.future.set_exception(exc)

@@ -50,12 +50,16 @@ launch is already a numeric-quarantine event on the dense path too.
 
 from __future__ import annotations
 
-import math
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+import logging
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..analysis.lockcheck import make_rlock, note_device_dispatch
+from ..ops.paged_attention import live_pages, table_pages
+from ..reliability import failpoints as _failpoints
+
+logger = logging.getLogger(__name__)
 
 #: Page id 0 is the TRASH page: never allocated, never in a block table.
 #: Masked gather slots and inactive-row writes point into it, so every flat
@@ -116,11 +120,6 @@ class PageAllocator:
         """Pages with a live reference (trash page included)."""
         with self._lock:
             return int((self._ref > 0).sum())
-
-    @property
-    def usable_pages(self) -> int:
-        """Capacity available to block tables (everything but trash)."""
-        return self.total_pages - 1
 
     @property
     def shared_pages(self) -> int:
@@ -260,6 +259,15 @@ def pages_for(tokens: int, page_size: int) -> int:
     return -(-int(tokens) // int(page_size)) if tokens > 0 else 0
 
 
+def row_reserve_pages(prompt_len: int, max_new: int, page_size: int) -> int:
+    """Pages one decoding row's writes can touch: gen positions occupy pages
+    plen//ps .. (plen+max_new-1)//ps; the first of those is the prompt's
+    partial page (CoW target) when plen % ps != 0, fresh otherwise — the +1
+    covers both cases."""
+    ps = page_size
+    return (prompt_len + max_new - 1) // ps - prompt_len // ps + 1
+
+
 def flat_slots(pages: Sequence[int], positions: np.ndarray, page_size: int) -> np.ndarray:
     """Map logical token positions to flat pool slot indices through a block
     table. Positions past the table map into the trash page (they are masked
@@ -289,6 +297,7 @@ class PagedKVPool:
     """
 
     def __init__(self, config, total_pages: int, page_size: int, dtype=None):
+        import jax
         import jax.numpy as jnp
 
         from ..models.llama import KVCache
@@ -306,70 +315,33 @@ class PagedKVPool:
         self.kv = KVCache(
             k=jnp.zeros(shape + (k_width,), dtype), v=jnp.zeros(shape + (v_width,), dtype)
         )
-        self._scatter_cache: Dict[Any, Any] = {}
-        self._gather_cache: Dict[Any, Any] = {}
-        self._copy_cache: Dict[Any, Any] = {}
 
-    @property
-    def flat_size(self) -> int:
-        return self.allocator.total_pages * self.page_size
+        # The jitted movers; jax.jit keeps a program per argument shape.
+        def _scatter(pool_k, pool_v, k_src, v_src, idx):
+            # k_src/v_src: [L, n, KVH, D]; idx: [n] flat slots.
+            return KVCache(
+                k=pool_k.at[:, idx].set(k_src.astype(pool_k.dtype)),
+                v=pool_v.at[:, idx].set(v_src.astype(pool_v.dtype)),
+            )
+
+        def _gather(pool_k, pool_v, idx):
+            # -> [L, 1, n, KVH, D]: the dense prefix layout every engine
+            # consumer (decode prefix, continuation seed) expects.
+            return KVCache(k=pool_k[:, idx][:, None], v=pool_v[:, idx][:, None])
+
+        def _copy(pool_k, pool_v, src_idx, dst_idx):
+            return KVCache(
+                k=pool_k.at[:, dst_idx].set(pool_k[:, src_idx]),
+                v=pool_v.at[:, dst_idx].set(pool_v[:, src_idx]),
+            )
+
+        self._scatter_fn = jax.jit(_scatter, donate_argnums=(0, 1))
+        self._gather_fn = jax.jit(_gather)
+        self._copy_fn = jax.jit(_copy, donate_argnums=(0, 1))
 
     def pool_bytes(self) -> int:
         with self.lock:
             return int(self.kv.k.nbytes) + int(self.kv.v.nbytes)
-
-    # -- jitted movers -----------------------------------------------------
-
-    def _scatter_fn(self, n: int):
-        fn = self._scatter_cache.get(n)
-        if fn is None:
-            import jax
-
-            from ..models.llama import KVCache
-
-            def _scatter(pool_k, pool_v, k_src, v_src, idx):
-                # k_src/v_src: [L, n, KVH, D]; idx: [n] flat slots.
-                return KVCache(
-                    k=pool_k.at[:, idx].set(k_src.astype(pool_k.dtype)),
-                    v=pool_v.at[:, idx].set(v_src.astype(pool_v.dtype)),
-                )
-
-            fn = jax.jit(_scatter, donate_argnums=(0, 1))
-            self._scatter_cache[n] = fn
-        return fn
-
-    def _gather_fn(self, n: int):
-        fn = self._gather_cache.get(n)
-        if fn is None:
-            import jax
-
-            from ..models.llama import KVCache
-
-            def _gather(pool_k, pool_v, idx):
-                # -> [L, 1, n, KVH, D]: the dense prefix layout every engine
-                # consumer (decode prefix, continuation seed) expects.
-                return KVCache(k=pool_k[:, idx][:, None], v=pool_v[:, idx][:, None])
-
-            fn = jax.jit(_gather)
-            self._gather_cache[n] = fn
-        return fn
-
-    def _copy_fn(self, n: int):
-        fn = self._copy_cache.get(n)
-        if fn is None:
-            import jax
-
-            from ..models.llama import KVCache
-
-            def _copy(pool_k, pool_v, src_idx, dst_idx):
-                return KVCache(
-                    k=pool_k.at[:, dst_idx].set(pool_k[:, src_idx]),
-                    v=pool_v.at[:, dst_idx].set(pool_v[:, src_idx]),
-                )
-
-            fn = jax.jit(_copy, donate_argnums=(0, 1))
-            self._copy_cache[n] = fn
-        return fn
 
     # -- public ops --------------------------------------------------------
 
@@ -381,9 +353,7 @@ class PagedKVPool:
         idx = jnp.asarray(np.asarray(slot_idx, np.int32))
         with self.lock:
             note_device_dispatch("paged kv scatter")
-            self.kv = self._scatter_fn(int(idx.shape[0]))(
-                self.kv.k, self.kv.v, k_src, v_src, idx
-            )
+            self.kv = self._scatter_fn(self.kv.k, self.kv.v, k_src, v_src, idx)
 
     def gather_tokens(self, slot_idx: np.ndarray):
         """Dense [L, 1, n, KVH, D] view of the given flat slots."""
@@ -392,7 +362,7 @@ class PagedKVPool:
         idx = jnp.asarray(np.asarray(slot_idx, np.int32))
         with self.lock:
             note_device_dispatch("paged kv gather")
-            return self._gather_fn(int(idx.shape[0]))(self.kv.k, self.kv.v, idx)
+            return self._gather_fn(self.kv.k, self.kv.v, idx)
 
     def copy_pages(self, src_pages: Sequence[int], dst_pages: Sequence[int]) -> None:
         """Device copy of whole pages (the CoW mover). Pads to a stable width
@@ -411,7 +381,7 @@ class PagedKVPool:
         )
         with self.lock:
             note_device_dispatch("paged kv page copy")
-            self.kv = self._copy_fn(int(src.shape[0]))(
+            self.kv = self._copy_fn(
                 self.kv.k, self.kv.v, jnp.asarray(src), jnp.asarray(dst)
             )
 
@@ -460,3 +430,277 @@ class PagedPrefixRun:
         idx = flat_slots(self.pages, np.arange(out_len), self.pool.page_size)
         idx[p:] = (np.arange(out_len - p) % self.pool.page_size).astype(np.int32)
         return self.pool.gather_tokens(idx)
+
+
+class SlotPages:
+    """The page books of one paged decode loop: per slot a block TABLE of pool
+    pages and the RESERVE its decode steps draw from, plus the flat index
+    mirrors the step program reads KV through. The protocol (sharing and
+    copy-on-write as in the module docstring):
+
+    - Admission shares ONE page run of the prompt between a request's n rows,
+      a reference each, and reserves every row's private generation pages up
+      front, so a step in flight can never fail on allocation. All of it is
+      taken or none (:meth:`admit`, :meth:`reserve_chunked`).
+    - :meth:`prepare_step` grows a table at a page boundary and copies a
+      shared page before a row's first write into it, out of the reserve.
+    - :meth:`release` drops every reference a retired slot holds.
+
+    Allocation goes through the caller's ``alloc`` (the engine's evicting
+    allocator: eviction is the prefix cache's policy, not the pool's) under
+    the engine's paged mutex, which the caller takes. The class has no lock
+    of its own: every method runs under the owning loop's lock, so the order
+    (loop, paged mutex, allocator) stays the caller's.
+    """
+
+    def __init__(self, page_size: int, width: int, max_prompt: int, max_new: int,
+                 pool_pages: Optional[int] = None) -> None:
+        self.page_size = int(page_size)
+        self.width, self.max_prompt, self.max_new = int(width), int(max_prompt), int(max_new)
+        #: Pages the pool has, or will be built with: what :meth:`fits` holds
+        #: a request's peak demand against before a pool exists.
+        self.planned_pages = int(pool_pages or self.default_pool_pages())
+        # All that follows — kllms: guarded-by[engine.continuous]
+        self.pool: Optional[PagedKVPool] = None
+        self._tables: List[List[int]] = [[] for _ in range(self.width)]
+        self._reserved: List[List[int]] = [[] for _ in range(self.width)]
+        # Token-level gather indices, one flat pool slot a position: what the
+        # step program takes today (the kernel turns them back into page
+        # tables on the device).
+        self.prefix_idx = np.zeros((self.width, self.max_prompt), np.int32)
+        self.gen_idx = np.zeros((self.width, self.max_new), np.int32)
+
+    # -- sizing ------------------------------------------------------------
+
+    def default_pool_pages(self) -> int:
+        """Pool sizing when neither the engine nor the backend pinned one:
+        every slot decoding a DISTINCT max-shape prompt (the no-sharing worst
+        case), plus one reserve page per slot for CoW, a couple of prompt-size
+        runs of prefix-cache slack, and the trash page."""
+        ps = self.page_size
+        per_slot = pages_for(self.max_prompt + self.max_new, ps) + 1
+        return self.width * per_slot + 2 * pages_for(self.max_prompt, ps) + 1
+
+    def need(self, plen: int, n: int, max_new: int) -> int:
+        """Peak page demand of one request alone, which is what admission
+        takes: one shared prompt run plus n private generation reserves."""
+        reserve = row_reserve_pages(plen, max_new, self.page_size)
+        return pages_for(plen, self.page_size) + max(1, n) * reserve
+
+    def fits(self, plen: int, n: int, max_new: int) -> bool:
+        """Can the pool hold this request even with the prefix cache fully
+        evicted (the trash page is no one's)? A hint where no lock is held:
+        admission finds out for certain."""
+        return self.need(plen, n, max_new) <= self.planned_pages - 1
+
+    # -- life cycle --------------------------------------------------------
+
+    def attach(self, pool: PagedKVPool) -> None:
+        self.pool = pool
+        self.planned_pages = pool.allocator.total_pages
+
+    def reset(self) -> None:
+        """Forget the pool and every table WITHOUT decref: the pool dies with
+        its torn-down engine, and a decref against a replaced allocator would
+        corrupt the new pool's accounting."""
+        self.pool = None
+        self._tables = [[] for _ in range(self.width)]
+        self._reserved = [[] for _ in range(self.width)]
+        self.prefix_idx[:] = 0
+        self.gen_idx[:] = 0
+
+    def held(self) -> int:
+        """Page references the slots hold (tables and reserves)."""
+        return sum(map(len, self._tables)) + sum(map(len, self._reserved))
+
+    # -- admission ---------------------------------------------------------
+
+    def _fan_out(self, run_pages: List[int], refs: int, n_rows: int, reserve: int,
+                 alloc: Callable[[int], List[int]]) -> List[List[int]]:
+        """``refs`` more references on the prompt run and a reserve of
+        ``reserve`` pages for each of ``n_rows`` rows; whatever was taken is
+        given back before a failure leaves here."""
+        allocator = self.pool.allocator
+        taken = 0
+        reserved: List[List[int]] = []
+        try:
+            for _ in range(refs):
+                allocator.incref(run_pages)
+                taken += 1
+            for _ in range(n_rows):
+                reserved.append(alloc(reserve))
+        except BaseException:
+            for lst in reserved:
+                allocator.decref(lst)
+            for _ in range(taken):
+                allocator.decref(run_pages)
+            raise
+        return reserved
+
+    def admit(self, rows: Sequence[int], run_pages: List[int], plen: int, max_new: int,
+              alloc: Callable[[int], List[int]]) -> None:
+        """Whole-prompt admission: the rows share ``run_pages`` (a prefill's
+        or a cache entry's run, whose owner keeps its own reference), one
+        reference each, and each gets its reserve. Raises
+        :class:`PagePoolExhausted` with everything rolled back if the
+        reserves don't fit."""
+        reserve = row_reserve_pages(plen, max_new, self.page_size)
+        reserved = self._fan_out(run_pages, len(rows), len(rows), reserve, alloc)
+        self.install(rows, run_pages, reserved, plen)
+
+    def reserve_chunked(self, n_rows: int, plen: int, max_new: int,
+                        alloc: Callable[[int], List[int]]) -> Tuple[List[int], List[List[int]]]:
+        """Chunked admission, before the first chunk: a fresh prompt run (its
+        allocation is the first row's reference, the other rows' are added)
+        and every row's reserve — :meth:`need` in full, so a half-prefilled
+        admission can never strand on allocation. ``(run_pages, reserved)``
+        are the caller's to :meth:`install` or :meth:`drop`."""
+        run_pages = alloc(pages_for(plen, self.page_size))
+        try:
+            reserve = row_reserve_pages(plen, max_new, self.page_size)
+            return run_pages, self._fan_out(run_pages, n_rows - 1, n_rows, reserve, alloc)
+        except BaseException:
+            self.pool.allocator.decref(run_pages)
+            raise
+
+    def chunk_slots(self, run_pages: Sequence[int], start: int, width: int,
+                    valid: int) -> np.ndarray:
+        """Flat pool slots for a ``width``-token chunk's KV columns, landing
+        in the run at offset ``start``; the pad positions past ``valid``
+        retarget to trash."""
+        ps = self.page_size
+        slots = flat_slots(run_pages, start + np.arange(width), ps)
+        trash = (np.arange(width) % ps + TRASH_PAGE * ps).astype(np.int32)
+        slots[valid:] = trash[valid:]
+        return slots
+
+    def install(self, rows: Sequence[int], run_pages: Sequence[int],
+                reserved: Sequence[List[int]], plen: int) -> None:
+        """Make the run the rows' tables (the references were taken by
+        :meth:`admit` or :meth:`reserve_chunked`)."""
+        for j, slot in enumerate(rows):
+            self._tables[slot] = list(run_pages)
+            self._reserved[slot] = reserved[j]
+            self._refresh(slot, plen)
+
+    def prefix_run(self, run_pages: Sequence[int], plen: int, bucket: int) -> PagedPrefixRun:
+        """The run as a prefix-cache entry, with a reference of its own (the
+        run is already scattered, so storing it is pure accounting)."""
+        run = PagedPrefixRun(self.pool, list(run_pages), plen, bucket)
+        run.retain()
+        return run
+
+    def drop(self, n_rows: int, run_pages: List[int], reserved: Sequence[List[int]]) -> None:
+        """Give back what :meth:`reserve_chunked` took, for an admission that
+        never installed. A corrupt allocator is contained: the references are
+        dropped (the pool audit quarantines it) so the caller can still fail
+        the request typed instead of wedging retirement."""
+        if self.pool is None:
+            return
+        try:
+            for lst in [run_pages] * n_rows + list(reserved):
+                self.pool.allocator.decref(lst)
+        except PageAccountingError:
+            logger.exception("page release failed retiring a PREFILLING admission")
+
+    # -- the decode step ---------------------------------------------------
+
+    def _refresh(self, slot: int, plen: int) -> None:
+        """Rebuild one slot's flat gather indices from its block table. Must
+        run after ANY table change (admit, extension, CoW, release): a stale
+        index could keep gathering a page that was freed and reused."""
+        ps = self.page_size
+        table = self._tables[slot]
+        P, G = self.max_prompt, self.max_new
+        pidx = flat_slots(table, np.arange(P), ps)
+        # Positions at/after the prompt end read through gen_idx instead;
+        # point them into the trash page (masked, but must stay in bounds).
+        pidx[plen:] = (np.arange(P - plen) % ps).astype(np.int32)
+        self.prefix_idx[slot] = pidx
+        self.gen_idx[slot] = flat_slots(table, plen + np.arange(G), ps)
+
+    def prepare_step(self, active: np.ndarray, prompt_lens: np.ndarray,
+                     gen_lens: np.ndarray) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Resolve each row's write slot for the upcoming step, performing
+        page-table maintenance on the way: append a reserved page when the
+        write crosses a page boundary, copy-on-write when the target page is
+        still shared with other readers. Returns the step's index arguments
+        ``(prefix_idx [W, P], gen_idx [W, G], write_idx [W])`` (inactive rows
+        write into the trash page). Never allocates — admission reserved
+        every page this can pop."""
+        pool = self.pool
+        ps = self.page_size
+        allocator = pool.allocator
+        W = self.width
+        write_idx = np.empty((W,), np.int32)
+        cow_src: List[int] = []
+        cow_dst: List[int] = []
+        for slot in range(W):
+            if not active[slot]:
+                write_idx[slot] = TRASH_PAGE * ps + slot % ps
+                continue
+            plen = int(prompt_lens[slot])
+            pos = plen + int(gen_lens[slot])
+            page_i = pos // ps
+            table = self._tables[slot]
+            if page_i == len(table):
+                table.append(self._reserved[slot].pop())
+                self._refresh(slot, plen)
+            elif allocator.refcount(table[page_i]) > 1:
+                # First divergent write into the shared partial prompt page:
+                # give this row a private copy, then retarget its table.
+                new_page = self._reserved[slot].pop()
+                cow_src.append(table[page_i])
+                cow_dst.append(new_page)
+                table[page_i] = new_page
+                allocator.note_cow()
+                self._refresh(slot, plen)
+            write_idx[slot] = table[page_i] * ps + pos % ps
+        if cow_src:
+            # Pad with trash->trash no-ops so every CoW batch shares one
+            # compiled copy program regardless of how many rows diverged.
+            pad = [TRASH_PAGE] * (W - len(cow_src))
+            pool.copy_pages(cow_src + pad, cow_dst + pad)
+            # Our reference on each source page must outlive the device copy
+            # that reads it — decref only after the copy is enqueued (the
+            # pool swap orders it before the next step's gathers).
+            allocator.decref(cow_src)
+        return self.prefix_idx, self.gen_idx, write_idx
+
+    def walk_counts(self, active: np.ndarray, prompt_lens: np.ndarray,
+                    gen_lens: np.ndarray) -> Tuple[int, int]:
+        """(pages the live rows' walks hold, pages the step's tables hold) for
+        the upcoming decode step, from what the step program hands the paged
+        kernel: the lengths with idle slots zeroed, the phase out of the gen
+        slot map."""
+        ps = self.page_size
+        n_prefix, n_gen = live_pages(
+            np.where(active, prompt_lens, 0), np.where(active, gen_lens, 0),
+            self.gen_idx[:, 0] % ps, ps,
+        )
+        tabled = self.width * sum(table_pages(self.max_prompt, self.max_new, ps))
+        return int(n_prefix.sum() + n_gen.sum()), tabled
+
+    # -- retirement --------------------------------------------------------
+
+    def release(self, slot: int) -> None:
+        """Drop a retired slot's page references (shared prompt pages survive
+        while the prefix cache or sibling rows still hold them)."""
+        if self.pool is None:
+            return
+        allocator = self.pool.allocator
+        spec = _failpoints.fire("engine.pages")
+        if spec is not None and spec.action == "leak":
+            allocator.leak(max(1, int(spec.kill)))
+        table, self._tables[slot] = self._tables[slot], []
+        reserved, self._reserved[slot] = self._reserved[slot], []
+        if table:
+            allocator.decref(table)
+        if reserved:
+            allocator.decref(reserved)
+        self._refresh(slot, 0)
+
+    def forget(self, slot: int) -> None:
+        """Drop a slot's references WITHOUT decref: containment over an
+        allocator that is already corrupt (and quarantined)."""
+        self._tables[slot], self._reserved[slot] = [], []

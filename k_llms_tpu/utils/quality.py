@@ -8,8 +8,7 @@ pipeline (``KLLMs(backend="fake")`` → consolidation → consensus), and score 
 consensus object's leaf-field accuracy against the truth — alongside the
 single-sample baseline the consensus must beat.
 
-Used by ``bench.py`` (quality metrics in the headline JSON) and
-``tests/test_quality_eval.py``.
+Used by ``tests/test_quality_eval.py``.
 """
 
 from __future__ import annotations

@@ -2,14 +2,13 @@
 that needs the chip is started behind a parent that already holds it.
 
 CPU-cheap by construction — no test here builds a model: the compile-cache
-function under both states of its environment variable, ``chip_smoke.py`` and
-``bench.py``'s chip section refusing a CPU before a model exists, a failing
-bench section failing the run, the compile-exempt watchdog wait, and the rule
-that no ``interpret=`` argument in the package is computed from the backend.
+function under both states of its environment variable, ``chip_smoke.py``
+refusing a CPU before a model exists, the compile-exempt watchdog wait, and
+the rule that no ``interpret=`` argument in the package is computed from the
+backend.
 """
 
 import ast
-import json
 import os
 import subprocess
 import sys
@@ -19,7 +18,6 @@ import time
 import jax
 import pytest
 
-import bench
 from k_llms_tpu.utils import compile_cache
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -146,89 +144,6 @@ def test_chip_smoke_parent_imports_only_the_standard_library():
     assert imported <= set(sys.stdlib_module_names), imported - set(sys.stdlib_module_names)
 
 
-# -- bench.py ----------------------------------------------------------------------
-
-_HERMETIC = (
-    "bench_quality", "bench_host_consensus", "bench_consensus", "bench_constrained",
-    "bench_paged_kv", "bench_paged_attention", "bench_hedging", "bench_tenancy",
-    "bench_batch_lane", "bench_chunked_prefill", "bench_serving",
-)
-
-
-def _stub_hermetic(monkeypatch):
-    for name in _HERMETIC:
-        monkeypatch.setattr(bench, name, lambda: {"ok": 1})
-
-
-def _last_json(capsys):
-    out = capsys.readouterr().out.strip().splitlines()
-    assert len(out) == 1  # exactly one JSON line on stdout
-    return json.loads(out[0])
-
-
-def test_emit_includes_error_field(capsys):
-    bench._emit(None, None, {"quality": {"ok": 1}}, error="RuntimeError: boom")
-    line = _last_json(capsys)
-    assert line["metric"] == "n32_consensus_p50_over_single_p50"
-    assert line["value"] is None
-    assert line["error"] == "RuntimeError: boom"
-    assert line["detail"]["quality"] == {"ok": 1}
-
-
-def test_bench_chip_section_refuses_cpu_before_building_a_model(monkeypatch, capsys):
-    import k_llms_tpu.backends.tpu as tpu
-
-    _stub_hermetic(monkeypatch)
-
-    def no_model(*a, **k):
-        raise AssertionError("the chip section built a model on a CPU")
-
-    monkeypatch.setattr(tpu, "TpuBackend", no_model)
-    with pytest.raises(SystemExit) as exc_info:
-        bench.main()
-    assert exc_info.value.code == 1
-    line = _last_json(capsys)
-    assert line["value"] is None and "needs an accelerator" in line["error"]
-    assert line["detail"]["quality"] == {"ok": 1}  # hermetic sections still reported
-
-
-def test_bench_section_that_raises_makes_the_exit_code_nonzero(monkeypatch, capsys):
-    _stub_hermetic(monkeypatch)
-
-    def broken():
-        raise ValueError("section bug")
-
-    monkeypatch.setattr(bench, "bench_tenancy", broken)
-    monkeypatch.setattr(bench, "bench_flagship", lambda: ({"ratio": 1.25}, object(), object()))
-    monkeypatch.setattr(bench, "bench_concurrency", lambda b, c: {"speedup": 3.0})
-    monkeypatch.setattr(bench, "bench_speculative", lambda b: {"ok": 1})
-    monkeypatch.setattr(bench, "bench_prefix_cache", lambda b: {"ok": 1})
-    with pytest.raises(SystemExit) as exc_info:
-        bench.main()
-    assert exc_info.value.code == 1
-    line = _last_json(capsys)
-    assert line["value"] == 1.25  # the run's numbers are kept ...
-    assert line["error"] == "sections failed: tenancy"  # ... and its failure named
-    assert "ValueError: section bug" in line["detail"]["tenancy"]["error"]
-
-    # With every section healthy the same run exits 0 and carries no error.
-    monkeypatch.setattr(bench, "bench_tenancy", lambda: {"ok": 1})
-    bench.main()
-    assert "error" not in _last_json(capsys)
-
-
-def test_bench_peak_table_has_no_default_device():
-    class Unknown:
-        device_kind = "TPU v99"
-
-    class V5e:
-        device_kind = "TPU v5 lite"
-
-    assert bench.peak_hbm_gbs(V5e()) == 819.0
-    with pytest.raises(RuntimeError, match="no published HBM peak"):
-        bench.peak_hbm_gbs(Unknown())
-
-
 # -- device facts are read, not assumed --------------------------------------------
 
 def test_hbm_size_is_read_from_an_accelerator_or_raises(monkeypatch):
@@ -275,7 +190,6 @@ def test_serving_entry_point_passes_quantization_and_model_parallel():
 # -- interpret mode is asked for by name ---------------------------------------------
 
 def _python_sources():
-    yield os.path.join(ROOT, "bench.py")
     yield os.path.join(ROOT, "__graft_entry__.py")
     for dirpath, _, files in os.walk(os.path.join(ROOT, "k_llms_tpu")):
         for name in files:

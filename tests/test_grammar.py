@@ -385,7 +385,7 @@ def test_continuous_loop_rejects_second_grammar_while_busy(loop):
 
 def _schema_of(value):
     """Structural JSON schema of a truth document (objects closed, arrays
-    typed from their first element) — the schemas bench_constrained uses."""
+    typed from their first element)."""
     if isinstance(value, bool):
         return {"type": "boolean"}
     if isinstance(value, int):

@@ -145,8 +145,8 @@ def test_prequantized_checkpoint_with_quantize_unset_on_mesh():
 
 
 def test_engine_aliases_an_already_quantized_tree():
-    """A second engine handed a fully quantized tree (bench.py's speculative
-    and prefix-cache twins share the flagship's int8 params) must alias the
+    """A second engine handed a fully quantized tree (speculative and
+    prefix-cache twins sharing one engine's int8 params) must alias the
     device buffers: a jitted identity 'quantize' copied all 8 GB and ran a
     16 GB v5e out of memory (PR 21 chip run)."""
     config = get_config("tiny")

@@ -551,7 +551,7 @@ def test_greedy_differential_failover_is_byte_identical(tpu_members):
 
 
 @pytest.mark.duration_budget(30)
-def test_hedged_dispatch_cancels_loser_through_abort_poller(tpu_members):
+def test_hedged_dispatch_cancels_loser_through_abort_poller(tpu_members, monkeypatch):
     """ISSUE acceptance: the hedge winner's result returns while the loser is
     cancelled mid-decode through the engine's io_callback abort poller
     (engine.decode_abort increments), and neither member's circuit breaker
@@ -565,22 +565,60 @@ def test_hedged_dispatch_cancels_loser_through_abort_poller(tpu_members):
         seed=3,
         max_tokens=48,
     )
-    # Warm both engines so the race below measures decode, not compilation.
+    # Warm both engines so the race below is not two cold starts.
     b0.chat_completion(dataclasses.replace(req))
-    t0 = time.perf_counter()
     b1.chat_completion(dataclasses.replace(req))
-    decode_s = time.perf_counter() - t0
 
     rs = ReplicaSet(members=[b0, b1], model="tiny", hedge=True, hedge_delay_s=0.05)
-    # Delay r0's (primary) launch so it is mid-decode — started, unfinished —
-    # when r1's hedge result lands: hedge_delay < sleep < hedge_delay + decode.
-    sleep_s = 0.05 + decode_s / 2
+    # r0 (primary) must be mid-decode — launched, unfinished — when r1's hedge
+    # result lands. Events order the two members, no measured wall clock (a
+    # sleep sized from one decode lost this race under a loaded machine):
+    # (1) r0's attempt starts once r1's decode is on the device, so r1's
+    #     program is the one ahead where the platform runs sharded programs
+    #     one at a time (the CPU's eight virtual devices do);
+    # (2) r1's decode passes its first token boundary once r0's launch has
+    #     begun, so r0 is past admission (not shed) before it can be cancelled;
+    # (3) r0's decode stays at a token boundary until the set has cancelled
+    #     its budget, which it does when r1's result lands.
+    # Every hold has a bound well under the launch watchdog's 60 s.
+    r1_decoding, r0_launched = threading.Event(), threading.Event()
+
+    def hooked(fn, announce=None, wait_for=None):
+        def call(*args, **kwargs):
+            if announce is not None:
+                announce.set()
+            if wait_for is not None:
+                wait_for.wait(10.0)
+            return fn(*args, **kwargs)
+
+        return call
+
+    monkeypatch.setattr(
+        b0, "dispatch_chat_completion",
+        hooked(b0.dispatch_chat_completion, wait_for=r1_decoding),
+    )
+    monkeypatch.setattr(
+        b0.engine, "generate_many", hooked(b0.engine.generate_many, announce=r0_launched)
+    )
+    monkeypatch.setattr(
+        b1.engine, "_poll_abort_flags",
+        hooked(b1.engine._poll_abort_flags, announce=r1_decoding, wait_for=r0_launched),
+    )
+    poll = b0.engine._poll_abort_flags
+    hold_until = []
+
+    def held_poll(num_requests):
+        hold_until.append(time.monotonic() + 10.0)
+        flags = poll(num_requests)
+        while not flags.any() and time.monotonic() < hold_until[0]:
+            time.sleep(0.002)
+            flags = poll(num_requests)
+        return flags
+
+    monkeypatch.setattr(b0.engine, "_poll_abort_flags", held_poll)
     aborts_before = FAILURE_EVENTS.get("engine.decode_abort")
     hedge_before = HEDGE_EVENTS.get("hedge.won_hedge")
-    with fp.failpoints(
-        {"replica.dispatch": FailSpec(action="sleep", member="r0", delay=sleep_s)}
-    ):
-        out = rs.dispatch_chat_completion(dataclasses.replace(req))
+    out = rs.dispatch_chat_completion(dataclasses.replace(req))
     assert out.choices and out.choices[0].message.content
     assert HEDGE_EVENTS.get("hedge.won_hedge") == hedge_before + 1
     assert rs.stats()["r1"]["hedges_won"] == 1

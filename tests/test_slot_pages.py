@@ -1,0 +1,260 @@
+"""The continuous loop's page protocol (engine/paging.py::SlotPages) without a
+model or a loop: admission fan-out and roll-back, copy-on-write, page
+boundaries, the chunked route, and the one reserve formula, against a bare
+``PageAllocator`` (and once against a real ``PagedKVPool`` for the copy)."""
+
+import numpy as np
+import pytest
+
+from k_llms_tpu.engine.paging import (
+    TRASH_PAGE,
+    PageAccountingError,
+    PageAllocator,
+    PagePoolExhausted,
+    SlotPages,
+    flat_slots,
+    pages_for,
+    row_reserve_pages,
+)
+from k_llms_tpu.ops.paged_attention import live_pages, table_pages
+
+W, P, G = 4, 64, 32
+
+
+class _Pool:
+    """What SlotPages needs of a pool, with the device copy recorded."""
+
+    def __init__(self, total_pages, page_size):
+        self.page_size = page_size
+        self.allocator = PageAllocator(total_pages, page_size)
+        self.copies = []
+
+    def copy_pages(self, src, dst):
+        self.copies.append((list(src), list(dst)))
+
+
+def _books(ps=8, total=64, width=W):
+    pool = _Pool(total, ps)
+    books = SlotPages(ps, width, P, G)
+    books.attach(pool)
+    return books, pool, pool.allocator
+
+
+def _admit(books, alloc, rows, plen, max_new, keep_owner=False):
+    """Whole-prompt admission as the loop does it: a prefill's run, a
+    reference a row, then the scratch owner lets go (or a cache keeps it)."""
+    run = alloc.alloc(pages_for(plen, books.page_size))
+    try:
+        books.admit(rows, run, plen, max_new, alloc.alloc)
+    finally:
+        if not keep_owner:
+            alloc.decref(run)
+    return run
+
+
+def _lens(rows, plen, gen):
+    active = np.zeros((W,), bool)
+    active[list(rows)] = True
+    return active, np.full((W,), plen, np.int32), np.full((W,), gen, np.int32)
+
+
+@pytest.mark.parametrize("n", [1, 3])
+def test_admit_then_release_returns_every_page(n):
+    books, _, alloc = _books()
+    free0 = alloc.free_pages
+    rows = list(range(n))
+    _admit(books, alloc, rows, plen=21, max_new=12)
+    assert books.held() == n * (pages_for(21, 8) + row_reserve_pages(21, 12, 8))
+    for step in range(12):
+        books.prepare_step(*_lens(rows, 21, step))
+    for slot in rows:
+        books.release(slot)
+    assert books.held() == 0
+    assert alloc.free_pages == free0
+    alloc.verify()
+    assert (books.prefix_idx // 8 == TRASH_PAGE).all()
+    assert (books.gen_idx // 8 == TRASH_PAGE).all()
+
+
+@pytest.mark.parametrize("route", ["admit", "reserve_chunked"])
+def test_exhaustion_part_way_rolls_back_every_reference(route):
+    plen, max_new, n = 21, 12, 3
+    # Enough for the run and two of the three reserves: the third one fails.
+    total = SlotPages(8, W, P, G).need(plen, n, max_new) - 1 + 1  # + the trash page
+    books, _, alloc = _books(total=total)
+    if route == "admit":
+        run = alloc.alloc(pages_for(plen, 8))
+        free0, refs0 = alloc.free_pages, alloc._ref.copy()
+        with pytest.raises(PagePoolExhausted):
+            books.admit(list(range(n)), run, plen, max_new, alloc.alloc)
+    else:
+        free0, refs0 = alloc.free_pages, alloc._ref.copy()
+        with pytest.raises(PagePoolExhausted):
+            books.reserve_chunked(n, plen, max_new, alloc.alloc)
+    assert alloc.free_pages == free0
+    assert (alloc._ref == refs0).all()
+    assert books.held() == 0
+    alloc.verify()
+
+
+@pytest.mark.parametrize("plen", [16, 21])
+def test_cow_fires_at_the_first_divergent_write_iff_the_prompt_ends_mid_page(plen):
+    ps, rows = 8, [0, 1]
+    books, pool, alloc = _books(ps)
+    run = _admit(books, alloc, rows, plen, max_new=12, keep_owner=True)
+    reserve0 = [len(books._reserved[s]) for s in rows]
+    _, _, write_idx = books.prepare_step(*_lens(rows, plen, 0))
+    own = [books._tables[s][plen // ps] for s in rows]
+    if plen % ps:
+        # One padded copy program: each row's private page from the shared one.
+        ((src, dst),) = pool.copies
+        assert src == [run[-1]] * 2 + [TRASH_PAGE] * (W - 2)
+        assert dst == own + [TRASH_PAGE] * (W - 2)
+        assert alloc.snapshot()["cow_copies"] == 2
+        # The source keeps its other reader (the cache entry), nothing else.
+        assert alloc.refcount(run[-1]) == 1
+    else:
+        assert pool.copies == []
+        assert alloc.snapshot()["cow_copies"] == 0
+        assert [len(books._tables[s]) for s in rows] == [len(run) + 1] * 2
+    assert len(set(own)) == 2 and not set(own) & set(run)
+    assert all(alloc.refcount(p) == 1 for p in own)
+    assert [len(books._reserved[s]) for s in rows] == [r - 1 for r in reserve0]
+    assert write_idx[:2].tolist() == [p * ps + plen % ps for p in own]
+    assert (write_idx[2:] // ps == TRASH_PAGE).all()
+    # Full prompt pages stay shared for the rows' lifetime.
+    shared = run[: plen // ps]
+    assert all(alloc.refcount(p) == 3 for p in shared)
+    # The second write lands in the page the row now owns: no more copies.
+    books.prepare_step(*_lens(rows, plen, 1))
+    assert len(pool.copies) == (1 if plen % ps else 0)
+    alloc.verify()
+
+
+def test_a_page_boundary_pops_exactly_one_reserved_page():
+    ps, plen, max_new = 8, 16, 20
+    books, pool, alloc = _books(ps)
+    _admit(books, alloc, [2], plen, max_new)
+    sizes = []
+    for step in range(max_new):
+        _, gen_idx, write_idx = books.prepare_step(*_lens([2], plen, step))
+        sizes.append((len(books._tables[2]), len(books._reserved[2])))
+        # The step reads its own write back through the gen map next step.
+        assert write_idx[2] == gen_idx[2, step]
+    grew = [i for i in range(1, max_new) if sizes[i] != sizes[i - 1]]
+    assert grew == [8, 16]  # positions 24 and 32
+    assert all(t + r == sizes[0][0] + sizes[0][1] for t, r in sizes)
+    assert pool.copies == []
+
+
+def test_the_chunked_route_leaves_the_tables_whole_prompt_admission_leaves():
+    ps, plen, max_new, rows, C = 8, 45, 12, [1, 3], 32
+    whole, _, alloc_w = _books(ps)
+    _admit(whole, alloc_w, rows, plen, max_new)
+    chunked, _, alloc_c = _books(ps)
+    run, reserved = chunked.reserve_chunked(len(rows), plen, max_new, alloc_c.alloc)
+    landed = []
+    for start in range(0, plen, C):
+        valid = min(C, plen - start)
+        slots = chunked.chunk_slots(run, start, C, valid)
+        assert (slots[valid:] // ps == TRASH_PAGE).all()
+        landed += slots[:valid].tolist()
+    assert landed == flat_slots(run, np.arange(plen), ps).tolist()
+    chunked.install(rows, run, reserved, plen)
+    assert chunked._tables == whole._tables
+    assert chunked._reserved == whole._reserved
+    assert (chunked.prefix_idx == whole.prefix_idx).all()
+    assert (chunked.gen_idx == whole.gen_idx).all()
+    assert (alloc_c._ref == alloc_w._ref).all()
+    assert alloc_c.free_pages == alloc_w.free_pages
+
+
+def test_drop_gives_back_a_chunked_reservation_and_contains_a_corrupt_allocator():
+    books, _, alloc = _books()
+    free0 = alloc.free_pages
+    run, reserved = books.reserve_chunked(3, 21, 12, alloc.alloc)
+    entry = books.prefix_run(run, 21, 32)  # a cache entry's own reference
+    assert all(alloc.refcount(p) == 4 for p in run)
+    books.drop(3, run, reserved)
+    assert all(alloc.refcount(p) == 1 for p in run)
+    assert entry.release() == len(run)
+    assert alloc.free_pages == free0
+    alloc.verify()
+    # A double drop is a double free: contained, not raised.
+    run, reserved = books.reserve_chunked(1, 5, 4, alloc.alloc)
+    books.drop(1, run, reserved)
+    books.drop(1, run, reserved)
+    with pytest.raises(PageAccountingError):
+        alloc.decref(run)
+
+
+@pytest.mark.parametrize("ps", [4, 16])
+@pytest.mark.parametrize("n", [1, 4])
+@pytest.mark.parametrize("plen,max_new", [(1, 1), (16, 16), (17, 32), (63, 5), (64, 32)])
+def test_need_is_what_admission_takes_and_what_decoding_uses(plen, max_new, n, ps):
+    books, _, alloc = _books(ps, total=256)
+    rows = list(range(n))
+    free0 = alloc.free_pages
+    _admit(books, alloc, rows, plen, max_new)
+    assert free0 - alloc.free_pages == books.need(plen, n, max_new)
+    assert books.fits(plen, n, max_new)
+    # Every write a row can make finds its page in the reserve...
+    for step in range(max_new):
+        books.prepare_step(*_lens(rows, plen, step))
+    # ...and a row that shared nothing to copy has at most the CoW page left.
+    assert all(len(books._reserved[s]) <= 1 for s in rows)
+    for slot in rows:
+        books.release(slot)
+    assert alloc.free_pages == free0
+    alloc.verify()
+
+
+def test_the_default_pool_fits_the_widest_request_and_nothing_wider():
+    books = SlotPages(16, W, P, G)
+    assert books.planned_pages == books.default_pool_pages()
+    assert books.fits(P, W, G)
+    assert not SlotPages(16, W, P, G, pool_pages=books.need(P, W, G)).fits(P, W, G)
+    assert SlotPages(16, W, P, G, pool_pages=books.need(P, W, G) + 1).fits(P, W, G)
+
+
+def test_walk_counts_are_live_pages_of_the_same_lengths():
+    ps = 8
+    books, _, alloc = _books(ps)
+    _admit(books, alloc, [0, 1], 21, 12)
+    _admit(books, alloc, [3], 40, 12)
+    active = np.array([True, True, False, True])
+    plens = np.array([21, 21, 33, 40], np.int32)  # slot 2: a retired tenant's
+    glens = np.array([5, 0, 7, 11], np.int32)
+    for g in range(int(glens.max()) + 1):  # the rows' writes so far, in order
+        books.prepare_step(active, plens, np.minimum(g, glens))
+    walked, tabled = books.walk_counts(active, plens, glens)
+    phase = np.array([21 % ps, 21 % ps, 0, 40 % ps])
+    n_prefix, n_gen = live_pages(
+        np.where(active, plens, 0), np.where(active, glens, 0), phase, ps
+    )
+    assert walked == int(n_prefix.sum() + n_gen.sum()) == (3 + 2) + (3 + 0) + 0 + (5 + 2)
+    assert tabled == W * sum(table_pages(P, G, ps))
+
+
+def test_cow_copies_the_shared_page_on_a_real_pool():
+    import jax.numpy as jnp
+
+    from k_llms_tpu.engine.paging import PagedKVPool
+    from k_llms_tpu.models import get_config
+
+    ps, plen = 8, 13
+    pool = PagedKVPool(get_config("tiny"), 16, ps)
+    books = SlotPages(ps, W, P, G)
+    books.attach(pool)
+    alloc = pool.allocator
+    run = alloc.alloc(pages_for(plen, ps))
+    marks = jnp.arange(plen, dtype=pool.kv.k.dtype)
+    shape = (pool.kv.k.shape[0], plen) + pool.kv.k.shape[2:]
+    cols = jnp.broadcast_to(marks[None, :, None, None], shape)
+    pool.scatter_tokens(cols, cols, flat_slots(run, np.arange(plen), ps))
+    books.admit([0, 1], run, plen, 4, alloc.alloc)
+    prefix_idx, _, _ = books.prepare_step(*_lens([0, 1], plen, 0))
+    for slot in (0, 1):
+        assert books._tables[slot][-1] != run[-1]
+        got = np.asarray(pool.kv.k[0, prefix_idx[slot, :plen], 0, 0])
+        assert got.tolist() == list(range(plen))

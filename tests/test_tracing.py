@@ -681,12 +681,12 @@ def test_paged_step_program_carries_scope_names():
         ).result(timeout=120)
         W = loop.width
         i32, f32 = (lambda: jnp.zeros((W,), jnp.int32)), (lambda: jnp.ones((W,), jnp.float32))
-        lowered = loop._step_paged_fn.lower(
+        lowered = loop._step_fn.lower(
             paged.params, loop._pool.kv.k, loop._pool.kv.v, i32(), i32(),
             jnp.ones((W,), jnp.int32), jnp.ones((W,), bool),
             jnp.zeros((W,), jnp.uint32), i32(), f32(), f32(),
-            jnp.asarray(loop._prefix_idx), jnp.asarray(loop._gen_idx), i32(),
-            jnp.zeros((W,), bool),
+            jnp.asarray(loop._pages.prefix_idx), jnp.asarray(loop._pages.gen_idx),
+            i32(), jnp.zeros((W,), bool),
         ).as_text(debug_info=True)
     finally:
         loop.stop()
