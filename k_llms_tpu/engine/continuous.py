@@ -21,7 +21,9 @@ Design:
   with different sampling configs share the batch — the coalescing scheduler's
   batch_key compatibility restriction disappears. temperature 0 is greedy per
   row; reported logprobs are the untempered model distribution's, matching
-  ``ops/sampling.sample_logits``. Row keys derive from
+  ``ops/sampling.sample_logits``, and each row's nucleus is cut by the same
+  search as there (``ops/sampling.nucleus_threshold``: a fixed 32 masked
+  reductions over [W, V], no sort of the vocabulary). Row keys derive from
   ``fold_in(fold_in(key(seed), step), sample_idx)`` — self-deterministic (same
   seed → same tokens) regardless of batch composition, like the batch loop.
 - The host drives the loop: eos / per-request max_new retirement, budget
@@ -65,6 +67,7 @@ from concurrent.futures import Future
 from ..analysis.lockcheck import make_condition, note_device_dispatch, race_exempt
 from ..models.llama import KVCache, init_cache, paged_verify_step, verify_step
 from ..ops.paged_attention import note_paged_attn_dispatch
+from ..ops.sampling import nucleus_threshold
 from ..reliability import failpoints as _failpoints
 from ..reliability.deadline import RequestBudget
 from ..types.wire import (
@@ -785,15 +788,9 @@ class ContinuousDecodeLoop:
             logits = jnp.where(row_ok, logits, 0.0)
             model_lps = jax.nn.log_softmax(logits, axis=-1)
             scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-            # Row-wise nucleus mask: keep the smallest prefix of the sorted
-            # distribution whose mass reaches top_p (boundary token kept).
-            sort_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-            probs = jax.nn.softmax(sort_desc, axis=-1)
-            cum = jnp.cumsum(probs, axis=-1)
-            keep = (cum - probs) < top_ps[:, None]
-            thresh = jnp.min(
-                jnp.where(keep, sort_desc, jnp.inf), axis=-1
-            )
+            # Row-wise nucleus mask: the smallest set, by descending logit,
+            # whose mass reaches top_p (boundary token and its ties kept).
+            thresh = nucleus_threshold(scaled, top_ps)
             masked = jnp.where(scaled >= thresh[:, None], scaled, -jnp.inf)
             sampled = jax.vmap(jax.random.categorical)(keys, masked)
             greedy = jnp.argmax(scaled, axis=-1)

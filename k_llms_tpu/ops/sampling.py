@@ -17,6 +17,63 @@ import jax
 import jax.numpy as jnp
 
 
+# One trip of the nucleus search per bit of a float32.
+NUCLEUS_SEARCH_TRIPS = 32
+
+
+def _ordered_bits(x: jax.Array) -> jax.Array:
+    """The order-preserving uint32 image of float32: ``a < b`` iff
+    ``image(a) < image(b)``, with -0.0 and +0.0 on one value (they compare
+    equal as floats, so they must as integers)."""
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0.0, 0.0, x), jnp.uint32)
+    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(0x80000000))
+
+
+def _from_ordered_bits(u: jax.Array) -> jax.Array:
+    bits = jnp.where(u >> 31 == 1, u & jnp.uint32(0x7FFFFFFF), ~u)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def nucleus_threshold(scaled: jax.Array, top_p: jax.Array) -> jax.Array:
+    """Each row's top-p boundary logit: ``{scaled >= result}`` is the smallest
+    set, taken in descending logit order, whose softmax mass reaches the row's
+    ``top_p`` (the boundary token and its equal-logit ties stay in; a row
+    always keeps its largest logit, so ``top_p`` 0 is top-1 and 1.0 keeps
+    everything of measurable mass). scaled: [B, V] f32 with no NaN (``-inf``
+    is a masked token); top_p: [B] f32. Returns [B] f32.
+
+    No sort: ``mass({scaled >= t})`` is monotone in ``t``, so the largest
+    ``t`` whose set still reaches ``top_p`` is found by bisection, and that
+    ``t`` is a logit of the row — the one a descending sort with a cumulative
+    sum would stop at. The bisection runs over the integer image of the
+    floats, one bit a trip from the top: ``NUCLEUS_SEARCH_TRIPS`` masked
+    reductions over [B, V] whatever the data (a float-midpoint bracket needs
+    25–35 and, where it closes on zero, ~126, with every row waiting for the
+    slowest). Mass is summed in vocabulary order where a sort sums in sorted
+    order, so a row whose cumulative mass lies within float32 rounding of
+    ``top_p`` at the boundary may keep one tie group more or less: either set
+    is a nucleus.
+    """
+    keys = _ordered_bits(scaled)
+    row_max = jnp.max(scaled, axis=-1)
+    weights = jnp.exp(scaled - row_max[:, None])  # unnormalised probabilities
+    need = top_p * jnp.sum(weights, axis=-1)
+
+    def _trip(i, t):
+        cand = t | (jnp.uint32(0x80000000) >> i.astype(jnp.uint32))
+        mass = jnp.sum(jnp.where(keys >= cand[:, None], weights, 0.0), axis=-1)
+        return jnp.where(mass >= need, cand, t)
+
+    t = jax.lax.fori_loop(
+        0, NUCLEUS_SEARCH_TRIPS, _trip, jnp.zeros(scaled.shape[:1], jnp.uint32)
+    )
+    # A nucleus holds the row's top logit (top_p 0: nothing else), and a
+    # search that never found its mass (top_p above the summed mass by
+    # rounding) keeps the whole row: -inf, not the NaN that image 0 decodes to.
+    t = jnp.clip(t, _ordered_bits(jnp.float32(-jnp.inf)), _ordered_bits(row_max))
+    return _from_ordered_bits(t)
+
+
 def sample_logits(
     logits: jax.Array,
     key: Optional[jax.Array],
@@ -60,47 +117,14 @@ def sample_logits(
             sampling_logits = jnp.where(sampling_logits < kth, -jnp.inf, sampling_logits)
 
         if top_p is not None and top_p < 1.0:
-            # Keep the smallest set with cumulative mass >= top_p (boundary
-            # token stays in; equal-logit ties stay in). Implemented as a
-            # bisection on the logit threshold instead of a full-vocab sort:
-            # mass({logit > t}) is monotone in t, and the loop runs until
-            # every row's bracket has collapsed to ADJACENT floats (midpoint
-            # rounds onto an endpoint — a stalled row no longer changes), at
-            # which point no representable logit lies strictly inside it and
-            # the kept set {logit > lo} is EXACTLY the sort-based set — at a
-            # fraction of the cost (XLA's 128k-wide sort is ~5.5 ms/step for
-            # n=32 on v5e; this is typically ~30 masked reductions).
-            probs = jax.nn.softmax(sampling_logits, axis=-1)
-            finite = jnp.isfinite(sampling_logits)
-            lo = (
-                jnp.min(jnp.where(finite, sampling_logits, jnp.inf), axis=-1) - 1.0
-            )  # below every value: mass({> lo}) = 1 >= top_p
-            hi = jnp.max(
-                jnp.where(finite, sampling_logits, -jnp.inf), axis=-1
-            )  # the max value: mass({> hi}) = 0 < top_p
-
-            def _progress(lohi):
-                lo, hi = lohi
-                mid = 0.5 * (lo + hi)
-                return jnp.any((mid > lo) & (mid < hi))
-
-            def _bisect(lohi):
-                lo, hi = lohi
-                mid = 0.5 * (lo + hi)
-                mass = jnp.sum(
-                    jnp.where(sampling_logits > mid[:, None], probs, 0.0), axis=-1
-                )
-                go_hi = mass < top_p
-                return jnp.where(go_hi, lo, mid), jnp.where(go_hi, mid, hi)
-
-            lo, hi = jax.lax.while_loop(_progress, _bisect, (lo, hi))
-            # The boundary token's logit: smallest present value above lo.
-            threshold = jnp.min(
-                jnp.where(sampling_logits > lo[:, None], sampling_logits, jnp.inf),
-                axis=-1,
-                keepdims=True,
+            # The smallest set with cumulative mass >= top_p, boundary token
+            # and its equal-logit ties in: the loop's search, one top_p a row.
+            threshold = nucleus_threshold(
+                sampling_logits, jnp.full((B,), top_p, jnp.float32)
             )
-            sampling_logits = jnp.where(sampling_logits < threshold, -jnp.inf, sampling_logits)
+            sampling_logits = jnp.where(
+                sampling_logits >= threshold[:, None], sampling_logits, -jnp.inf
+            )
 
         if row_keys is None:
             keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(key, jnp.arange(B))

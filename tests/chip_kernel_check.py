@@ -31,6 +31,19 @@ Prints one line per comparison and exits non-zero if any failed.
 ``--rehearse`` runs that stage alone at toy size with the interpreted kernel,
 on any platform, to debug this script before a chip call.
 
+``--sampler`` runs the sampler stage alone (it is also the default run's last
+stage): the loop's nucleus search (``ops/sampling.py::nucleus_threshold``)
+against the descending sort it replaced in PR 31, at the two published
+vocabularies the cells serve — qwen2-7b's ``[32, 152064]`` and
+xing4-29b-a4b's ``[32, 131072]`` — on the model's own last-position logits
+for 32 distinct contexts, at the cells' temperature 0.8 and top_p 0.95 and
+over mixed per-row values. Kept sets are compared as sets; where the two
+differ, every token between the two cuts must lie within float32 rounding of
+``top_p`` in float64 cumulative mass (the sort sums in sorted order, the
+search in vocabulary order). Then the loop's whole ``_sample_rows`` is timed,
+new and sort-based, ``SAMPLER_CALLS`` calls chained inside one program so the
+host's dispatch is not in the number.
+
 The latent model (``xing4-29b-a4b``: MLA pages, routed experts,
 hyper-connections) runs no Pallas kernel of this repo and is not checked here:
 its on-chip comparison, the loop's programs at full width against the plain
@@ -40,6 +53,7 @@ float32 reference, is ``python benchmark/check_xing4.py``.
 import math
 import os
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +62,9 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from test_nucleus import (  # noqa: E402
+    ROUNDING, exclusive_mass, sort_reference_threshold, sort_sample_rows,
+)
 from test_paged_attention_kernel import _build_tables  # noqa: E402
 
 from k_llms_tpu.ops.attention import attention_xla, flash_attention  # noqa: E402
@@ -400,6 +417,103 @@ def greedy_model_case(model="qwen2-7b", quantize="int8", kernel="pallas", page_s
                   f"greedy {name} prompt {i}")
 
 
+SAMPLER_CALLS = 20
+SAMPLER_CASES = (  # (model, quantize): the cells' two large vocabularies
+    ("qwen2-7b", "int8"),
+    ("xing4-29b-a4b-cut7", False),
+)
+
+
+def sampler_case(model, quantize, rows=32, context=16):
+    """The shared nucleus search against the sort, and the loop's sampler
+    timed with each, on ``model``'s own logits (see the module docstring)."""
+    import gc
+
+    from k_llms_tpu.engine.continuous import ContinuousDecodeLoop
+    from k_llms_tpu.engine.engine import LocalEngine
+    from k_llms_tpu.models.llama import forward
+    from k_llms_tpu.ops.sampling import nucleus_threshold
+
+    eng = LocalEngine(model, quantize=quantize, use_mesh=False)
+    config = eng.config
+    V = config.vocab_size
+    tokens = jnp.asarray(
+        np.random.default_rng(31).integers(0, 256, (rows, context)), jnp.int32)
+    logits = jax.jit(lambda p, t: forward(config, p, t, jnp.ones_like(t))[0][:, -1])(
+        eng.params, tokens)
+    _, sample_rows, mask_pad = ContinuousDecodeLoop(
+        eng, width=rows, max_prompt=64, max_new=32)._sampler()
+    logits = jax.block_until_ready(mask_pad(logits))
+    del eng
+    gc.collect()
+    host = np.asarray(logits)
+    print(f"note {model}: logits [{rows}, {V}] mean {host[np.isfinite(host)].mean():.3f} "
+          f"sd {host[np.isfinite(host)].std():.3f} max {host.max():.3f}", flush=True)
+
+    mixes = {
+        "T 0.8, top_p 0.95 (the cells')": (np.full(rows, 0.8), np.full(rows, 0.95)),
+        "mixed rows": (np.resize([0.8, 1.0, 0.3, 0.0, 1.5], rows),
+                       np.resize([0.0, 0.5, 0.9, 0.95, 1.0, 0.1, 0.99], rows)),
+    }
+    for label, (temps, top_ps) in mixes.items():
+        temps, top_ps = np.float32(temps), np.float32(top_ps)
+        scaled = jnp.asarray(host) / jnp.maximum(jnp.asarray(temps), 1e-6)[:, None]
+        new = np.asarray(jax.jit(nucleus_threshold)(scaled, jnp.asarray(top_ps)))
+        old = np.asarray(jax.jit(sort_reference_threshold)(scaled, jnp.asarray(top_ps)))
+        scaled = np.asarray(scaled)
+        equal = within = 0
+        worst, sizes = 0.0, []
+        for r in range(rows):
+            kept_new, kept_old = scaled[r] >= new[r], scaled[r] >= old[r]
+            sizes.append(int(kept_new.sum()))
+            if top_ps[r] == 0.0:  # top-1 by the search; the sort keeps nothing at all
+                equal += bool((kept_new == (scaled[r] == scaled[r].max())).all())
+                continue
+            if (kept_new == kept_old).all():
+                equal += 1
+                continue
+            between = kept_new != kept_old
+            off = np.abs(exclusive_mass(scaled[r])[between] - top_ps[r]).max()
+            worst = max(worst, float(off))
+            within += bool(off < ROUNDING)
+        check(equal + within == rows,
+              f"nucleus search vs sort, {model} [{rows}, {V}], {label}: kept sets equal in "
+              f"{equal} rows, {within} more differ only by tokens within {ROUNDING} of top_p in "
+              f"float64 mass (furthest {worst:.2e}); kept {min(sizes)}..{max(sizes)} of {V}",
+              f"nucleus {model} {label}")
+
+    def chained(sample):
+        def run(logits, keys, temps, top_ps):
+            # Each call hangs on the one before (through ``c``) and every
+            # result is used, so nothing is hoisted out of the loop or dropped.
+            def body(_, c):
+                tok, lp, _ = sample(logits + c * 1e-30, keys, temps + c * 1e-9, top_ps)
+                return (tok.sum() % 2).astype(jnp.float32) + 0.0 * lp.sum()
+            return jax.lax.fori_loop(0, SAMPLER_CALLS, body, jnp.float32(0))
+        return jax.jit(run)
+
+    keys = jax.vmap(lambda i: jax.random.fold_in(jax.random.key(7), i))(jnp.arange(rows))
+    temps, top_ps = (jnp.asarray(np.float32(a)) for a in mixes["T 0.8, top_p 0.95 (the cells')"])
+    tok_new = sample_rows(logits, keys, temps, top_ps)[0]
+    tok_old = jax.jit(sort_sample_rows)(logits, keys, temps, top_ps)[0]
+    same = int((np.asarray(tok_new) == np.asarray(tok_old)).sum())
+    times = {}
+    for name, fn in (("sort", chained(sort_sample_rows)), ("search", chained(sample_rows))):
+        jax.block_until_ready(fn(logits, keys, temps, top_ps))
+        reads = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(logits, keys, temps, top_ps))
+            reads.append((time.perf_counter() - t0) / SAMPLER_CALLS * 1e3)
+        times[name] = sorted(reads)[len(reads) // 2]
+    check(same == rows and times["search"] < 2.5,
+          f"loop sampler, {model} [{rows}, {V}], T 0.8 top_p 0.95: tokens equal in {same} of "
+          f"{rows} rows; ms a call on {jax.devices()[0].device_kind}, {SAMPLER_CALLS} chained in "
+          f"one program, median of 5: "
+          f"sort {times['sort']:.3f}, search {times['search']:.3f} (budget < 2.5)",
+          f"sampler time {model}")
+
+
 def mesh_name(mesh):
     return "1 device" if mesh is None else f"mesh {dict(mesh.shape)}"
 
@@ -411,11 +525,16 @@ def main():
         # in f32, the kernel in the interpreter (the loops run what the
         # platform resolves). Debugs this script without a chip; proves no kernel.
         greedy_model_case("tiny", False, "pallas_interpret", 8)
+        sampler_case("tiny", False, rows=4)
         sys.exit(f"rehearsal FAILED: {failures}" if failures else 0)
     if device.platform == "cpu":
         sys.exit("chip_kernel_check: needs the accelerator (the CPU only has the interpreter)")
     n = len(jax.devices())
     print(f"chip_kernel_check: {n} x {device.device_kind} ({device.platform})", flush=True)
+    if "--sampler" in sys.argv[1:]:
+        for case in SAMPLER_CASES:
+            sampler_case(*case)
+        sys.exit(f"chip_kernel_check FAILED: {failures}" if failures else 0)
     meshes = [None]
     if n >= 4:
         meshes += [make_mesh(4, 1), make_mesh(2, 2)]
@@ -435,6 +554,8 @@ def main():
                 flash_case(dtype, mesh)
     if "--skip-model" not in sys.argv[1:]:
         greedy_model_case()
+        for case in SAMPLER_CASES:
+            sampler_case(*case)
     if failures:
         sys.exit(f"chip_kernel_check FAILED: {failures}")
     print("chip_kernel_check ok", flush=True)
