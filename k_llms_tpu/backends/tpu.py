@@ -313,7 +313,9 @@ class HbmMemoryModel:
     every layer's KV heads for the GQA block, one ``kv_lora_rank +
     qk_rope_head_dim`` latent row a layer and no V for a latent (MLA) model
     (8,064 B a token for the 7-layer Xing4.0 cut against qwen2-7b's
-    57,344)."""
+    57,344); a hybrid stack counts its attention layers alone (1,024 B a token
+    for the 9-layer Nemotron-3 cut) and charges each row its fixed recurrent
+    state (8.5 MB there) in the row margin."""
 
     def __init__(
         self,
@@ -336,8 +338,11 @@ class HbmMemoryModel:
         # model axis with the attention that consumes them.
         self.kv_bytes_per_token = config.kv_bytes_per_token
         # Per-row non-KV working set: the decode loop materializes f32 logits
-        # and sampling buffers per row; 4 bytes * vocab is the dominant term.
-        self.row_margin_bytes = 4 * config.vocab_size + (64 << 10)
+        # and sampling buffers per row; 4 bytes * vocab is the dominant term
+        # for every model whose rows hold no recurrent state.
+        self.row_margin_bytes = (
+            4 * config.vocab_size + (64 << 10) + config.state_bytes_per_row
+        )
 
     def budget_bytes(self) -> int:
         """Bytes available for per-row state after params, per device."""
@@ -524,6 +529,11 @@ class TpuBackend(Backend):
             # Validate before the (potentially multi-GB) checkpoint load.
             raise ValueError(
                 f"Unsupported quantization {cfg.quantization!r}; use 'int8' or 'int4'"
+            )
+        if model_config.is_hybrid and not cfg.continuous_batching:
+            raise NotImplementedError(
+                f"{model_config.name}: only the continuous loop carries the rows' recurrent "
+                "state; build with continuous_batching=True"
             )
         self._model_config = model_config
         self._mesh = mesh
@@ -1275,6 +1285,9 @@ class TpuBackend(Backend):
             "paged": getattr(self.engine, "kv_layout", "dense") == "paged",
             "page_size": getattr(self.engine, "kv_page_size", None),
         }
+        if self._continuous is not None:
+            # The rows' recurrent state beside the pool (0 for most models).
+            hbm["state_bytes"] = snap["continuous"]["state_bytes"]
         pool = getattr(self.engine, "_kv_pool", None)
         if pool is not None:
             hbm["page_pool"] = pool.allocator.snapshot()

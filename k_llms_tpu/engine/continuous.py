@@ -65,7 +65,7 @@ import numpy as np
 from concurrent.futures import Future
 
 from ..analysis.lockcheck import make_condition, note_device_dispatch, race_exempt
-from ..models.llama import KVCache, init_cache, paged_verify_step, verify_step
+from ..models.llama import KVCache, init_cache, init_state, paged_verify_step, verify_step
 from ..ops.paged_attention import note_paged_attn_dispatch
 from ..ops.sampling import nucleus_threshold
 from ..reliability import failpoints as _failpoints
@@ -95,6 +95,16 @@ from .engine import (
 from .paging import PageAccountingError, PagePoolExhausted, SlotPages
 
 logger = logging.getLogger(__name__)
+
+#: Admission's fork of a prompt's final recurrent state into a request's rows:
+#: (the loop's state [W, ...] a leaf, the lane's [1, ...], rows [W] int32
+#: padded with W: an index past the end is dropped) -> the loop's state.
+_install_rows = jax.jit(
+    lambda state, lane, rows: jax.tree.map(
+        lambda s, l: s.at[rows].set(l.astype(s.dtype), mode="drop"), state, lane
+    ),
+    donate_argnums=(0,),
+)
 
 
 @dataclass
@@ -165,8 +175,10 @@ class _Prefilling:
     being ingested chunk by chunk between decode steps instead of in one
     blocking prefill. Owns its slot rows (popped from ``_free`` but NOT in
     ``_active`` — the decode step must never see a half-prefilled row), the
-    1-row staging KV the chunks extend, and, in paged mode, the prompt page
-    run (n row references) plus each row's pre-reserved generation pages.
+    1-row staging KV the chunks extend with the lane's recurrent state
+    (``state``: one row's, ``{}`` for a model without any), and, in paged mode,
+    the prompt page run (n row references) plus each row's pre-reserved
+    generation pages.
     All fields are guarded by the loop lock; the dispatch closure only reads
     snapshots taken under it."""
 
@@ -178,6 +190,7 @@ class _Prefilling:
     bucket: int
     run_pages: Optional[List[int]]
     reserved: List[List[int]]
+    state: Dict[str, Any]
     cursor: int = 0
 
 
@@ -388,6 +401,7 @@ class ContinuousDecodeLoop:
             "_step_fn",
             "_paged_attn_impl",
             "_pool",
+            "_state",
             "_results_at",
             "_host_annotation",
         )
@@ -503,6 +517,13 @@ class ContinuousDecodeLoop:
         self._paged_attn_impl = "xla"
         # kllms: unguarded — epoch-fenced handoff to the step dispatch thread
         self._dense: Optional[_DenseSlots] = None
+        # The rows' recurrent state beside their pages (models/hybrid.py):
+        # ``{name: (an array [W, ...] a state layer, ...)}``, passed to and returned from the
+        # step program and donated like the pool's buffers; admission writes a
+        # request's rows (``_install_state``), a release writes nothing. ``{}``
+        # for a model without such state: no operand, no program.
+        # kllms: unguarded — epoch-fenced handoff to the step dispatch thread
+        self._state: Dict[str, Any] = {}
         if self.paged:
             pool = getattr(engine, "_kv_pool", None)
             self._pages = SlotPages(
@@ -533,6 +554,9 @@ class ContinuousDecodeLoop:
             "blocked_slots": 0,
             "blocked_lane": 0,
             "blocked_pages": 0,
+            # What the rows' recurrent state holds on the device (0 until the
+            # loop is built, and for a model without such state).
+            "state_bytes": 0,
         }
         self._thread: Optional[threading.Thread] = None
 
@@ -749,6 +773,8 @@ class ContinuousDecodeLoop:
             )
         else:
             self._dense = _DenseSlots(config, W, P, G)
+        self._state = init_state(config, W)
+        self._stats["state_bytes"] = sum(int(a.nbytes) for a in jax.tree.leaves(self._state))
         self._step_fn = self._build_step(grammar=False)
         self._admit_sample_fn = self._build_first_token(grammar=False)
         self._built = True
@@ -837,8 +863,10 @@ class ContinuousDecodeLoop:
         slots; the four programs that come out keep the names every profile
         and the ledger's breakdown know them by: ``_step``, ``_step_paged``,
         ``_step_g``, ``_step_paged_g``. Arguments: ``(params, *layout_state,
-        *row_args, *layout_idx, poison, *grammar_args)``; results ``(tok, lp,
-        bad, *new_kv[, g_states], aux)``."""
+        *row_args, *layout_idx, poison, *grammar_args, state=...)``; results
+        ``(tok, lp, bad, *new_kv[, g_states], aux, state)``. ``state`` is the
+        rows' recurrent state, by keyword (an empty dict, so no operand, for
+        most models)."""
         config = self.engine.config
         mesh = getattr(self.engine, "mesh", None)
         paged = self.paged
@@ -851,12 +879,13 @@ class ContinuousDecodeLoop:
         n_idx = 3 if paged else 0
 
         def _body(params, kv_a, kv_b, cur, gen_lens, prompt_lens, active,
-                  seeds, sample_idx, temps, top_ps, *rest):
+                  seeds, sample_idx, temps, top_ps, *rest, state=None):
             layout_idx, (poison, *g_args) = rest[:n_idx], rest[n_idx:]
             # ``aux``: what the model's stack counts (router loads, cache
             # rows read: utils/observability.py::note_model_aux adds them at
             # readback); empty for a model that counts nothing.
             aux: Dict[str, Any] = {}
+            state = dict(state or {})
             if paged:
                 # Rows read their KV through block-table gathers into the
                 # shared pool and write cur's column back at a host-computed
@@ -874,7 +903,7 @@ class ContinuousDecodeLoop:
                     jnp.where(active, prompt_lens, 0),
                     KVCache(k=pool_k, v=pool_v), prefix_idx, gen_idx,
                     attn_impl=attn_impl, page_size=page_size,
-                    mesh=mesh, aux=aux,
+                    mesh=mesh, aux=aux, state=state, active=active,
                 )
                 with jax.named_scope("kv_write"):
                     pool_k = pool_k.at[:, write_idx].set(k_cols.astype(pool_k.dtype))
@@ -907,15 +936,17 @@ class ContinuousDecodeLoop:
             out = (tok, lp, bad & active) + new_kv
             if grammar:
                 out += (advance(tok, g_states, g_flags, tabs),)
-            return out + (aux,)
+            return out + (aux, state)
 
         _body.__name__ = (
             "_step" + ("_paged" if paged else "") + ("_g" if grammar else "")
         )
         # The step's KV is donated (the pool's pair; the dense generation
-        # cache): its only owner re-passes it every step, so the update
-        # happens in place on device.
-        return jax.jit(_body, donate_argnums=(1, 2) if paged else (2,))
+        # cache) and so is the rows' recurrent state: their only owner
+        # re-passes them every step, so the update happens in place on device.
+        return jax.jit(
+            _body, donate_argnums=(1, 2) if paged else (2,), donate_argnames=("state",)
+        )
 
     def _build_first_token(self, grammar: bool):
         """The first token, sampled at admission from the prefill logits at
@@ -1268,6 +1299,8 @@ class ContinuousDecodeLoop:
         self._admit_sample_fn = None
         self._dense = None
         self._pool = None
+        self._state = {}
+        self._stats["state_bytes"] = 0
         if self._pages is not None:
             self._pages.reset()
         self._pool_fault = None
@@ -1439,7 +1472,7 @@ class ContinuousDecodeLoop:
             # (or the cache entry's) page run, a reference for each row, and
             # each row's generation reserve; PagePoolExhausted leaves here
             # with everything rolled back.
-            first_logits, run, transient = engine.paged_admit_prefix(
+            first_logits, run, transient, lane_state = engine.paged_admit_prefix(
                 _ids, _plen, bucket
             )
             try:
@@ -1453,10 +1486,24 @@ class ContinuousDecodeLoop:
                     # Uncached prefill: the run was a scratch owner of the
                     # prompt pages; the rows' references now keep them alive.
                     run.release()
+            self._install_state(rows, lane_state)
         else:
             first_logits, prefix = engine._prefill_routed(_ids, _plen, bucket)
             self._dense.install(rows, prefix, bucket)
         self._admit_rows(req, rows, first_logits)
+
+    def _install_state(self, rows: List[int], lane_state: Dict[str, Any]) -> None:
+        """Fork the prompt's final recurrent state (one row: the chunk lane's,
+        or whole-prompt admission's) into each of the request's rows, one
+        device copy (pages are shared, state is not). Admission always
+        overwrites, so a slot never sees its last tenant's state and a release
+        needs no device work. Nothing to do for a model without such state."""
+        if not self._state:
+            return
+        with LATENCY.span("continuous.state_install"):
+            idx = np.full((self.width,), self.width, np.int32)
+            idx[: len(rows)] = rows
+            self._state = _install_rows(self._state, lane_state, jnp.asarray(idx))
 
     def _admit_rows(self, req, rows, first_logits) -> None:
         """The layout-independent admission tail, shared by whole-prompt
@@ -1606,7 +1653,7 @@ class ContinuousDecodeLoop:
         req.chunk_cursor = 0
         self._prefilling = _Prefilling(
             req, list(rows), list(_ids), cache, _plen, bucket,
-            run_pages, reserved,
+            run_pages, reserved, init_state(engine.config, 1),
         )
 
     def _prefill_chunk_once(self) -> None:
@@ -1640,7 +1687,7 @@ class ContinuousDecodeLoop:
                 pad_id = self.engine.config.pad_token_id
                 chunk = np.full((1, C), pad_id, np.int32)
                 chunk[0, :valid] = pf.ids[start:end]
-                cache, bucket = pf.cache, pf.bucket
+                cache, lane_state, bucket = pf.cache, pf.state, pf.bucket
                 # Paged: the chunk's KV columns land in the admission's page
                 # run at its current offset.
                 pool = self._pool
@@ -1661,10 +1708,11 @@ class ContinuousDecodeLoop:
                 raise _StaleStep("prefill chunk fenced before dispatch")
             note_device_dispatch("continuous prefill chunk")
             with LATENCY.span("continuous.dispatch", chunk=chunk_no):
-                # (logits, staging cache[, the chunk's k and v columns], aux)
-                logits, new_cache, *cols, aux = fn(
+                # (logits, staging cache[, the chunk's k and v columns], aux,
+                # the lane's recurrent state)
+                logits, new_cache, *cols, aux, new_state = fn(
                     self.engine.params, jnp.asarray(chunk), cache,
-                    jnp.int32(start), jnp.int32(valid),
+                    jnp.int32(start), jnp.int32(valid), state=lane_state,
                 )
                 if self._loop_epoch != epoch:
                     raise _StaleStep("prefill chunk fenced post-dispatch")
@@ -1677,13 +1725,13 @@ class ContinuousDecodeLoop:
                 _, aux = jax.device_get((logits, aux))
             self._results_at = time.perf_counter()
             note_model_aux(aux)
-            return logits, new_cache
+            return logits, new_cache, new_state
 
         # Deliberately NOT fed to observe_step: a C-token chunk would pollute
         # the decode loop's per-step EWMA.
         self._close_host()
         with LATENCY.span("continuous.prefill_chunk") as chunk_span:
-            (first_logits, new_cache), _ = self._hand_off(
+            (first_logits, new_cache, new_state), _ = self._hand_off(
                 _dispatch, "prefill chunk"
             )
         self._open_host()
@@ -1692,6 +1740,7 @@ class ContinuousDecodeLoop:
                 if self._loop_epoch != epoch or self._prefilling is not pf:
                     return
                 pf.cache = new_cache
+                pf.state = new_state
                 pf.cursor = end
                 req.chunk_cursor = end
                 self._stats["prefill_chunks"] += 1
@@ -1716,6 +1765,7 @@ class ContinuousDecodeLoop:
         engine = self.engine
         req, rows = pf.req, pf.rows
         cached = getattr(engine, "prefix_cache_size", 0) > 0
+        self._install_state(rows, pf.state)
         if self.paged:
             self._pages.install(rows, pf.run_pages, pf.reserved, pf.plen)
             if cached:
@@ -1781,7 +1831,7 @@ class ContinuousDecodeLoop:
                 # A loop with page books keeps them here: table growth and
                 # copy-on-write for the rows' next write, which yield the
                 # step's index arguments.
-                pool, dense = self._pool, self._dense
+                pool, dense, state = self._pool, self._dense, self._state
                 layout_idx: tuple = ()
                 pages = None
                 if self._pages is not None:
@@ -1815,12 +1865,14 @@ class ContinuousDecodeLoop:
                 with LATENCY.span("continuous.dispatch", step=step_no):
                     out = step_fn(
                         self.engine.params, *layout_state, *row_args,
-                        *layout_idx, poison, *grammar_args,
+                        *layout_idx, poison, *grammar_args, state=state,
                     )
                 # An abandoned thread waking into a rebuilt loop must not
                 # clobber the new KV with the old epoch's.
                 if self._loop_epoch != epoch:
                     raise _StaleStep("continuous step fenced post-dispatch")
+                # The donated state's successor, like the KV's below.
+                *out, self._state = out
                 return out
 
             if pool is not None:

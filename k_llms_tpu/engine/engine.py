@@ -56,7 +56,7 @@ from ..reliability import failpoints as _failpoints
 from ..reliability.deadline import RequestBudget
 from ..types.wire import BackendUnavailableError, KLLMsError
 from ..utils.compile_cache import configure_compile_cache
-from ..utils.observability import FAILURE_EVENTS, QUARANTINE_EVENTS
+from ..utils.observability import FAILURE_EVENTS, QUARANTINE_EVENTS, note_model_aux
 
 logger = logging.getLogger(__name__)
 
@@ -306,9 +306,14 @@ class LocalEngine:
         self.mesh = mesh
         if quantize is True:
             quantize = "int8"
-        if self.config.is_latent:
-            # What the latent block (models/latent.py) cannot do yet fails
-            # here, by name, before anything is built; nothing falls back.
+        if self.config.is_latent or self.config.is_hybrid:
+            # What the latent block (models/latent.py) and the hybrid stack
+            # (models/hybrid.py) cannot do yet fails here, by name, before
+            # anything is built; nothing falls back. The hybrid stack's last
+            # two: each needs an answer for the rows' recurrent state that is
+            # not written (a snapshot at page boundaries; a decode loop other
+            # than the paged continuous one that carries it).
+            hybrid = self.config.is_hybrid
             refused = [
                 what for what, asked in (
                     (f"a device mesh ({len(jax.devices())} devices; build the "
@@ -317,14 +322,19 @@ class LocalEngine:
                     (f"quantize={quantize!r} (int8/int4 expert stacks)", bool(quantize)),
                     ("sp_prefill_min_tokens (sequence-parallel prefill)",
                      sp_prefill_min_tokens is not None),
-                    (f"speculative={speculative!r} (the dense-cache speculation path)",
-                     speculative is not None),
+                    (f"speculative={speculative!r} (the dense-cache speculation path; a "
+                     "recurrent state cannot be rolled back)", speculative is not None),
+                    (f"prefix_cache_size={prefix_cache_size} (a cached prefix needs the "
+                     "state at its page boundary)", hybrid and prefix_cache_size > 0),
+                    (f"kv_layout={kv_layout!r} (only the paged continuous loop carries the "
+                     "state; build with kv_layout='paged')", hybrid and kv_layout != "paged"),
                 ) if asked
             ]
             if refused:
+                block = ("the hybrid stack (recurrent state beside the cache)" if hybrid
+                         else "the latent block")
                 raise NotImplementedError(
-                    f"{self.config.name}: the latent block is not implemented for "
-                    + "; ".join(refused)
+                    f"{self.config.name}: {block} is not implemented for " + "; ".join(refused)
                 )
         if params is not None and not quantize:
             # A PRE-quantized checkpoint passed with quantize unset must still
@@ -616,9 +626,15 @@ class LocalEngine:
         fn = self._prefill_cache.get(bucket)
         if fn is None:
             def _prefill(params, tokens, prompt_len):
+                # Beside the logits and the cache: what the stack counted
+                # and the prompt's final recurrent state, as the chunk
+                # program returns them (both empty for most models).
+                state: Dict[str, Any] = {}
+                aux: Dict[str, Any] = {}
                 return prefill(
-                    self.config, params, tokens, prompt_len, mesh=self.mesh
-                )
+                    self.config, params, tokens, prompt_len, mesh=self.mesh,
+                    aux=aux, state=state,
+                ) + (aux, state)
 
             if self.mesh is not None:
                 out_shardings = (
@@ -627,6 +643,8 @@ class LocalEngine:
                         k=NamedSharding(self.mesh, cache_specs(shared_prefix=True)),
                         v=NamedSharding(self.mesh, cache_specs(shared_prefix=True)),
                     ),
+                    {},
+                    {},
                 )
                 fn = jax.jit(_prefill, out_shardings=out_shardings)
             else:
@@ -832,14 +850,18 @@ class LocalEngine:
         if fn is None:
             step = prefill_chunk_step_paged if paged else prefill_chunk_step
 
-            def _chunk(params, chunk_tokens, cache, cursor, valid_len):
-                # Last output: what the model's stack counted (see the loop's
-                # step programs); an empty dict for most models.
+            def _chunk(params, chunk_tokens, cache, cursor, valid_len, state=None):
+                # ``state``: the prompt's recurrent state so far, handed back
+                # (last output) as it is after the chunk's valid tokens; an
+                # empty dict for most models: no operand. Before it: what the
+                # model's stack counted (see the loop's step programs);
+                # likewise.
                 aux: Dict[str, Any] = {}
+                state = dict(state or {})
                 return step(
                     self.config, params, chunk_tokens, cache, cursor, valid_len,
-                    mesh=self.mesh, aux=aux,
-                ) + (aux,)
+                    mesh=self.mesh, aux=aux, state=state,
+                ) + (aux, state)
 
             if self.mesh is not None:
                 kv_sh = KVCache(
@@ -851,12 +873,13 @@ class LocalEngine:
                     # Chunk KV columns [L, C, KVH, D]: heads shard tp, like
                     # the pool they are scattered into.
                     cols_sh = NamedSharding(self.mesh, P(None, None, MODEL_AXIS, None))
-                    out_shardings = (logits_sh, kv_sh, cols_sh, cols_sh, {})
+                    out_shardings = (logits_sh, kv_sh, cols_sh, cols_sh, {}, {})
                 else:
-                    out_shardings = (logits_sh, kv_sh, {})
-                fn = jax.jit(_chunk, out_shardings=out_shardings, donate_argnums=(2,))
+                    out_shardings = (logits_sh, kv_sh, {}, {})
+                fn = jax.jit(_chunk, out_shardings=out_shardings, donate_argnums=(2,),
+                             donate_argnames=("state",))
             else:
-                fn = jax.jit(_chunk, donate_argnums=(2,))
+                fn = jax.jit(_chunk, donate_argnums=(2,), donate_argnames=("state",))
             self._chunk_cache[key] = fn
         return fn
 
@@ -1035,7 +1058,10 @@ class LocalEngine:
 
     def paged_admit_prefix(self, prompt_ids: List[int], prompt_len: int, bucket: int):
         """Admission-time prefix for the continuous decode loop's PAGED mode:
-        returns ``(first_logits, run, transient)``. A cached paged entry's run
+        returns ``(first_logits, run, transient, state)``; ``state`` is the
+        prompt's final recurrent state (``{}`` for a model without any, and
+        on a cache hit, which such a model never has: a prefix cache is
+        refused for it at build time). A cached paged entry's run
         is returned directly (zero device work, pages shared); otherwise the
         routed prefill runs and its result becomes either the just-stored
         cache run or, with the cache disabled, a TRANSIENT run the caller
@@ -1051,15 +1077,17 @@ class LocalEngine:
                 if hit is not None and isinstance(hit[1], PagedPrefixRun):
                     self._prefix_entries.move_to_end(key)
                     self.prefix_cache_stats["hits"] += 1
-                    return hit[0], hit[1], False
-        first_logits, prefix = self._prefill_routed(prompt_ids, prompt_len, bucket)
+                    return hit[0], hit[1], False, {}
+        first_logits, prefix, state = self._prefill_routed(
+            prompt_ids, prompt_len, bucket, with_state=True
+        )
         with self._paged_mutex:
             if self.prefix_cache_size > 0:
                 hit = self._prefix_entries.get(key)
                 if hit is not None and isinstance(hit[1], PagedPrefixRun):
-                    return first_logits, hit[1], False
+                    return first_logits, hit[1], False, state
             run = self._run_from_dense(prefix, prompt_len, bucket)
-            return first_logits, run, True
+            return first_logits, run, True, state
 
     def _prefix_store(
         self,
@@ -1249,19 +1277,31 @@ class LocalEngine:
         )
         return first_logits, prefix
 
-    def _prefill_full(self, prompt_ids: List[int], prompt_len: int, bucket: int):
+    def _prefill_full(
+        self, prompt_ids: List[int], prompt_len: int, bucket: int, with_state: bool = False
+    ):
         """One full-prompt prefill: dense, or sequence-parallel when the
         prompt qualifies (the single dispatch point for generate,
-        generate_many, and the prefix-cache miss path)."""
+        generate_many, the prefix-cache miss path and the loop's whole-prompt
+        admission, which asks ``with_state`` for the prompt's final recurrent
+        state as a third result). What the model's stack counted goes to
+        ``MODEL_COUNTERS`` here, as the loop's programs' does at readback."""
         tokens = jnp.array(
             [prompt_ids + [self.config.pad_token_id] * (bucket - prompt_len)],
             jnp.int32,
         )
         if self._use_sp_prefill(prompt_len, bucket):
+            # Refused at build time for a model with recurrent state.
             return self._get_sp_prefill(bucket)(
                 self.params, tokens, jnp.int32(prompt_len)
-            )
-        return self._get_prefill(bucket)(self.params, tokens, jnp.int32(prompt_len))
+            ) + (({},) if with_state else ())
+        first_logits, prefix, aux, state = self._get_prefill(bucket)(
+            self.params, tokens, jnp.int32(prompt_len)
+        )
+        if aux:
+            # kllms: ignore[host-sync-hot-path] — a few counters a prompt; admission reads the first logits next anyway
+            note_model_aux(jax.device_get(aux))
+        return (first_logits, prefix, state) if with_state else (first_logits, prefix)
 
     def _prefill_routed(
         self,
@@ -1269,12 +1309,15 @@ class LocalEngine:
         prompt_len: int,
         bucket: int,
         allow_seq_sharded: bool = False,
+        with_state: bool = False,
     ):
         if self.prefix_cache_size > 0:
+            # No model with recurrent state gets here: a prefix cache is
+            # refused for it at build time.
             return self._prefill_with_cache(
                 prompt_ids, prompt_len, bucket, allow_seq_sharded=allow_seq_sharded
-            )
-        return self._prefill_full(prompt_ids, prompt_len, bucket)
+            ) + (({},) if with_state else ())
+        return self._prefill_full(prompt_ids, prompt_len, bucket, with_state=with_state)
 
     # -- decode loop ------------------------------------------------------
     # -- cancellation plumbing --------------------------------------------
@@ -2400,6 +2443,19 @@ class LocalEngine:
             v[t] = float(bias)
         return jnp.asarray(v)
 
+    def _refuse_outside_loop(self, what: str) -> None:
+        """The engine's own decode loops carry no recurrent state: a model
+        that has one is served by the paged continuous loop alone, and a
+        request that loop does not take (top_logprobs, penalties, logit_bias,
+        a prompt or n beyond its bounds) fails here, by name."""
+        if self.config.is_hybrid:
+            raise NotImplementedError(
+                f"{self.config.name}: {what} outside the continuous loop is not implemented "
+                "for a model with recurrent state beside the cache (the request asked for "
+                "top_logprobs, penalties, logit_bias, or a prompt, n or max_tokens beyond "
+                "the loop's bounds)"
+            )
+
     # -- request prep -----------------------------------------------------
     def _prep_prompt(self, prompt_ids: Sequence[int]) -> Tuple[List[int], int, int]:
         """Normalize a prompt: BOS fallback, left-truncate to max_seq_len, and
@@ -2485,6 +2541,7 @@ class LocalEngine:
         token_sink: Optional[Callable[[int, np.ndarray], None]] = None,
     ) -> GenerationResult:
         config = self.config
+        self._refuse_outside_loop("generate")
         if budget is not None:
             # Fail before any device work: a spent budget must not trigger a
             # prefill (or worse, a compile).
@@ -2618,6 +2675,7 @@ class LocalEngine:
         it can back off its coalescing width (see
         ``EngineScheduler.note_oom``). See :meth:`_generate_many_attempt` for
         the decode semantics."""
+        self._refuse_outside_loop("generate_many")
         if not items:
             return []
         try:
@@ -2968,7 +3026,7 @@ class LocalEngine:
         try:
             first_list = []
             for ids, prompt_len, bucket in preps:
-                fl, run, transient = self.paged_admit_prefix(
+                fl, run, transient, _ = self.paged_admit_prefix(
                     ids, prompt_len, bucket
                 )
                 with self._paged_mutex:

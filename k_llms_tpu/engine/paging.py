@@ -310,7 +310,7 @@ class PagedKVPool:
         self.lock = make_rlock("engine.kv_pool", allow_dispatch=True)
         flat = int(total_pages) * int(page_size)
         heads, k_width, v_width = config.cache_widths
-        shape = (config.num_layers, flat, heads)
+        shape = (config.paging_layers, flat, heads)
         dtype = dtype or config.jax_dtype
         self.kv = KVCache(
             k=jnp.zeros(shape + (k_width,), dtype), v=jnp.zeros(shape + (v_width,), dtype)

@@ -64,7 +64,8 @@ class ModelConfig:
     sliding_window: "int | None" = None
     sliding_window_layers: str = "all"
     # Gemma-family variants:
-    # - act: MLP gate activation, "silu" (Llama) or "gelu" (GeGLU).
+    # - act: MLP gate activation, "silu" (Llama) or "gelu" (GeGLU); "relu2"
+    #   (non-gated relu(x)^2 experts: hybrid stacks only).
     # - norm_offset: RMSNorm scales by (1 + w) instead of w.
     # - embed_scale: multiply token embeddings by sqrt(hidden_size).
     # - post_block_norms: Gemma-2 extra norms on the attention and MLP outputs
@@ -107,6 +108,29 @@ class ModelConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: float = 30.0
+    # Hybrid stacks (Nemotron-H; models/hybrid.py): a non-empty layer_pattern
+    # switches the whole stack. One character a layer, each layer ONE pre-norm
+    # mixer and no MLP of its own: "M" a Mamba-2 mixer (mamba_num_heads heads
+    # of mamba_head_dim, mamba_n_groups B/C groups of ssm_state_size, a causal
+    # depthwise conv of mamba_conv_kernel taps over x|B|C), "E" routed experts
+    # (the latent block's router; non-gated relu^2 experts of
+    # moe_intermediate_size plus one shared expert of
+    # moe_shared_intermediate_size), "*" GQA attention (use_rope False: no
+    # rotary embedding). Only "*" layers page; an "M" layer keeps a
+    # fixed-size recurrent state a row (:attr:`state_shapes`).
+    layer_pattern: str = ""
+    use_rope: bool = True
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 1
+    ssm_state_size: int = 0
+    mamba_conv_kernel: int = 4
+    mamba_chunk: int = 128  # block length of the chunked (SSD) scan
+    # Seeded initialisation of the step dt (log-uniform, as published).
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    moe_shared_intermediate_size: int = 0  # 0: moe_intermediate_size * n_shared_experts
     # byte tokenizer vocab fits any vocab_size >= 260; HF tokenizers use the full space
     bos_token_id: int = 256
     eos_token_id: int = 257
@@ -129,6 +153,38 @@ class ModelConfig:
         return self.kv_lora_rank > 0
 
     @property
+    def is_hybrid(self) -> bool:
+        return bool(self.layer_pattern)
+
+    @property
+    def paging_layers(self) -> int:
+        """Layers that hold keys and values, so the leading axis of every
+        cache and of the page pool: all of them, or a hybrid stack's "*"."""
+        return self.layer_pattern.count("*") if self.is_hybrid else self.num_layers
+
+    def state_shapes(self, rows: int) -> "Dict[str, tuple]":
+        """The recurrent state ``rows`` rows hold beside their pages, as
+        ``{name: (state layers, shape, dtype)}``: the float32 SSM state
+        ``[rows, heads, head_dim, N]`` and the conv's last inputs ``[rows,
+        taps - 1, channels]`` of each "M" layer (one array a layer: a layer's
+        update then happens in place). Empty for a model without such layers,
+        so a pytree built from it adds no operand to any program."""
+        m = self.layer_pattern.count("M")
+        if not m:
+            return {}
+        conv_dim = self.mamba_num_heads * self.mamba_head_dim + 2 * self.mamba_n_groups * self.ssm_state_size
+        return {
+            "ssm": (m, (rows, self.mamba_num_heads, self.mamba_head_dim, self.ssm_state_size),
+                    jnp.dtype("float32")),
+            "conv": (m, (rows, self.mamba_conv_kernel - 1, conv_dim), self.jax_dtype),
+        }
+
+    @property
+    def state_bytes_per_row(self) -> int:
+        return sum(m * math.prod(shape) * dtype.itemsize
+                   for m, shape, dtype in self.state_shapes(1).values())
+
+    @property
     def cache_widths(self) -> "tuple[int, int, int]":
         """(heads, k width, v width) of one token's cache row in one layer.
         A latent model caches one [c_kv | k_rope] row and no V: its ``v``
@@ -140,9 +196,9 @@ class ModelConfig:
 
     @property
     def kv_bytes_per_token(self) -> int:
-        """Cache bytes one token holds over all layers, in the model dtype."""
+        """Cache bytes one token holds over the paging layers, in the model dtype."""
         heads, k_width, v_width = self.cache_widths
-        return self.num_layers * heads * (k_width + v_width) * self.jax_dtype.itemsize
+        return self.paging_layers * heads * (k_width + v_width) * self.jax_dtype.itemsize
 
     @property
     def attn_scale(self) -> float:
@@ -440,6 +496,76 @@ register_config(
         v_head_dim=16,
         moe_intermediate_size=32,
         first_k_dense=1,
+    )
+)
+
+# NVIDIA-Nemotron-3-Nano-30B-A3B (model_type nemotron_h,
+# https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16): 52
+# single-mixer blocks, 23 Mamba-2 / 23 routed-expert (128 non-gated relu^2
+# experts top-6 + one shared) / 6 GQA attention without rotary embedding.
+# The published preset is for shape arithmetic (31.58 B parameters do not fit
+# a chip). ``-cut9`` is the one-chip cut the benchmark serves: depth only, the
+# first nine layers of the pattern (4 M : 4 E : 1 *), every width, all 128
+# experts and the whole vocabulary (benchmark/configs/nemotron3-nano-30b-a3b.json
+# has the arithmetic and what is assumed). Served in bfloat16 through the
+# paged continuous loop only; what the recurrent state has no answer for is
+# refused by name (engine/engine.py).
+_NEMOTRON3 = ModelConfig(
+    name="nemotron3-nano-30b-a3b",
+    vocab_size=131072,
+    hidden_size=2688,
+    intermediate_size=1856,  # unused: the pattern has no dense-MLP layer
+    num_layers=52,
+    num_heads=32,
+    num_kv_heads=2,
+    head_dim=128,
+    rope_theta=10000.0,  # unused: use_rope is False
+    use_rope=False,
+    rms_eps=1e-5,
+    max_seq_len=8192,  # served context; the config declares 262,144
+    act="relu2",
+    num_experts=128,
+    num_experts_per_tok=6,
+    moe_intermediate_size=1856,
+    moe_shared_intermediate_size=3712,
+    n_shared_experts=1,
+    routed_scaling_factor=2.5,
+    layer_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+    mamba_num_heads=64,
+    mamba_head_dim=64,
+    mamba_n_groups=8,
+    ssm_state_size=128,
+    mamba_conv_kernel=4,
+    mamba_chunk=128,
+)
+register_config(_NEMOTRON3)
+register_config(
+    _NEMOTRON3.with_(name="nemotron3-nano-30b-a3b-cut9", num_layers=9, layer_pattern="MEMEM*EME")
+)
+# CPU test size of the same stack: the cut's pattern, 8 experts top-2, the
+# published ratios (state 2 x head_dim, the shared expert twice an expert).
+register_config(
+    _NEMOTRON3.with_(
+        name="nemotron3-tiny",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=48,
+        num_layers=9,
+        num_heads=4,
+        num_kv_heads=2,
+        head_dim=16,
+        max_seq_len=4096,
+        dtype="float32",
+        num_experts=8,
+        num_experts_per_tok=2,
+        moe_intermediate_size=48,
+        moe_shared_intermediate_size=96,
+        layer_pattern="MEMEM*EME",
+        mamba_num_heads=8,
+        mamba_head_dim=16,
+        mamba_n_groups=2,
+        ssm_state_size=32,
+        mamba_chunk=16,
     )
 )
 

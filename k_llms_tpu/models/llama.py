@@ -35,7 +35,8 @@ Params = Dict[str, Any]
 
 
 class KVCache(NamedTuple):
-    """Stacked per-layer cache: k/v are [num_layers, batch, max_len, kv_heads, head_dim]."""
+    """Stacked per-layer cache: k/v are [paging layers, batch, max_len, kv_heads,
+    head_dim] (every layer, or a hybrid stack's attention layers)."""
 
     k: jax.Array
     v: jax.Array
@@ -48,8 +49,16 @@ class KVCache(NamedTuple):
 def init_cache(config: ModelConfig, batch: int, max_len: int, dtype=None) -> KVCache:
     dtype = dtype or config.jax_dtype
     heads, k_width, v_width = config.cache_widths
-    shape = (config.num_layers, batch, max_len, heads)
+    shape = (config.paging_layers, batch, max_len, heads)
     return KVCache(k=jnp.zeros(shape + (k_width,), dtype), v=jnp.zeros(shape + (v_width,), dtype))
+
+
+def init_state(config: ModelConfig, rows: int) -> Dict[str, jax.Array]:
+    """The recurrent state ``rows`` rows hold beside their cache, zeroed:
+    ``{}`` for a model without any (see ``ModelConfig.state_shapes``), so it
+    adds no operand to a program it is passed to."""
+    return {k: tuple(jnp.zeros(shape, dtype) for _ in range(m))
+            for k, (m, shape, dtype) in config.state_shapes(rows).items()}
 
 
 # ---------------------------------------------------------------------------
@@ -63,6 +72,10 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=None) -> Params:
         from . import latent
 
         return latent.init_params(config, key, dtype)
+    if config.is_hybrid:
+        from . import hybrid
+
+        return hybrid.init_params(config, key, dtype)
     dtype = dtype or config.jax_dtype
     H, I, V = config.hidden_size, config.intermediate_size, config.vocab_size
     L, Q, KV = config.num_layers, config.q_dim, config.kv_dim
@@ -271,14 +284,18 @@ def _attn_qkv(
     k = k.reshape(B, Sq, config.num_kv_heads, config.head_dim)
     v = v.reshape(B, Sq, config.num_kv_heads, config.head_dim)
 
-    q = rope_embed(q, positions, config.rope_theta, config.rope_scaling)
-    k = rope_embed(k, positions, config.rope_theta, config.rope_scaling)
+    if config.use_rope:
+        q = rope_embed(q, positions, config.rope_theta, config.rope_scaling)
+        k = rope_embed(k, positions, config.rope_theta, config.rope_scaling)
     return q, k, v
 
 
 @jax.named_scope("mlp")
 def _mlp_sublayer(config: ModelConfig, layer: Params, x: jax.Array) -> jax.Array:
-    """Post-attention MLP sublayer with its residual (dense MLP or MoE)."""
+    """Post-attention MLP sublayer with its residual (dense MLP or MoE; none
+    in a hybrid stack's attention block, whose layer is the mixer alone)."""
+    if "mlp_norm" not in layer:
+        return x
     offset = config.norm_offset
     h = rms_norm(x, layer["mlp_norm"], config.rms_eps, offset)
     if "w_router" in layer:  # MoE (Mixtral)
@@ -573,6 +590,8 @@ def _apply_stack(
     sp_ring_mesh=None,
     mesh=None,
     aux: Optional[dict] = None,
+    valid_len: Optional[jax.Array] = None,
+    state: Optional[dict] = None,
 ) -> Tuple[jax.Array, KVCache]:
     """Scan the layer stack. cache k/v: [L, B, Smax, KVH, D].
 
@@ -583,7 +602,20 @@ def _apply_stack(
     ``aux``: a dict the caller wants the stack's own counts in (what a model
     counts is its own affair: see models/latent.py; the dense block counts
     nothing and leaves it empty). A latent model's stack is models/latent.py's.
+
+    ``valid_len`` [B] and ``state`` are for a stack with recurrent state
+    (models/hybrid.py): how many of each row's positions are tokens, and the
+    rows' state going in and coming out (a dict like ``aux``). Every other
+    stack ignores both.
     """
+    if config.is_hybrid:
+        from . import hybrid
+
+        return hybrid.apply_stack(
+            config, params, x, positions, cache, write_index, key_mask, valid_len,
+            key_lengths=key_lengths, prefix=prefix, state=state, aux=aux,
+            sp_ring_mesh=sp_ring_mesh, mesh=mesh,
+        )
     if config.is_latent:
         from . import latent
 
@@ -716,6 +748,7 @@ def forward(
         key_lengths=key_lengths,
         key_mask_global=key_mask_global,
         mesh=mesh,
+        valid_len=key_lengths,  # a recurrent state takes the padding to be on the right
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     logits = _logits(config, params, h)
@@ -728,10 +761,14 @@ def prefill(
     tokens: jax.Array,
     prompt_len: jax.Array,
     mesh=None,
+    aux: Optional[dict] = None,
+    state: Optional[dict] = None,
 ) -> Tuple[jax.Array, KVCache]:
     """Prefill the shared prompt at batch=1. tokens: [1, S] (bucket-padded on the
     right), prompt_len: scalar valid length. Returns (last-token logits [1, V],
-    prefix KVCache [L, 1, S, KVH, D])."""
+    prefix KVCache [L, 1, S, KVH, D]). ``aux`` and ``state`` as in
+    :func:`_apply_stack`: a model with recurrent state leaves the state after
+    the prompt's last token in ``state``."""
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
     x = _embed(config, params, tokens)
@@ -759,6 +796,9 @@ def prefill(
         key_lengths=key_lengths,
         key_mask_global=key_mask_global,
         mesh=mesh,
+        aux=aux,
+        valid_len=key_lengths,
+        state=state,
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     last = jnp.take_along_axis(h, (prompt_len - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1)
@@ -775,6 +815,7 @@ def prefill_continue(
     total_len: jax.Array,
     mesh=None,
     aux: Optional[dict] = None,
+    state: Optional[dict] = None,
 ) -> Tuple[jax.Array, KVCache]:
     """Prefill a prompt SUFFIX against an already-computed prompt-prefix KV —
     the prefix-caching path (the reference has no model layer; its provider
@@ -786,7 +827,9 @@ def prefill_continue(
     cache is returned — directly the decode loop's shared-prefix cache and
     the next cache entry. Attention masks are built over absolute positions,
     so sliding windows (static or alternating) and softcaps work unchanged.
-    Returns (last-valid-token logits [1, V], updated KVCache).
+    Returns (last-valid-token logits [1, V], updated KVCache). ``state``: the
+    recurrent state after the prefix going in, after the suffix's last valid
+    token coming out (a model without any ignores it).
     """
     B, Sq = suffix_tokens.shape
     Btot = cache.k.shape[2]
@@ -813,6 +856,8 @@ def prefill_continue(
         key_mask_global=key_mask_global,
         mesh=mesh,
         aux=aux,
+        valid_len=jnp.broadcast_to(total_len - prefix_len, (B,)).astype(jnp.int32),
+        state=state,
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     last_row = (total_len - prefix_len - 1).reshape(B, 1, 1).astype(jnp.int32)
@@ -830,6 +875,7 @@ def prefill_chunk_step(
     valid_len: jax.Array,
     mesh=None,
     aux: Optional[dict] = None,
+    state: Optional[dict] = None,
 ) -> Tuple[jax.Array, KVCache]:
     """Extend a partially-filled prompt prefix by one chunk — the unit of
     chunked prefill (Sarathi-style: prompt ingestion interleaved with decode
@@ -850,7 +896,7 @@ def prefill_chunk_step(
     """
     return prefill_continue(
         config, params, chunk_tokens, cache, cursor, cursor + valid_len,
-        mesh=mesh, aux=aux,
+        mesh=mesh, aux=aux, state=state,
     )
 
 
@@ -863,6 +909,7 @@ def prefill_chunk_step_paged(
     valid_len: jax.Array,
     mesh=None,
     aux: Optional[dict] = None,
+    state: Optional[dict] = None,
 ) -> Tuple[jax.Array, KVCache, jax.Array, jax.Array]:
     """Paged twin of :func:`prefill_chunk_step`: identical compute against the
     dense staging cache (byte-identity comes for free from the shared path),
@@ -872,7 +919,8 @@ def prefill_chunk_step_paged(
     v_cols [L, C, KVH, D])."""
     C = chunk_tokens.shape[1]
     logits, cache = prefill_chunk_step(
-        config, params, chunk_tokens, cache, cursor, valid_len, mesh=mesh, aux=aux
+        config, params, chunk_tokens, cache, cursor, valid_len, mesh=mesh, aux=aux,
+        state=state,
     )
     k_cols = jax.lax.dynamic_slice_in_dim(cache.k[:, 0], cursor, C, axis=1)
     v_cols = jax.lax.dynamic_slice_in_dim(cache.v[:, 0], cursor, C, axis=1)
@@ -1155,6 +1203,8 @@ def _apply_stack_paged(
     page_size: Optional[int] = None,
     mesh=None,
     aux: Optional[dict] = None,
+    valid_len: Optional[jax.Array] = None,
+    state: Optional[dict] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Paged twin of :func:`_apply_stack`: per-layer KV lives in a flat page
     pool addressed through block tables instead of dense caches.
@@ -1179,8 +1229,17 @@ def _apply_stack_paged(
     0``), so the whole stack is byte-identical to :func:`_apply_stack` on
     equal inputs. Returns ``(x, k_cols, v_cols)`` with the cols
     ``[L, B, KVH, D]`` — each row's freshly written KV column for the
-    caller's pool scatter.
+    caller's pool scatter. ``valid_len`` and ``state`` as in
+    :func:`_apply_stack`.
     """
+    if config.is_hybrid:
+        from . import hybrid
+
+        return hybrid.apply_stack_paged(
+            config, params, x, positions, pool_kv, prefix_idx, gen_idx, write_index,
+            key_mask, prefix_mask, valid_len, prefix_lengths=prefix_lengths,
+            attn_impl=attn_impl, page_size=page_size, state=state, aux=aux, mesh=mesh,
+        )
     if config.is_latent:
         from . import latent
 
@@ -1249,6 +1308,8 @@ def paged_verify_step(
     page_size: Optional[int] = None,
     mesh=None,
     aux: Optional[dict] = None,
+    state: Optional[dict] = None,
+    active: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Paged twin of :func:`verify_step` at ``Sq == 1`` — the continuous
     decode loop's step when its slots hold block tables into a shared page
@@ -1263,8 +1324,10 @@ def paged_verify_step(
     logits — pinned by tests/test_paged_differential.py. ``attn_impl``
     selects the fused attention ("xla" reference, "pallas" kernel, or the
     tests-only "pallas_interpret"); ``page_size`` is required for the Pallas
-    paths (slot->page table derivation). Returns (logits f32 [B, 1, V],
-    k_cols, v_cols [L, B, KVH, D]).
+    paths (slot->page table derivation). ``state``: the rows' recurrent
+    state, advanced by this token for the rows that are ``active`` ([B] bool;
+    None: all) and left as it is for the others. Returns (logits f32
+    [B, 1, V], k_cols, v_cols [L, B, KVH, D]).
     """
     B, Sq = tokens.shape
     G = gen_idx.shape[1]
@@ -1309,6 +1372,8 @@ def paged_verify_step(
         page_size=page_size,
         mesh=mesh,
         aux=aux,
+        valid_len=active,
+        state=state,
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     logits = _logits(config, params, h)

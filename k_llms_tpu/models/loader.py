@@ -300,6 +300,11 @@ def load_checkpoint(path: str, config: ModelConfig, dtype=None) -> Dict[str, Any
             f"{config.name}: no checkpoint mapping for the latent block (MLA projections, "
             "expert stacks, hyper-connection mixers); it runs on seeded weights"
         )
+    if config.is_hybrid:
+        raise NotImplementedError(
+            f"{config.name}: no checkpoint mapping for the hybrid stack (Mamba-2 mixers, "
+            "non-gated expert stacks, a per-layer pattern); it runs on seeded weights"
+        )
     if os.path.isdir(path) and any(f.endswith(".safetensors") for f in os.listdir(path)):
         params = load_safetensors(path, config, dtype)
     else:
@@ -351,6 +356,12 @@ def config_from_hf(path: str) -> Optional[ModelConfig]:
         raise NotImplementedError(
             f"config.json of model_type {hf.get('model_type')!r} describes latent attention; "
             "use the registered preset (models/config.py), no checkpoint mapping exists"
+        )
+    if "hybrid_override_pattern" in hf:
+        raise NotImplementedError(
+            f"config.json of model_type {hf.get('model_type')!r} describes a hybrid stack "
+            "(a per-layer pattern of mixers); use the registered preset (models/config.py), "
+            "no checkpoint mapping exists"
         )
     hidden = hf["hidden_size"]
     heads = hf["num_attention_heads"]

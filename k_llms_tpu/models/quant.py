@@ -122,9 +122,11 @@ def int4_mesh_compatible(config, tp: int) -> bool:
 
 def _quant_leaf_nodes(params: "Dict[str, Any]"):
     """The quantizable matmul leaf-nodes of a params tree (single source for
-    every stored-layout probe)."""
-    for key in _QUANT_LAYER_KEYS:
-        yield params["layers"].get(key)
+    every stored-layout probe). The hybrid stack's per-layer list
+    (models/hybrid.py) holds none: ``quantize_params`` refuses it."""
+    if isinstance(params["layers"], dict):
+        for key in _QUANT_LAYER_KEYS:
+            yield params["layers"].get(key)
     yield params.get("lm_head")
 
 
@@ -280,6 +282,12 @@ def quantize_params(params: Dict[str, Any], bits: int = 8) -> Dict[str, Any]:
             "quantize_params: the latent block's tree (models/latent.py) is served in "
             "its own dtype; int8/int4 expert stacks under the grouped products are not written"
         )
+    if isinstance(params["layers"], list):
+        raise NotImplementedError(
+            "quantize_params: the hybrid stack's tree (models/hybrid.py) is served in its "
+            "own dtype; int8/int4 for the non-gated expert stacks and the Mamba-2 "
+            "projections are not written"
+        )
     layers = dict(params["layers"])
     for key in _QUANT_LAYER_KEYS:
         layers[key] = quantize_weight_bits(layers[key], bits)
@@ -318,6 +326,11 @@ def init_params_quantized(
         raise NotImplementedError(
             f"{config.name}: no quantized init for the latent block; it is served in "
             f"{config.dtype} (int8/int4 expert stacks are not written)"
+        )
+    if config.is_hybrid:
+        raise NotImplementedError(
+            f"{config.name}: no quantized init for the hybrid stack; it is served in "
+            f"{config.dtype} (int8/int4 for the non-gated expert stacks are not written)"
         )
     cheap = dist == "cheap"
     dtype = dtype or config.jax_dtype

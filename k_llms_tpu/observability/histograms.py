@@ -207,6 +207,9 @@ class LatencyHistograms:
 #: jitted call until it returns) and ``continuous.readback`` (the
 #: ``device_get``: host blocked, device busy); ``continuous.idle`` — the
 #: worker's wait when it has neither a live row nor a PREFILLING admission.
+#: ``continuous.state_install`` — an admission's copy of the prefill lane's
+#: recurrent state into the request's rows (no sample for a model without
+#: such state).
 #: Per request: ``continuous.prefill_wall`` (dequeue -> its rows installed)
 #: and ``continuous.decode_wall`` (rows installed -> future resolved), wall
 #: clock, other requests' steps and chunks included; also phases of its trace.
@@ -224,6 +227,7 @@ LATENCY = LatencyHistograms(declared=(
     "continuous.bookkeep",
     "continuous.emit",
     "continuous.admit",
+    "continuous.state_install",
     "continuous.idle",
     "continuous.prefill_wall",
     "continuous.decode_wall",

@@ -26,6 +26,12 @@ def param_specs(config: ModelConfig) -> Dict[str, Any]:
             "(dense_layers/layers groups, latent projections, expert stacks, mixers): "
             "it runs on one device"
         )
+    if config.is_hybrid:
+        raise NotImplementedError(
+            f"{config.name}: no partition specs for the hybrid stack's tree yet (per-layer "
+            "Mamba-2, expert and attention dicts) nor for its recurrent state: it runs on "
+            "one device"
+        )
     layers = {
         "attn_norm": P(None, None),
         "wq": P(None, None, MODEL_AXIS),

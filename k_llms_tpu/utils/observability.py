@@ -312,14 +312,21 @@ GRAMMAR_EVENTS = EventCounters(declared=(
 #: layers and calls (times the bytes of one expert: what the grouped products
 #: streamed); ``moe_max_load`` — the busiest expert's token count, summed
 #: likewise; ``mla_latent_rows_read`` — latent cache rows the paged decode
-#: steps attended, summed over rows and layers. A model with no routed
-#: experts or no latent cache leaves its counters at zero.
+#: steps attended, summed over rows and layers; ``ssm_state_updates`` — the
+#: recurrent states a program call advanced (rows with a valid token x
+#: state-space layers: a decode step counts its live rows, a chunk or a whole
+#: prompt one row; times the bytes of one state, read and written: what the
+#: update streamed); ``ssm_tokens_scanned`` — valid tokens x state-space
+#: layers. A model with no routed experts, no latent cache or no state-space
+#: layer leaves its counters at zero.
 MODEL_COUNTERS = EventCounters(declared=(
     "moe_layer_calls",
     "moe_pairs",
     "moe_experts_touched",
     "moe_max_load",
     "mla_latent_rows_read",
+    "ssm_state_updates",
+    "ssm_tokens_scanned",
 ))
 
 
@@ -337,7 +344,8 @@ PAGED_ATTN_PAGES = EventCounters(declared=(
 
 
 def note_model_aux(aux: Dict[str, Any]) -> None:
-    """Add one program call's ``aux`` (host arrays: see models/latent.py) to
+    """Add one program call's ``aux`` (host arrays: see models/latent.py and
+    models/hybrid.py) to
     :data:`MODEL_COUNTERS`. ``moe_counts`` is ``[expert layers, experts]``
     tokens per expert over the rows the call computed."""
     counts = aux.get("moe_counts")
@@ -346,9 +354,11 @@ def note_model_aux(aux: Dict[str, Any]) -> None:
         MODEL_COUNTERS.record("moe_pairs", int(counts.sum()))
         MODEL_COUNTERS.record("moe_experts_touched", int((counts > 0).sum()))
         MODEL_COUNTERS.record("moe_max_load", int(counts.max(axis=-1).sum()))
-    rows = aux.get("mla_latent_rows_read")
-    if rows is not None:
-        MODEL_COUNTERS.record("mla_latent_rows_read", int(rows))
+    if aux.get("mla_latent_rows_read") is not None:
+        MODEL_COUNTERS.record("mla_latent_rows_read", int(aux["mla_latent_rows_read"]))
+    if aux.get("ssm_rows_updated") is not None:
+        MODEL_COUNTERS.record("ssm_state_updates", int(aux["ssm_rows_updated"]))
+        MODEL_COUNTERS.record("ssm_tokens_scanned", int(aux["ssm_tokens_scanned"]))
 
 
 #: Process-wide SSE-streaming counters (streams.opened, streams.completed,

@@ -193,10 +193,10 @@ def recorded_loop(request):
         def wrapped(grammar):
             fn = build(grammar)
 
-            def call(*args):
+            def call(*args, **state):  # the step takes the rows' recurrent state by keyword
                 seen[fn.__name__] = (fn, jax.tree.map(
                     lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args))
-                return fn(*args)
+                return fn(*args, **state)
 
             return call
         return wrapped

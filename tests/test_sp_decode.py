@@ -153,7 +153,7 @@ def test_sp_exact_hit_ignores_replicated_layout_entry():
     tokens = jnp.array(
         [PROMPT + [cfg.pad_token_id] * (bucket - len(PROMPT))], jnp.int32
     )
-    fl, pref = eng._get_prefill(bucket)(eng.params, tokens, jnp.int32(len(PROMPT)))
+    fl, pref = eng._get_prefill(bucket)(eng.params, tokens, jnp.int32(len(PROMPT)))[:2]
     assert not eng._kv_seq_sharded(pref)
     eng._prefix_store(PROMPT, fl, pref, seq_sharded=False)
 

@@ -186,9 +186,10 @@ def test_greedy_tokens_equal_through_the_loop_and_the_dense_path(layout):
         pages = loop.stats.get("pages")
     finally:
         loop.stop()
+    # Taken before ``generate``: its whole-prompt prefill program counts too.
+    grew = {k: v - before.get(k, 0) for k, v in MODEL_COUNTERS.snapshot().items()}
     want = shared_engine("xing4-tiny").generate(prompt, n=8, max_new_tokens=12, temperature=0.0, seed=3)
     np.testing.assert_array_equal(np.asarray(got.tokens)[:, :12], np.asarray(want.tokens)[:, :12])
-    grew = {k: v - before.get(k, 0) for k, v in MODEL_COUNTERS.snapshot().items()}
     # 4 chunks and 11 steps, 2 expert layers each; 32 tokens a chunk, 8 rows a step, top-2.
     assert grew["moe_layer_calls"] == (4 + 11) * 2
     assert grew["moe_pairs"] == (4 * 32 + 11 * 8) * 2 * 2
@@ -210,10 +211,12 @@ def test_the_dense_block_counts_nothing_and_aux_adds_up_into_the_counters():
 
     before = MODEL_COUNTERS.snapshot()
     note_model_aux({"moe_counts": np.asarray([[3, 0, 1], [0, 0, 4]]),
-                    "mla_latent_rows_read": np.int32(7)})
+                    "mla_latent_rows_read": np.int32(7),
+                    "ssm_rows_updated": np.int32(12), "ssm_tokens_scanned": np.int32(40)})
     grew = {k: v - before.get(k, 0) for k, v in MODEL_COUNTERS.snapshot().items()}
     assert grew == {"moe_layer_calls": 2, "moe_pairs": 8, "moe_experts_touched": 3,
-                    "moe_max_load": 7, "mla_latent_rows_read": 7}
+                    "moe_max_load": 7, "mla_latent_rows_read": 7,
+                    "ssm_state_updates": 12, "ssm_tokens_scanned": 40}
     assert set(MODEL_COUNTERS.declared) == set(grew)
 
 
@@ -232,7 +235,7 @@ def test_metrics_page_exports_model_counters():
     asyncio.run(app._metrics({}, None, send, {}))
     body = b"".join(m.get("body", b"") for m in sent).decode()
     for name in ("moe_layer_calls", "moe_pairs", "moe_experts_touched", "moe_max_load",
-                 "mla_latent_rows_read"):
+                 "mla_latent_rows_read", "ssm_state_updates", "ssm_tokens_scanned"):
         assert f"\nkllms_{name} " in body and f"# TYPE kllms_{name} gauge" in body
 
 
