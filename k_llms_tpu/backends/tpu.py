@@ -208,10 +208,12 @@ class BackendConfig(BaseModel):
     kv_pool_pages: Optional[int] = None
     # -- paged decode everywhere (PR 11) ----------------------------------
     # Paged-attention implementation for paged decode steps: "auto" picks
-    # the fused Pallas kernel on TPU and the jittable XLA reference
-    # elsewhere; "pallas" requests the kernel explicitly (COUNTED fallback
-    # to XLA when unavailable — kernel.paged_attn_fallback.<reason>); "xla"
-    # forces the reference. See ops/paged_attention.py.
+    # the fused Pallas kernel on TPU (a model whose every layer has a sliding
+    # window included: the kernel's walk and mask take it) and the jittable
+    # XLA reference elsewhere; "pallas" requests the kernel explicitly
+    # (COUNTED fallback to XLA when unavailable —
+    # kernel.paged_attn_fallback.<reason>); "xla" forces the reference. See
+    # ops/paged_attention.py.
     paged_attention_impl: str = "auto"
     # Route coalesced generate_many batches through the page pool too
     # (block-table decode, prompt pages shared via admission; byte-identical

@@ -1842,7 +1842,9 @@ class ContinuousDecodeLoop:
                     # The fused kernel's walk, counted here where the
                     # lengths are coherent (the XLA path gathers whole tables).
                     if self._paged_attn_impl != "xla":
-                        pages = self._pages.walk_counts(*lens)
+                        pages = self._pages.walk_counts(
+                            *lens, window=self.engine.config.sliding_window
+                        )
             # All-False in production; with an active ``engine.logits`` nan
             # failpoint, a seeded subset of the LIVE rows is poisoned — the
             # loop-scoped twin of the batch path's first-step injection.
@@ -1878,8 +1880,10 @@ class ContinuousDecodeLoop:
             if pool is not None:
                 note_paged_attn_dispatch(self._paged_attn_impl)
                 if pages is not None:
-                    PAGED_ATTN_PAGES.record("paged_attn_pages_walked", pages[0])
-                    PAGED_ATTN_PAGES.record("paged_attn_pages_tabled", pages[1])
+                    walked, tabled, windowed_out = pages
+                    PAGED_ATTN_PAGES.record("paged_attn_pages_walked", walked)
+                    PAGED_ATTN_PAGES.record("paged_attn_pages_tabled", tabled)
+                    PAGED_ATTN_PAGES.record("paged_attn_pages_windowed_out", windowed_out)
                 # The pool's buffers are donated to the step, so ``pool.kv``
                 # must point at the returned ones before anyone else can
                 # dispatch: dispatch-and-swap under the pool lock.

@@ -668,18 +668,21 @@ class SlotPages:
         return self.prefix_idx, self.gen_idx, write_idx
 
     def walk_counts(self, active: np.ndarray, prompt_lens: np.ndarray,
-                    gen_lens: np.ndarray) -> Tuple[int, int]:
-        """(pages the live rows' walks hold, pages the step's tables hold) for
-        the upcoming decode step, from what the step program hands the paged
-        kernel: the lengths with idle slots zeroed, the phase out of the gen
-        slot map."""
+                    gen_lens: np.ndarray, window: Optional[int] = None
+                    ) -> Tuple[int, int, int]:
+        """(pages the live rows' walks hold, pages the step's tables hold,
+        pages holding a pool position that lie before the window's first page
+        and so are not walked) for the upcoming decode step, from what the step
+        program hands the paged kernel: the lengths with idle slots zeroed,
+        the phase out of the gen slot map, the model's sliding window."""
         ps = self.page_size
-        n_prefix, n_gen = live_pages(
+        (p0, n_prefix), (g0, n_gen) = live_pages(
             np.where(active, prompt_lens, 0), np.where(active, gen_lens, 0),
-            self.gen_idx[:, 0] % ps, ps,
+            self.gen_idx[:, 0] % ps, ps, window,
         )
         tabled = self.width * sum(table_pages(self.max_prompt, self.max_new, ps))
-        return int(n_prefix.sum() + n_gen.sum()), tabled
+        windowed_out = int(np.sum(p0) + np.sum(g0))
+        return int(n_prefix.sum() + n_gen.sum()) - windowed_out, tabled, windowed_out
 
     # -- retirement --------------------------------------------------------
 

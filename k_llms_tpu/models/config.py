@@ -57,7 +57,9 @@ class ModelConfig:
     # Architecture variants beyond Llama:
     # - qkv_bias: additive bias on q/k/v projections (Qwen2 family).
     # - sliding_window: each query attends only to the last W keys
-    #   (Mistral family); None = full causal. Forces the XLA attention path.
+    #   (Mistral family); None = full causal. Forces the XLA path of the
+    #   dense flash kernels; the paged decode kernel takes a window that every
+    #   layer has (ops/paged_attention.py).
     # - sliding_window_layers: "all" (every layer windowed — Mistral) or
     #   "alternating" (even layers windowed, odd layers global — Gemma-2).
     qkv_bias: bool = False
@@ -155,6 +157,12 @@ class ModelConfig:
     @property
     def is_hybrid(self) -> bool:
         return bool(self.layer_pattern)
+
+    @property
+    def mixes_windowed_layers(self) -> bool:
+        """Windowed and global layers in one stack (Gemma-2's "alternating"),
+        against no window or one that every layer has."""
+        return self.sliding_window is not None and self.sliding_window_layers != "all"
 
     @property
     def paging_layers(self) -> int:

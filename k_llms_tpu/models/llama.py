@@ -567,7 +567,7 @@ def _block(
 def _local_layer_flags(config: ModelConfig) -> Optional[jax.Array]:
     """[L] bool: layer uses the windowed mask. None when no per-layer mixing
     (full causal everywhere, or every layer windowed)."""
-    if config.sliding_window is None or config.sliding_window_layers == "all":
+    if not config.mixes_windowed_layers:
         return None
     # "alternating" (Gemma-2): even layers local, odd layers global.
     return jnp.arange(config.num_layers) % 2 == 0
@@ -1128,7 +1128,7 @@ def _block_paged(
             and page_tables is not None
             and prefix_lengths is not None
             and config.attn_softcap is None
-            and config.sliding_window is None
+            and not config.mixes_windowed_layers  # the kernel takes one window
         ):
             prefix_pages, gen_pages, gen_phase = page_tables
             plen = jnp.asarray(prefix_lengths, jnp.int32).reshape(-1)
@@ -1147,6 +1147,7 @@ def _block_paged(
                 write_index.astype(jnp.int32),
                 page_size=page_size,
                 sm_scale=scale,
+                window=config.sliding_window,
                 interpret=attn_impl == "pallas_interpret",
                 mesh=mesh,
             )[:, None]  # [B, 1, QH, D]

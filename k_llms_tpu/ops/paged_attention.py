@@ -34,7 +34,10 @@ sliding_window / mla / platform) when "pallas" is requested but can't run, or
 when "auto" on a TPU meets a model outside the kernel's support; "auto"
 choosing XLA off-TPU is the documented CPU posture, not a fallback, so it is
 not counted. The ``ops.paged_attn`` failpoint forces the fallback branch for
-drills.
+drills. A sliding window that every layer has (Mistral) is inside the kernel's
+support: it is part of the walk and of the mask (:func:`live_pages`). Only a
+per-layer mix of windowed and global layers (Gemma-2's "alternating") is
+``sliding_window`` now.
 
 Masking contract (shared with `gather_kv_pages`): out-of-table positions
 point into the trash page; their values are arbitrary-but-finite and every
@@ -80,8 +83,11 @@ def resolve_paged_attention_impl(
     """Pick the paged-attention implementation for the current process.
 
     requested: "auto" | "pallas" | "xla"; config: optional ModelConfig — a
-    model using attention softcap, sliding windows or a latent (MLA) cache is
-    outside the kernel's support and resolves to "xla". Resolution is host-side and happens once
+    model using attention softcap, a latent (MLA) cache, or a sliding window
+    on some layers and not on others (``sliding_window_layers ==
+    "alternating"``: the kernel takes one window for the whole stack) is
+    outside the kernel's support and resolves to "xla". A window on every
+    layer is served by the kernel. Resolution is host-side and happens once
     per loop/launch build, not per step. An explicit "pallas" request that
     cannot be honored records ``kernel.paged_attn_fallback.<reason>``, where
     the reason distinguishes config-driven fallbacks (``softcap``,
@@ -109,7 +115,7 @@ def resolve_paged_attention_impl(
         blocked: Optional[str] = "mla"  # a latent page is no (KVH, D) tile
     elif config is not None and config.attn_softcap is not None:
         blocked = "softcap"
-    elif config is not None and config.sliding_window is not None:
+    elif config is not None and config.mixes_windowed_layers:
         blocked = "sliding_window"
     else:
         blocked = None
@@ -296,21 +302,41 @@ def pages_per_block(page_bytes: int, table_pages: int) -> int:
     return max(1, min(PAGE_BUFFER_BYTES // (4 * page_bytes), table_pages))
 
 
-def live_pages(prompt_lens, gen_lens, gen_phase, page_size: int):
-    """The pages of a row's table that hold a position it attends to:
-    ``(prefix pages, generated pages)``, the first ones of each table.
+def live_pages(prompt_lens, gen_lens, gen_phase, page_size: int, window=None):
+    """The pages of a row's table that hold a position it attends to, a
+    contiguous run of each table: ``((first, end) of the prefix table's,
+    (first, end) of the generated table's)``, ``end`` exclusive.
 
     prompt_lens / gen_lens: valid prompt positions and generated tokens in the
     pool (the current token's fresh column is not in it); gen_phase: the
     in-page offset of generated position 0. A row with no generated token has
     no generated page whatever its phase, so a row with nothing has none at
-    all. Plain integer arithmetic: the kernel calls it on its SMEM scalars,
-    the loop's page counter on numpy vectors.
+    all. window: the model's sliding window W, or None. The query at absolute
+    position ``q = prompt_lens + gen_lens`` sees key ``a`` iff ``a > q - W``
+    (itself and W - 1 before it), so the first prompt position it sees is
+    ``max(0, q - W + 1)`` and the first generated one ``max(0, gen_lens - W +
+    1)``; the run starts at the page that holds it, and a page before it holds
+    only positions the reference's masks give weight 0. Without a window both
+    runs start at the literal 0. Plain integer arithmetic: the kernel calls it
+    on its SMEM scalars, the loop's page counter on numpy vectors.
     """
     ps = page_size
     n_prefix = (prompt_lens + (ps - 1)) // ps
     n_gen = (gen_phase + gen_lens + (ps - 1)) // ps * (gen_lens > 0)
-    return n_prefix, n_gen
+    if window is None:
+        return (0, n_prefix), (0, n_gen)
+
+    def at_least_0(x):
+        return x * (x > 0)
+
+    first_prompt = at_least_0(prompt_lens + gen_lens - (window - 1))
+    first_gen = at_least_0(gen_lens - (window - 1))
+    # The run starts at the page of the first position seen, where the pool
+    # holds one: a window that has left the prompt, or that holds the fresh
+    # column alone, leaves an empty run (first == end).
+    p0 = n_prefix - (n_prefix - first_prompt // ps) * (first_prompt < prompt_lens)
+    g0 = n_gen - (n_gen - (gen_phase + first_gen) // ps) * (first_gen < gen_lens)
+    return (p0, n_prefix), (g0, n_gen)
 
 
 def _paged_decode_kernel(
@@ -339,10 +365,12 @@ def _paged_decode_kernel(
     pages_per_layer: int,
     kv_heads: int,
     block_pages: int,
+    window: Optional[int],
 ):
     # Grid (row,). A row walks its own live pages and no others: the first
     # n_prefix columns of its prefix table, then the first n_gen of its gen
-    # table, K pages a block. Each page is one DMA out of the pool in HBM,
+    # table (under a window each run starts at the window's first page: p0,
+    # g0), K pages a block. Each page is one DMA out of the pool in HBM,
     # addressed through the block table and the layer number here in the
     # body. Two slots: while a block is scored out of one, the other fills
     # with the row's next. The trip count is read from SMEM, so a row with
@@ -354,12 +382,20 @@ def _paged_decode_kernel(
     # masked slot). That computes KVH times the scores it keeps, and takes
     # the pages exactly as the pool lays them out: cutting one head's rows
     # out of the (position, head) interleave cost more than the scores.
+    #
+    # ``window`` is static. Without one every windowed term below is a Python
+    # branch not taken, and the kernel is the one a model without a window
+    # always had, operation for operation.
     b = pl.program_id(0)
     ps, K, KVH = page_size, block_pages, kv_heads
     plen, glen, phase = plen_ref[b], glen_ref[b], phase_ref[b]
-    n_prefix, n_gen = live_pages(plen, glen, phase, ps)
+    (p0, n_prefix), (g0, n_gen) = live_pages(plen, glen, phase, ps, window)
     n_prefix = jnp.minimum(n_prefix, num_prefix_pages)
-    live = n_prefix + jnp.minimum(n_gen, num_gen_pages)
+    n_gen = jnp.minimum(n_gen, num_gen_pages)
+    if window is not None:  # the runs' lengths from here on, not their ends
+        n_prefix = n_prefix - jnp.minimum(p0, n_prefix)
+        n_gen = n_gen - jnp.minimum(g0, n_gen)
+    live = n_prefix + n_gen
     n_blocks = (live + (K - 1)) // K
     base = layer_ref[0] * pages_per_layer
 
@@ -376,6 +412,8 @@ def _paged_decode_kernel(
             @pl.when(i < live)
             def _start():
                 col = jnp.where(i < n_prefix, i, num_prefix_pages + i - n_prefix)
+                if window is not None:
+                    col = col + jnp.where(i < n_prefix, p0, g0)
                 for copy in page_copies(base + tables_ref[b, col], slot, t):
                     copy.start()
 
@@ -417,12 +455,19 @@ def _paged_decode_kernel(
         # in-page offset (phase + g) % ps of gen page (phase + g) // ps).
         # Anything outside [0, limit) — padding, the phase shift's dead
         # lead-in, the block's unfetched tail — scores NEG_INF and
-        # contributes an exact 0 (the TRASH_PAGE contract).
+        # contributes an exact 0 (the TRASH_PAGE contract). So does a
+        # position the window has passed: the query at plen + glen sees
+        # absolute position a (a prompt position's own, plen + a generated
+        # one's) iff a > plen + glen - window.
         prefix_toks = (n_prefix - blk * K) * ps
         is_prefix = tok < prefix_toks
         pos = jnp.where(is_prefix, tok + blk * (K * ps), tok - prefix_toks - phase)
+        if window is not None:
+            pos = pos + jnp.where(is_prefix, p0, g0) * ps
         limit = jnp.where(is_prefix, plen, glen)
         valid = own & (pos >= 0) & (pos < limit)
+        if window is not None:
+            valid = valid & (pos > jnp.where(is_prefix, plen, 0) + glen - window)
 
         wait_block(blk, slot)
         s = lax.dot_general(
@@ -478,6 +523,7 @@ def paged_decode_attention_pallas(
     *,
     page_size: int,
     sm_scale: float,
+    window: Optional[int] = None,
     interpret: bool = False,
     mesh=None,
 ) -> jax.Array:
@@ -491,7 +537,9 @@ def paged_decode_attention_pallas(
     :func:`paged_attention_page_tables`;
     new_k/new_v [B, KVH, D]: this step's fresh column; prompt_lens /
     gen_lens [B]: per-row valid counts — they are the walk's trip count too
-    (:func:`live_pages`), so a row whose lengths are zero reads no page.
+    (:func:`live_pages`), so a row whose lengths are zero reads no page;
+    window: the sliding window every layer of the model has, or None — the
+    walk starts at the window's first page and the mask ends at its edge.
     Returns [B, QH, D] f32 — the normalized output the XLA reference
     produces, up to the float ordering of an online softmax over blocks
     (f32 accumulation: 2e-5 beside the reference over an f32 pool, bf16's own
@@ -517,7 +565,7 @@ def paged_decode_attention_pallas(
         )
     local = functools.partial(
         _paged_decode_local, page_size=page_size, sm_scale=sm_scale,
-        interpret=interpret,
+        window=window, interpret=interpret,
     )
     if multi_device(mesh):
         b_ax = mesh_axis(mesh, DATA_AXIS, B)
@@ -539,7 +587,7 @@ def paged_decode_attention_pallas(
 
 def _paged_decode_local(
     q, pool_k, pool_v, layer, prefix_pages, gen_pages, gen_phase, new_k, new_v,
-    prompt_lens, gen_lens, *, page_size, sm_scale, interpret,
+    prompt_lens, gen_lens, *, page_size, sm_scale, window, interpret,
 ):
     """One shard's fused paged decode (the whole call on a single device)."""
     B, QH, D = q.shape
@@ -568,6 +616,7 @@ def _paged_decode_local(
         pages_per_layer=npages,
         kv_heads=KVH,
         block_pages=K,
+        window=window,
     )
     row = pl.BlockSpec((1, QH, D), lambda b, *_: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(

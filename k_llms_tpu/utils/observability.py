@@ -270,8 +270,10 @@ CONSENSUS_EVENTS = EventCounters(declared=(
 #: launch, not per token; kernel.paged_attn_fallback.<reason> — an explicit
 #: "pallas" request degraded to the XLA reference, with the reason suffix
 #: naming what blocked it: ``failpoint`` (the ops.paged_attn failpoint),
-#: ``softcap`` / ``sliding_window`` (model config the kernel doesn't cover —
-#: capability-driven), or ``platform`` (no TPU — environment-driven); "auto"
+#: ``softcap`` / ``sliding_window`` / ``mla`` (model config the kernel doesn't
+#: cover — capability-driven; ``sliding_window`` is a per-layer mix of windowed
+#: and global layers, a window on every layer is served), or ``platform`` (no
+#: TPU — environment-driven); "auto"
 #: choosing XLA on CPU is the documented posture and is NOT counted as a
 #: fallback), fed by ops/paged_attention.py and surfaced via scheduler
 #: stats/health and ``/metrics`` as ``kllms_kernel_*``.
@@ -336,10 +338,14 @@ MODEL_COUNTERS = EventCounters(declared=(
 #: ``/metrics``. ``paged_attn_pages_walked`` — pages holding a position some
 #: live row attends to (``live_pages``, summed over rows: what the kernel
 #: fetches a layer); ``paged_attn_pages_tabled`` — rows x table pages, what a
-#: walk of whole tables would fetch. Zero where the XLA path serves.
+#: walk of whole tables would fetch; ``paged_attn_pages_windowed_out`` — pages
+#: that hold a position in the pool and lie before the sliding window's first
+#: page, so the walk starts past them (0 while no row outgrows its window).
+#: Zero where the XLA path serves.
 PAGED_ATTN_PAGES = EventCounters(declared=(
     "paged_attn_pages_walked",
     "paged_attn_pages_tabled",
+    "paged_attn_pages_windowed_out",
 ))
 
 

@@ -112,17 +112,48 @@ def _build_tables(plens, G, ps, *, continuous):
 POOL_LAYERS = 3
 LAYERS = [0, 2]
 
+# Sliding windows by where they bind, as functions of the page size: inside
+# the longer rows' prompts (the walk starts at a later prefix page), inside
+# the generated run (the prompt is out of sight and the walk starts at a later
+# gen page), and nowhere. ``None`` is a model without one.
+WINDOWS = {
+    "none": lambda ps: None,
+    "in_prompt": lambda ps: 2 * ps + 1,
+    "in_generated": lambda ps: ps // 2 + 1,
+    "wide": lambda ps: 100 * ps,
+}
+# Each window on one layer of the pool, no window on both.
+LAYER_WINDOWS = [
+    (0, "none"), (2, "none"), (2, "in_prompt"), (2, "in_generated"), (0, "wide"),
+]
+
+
+def _reference_masks(plens, glens, P, G, window):
+    """``(key_mask [B, 1, G], prefix_mask [B, 1, P])`` as ``paged_verify_step``
+    builds them: the fresh column at ``glens`` included, and under a window
+    only the keys at absolute positions above ``plens + glens - window``."""
+    s = np.arange(G)[None, None, :]
+    c = np.arange(P)[None, None, :]
+    glens, plens = glens[:, None, None], plens[:, None, None]
+    key_mask, prefix_mask = s <= glens, c < plens
+    if window is not None:
+        key_mask = key_mask & (s > glens - window)
+        prefix_mask = prefix_mask & (c > plens + glens - window)
+    return key_mask, prefix_mask
+
 
 @pytest.mark.parametrize("page_size", PAGE_SIZES)
 @pytest.mark.parametrize("continuous", [False, True])
-@pytest.mark.parametrize("layer", LAYERS)
-def test_op_pallas_interpret_matches_xla(page_size, continuous, layer):
+@pytest.mark.parametrize("layer,window", LAYER_WINDOWS)
+def test_op_pallas_interpret_matches_xla(page_size, continuous, layer, window):
     """Ragged prompt/gen lengths, every page-boundary alignment class
     (mid-page, exact multiple, single-slot), trash garbage in the pool:
     the fused kernel must agree with the reference on both the coalesced
     (phase 0) and continuous (phase-shifted) gen layouts — reading its layer
-    out of the whole pool, as the reference does bit for bit."""
+    out of the whole pool, as the reference does bit for bit — and under a
+    sliding window, against the reference given the windowed masks."""
     ps = page_size
+    window = WINDOWS[window](ps)
     B, G = 4, 12
     QH, KVH, D = 4, 2, 16
     plens = np.array([1, ps, 2 * ps + 3, 2 * ps - 1], np.int32)
@@ -147,10 +178,9 @@ def test_op_pallas_interpret_matches_xla(page_size, continuous, layer):
     nv = jax.random.normal(keys[4], (B, 1, KVH, D), jnp.float32)
     sm_scale = 1.0 / math.sqrt(D)
 
-    s = np.arange(G)[None, None, :]
-    key_mask = jnp.asarray(s <= wis[:, None, None])  # fresh column included
-    c = np.arange(prefix_idx.shape[1])[None, None, :]
-    prefix_mask = jnp.asarray(c < plens[:, None, None])
+    key_mask, prefix_mask = map(
+        jnp.asarray, _reference_masks(plens, wis, prefix_idx.shape[1], G, window)
+    )
 
     def xla_op(pk, pv, l):
         return paged_decode_attention_xla(
@@ -170,7 +200,7 @@ def test_op_pallas_interpret_matches_xla(page_size, continuous, layer):
     out_p = paged_decode_attention_pallas(
         q[:, 0], pool_k, pool_v, jnp.int32(layer), *tables, nk[:, 0], nv[:, 0],
         jnp.asarray(plens), jnp.asarray(wis),
-        page_size=ps, sm_scale=sm_scale, interpret=True,
+        page_size=ps, sm_scale=sm_scale, window=window, interpret=True,
     )
     np.testing.assert_allclose(
         np.asarray(out_p), np.asarray(out_x[:, 0]), rtol=2e-5, atol=2e-6
@@ -204,19 +234,21 @@ RAGGED_CASES = {
 
 @pytest.mark.parametrize("page_size", PAGE_SIZES)
 @pytest.mark.parametrize("continuous", [False, True])
-@pytest.mark.parametrize("layer", LAYERS)
+@pytest.mark.parametrize("layer,window", LAYER_WINDOWS)
 @pytest.mark.parametrize("case", sorted(RAGGED_CASES))
 def test_op_ragged_walk_reads_live_pages_only(
-    case, layer, continuous, page_size, monkeypatch
+    case, layer, window, continuous, page_size, monkeypatch
 ):
     """Each row walks its own pages, K = 3 a block: the kernel agrees with
     the reference, which sees a clean pool, while every page that holds no
     position some row attends to — the trash page, table tails, idle rows'
-    pages, other layers' copies of them — is NaN in the kernel's pool. A
-    fetched page's values reach the accumulator even under a zero weight
-    (0 * NaN), so a finite, equal result says the walk stopped where the
-    row's pages stop, and equality that it did not stop short."""
+    pages, pages the sliding window has passed, other layers' copies of them
+    — is NaN in the kernel's pool. A fetched page's values reach the
+    accumulator even under a zero weight (0 * NaN), so a finite, equal result
+    says the walk started at the window's first page and stopped where the
+    row's pages stop, and equality that it skipped nothing it should see."""
     ps = page_size
+    window = WINDOWS[window](ps)
     G = 3 * ps  # gen table: 3 pages and the phase shift's spare
     plens = np.array(RAGGED_CASES[case][0](ps), np.int32)
     wis = np.array(RAGGED_CASES[case][1](ps, G), np.int32)
@@ -238,8 +270,7 @@ def test_op_ragged_walk_reads_live_pages_only(
     nv = jax.random.normal(keys[4], (B, 1, KVH, D), jnp.float32)
     sm_scale = 1.0 / math.sqrt(D)
 
-    key_mask = np.arange(G)[None, None, :] <= wis[:, None, None]
-    prefix_mask = np.arange(prefix_idx.shape[1])[None, None, :] < plens[:, None, None]
+    key_mask, prefix_mask = _reference_masks(plens, wis, prefix_idx.shape[1], G, window)
     out_x = paged_decode_attention_xla(
         q, pool_k, pool_v, jnp.int32(layer),
         jnp.asarray(prefix_idx), jnp.asarray(gen_idx),
@@ -262,42 +293,51 @@ def test_op_ragged_walk_reads_live_pages_only(
         jnp.where(dead_slots, jnp.nan, pool_k), jnp.where(dead_slots, jnp.nan, pool_v),
         jnp.int32(layer), *tables, nk[:, 0], nv[:, 0],
         jnp.asarray(plens), jnp.asarray(wis),
-        page_size=ps, sm_scale=sm_scale, interpret=True,
+        page_size=ps, sm_scale=sm_scale, window=window, interpret=True,
     )
     np.testing.assert_allclose(
         np.asarray(out_p), np.asarray(out_x[:, 0]), rtol=2e-5, atol=2e-6
     )
 
 
+@pytest.mark.parametrize("window", sorted(WINDOWS))
 @pytest.mark.parametrize("page_size", [4, 8, 64])
-def test_live_pages_counts_the_pages_the_reference_masks_leave(page_size):
+def test_live_pages_counts_the_pages_the_reference_masks_leave(page_size, window):
     """``live_pages`` against a brute-force count over the masks the XLA
     reference is given: a table page is live when some position of it is
     unmasked and in the pool — prefix position c < plen sits on prefix page
-    c // ps, generated position g < glen on gen page (phase + g) // ps."""
+    c // ps, generated position g < glen on gen page (phase + g) // ps — and
+    under a sliding window the masks keep only the last W keys."""
     ps = page_size
+    window = WINDOWS[window](ps)
     rng = np.random.default_rng(ps)
     P, G = 9 * ps, 4 * ps
     plens = np.concatenate([[0, 1, ps - 1, ps, ps + 1, P], rng.integers(0, P + 1, 58)])
     glens = np.concatenate([[0, 0, 1, ps, G - 1, G - 1], rng.integers(0, G, 58)])
+    key_mask, prefix_mask = _reference_masks(plens, glens, P, G, window)
+    gen_mask = key_mask[:, 0] & (np.arange(G)[None, :] < glens[:, None])  # in the pool
     for phase in (np.zeros_like(plens), plens % ps):  # coalesced, continuous
-        n_prefix, n_gen = live_pages(plens, glens, phase, ps)
-        prefix_mask = np.arange(P)[None, :] < plens[:, None]
-        gen_mask = np.arange(G)[None, :] < glens[:, None]
+        (p0, n_prefix), (g0, n_gen) = live_pages(plens, glens, phase, ps, window)
+        p0, g0 = np.broadcast_to(p0, plens.shape), np.broadcast_to(g0, plens.shape)
         for b in range(len(plens)):
-            prefix_pages = np.unique(np.flatnonzero(prefix_mask[b]) // ps)
+            prefix_pages = np.unique(np.flatnonzero(prefix_mask[b, 0]) // ps)
             gen_pages = np.unique((phase[b] + np.flatnonzero(gen_mask[b])) // ps)
-            # the walk takes the FIRST n of each table: the live pages are those
-            np.testing.assert_array_equal(prefix_pages, np.arange(n_prefix[b]))
-            np.testing.assert_array_equal(gen_pages, np.arange(n_gen[b]))
+            # the walk takes one run of each table: the live pages are those
+            np.testing.assert_array_equal(prefix_pages, np.arange(p0[b], n_prefix[b]))
+            np.testing.assert_array_equal(gen_pages, np.arange(g0[b], n_gen[b]))
         NP, NG = table_pages(P, G, ps)
         assert n_prefix.max() <= NP and n_gen.max() <= NG
+        # both runs start past page 0 for some row exactly when the window binds
+        assert (p0.any() and g0.any()) == (window is not None and window < P + G)
 
 
-def test_loop_counts_the_pages_its_steps_walk(monkeypatch):
+@pytest.mark.parametrize("window", [None, 3])
+def test_loop_counts_the_pages_its_steps_walk(window, monkeypatch):
     """A small paged loop on the interpreted kernel: greedy tokens equal the
-    XLA-paged loop's, and the two ``/metrics`` gauges advance by the sums the
-    rows' lengths give — idle slots walking nothing."""
+    XLA-paged loop's, and the ``/metrics`` gauges advance by the sums the
+    rows' lengths give — idle slots walking nothing, and under a sliding
+    window (3: it leaves the prompt's first page, then the prompt, then the
+    first generated page) the pages before its first one counted apart."""
     import asyncio
 
     from conftest import shared_engine
@@ -306,9 +346,11 @@ def test_loop_counts_the_pages_its_steps_walk(monkeypatch):
     from k_llms_tpu.engine.continuous import ContinuousDecodeLoop
     from k_llms_tpu.serving.app import create_app
 
-    ps, width, max_prompt, max_new, n, new = 8, 4, 64, 16, 2, 7
+    ps, width, max_prompt, max_new, n, new = 8, 4, 64, 16, 2, 10
     prompt = [5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15]  # 11 tokens: phase 3
-    engine = shared_engine(model="tiny", kv_layout="paged", kv_page_size=ps)
+    engine = shared_engine(
+        model=CONFIG.with_(sliding_window=window), kv_layout="paged", kv_page_size=ps
+    )
     runs = {}
     for impl in ("xla", "pallas_interpret"):
         monkeypatch.setattr(
@@ -336,12 +378,24 @@ def test_loop_counts_the_pages_its_steps_walk(monkeypatch):
     # The first token comes from the prefill; step t has t tokens in the pool.
     assert steps == new - 1
     plen, phase = len(prompt), len(prompt) % ps
-    walked = sum(
-        n * (-(-plen // ps) + (-(-(phase + t) // ps) if t else 0)) for t in range(steps)
-    )
+    held = windowed_out = 0
+    for t in range(steps):
+        # Table pages with a position in the pool, and those of them with a
+        # position the query at plen + t still sees.
+        prompt_pages = {c // ps for c in range(plen)}
+        gen_pages = {(phase + g) // ps for g in range(t)}
+        held += n * (len(prompt_pages) + len(gen_pages))
+        if window is not None:
+            first = plen + t - window + 1
+            prompt_pages -= {c // ps for c in range(plen) if c >= first}
+            gen_pages -= {(phase + g) // ps for g in range(t) if plen + g >= first}
+            windowed_out += n * (len(prompt_pages) + len(gen_pages))
+    walked = held - windowed_out
+    assert (windowed_out > 0) == (window is not None)
     assert grew == {
         "paged_attn_pages_walked": walked,
         "paged_attn_pages_tabled": steps * width * sum(table_pages(max_prompt, max_new, ps)),
+        "paged_attn_pages_windowed_out": windowed_out,
     }
     assert set(runs["xla"][2].values()) == {0}  # the XLA path gathers whole tables
 
@@ -549,17 +603,22 @@ def _step_case(ps, *, fork_gen_page=False, seed=0):
     )
 
 
+@pytest.mark.parametrize("window", [None, 6])
 @pytest.mark.parametrize("page_size", PAGE_SIZES)
-def test_step_xla_bitwise_dense_pallas_greedy(page_size):
+def test_step_xla_bitwise_dense_pallas_greedy(page_size, window):
+    """The whole step on the coalesced layout ([R, P] shared prefix tables).
+    Under the window, 6: it starts inside one request's prompt and has left
+    the other's, whose rows then see generated tokens alone."""
+    config = CONFIG.with_(sliding_window=window)
     params = _params()
     tokens, lengths, plens, dense, paged = _step_case(page_size)
 
     logits_d, cache_d = verify_step(
-        CONFIG, params, tokens, lengths, plens,
+        config, params, tokens, lengths, plens,
         dense["gen_cache"], dense["prefix"],
     )
     logits_x, k_cols, v_cols = paged_verify_step(
-        CONFIG, params, tokens, lengths, plens,
+        config, params, tokens, lengths, plens,
         paged["pool_kv"], paged["prefix_idx"], paged["gen_idx"],
         attn_impl="xla", page_size=page_size,
     )
@@ -576,7 +635,7 @@ def test_step_xla_bitwise_dense_pallas_greedy(page_size):
         )
 
     logits_p, _, _ = paged_verify_step(
-        CONFIG, params, tokens, lengths, plens,
+        config, params, tokens, lengths, plens,
         paged["pool_kv"], paged["prefix_idx"], paged["gen_idx"],
         attn_impl="pallas_interpret", page_size=page_size,
     )
@@ -696,19 +755,57 @@ def test_resolve_cpu_posture_counts_only_unsatisfied_pallas():
 
 def test_resolve_names_the_unsupported_feature_in_the_fallback_key():
     """Config-driven fallbacks are distinguishable from platform ones on
-    /metrics: softcap and sliding-window models record their own reason
-    suffix, and the config reason wins over the platform reason."""
+    /metrics: softcap models and those that window some layers and not others
+    record their own reason suffix, and the config reason wins over the
+    platform reason. A window on every layer is the kernel's to serve: on a
+    CPU an explicit "pallas" for such a model lacks the platform alone."""
     import dataclasses
 
     before = _snap()
     softcap = dataclasses.replace(CONFIG, attn_softcap=30.0)
     assert resolve_paged_attention_impl("pallas", config=softcap) == "xla"
-    sliding = dataclasses.replace(CONFIG, sliding_window=128)
-    assert resolve_paged_attention_impl("pallas", config=sliding) == "xla"
+    mixed = dataclasses.replace(
+        CONFIG, sliding_window=128, sliding_window_layers="alternating"
+    )
+    assert resolve_paged_attention_impl("pallas", config=mixed) == "xla"
     after = _snap()
     assert _delta(before, after, "kernel.paged_attn_fallback.softcap") == 1
     assert _delta(before, after, "kernel.paged_attn_fallback.sliding_window") == 1
     assert _delta(before, after, "kernel.paged_attn_fallback.platform") == 0
+
+    sliding = dataclasses.replace(CONFIG, sliding_window=128)
+    assert sliding.sliding_window_layers == "all"
+    assert resolve_paged_attention_impl("pallas", config=sliding) == "xla"
+    windowed = _snap()
+    assert _delta(after, windowed, "kernel.paged_attn_fallback.sliding_window") == 0
+    assert _delta(after, windowed, "kernel.paged_attn_fallback.platform") == 1
+
+
+@pytest.mark.parametrize(
+    "overrides,impl",
+    [
+        (dict(), "pallas"),
+        (dict(sliding_window=4096), "pallas"),
+        (dict(sliding_window=4096, sliding_window_layers="alternating"), "xla"),
+        (dict(attn_softcap=30.0), "xla"),
+    ],
+)
+def test_resolve_on_a_tpu_takes_the_kernel_for_a_window_on_every_layer(
+    overrides, impl, monkeypatch
+):
+    """What "auto" picks where the platform is a TPU: the kernel for a model
+    without a window and for one whose every layer has it (``mistral-7b``),
+    the counted XLA fallback for a per-layer mix and for softcap."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    before = _snap()
+    config = CONFIG.with_(**overrides)
+    assert resolve_paged_attention_impl("auto", config=config) == impl
+    fallbacks = {k: v for k, v in _snap().items() if "fallback" in k and v != before.get(k, 0)}
+    if impl == "pallas":
+        assert not fallbacks
+    else:
+        reason = "softcap" if "attn_softcap" in overrides else "sliding_window"
+        assert list(fallbacks) == [f"kernel.paged_attn_fallback.{reason}"]
 
 
 def test_ops_paged_attn_failpoint_forces_counted_fallback():

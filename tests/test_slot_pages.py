@@ -217,7 +217,8 @@ def test_the_default_pool_fits_the_widest_request_and_nothing_wider():
     assert SlotPages(16, W, P, G, pool_pages=books.need(P, W, G) + 1).fits(P, W, G)
 
 
-def test_walk_counts_are_live_pages_of_the_same_lengths():
+@pytest.mark.parametrize("window", [None, 64, 16, 4])
+def test_walk_counts_are_live_pages_of_the_same_lengths(window):
     ps = 8
     books, _, alloc = _books(ps)
     _admit(books, alloc, [0, 1], 21, 12)
@@ -227,12 +228,20 @@ def test_walk_counts_are_live_pages_of_the_same_lengths():
     glens = np.array([5, 0, 7, 11], np.int32)
     for g in range(int(glens.max()) + 1):  # the rows' writes so far, in order
         books.prepare_step(active, plens, np.minimum(g, glens))
-    walked, tabled = books.walk_counts(active, plens, glens)
+    walked, tabled, windowed_out = books.walk_counts(active, plens, glens, window=window)
     phase = np.array([21 % ps, 21 % ps, 0, 40 % ps])
-    n_prefix, n_gen = live_pages(
-        np.where(active, plens, 0), np.where(active, glens, 0), phase, ps
+    (p0, n_prefix), (g0, n_gen) = live_pages(
+        np.where(active, plens, 0), np.where(active, glens, 0), phase, ps, window
     )
-    assert walked == int(n_prefix.sum() + n_gen.sum()) == (3 + 2) + (3 + 0) + 0 + (5 + 2)
+    held = (3 + 2) + (3 + 0) + 0 + (5 + 2)  # pages with a position in the pool
+    assert int(n_prefix.sum() + n_gen.sum()) == held
+    assert windowed_out == int(np.sum(p0) + np.sum(g0))
+    assert walked == held - windowed_out
+    # Queries at 26, 21 and 51: W = 16 sees from 11, 6 and 36; W = 4 from 23
+    # (past the prompt's 21: all 3 prompt pages out; gen 2 is on gen page 0),
+    # 18 (prompt page 2) and 48 (past the prompt's 40: 5 pages; gen 8 with
+    # 40 % 8 = 0 is on gen page 1).
+    assert windowed_out == {None: 0, 64: 0, 16: 1 + 0 + 4, 4: 3 + 2 + (5 + 1)}[window]
     assert tabled == W * sum(table_pages(P, G, ps))
 
 
