@@ -36,6 +36,19 @@ Design:
   DRAINING/STOPPED lifecycle gates admission via
   ``EngineScheduler.admission_error``.
 
+Drafting. For a model with a next-token module (``ModelConfig.
+num_nextn_predict_layers``: the preset decides, no option here) the paged
+loop's step is the DRAFTED step (``_build_drafted_step``): a row carries
+``cur`` at position P and the module's draft ``d`` for P+1, the stack verifies
+both positions in one pass, ``t1`` and ``t2`` are sampled with the keys and
+masks the one-token loop would use at those positions, and the row emits
+``t1``, and ``t2`` too iff ``t1 == d`` (sample-and-match: every emitted token
+is the draw the undrafted loop would have made). The module then runs on
+``(h_P, t1)`` and ``(h_{P+1}, t2)`` and its masked argmax at the last emitted
+position is the next draft. A rejected draft's cache rows are overwritten by
+the next step before anything reads them; admission leaves each row its first
+token and its first draft (``_admit_drafts``).
+
 Requests that need top_logprobs, penalties, or logit_bias stay on the
 coalescing path (TpuBackend routes; see ``_generate_batched``) — those
 features key the compiled program, which would fragment the shared loop.
@@ -65,7 +78,14 @@ import numpy as np
 from concurrent.futures import Future
 
 from ..analysis.lockcheck import make_condition, note_device_dispatch, race_exempt
-from ..models.llama import KVCache, init_cache, init_state, paged_verify_step, verify_step
+from ..models.llama import (
+    KVCache,
+    init_cache,
+    init_state,
+    paged_draft_step,
+    paged_verify_step,
+    verify_step,
+)
 from ..ops.paged_attention import note_paged_attn_dispatch
 from ..ops.sampling import nucleus_threshold
 from ..reliability import failpoints as _failpoints
@@ -83,6 +103,7 @@ from ..utils.observability import (
     LATENCY,
     PAGED_ATTN_PAGES,
     RECOVERY_EVENTS,
+    SPEC_COUNTERS,
     current_trace,
     note_model_aux,
 )
@@ -105,6 +126,32 @@ _install_rows = jax.jit(
     ),
     donate_argnums=(0,),
 )
+
+
+@jax.named_scope("kv_write")
+def write_drafted_rows(pool_k, stack_cols, module_cols, write_idx):
+    """A drafted step's (or, with one position, an admission draft's) cache
+    rows into the pool ``[L + 1, flat, 1, width]`` in ONE scatter over its
+    rows ``[(L + 1) * flat, 1, width]``, each cache row addressed by layer and
+    slot: the stack's L layers write the first ``S`` of ``write_idx``'s
+    positions (``stack_cols`` [L, W, S, 1, width]; None: no position), the
+    module's layer the last ``S`` (``module_cols`` [W, S, 1, width]).
+    ``write_idx`` [W, n]: P, P+1, P+2 for a step (S = 2), L alone for an
+    admission (S = 1). On the chip the pool's flat axis is its minor-most, so
+    the step's latent gather already reads a relaid-out copy of the pool; this
+    scatter goes into that same copy, where one per layer axis would lay the
+    pool out a third way (8.3 against 5.6 ms a call; my chip run, PR 34)."""
+    flat, row = pool_k.shape[1], pool_k.shape[2:]
+    L, S = pool_k.shape[0] - 1, module_cols.shape[1]
+    idx = [(L * flat + write_idx[:, write_idx.shape[1] - S:]).reshape(-1)]
+    cols = [module_cols.reshape(-1, *row)]
+    if stack_cols is not None:
+        layer_base = jnp.arange(L, dtype=write_idx.dtype)[:, None, None] * flat
+        idx.insert(0, (layer_base + write_idx[None, :, :S]).reshape(-1))
+        cols.insert(0, stack_cols.reshape(-1, *row))
+    rows = pool_k.reshape(-1, *row).at[jnp.concatenate(idx)].set(
+        jnp.concatenate(cols).astype(pool_k.dtype))
+    return rows.reshape(pool_k.shape)
 
 
 @dataclass
@@ -484,6 +531,11 @@ class ContinuousDecodeLoop:
         self._temps = np.ones((self.width,), np.float32)
         self._top_ps = np.ones((self.width,), np.float32)
         self._active_mask = np.zeros((self.width,), bool)
+        # The drafted loop (see the module docstring): each row's draft for the
+        # position after ``_cur``'s, and its request's token limit.
+        self._drafting = bool(getattr(engine.config, "num_nextn_predict_layers", 0))
+        self._draft = np.full((self.width,), engine.config.pad_token_id, np.int32)
+        self._max_news = np.zeros((self.width,), np.int32)
         # Grammar-constrained rows: per-slot automaton state + flag mirrors,
         # the resident CompiledGrammar (one schema's tables live on device at
         # a time; same-digest requests share them, different-digest requests
@@ -504,6 +556,7 @@ class ContinuousDecodeLoop:
         # kllms: unguarded — epoch-fenced handoff to the step dispatch thread
         self._step_fn = None
         self._admit_sample_fn = None
+        self._admit_draft_fn = None
         self._built = False
         # The loop follows the engine's KV layout, and this is where the two
         # part. PAGED: the engine's page pool holds the KV and ``_pages``
@@ -532,6 +585,9 @@ class ContinuousDecodeLoop:
                     pool.allocator.total_pages if pool is not None
                     else engine.kv_pool_pages
                 ),
+                # A drafted step writes its draft's row and the module's one
+                # position further.
+                lookahead=2 if self._drafting else 0,
             )
         # Stats (reported via backend health() and the bench workload).
         self._stats: Dict[str, Any] = {
@@ -777,6 +833,8 @@ class ContinuousDecodeLoop:
         self._stats["state_bytes"] = sum(int(a.nbytes) for a in jax.tree.leaves(self._state))
         self._step_fn = self._build_step(grammar=False)
         self._admit_sample_fn = self._build_first_token(grammar=False)
+        if self._drafting:
+            self._admit_draft_fn = self._build_admit_draft(grammar=False)
         self._built = True
 
     def _sampler(self) -> tuple:
@@ -867,6 +925,8 @@ class ContinuousDecodeLoop:
         ``(tok, lp, bad, *new_kv[, g_states], aux, state)``. ``state`` is the
         rows' recurrent state, by keyword (an empty dict, so no operand, for
         most models)."""
+        if self._drafting:
+            return self._build_drafted_step(grammar)
         config = self.engine.config
         mesh = getattr(self.engine, "mesh", None)
         paged = self.paged
@@ -948,6 +1008,140 @@ class ContinuousDecodeLoop:
             _body, donate_argnums=(1, 2) if paged else (2,), donate_argnames=("state",)
         )
 
+    def _build_drafted_step(self, grammar: bool):
+        """The decode step of a model with a next-token module (paged layout
+        only): ``_step_paged_mtp`` / ``_step_paged_mtp_g``. Arguments as
+        :meth:`_build_step`'s with two more row arrays behind ``top_ps``,
+        ``draft`` [W] and ``room`` [W] (the row's ``max_tokens`` leaves room
+        for two), and ``write_idx`` [W, 3]: positions P, P+1, P+2. Results
+        ``(toks [W, 2], lps [W, 2], bad, pool_k, pool_v[, g_states], emitted
+        [W], next draft [W], aux, state)``."""
+        config = self.engine.config
+        mesh = getattr(self.engine, "mesh", None)
+        pad_id = config.pad_token_id
+        row_keys, sample_rows, mask_pad = self._sampler()
+        if grammar:
+            apply_mask, advance = self._grammar_ops()
+        attn_impl, page_size = self._paged_attn_impl, self._pool.page_size
+        eos_arr = jnp.asarray(self.eos_ids, jnp.int32)
+
+        def _body(params, pool_k, pool_v, cur, gen_lens, prompt_lens, active,
+                  seeds, sample_idx, temps, top_ps, draft, room,
+                  prefix_idx, gen_idx, write_idx, poison, *g_args, state=None):
+            aux: Dict[str, Any] = {}
+            state = dict(state or {})
+            lens = jnp.where(active, gen_lens, 0)
+            plens = jnp.where(active, prompt_lens, 0)
+            pool = KVCache(k=pool_k, v=pool_v)
+            # The stack over both positions: the draft's row attends cur's
+            # fresh latent beside the pages.
+            logits, k_cols, _, hidden = paged_verify_step(
+                config, params, jnp.stack([cur, draft], axis=1), lens, plens,
+                pool, prefix_idx, gen_idx, attn_impl=attn_impl, page_size=page_size,
+                mesh=mesh, aux=aux, state=state, active=active, return_hidden=True,
+            )
+            logits = jnp.where(poison[:, None, None], jnp.float32(jnp.nan), logits)
+            after_cur, after_draft = mask_pad(logits[:, 0]), mask_pad(logits[:, 1])
+            if grammar:
+                g_states, g_flags, *tabs = g_args
+                drafted = advance(draft, g_states, g_flags, tabs)
+                after_cur = apply_mask(after_cur, g_states, g_flags, tabs)
+                after_draft = apply_mask(after_draft, drafted, g_flags, tabs)
+            # The keys of gen_len + 1 and + 2: the one-token loop's draws.
+            t1, lp1, bad1 = sample_rows(
+                after_cur, row_keys(seeds, gen_lens + 1, sample_idx), temps, top_ps)
+            t2, lp2, bad2 = sample_rows(
+                after_draft, row_keys(seeds, gen_lens + 2, sample_idx), temps, top_ps)
+            with jax.named_scope("spec_accept"):
+                ended = jnp.any(t1[:, None] == eos_arr[None, :], axis=-1)
+                # A poisoned second position is not emitted: the next step
+                # computes it again as its first and quarantines the row there.
+                accept = active & (t1 == draft) & ~ended & room & ~bad1 & ~bad2
+            toks = jnp.stack([t1, t2], axis=1)
+            # The module on (h_P, t1) and (h_{P+1}, t2); its rows land one
+            # position on, and the second pair counts only where t2 was emitted.
+            mlogits, m_cols = paged_draft_step(
+                config, params, hidden, toks, lens + 1, plens, pool, prefix_idx, gen_idx,
+                aux=aux,
+            )
+            mlogits = mask_pad(jnp.where(accept[:, None], mlogits[:, 1], mlogits[:, 0]))
+            out_g = ()
+            if grammar:
+                g_next = jnp.where(
+                    accept, advance(t2, drafted, g_flags, tabs),
+                    advance(t1, g_states, g_flags, tabs))
+                mlogits = apply_mask(mlogits, g_next, g_flags, tabs)
+                out_g = (g_next,)
+            next_draft = jnp.argmax(mlogits, axis=-1).astype(jnp.int32)
+            pool_k = write_drafted_rows(pool_k, k_cols, m_cols, write_idx)
+            toks = jnp.where(active[:, None], toks, jnp.int32(pad_id))
+            lps = jnp.where(active[:, None], jnp.stack([lp1, lp2], axis=1), 0.0)
+            emitted = jnp.where(active, 1 + accept.astype(jnp.int32), 0)
+            return (toks, lps, bad1 & active, pool_k, pool_v) + out_g + (
+                emitted, jnp.where(active, next_draft, jnp.int32(pad_id)), aux, state)
+
+        _body.__name__ = "_step_paged_mtp" + ("_g" if grammar else "")
+        return jax.jit(_body, donate_argnums=(1, 2), donate_argnames=("state",))
+
+    def _build_admit_draft(self, grammar: bool):
+        """Admission's second program for a drafting model, ``_admit_draft``
+        / ``_admit_draft_g``: the module on ``(h_{L-1}, first token)`` for the
+        rows just installed (every other row idles), its cache row written at
+        position L of each, and the row's first draft: the module's argmax,
+        pad-masked and, under a grammar, masked by the state after the first
+        token."""
+        config = self.engine.config
+        pad_id = config.pad_token_id
+        _, _, mask_pad = self._sampler()
+        if grammar:
+            apply_mask, _ = self._grammar_ops()
+
+        def _body(params, pool_k, pool_v, h_last, tok0, prompt_lens, rows,
+                  prefix_idx, gen_idx, write_idx, *g_args):
+            mlogits, m_cols = paged_draft_step(
+                config, params, h_last[:, None], tok0[:, None], jnp.zeros_like(prompt_lens),
+                jnp.where(rows, prompt_lens, 0), KVCache(k=pool_k, v=pool_v),
+                prefix_idx, gen_idx,
+            )
+            mlogits = mask_pad(mlogits[:, 0])
+            if grammar:
+                g_states, g_flags, *tabs = g_args
+                mlogits = apply_mask(mlogits, g_states, g_flags & rows, tabs)
+            draft = jnp.where(rows, jnp.argmax(mlogits, axis=-1).astype(jnp.int32),
+                              jnp.int32(pad_id))
+            return draft, write_drafted_rows(pool_k, None, m_cols, write_idx[:, None]), pool_v
+
+        _body.__name__ = "_admit_draft" + ("_g" if grammar else "")
+        return jax.jit(_body, donate_argnums=(1, 2))
+
+    def _admit_drafts(self, req, rows: List[int]) -> None:
+        """Leave each newly installed row its first draft (lock held, worker
+        thread). The rows' positions L .. L+2 are made private first: the
+        module's row for ``(h_{L-1}, first token)`` differs by sample, and it
+        lies at L, on the row's own page."""
+        W = self.width
+        mask = np.zeros((W,), bool)
+        mask[rows] = True
+        prefix_idx, gen_idx, write_idx = self._pages.prepare_step(
+            mask, self._prompt_lens, self._gen_lens)
+        fn, grammar_args = self._admit_draft_fn, ()
+        if req.grammar is not None:
+            fn = self._grammar_programs()["draft"]
+            grammar_args = (
+                jnp.asarray(self._g_states), jnp.asarray(self._g_flags), *self._g_tabs())
+        pool = self._pool
+        with pool.lock:
+            note_device_dispatch("continuous admission draft")
+            draft, new_k, new_v = fn(
+                self.engine.params, pool.kv.k, pool.kv.v, self._state["mtp_h"][0],
+                jnp.asarray(self._cur), jnp.asarray(self._prompt_lens), jnp.asarray(mask),
+                jnp.asarray(prefix_idx), jnp.asarray(gen_idx), jnp.asarray(write_idx[:, 0]),
+                *grammar_args,
+            )
+            pool.kv = KVCache(k=new_k, v=new_v)
+        # kllms: ignore[host-sync-hot-path] — admission's readback of the first drafts, beside the first tokens'
+        self._draft[rows] = np.asarray(jax.device_get(draft))[rows]
+
     def _build_first_token(self, grammar: bool):
         """The first token, sampled at admission from the prefill logits at
         step 0 — padded to W rows so every admission shares one program.
@@ -1019,6 +1213,8 @@ class ContinuousDecodeLoop:
             "admit": self._build_first_token(grammar=True),
             "step": self._build_step(grammar=True),
         }
+        if self._drafting:
+            fns["draft"] = self._build_admit_draft(grammar=True)
         self._g_programs = (shape_key, fns)
         return fns
 
@@ -1292,11 +1488,14 @@ class ContinuousDecodeLoop:
         self._top_ps[:] = 1.0
         self._g_states[:] = 0
         self._g_flags[:] = False
+        self._draft[:] = pad
+        self._max_news[:] = 0
         self._grammar = None
         self._dgrammar = None
         self._g_programs = None
         self._step_fn = None
         self._admit_sample_fn = None
+        self._admit_draft_fn = None
         self._dense = None
         self._pool = None
         self._state = {}
@@ -1415,9 +1614,9 @@ class ContinuousDecodeLoop:
                 else:
                     self._admit_device(req, rows)
                     if req.trace is not None:
-                        req.trace.add_phase(
-                            "prefill", time.perf_counter() - _admit_t0
-                        )
+                        # To the rows' installation, where prefill_wall ends
+                        # too: delivery and resolution after it are not prefill.
+                        req.trace.add_phase("prefill", req.installed_at - _admit_t0)
             except PagePoolExhausted as e:
                 # Pages are a transient resource: in-flight rows free theirs
                 # as they retire, so park the head request and retry after the
@@ -1562,6 +1761,7 @@ class ContinuousDecodeLoop:
             self._top_ps[slot] = top_p
             self._g_flags[slot] = req.grammar is not None
             self._g_states[slot] = st0[j]
+            self._max_news[slot] = req.max_new
             req.tokens.append([int(tok0[j])])
             req.logprobs.append([float(lp0[j])])
             req.sample_errors.append(None)
@@ -1578,6 +1778,8 @@ class ContinuousDecodeLoop:
             note = getattr(self.engine, "_note_quarantine", None)
             if note is not None:
                 note(quarantined, n)
+        if self._drafting:
+            self._admit_drafts(req, rows)
         # The rows are installed: the request's prefill_wall (everything since
         # its dequeue, other requests' steps and chunks included) ends here
         # and its decode_wall begins.
@@ -1815,6 +2017,10 @@ class ContinuousDecodeLoop:
                     self._active_mask, self._seeds, self._sample_idx,
                     self._temps, self._top_ps,
                 )))
+                if self._drafting:
+                    # len(tokens) is gen_lens + 1: room for two more.
+                    room = self._gen_lens + 3 <= self._max_news
+                    row_args += (jnp.asarray(self._draft), jnp.asarray(room))
                 live_rows = np.flatnonzero(self._active_mask)
                 # Grammar twins run only when a constrained row is live: steps
                 # with no grammar work dispatch the ORIGINAL programs, so the
@@ -1919,13 +2125,30 @@ class ContinuousDecodeLoop:
         step_s = step_span.seconds
         with LATENCY.span("continuous.bookkeep"):
             tok_np, lp_np, bad_np = fetched[0], fetched[1], fetched[2]
+            if self._drafting:
+                # One or two tokens a row: [W, 2] tokens and logprobs, the
+                # emitted counts, and each row's next draft.
+                emitted_np, draft_np = fetched[-2], fetched[-1]
+            else:
+                tok_np, lp_np = tok_np[:, None], lp_np[:, None]
+                emitted_np = draft_np = None
             quarantined = 0
             with self._lock:
                 if n_masked:
                     # .copy(): device_get may hand back a read-only view, and the
                     # mirror is written per-slot at admission/retirement.
                     self._g_states = fetched[3].copy()
-                    GRAMMAR_EVENTS.record("grammar.masked_steps", n_masked)
+                    # A drafted step masks each token it emits.
+                    GRAMMAR_EVENTS.record(
+                        "grammar.masked_steps",
+                        n_masked if emitted_np is None
+                        else int(emitted_np[self._g_flags & self._active_mask].sum()),
+                    )
+                if emitted_np is not None:
+                    live = int(self._active_mask.sum())
+                    SPEC_COUNTERS.record("spec_drafts_verified", live)
+                    SPEC_COUNTERS.record("spec_drafts_accepted", int((emitted_np == 2).sum()))
+                    SPEC_COUNTERS.record("spec_tokens_emitted", int(emitted_np.sum()))
                 self._stats["steps"] += 1
                 self._stats["row_steps"] += int(self._active_mask.sum())
                 self._stats["max_active_rows"] = max(
@@ -1942,7 +2165,9 @@ class ContinuousDecodeLoop:
                     j = req.slots.index(slot)
                     if req.done[j]:
                         continue
-                    self._gen_lens[slot] += 1  # cur's KV is now written
+                    took = 1 if emitted_np is None else int(emitted_np[slot])
+                    # cur's KV is now written, and an accepted draft's.
+                    self._gen_lens[slot] += took
                     if bad_np[slot]:
                         # Numeric poison: freeze + retire this row only; its
                         # garbage token never reaches the accumulators or sinks.
@@ -1950,16 +2175,24 @@ class ContinuousDecodeLoop:
                         quarantined += 1
                         touched.add(id(req))
                         continue
-                    t = int(tok_np[slot])
-                    self._cur[slot] = t
-                    req.tokens[j].append(t)
-                    req.logprobs[j].append(float(lp_np[slot]))
-                    if t in self.eos_ids:
-                        req.done[j] = True
-                        req.finish[j] = "stop"
-                    elif len(req.tokens[j]) >= req.max_new:
-                        req.done[j] = True
-                        req.finish[j] = "length"
+                    if draft_np is not None:
+                        self._draft[slot] = draft_np[slot]
+                        if req.trace is not None:
+                            req.trace.bump("drafts_verified")
+                            req.trace.bump("drafts_accepted", took - 1)
+                    for k in range(took):
+                        # The device emits a second token only where the first
+                        # neither ends the row nor fills it.
+                        t = int(tok_np[slot, k])
+                        self._cur[slot] = t
+                        req.tokens[j].append(t)
+                        req.logprobs[j].append(float(lp_np[slot, k]))
+                        if t in self.eos_ids:
+                            req.done[j] = True
+                            req.finish[j] = "stop"
+                        elif len(req.tokens[j]) >= req.max_new:
+                            req.done[j] = True
+                            req.finish[j] = "length"
                     touched.add(id(req))
                 for rid in touched:
                     req = next(
@@ -1970,7 +2203,7 @@ class ContinuousDecodeLoop:
                     if req.budget is not None and req.budget.should_abort():
                         self._abort_request(req)
                         continue
-                    self._deliver_sink(req)
+                    self._deliver_ready(req)
                     self._retire_finished_rows(req)
                     self._resolve_if_done(req)
                 self._lock.notify_all()
@@ -1983,6 +2216,17 @@ class ContinuousDecodeLoop:
 
     # -- retirement --------------------------------------------------------
 
+    def _deliver_ready(self, req: _SlotRequest) -> None:
+        """After a step: deliver every token index that each of the request's
+        unfinished rows has reached (one a step in the one-token loop, where
+        its rows march in lockstep; a drafted step moves a row by one or two)."""
+        if req.token_sink is None:
+            return
+        open_rows = [len(s) for s, done in zip(req.tokens, req.done) if not done]
+        ready = min(open_rows) if open_rows else max(len(s) for s in req.tokens)
+        while req.token_sink is not None and req.steps_delivered < ready:
+            self._deliver_sink(req)
+
     def _deliver_sink(self, req: _SlotRequest) -> None:
         if req.token_sink is None:
             return
@@ -1994,9 +2238,9 @@ class ContinuousDecodeLoop:
         # them — the SSE consumer sees one contiguous stream.
         if step < req.delivered_watermark:
             return
-        # Every live sample has produced its step-th token by construction
-        # (rows of one request march in lockstep until they finish; finished
-        # rows report pad thereafter, which the sink's detokenizer skips).
+        # Every live sample has produced its step-th token (the caller's
+        # business: :meth:`_deliver_ready`); finished rows report pad
+        # thereafter, which the sink's detokenizer skips.
         pad = self.engine.config.pad_token_id
         with LATENCY.span("continuous.emit"):
             row = np.array(
@@ -2020,6 +2264,7 @@ class ContinuousDecodeLoop:
                 self._active[slot] = None
                 self._g_flags[slot] = False
                 self._g_states[slot] = 0
+                self._draft[slot] = self.engine.config.pad_token_id
                 if self._pages is not None:
                     self._pages.release(slot)
                 self._free.append(slot)
@@ -2066,6 +2311,12 @@ class ContinuousDecodeLoop:
         LATENCY.observe("continuous.decode_wall", decode_wall_s)
         if req.trace is not None:
             req.trace.add_phase("decode_wall", decode_wall_s)
+            notes = req.trace.annotations_snapshot()
+            if notes.get("drafts_verified"):
+                req.trace.annotate(
+                    "draft_accepted_share",
+                    notes.get("drafts_accepted", 0) / notes["drafts_verified"],
+                )
         if not req.future.done():
             req.future.set_result(result)
 

@@ -314,6 +314,10 @@ class LocalEngine:
             # not written (a snapshot at page boundaries; a decode loop other
             # than the paged continuous one that carries it).
             hybrid = self.config.is_hybrid
+            # A next-token module drafts in the paged continuous loop alone:
+            # its cache layer is a paging layer and its first draft needs the
+            # prompt's last hidden state, which a cached prefix does not hold.
+            drafts = bool(self.config.num_nextn_predict_layers)
             refused = [
                 what for what, asked in (
                     (f"a device mesh ({len(jax.devices())} devices; build the "
@@ -328,6 +332,12 @@ class LocalEngine:
                      "state at its page boundary)", hybrid and prefix_cache_size > 0),
                     (f"kv_layout={kv_layout!r} (only the paged continuous loop carries the "
                      "state; build with kv_layout='paged')", hybrid and kv_layout != "paged"),
+                    (f"prefix_cache_size={prefix_cache_size} (the next-token module's first "
+                     "draft needs the prompt's last hidden state, which a cached prefix does "
+                     "not hold)", drafts and prefix_cache_size > 0),
+                    (f"kv_layout={kv_layout!r} (the next-token module drafts in the paged "
+                     "continuous loop alone; build with kv_layout='paged')",
+                     drafts and kv_layout != "paged"),
                 ) if asked
             ]
             if refused:

@@ -443,7 +443,10 @@ class SlotPages:
       front, so a step in flight can never fail on allocation. All of it is
       taken or none (:meth:`admit`, :meth:`reserve_chunked`).
     - :meth:`prepare_step` grows a table at a page boundary and copies a
-      shared page before a row's first write into it, out of the reserve.
+      shared page before a row's first write into it, out of the reserve. A
+      drafted step writes ``lookahead`` positions past the row's next one (its
+      draft's row, and the next-token module's one further), so all of them
+      are made private, and every reserve is reckoned with them.
     - :meth:`release` drops every reference a retired slot holds.
 
     Allocation goes through the caller's ``alloc`` (the engine's evicting
@@ -454,9 +457,10 @@ class SlotPages:
     """
 
     def __init__(self, page_size: int, width: int, max_prompt: int, max_new: int,
-                 pool_pages: Optional[int] = None) -> None:
+                 pool_pages: Optional[int] = None, lookahead: int = 0) -> None:
         self.page_size = int(page_size)
         self.width, self.max_prompt, self.max_new = int(width), int(max_prompt), int(max_new)
+        self.lookahead = int(lookahead)
         #: Pages the pool has, or will be built with: what :meth:`fits` holds
         #: a request's peak demand against before a pool exists.
         self.planned_pages = int(pool_pages or self.default_pool_pages())
@@ -468,7 +472,7 @@ class SlotPages:
         # step program takes today (the kernel turns them back into page
         # tables on the device).
         self.prefix_idx = np.zeros((self.width, self.max_prompt), np.int32)
-        self.gen_idx = np.zeros((self.width, self.max_new), np.int32)
+        self.gen_idx = np.zeros((self.width, self.max_new + self.lookahead), np.int32)
 
     # -- sizing ------------------------------------------------------------
 
@@ -478,14 +482,18 @@ class SlotPages:
         case), plus one reserve page per slot for CoW, a couple of prompt-size
         runs of prefix-cache slack, and the trash page."""
         ps = self.page_size
-        per_slot = pages_for(self.max_prompt + self.max_new, ps) + 1
+        per_slot = pages_for(self.max_prompt + self.max_new + self.lookahead, ps) + 1
         return self.width * per_slot + 2 * pages_for(self.max_prompt, ps) + 1
 
     def need(self, plen: int, n: int, max_new: int) -> int:
         """Peak page demand of one request alone, which is what admission
         takes: one shared prompt run plus n private generation reserves."""
-        reserve = row_reserve_pages(plen, max_new, self.page_size)
-        return pages_for(plen, self.page_size) + max(1, n) * reserve
+        return pages_for(plen, self.page_size) + max(1, n) * self._row_reserve(plen, max_new)
+
+    def _row_reserve(self, plen: int, max_new: int) -> int:
+        """:func:`row_reserve_pages` with the positions a drafted step writes
+        ahead: a row's last step still finds its pages reserved."""
+        return row_reserve_pages(plen, max_new + self.lookahead, self.page_size)
 
     def fits(self, plen: int, n: int, max_new: int) -> bool:
         """Can the pool hold this request even with the prefix cache fully
@@ -544,7 +552,7 @@ class SlotPages:
         reference each, and each gets its reserve. Raises
         :class:`PagePoolExhausted` with everything rolled back if the
         reserves don't fit."""
-        reserve = row_reserve_pages(plen, max_new, self.page_size)
+        reserve = self._row_reserve(plen, max_new)
         reserved = self._fan_out(run_pages, len(rows), len(rows), reserve, alloc)
         self.install(rows, run_pages, reserved, plen)
 
@@ -557,7 +565,7 @@ class SlotPages:
         are the caller's to :meth:`install` or :meth:`drop`."""
         run_pages = alloc(pages_for(plen, self.page_size))
         try:
-            reserve = row_reserve_pages(plen, max_new, self.page_size)
+            reserve = self._row_reserve(plen, max_new)
             return run_pages, self._fan_out(run_pages, n_rows - 1, n_rows, reserve, alloc)
         except BaseException:
             self.pool.allocator.decref(run_pages)
@@ -611,7 +619,7 @@ class SlotPages:
         index could keep gathering a page that was freed and reused."""
         ps = self.page_size
         table = self._tables[slot]
-        P, G = self.max_prompt, self.max_new
+        P, G = self.max_prompt, self.gen_idx.shape[1]
         pidx = flat_slots(table, np.arange(P), ps)
         # Positions at/after the prompt end read through gen_idx instead;
         # point them into the trash page (masked, but must stay in bounds).
@@ -621,18 +629,20 @@ class SlotPages:
 
     def prepare_step(self, active: np.ndarray, prompt_lens: np.ndarray,
                      gen_lens: np.ndarray) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """Resolve each row's write slot for the upcoming step, performing
-        page-table maintenance on the way: append a reserved page when the
-        write crosses a page boundary, copy-on-write when the target page is
+        """Resolve each row's write slots for the upcoming step, performing
+        page-table maintenance on the way: append a reserved page when a
+        write crosses a page boundary, copy-on-write when a target page is
         still shared with other readers. Returns the step's index arguments
-        ``(prefix_idx [W, P], gen_idx [W, G], write_idx [W])`` (inactive rows
-        write into the trash page). Never allocates — admission reserved
+        ``(prefix_idx [W, P], gen_idx [W, G], write_idx)`` (inactive rows
+        write into the trash page); ``write_idx`` is ``[W]``, the row's next
+        position, or with a ``lookahead`` ``[W, 1 + lookahead]``, that position
+        and the ones after it. Never allocates — admission reserved
         every page this can pop."""
         pool = self.pool
         ps = self.page_size
         allocator = pool.allocator
-        W = self.width
-        write_idx = np.empty((W,), np.int32)
+        W, ahead = self.width, self.lookahead
+        write_idx = np.empty((W, 1 + ahead), np.int32)
         cow_src: List[int] = []
         cow_dst: List[int] = []
         for slot in range(W):
@@ -641,21 +651,21 @@ class SlotPages:
                 continue
             plen = int(prompt_lens[slot])
             pos = plen + int(gen_lens[slot])
-            page_i = pos // ps
             table = self._tables[slot]
-            if page_i == len(table):
-                table.append(self._reserved[slot].pop())
-                self._refresh(slot, plen)
-            elif allocator.refcount(table[page_i]) > 1:
-                # First divergent write into the shared partial prompt page:
-                # give this row a private copy, then retarget its table.
-                new_page = self._reserved[slot].pop()
-                cow_src.append(table[page_i])
-                cow_dst.append(new_page)
-                table[page_i] = new_page
-                allocator.note_cow()
-                self._refresh(slot, plen)
-            write_idx[slot] = table[page_i] * ps + pos % ps
+            for page_i in range(pos // ps, (pos + ahead) // ps + 1):
+                if page_i == len(table):
+                    table.append(self._reserved[slot].pop())
+                    self._refresh(slot, plen)
+                elif allocator.refcount(table[page_i]) > 1:
+                    # First divergent write into the shared partial prompt page:
+                    # give this row a private copy, then retarget its table.
+                    new_page = self._reserved[slot].pop()
+                    cow_src.append(table[page_i])
+                    cow_dst.append(new_page)
+                    table[page_i] = new_page
+                    allocator.note_cow()
+                    self._refresh(slot, plen)
+            write_idx[slot] = [table[at // ps] * ps + at % ps for at in range(pos, pos + ahead + 1)]
         if cow_src:
             # Pad with trash->trash no-ops so every CoW batch shares one
             # compiled copy program regardless of how many rows diverged.
@@ -665,7 +675,7 @@ class SlotPages:
             # that reads it — decref only after the copy is enqueued (the
             # pool swap orders it before the next step's gathers).
             allocator.decref(cow_src)
-        return self.prefix_idx, self.gen_idx, write_idx
+        return self.prefix_idx, self.gen_idx, write_idx if ahead else write_idx[:, 0]
 
     def walk_counts(self, active: np.ndarray, prompt_lens: np.ndarray,
                     gen_lens: np.ndarray, window: Optional[int] = None

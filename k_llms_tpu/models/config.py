@@ -106,10 +106,23 @@ class ModelConfig:
     n_shared_experts: int = 0
     first_k_dense: int = 0
     routed_scaling_factor: float = 1.0
-    hc_mult: int = 1
+    hc_mult: int = 1  # 1: no streams, no mixer parameters; X <- X + f(RMSNorm(X))
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: float = 30.0
+    # The expert layer's share of a layer's experts (latent block): the router
+    # is ``num_experts`` wide and chooses among all of them; this chip holds
+    # the stacks of experts [expert_offset, expert_offset + experts_held) and
+    # computes those pairs alone (another chip holds the rest; its sum is
+    # added elsewhere). 0 held: all of them.
+    experts_held: int = 0
+    expert_offset: int = 0
+    # Next-token modules (DeepSeek-V3 section 2.2, depth 1; models/latent.py):
+    # one more expert layer with its own cache layer that reads the main
+    # stack's output at position i beside the embedding of token i+1 and
+    # predicts token i+2 through the shared head. The paged continuous loop
+    # drafts with it (engine/continuous.py); nothing else runs it.
+    num_nextn_predict_layers: int = 0
     # Hybrid stacks (Nemotron-H; models/hybrid.py): a non-empty layer_pattern
     # switches the whole stack. One character a layer, each layer ONE pre-norm
     # mixer and no MLP of its own: "M" a Mamba-2 mixer (mamba_num_heads heads
@@ -167,16 +180,29 @@ class ModelConfig:
     @property
     def paging_layers(self) -> int:
         """Layers that hold keys and values, so the leading axis of every
-        cache and of the page pool: all of them, or a hybrid stack's "*"."""
-        return self.layer_pattern.count("*") if self.is_hybrid else self.num_layers
+        cache and of the page pool: all of them (a next-token module's layer
+        behind the stack's), or a hybrid stack's "*"."""
+        if self.is_hybrid:
+            return self.layer_pattern.count("*")
+        return self.num_layers + self.num_nextn_predict_layers
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose stacks this chip holds (see ``experts_held``)."""
+        return self.experts_held or self.num_experts
 
     def state_shapes(self, rows: int) -> "Dict[str, tuple]":
         """The recurrent state ``rows`` rows hold beside their pages, as
         ``{name: (state layers, shape, dtype)}``: the float32 SSM state
         ``[rows, heads, head_dim, N]`` and the conv's last inputs ``[rows,
         taps - 1, channels]`` of each "M" layer (one array a layer: a layer's
-        update then happens in place). Empty for a model without such layers,
-        so a pytree built from it adds no operand to any program."""
+        update then happens in place); for a model with a next-token module,
+        ``mtp_h`` ``[rows, hidden]``: the main stack's output at a prompt's
+        last position, which the module pairs with the first sampled token.
+        Empty for a model with neither, so a pytree built from it adds no
+        operand to any program."""
+        if self.num_nextn_predict_layers:
+            return {"mtp_h": (1, (rows, self.hidden_size), self.jax_dtype)}
         m = self.layer_pattern.count("M")
         if not m:
             return {}
@@ -504,6 +530,72 @@ register_config(
         v_head_dim=16,
         moe_intermediate_size=32,
         first_k_dense=1,
+    )
+)
+
+# JoyAI-LLM-Flash (https://huggingface.co/jdopensource/JoyAI-LLM-Flash, model_type
+# joyai_llm_flash, "48B-A2.7B"): the latent block without streams (plain
+# pre-norm residuals, plain RoPE at theta 3.2e7): MLA, 1 dense layer, then 256
+# routed SwiGLU experts top-8 (sigmoid noaux_tc, scaling 2.5) + 1 shared, and
+# one next-token module. The published preset is for shape arithmetic.
+# ``-cut8`` is what the benchmark serves on one chip: 1 dense + 7 of the 39
+# expert layers + the module, every width and the whole vocabulary as
+# published, and of each layer's 256 experts the 128 that one of two chips
+# holds (benchmark/configs/joyai-llm-flash.json has the arithmetic, the
+# deployment and what is assumed). bfloat16, the paged continuous loop, which
+# drafts with the module by the preset alone.
+_JOYAI = ModelConfig(
+    name="joyai-llm-flash",
+    vocab_size=129280,
+    hidden_size=2048,
+    intermediate_size=7168,
+    num_layers=40,
+    num_heads=32,
+    num_kv_heads=32,
+    head_dim=192,  # unused by the latent block; the qk width, for readers
+    rope_theta=32000000.0,
+    rope_scaling=None,
+    rms_eps=1e-6,
+    max_seq_len=8192,  # served context; the config declares 131,072
+    num_experts=256,
+    num_experts_per_tok=8,
+    q_lora_rank=1536,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    moe_intermediate_size=768,
+    n_shared_experts=1,
+    first_k_dense=1,
+    routed_scaling_factor=2.5,
+    hc_mult=1,
+    num_nextn_predict_layers=1,
+)
+register_config(_JOYAI)
+register_config(_JOYAI.with_(name="joyai-llm-flash-cut8", num_layers=8, experts_held=128))
+# CPU test size of the same block: 1 dense + 2 expert layers + the module, 8
+# experts top-2 of which 4 are held, the published ratios of the head widths.
+register_config(
+    _JOYAI.with_(
+        name="joyai-tiny",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=160,
+        num_layers=3,
+        num_heads=4,
+        num_kv_heads=4,
+        head_dim=24,
+        max_seq_len=4096,
+        dtype="float32",
+        num_experts=8,
+        num_experts_per_tok=2,
+        experts_held=4,
+        q_lora_rank=48,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        moe_intermediate_size=24,
     )
 )
 

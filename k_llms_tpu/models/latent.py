@@ -23,7 +23,23 @@ when ``config.is_latent``.
   (``jax.lax.ragged_dot``): an expert no token chose is never read, and the
   stacks of all layers are read in place (no layer's slice is copied out).
 - **Streams.** The scan carries ``[B, S, hc_mult, H]``; the mixers run in
-  float32 with no MXU pass in the stream arithmetic.
+  float32 with no MXU pass in the stream arithmetic. ``hc_mult == 1`` is the
+  block without streams (JoyAI-LLM-Flash): no mixer parameters, and a
+  sublayer is the plain pre-norm residual ``X + f(RMSNorm(X))``.
+- **A share of the experts.** ``config.experts_held`` of the router's
+  ``num_experts`` have their stacks here (another chip holds the rest): the
+  router still chooses among all of them and normalises over all it chose, and
+  a pair whose expert lies elsewhere contributes nothing to this chip's sum.
+- **The next-token module** (``params["mtp"]``, ``num_nextn_predict_layers``
+  1): one more expert layer whose input at slot ``p`` is ``W_eh [RMSNorm_h(h_{p-1});
+  RMSNorm_e(Emb(t_p))]``, with ``h`` the main stack's output before
+  ``final_norm``; its own final norm, the shared head, logits for ``t_{p+1}``.
+  Its cache layer is the last paging layer and its row for the pair
+  ``(h_{p-1}, t_p)`` lies at position ``p``, the token it embeds (position 0
+  holds nothing and is masked), so rows that differ by sample lie on a
+  request row's private pages. Prompts write the module's cache rows beside the main
+  stack's (:func:`mtp_ingest`); the loop's drafted step runs the whole block
+  (:func:`mtp_paged`).
 
 What the stack counts for the loop goes into ``aux`` (a dict the caller
 passes and returns from its program): ``moe_counts`` ``[expert layers, E]``,
@@ -68,7 +84,7 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=None) -> Params:
     zero."""
     dtype = dtype or config.jax_dtype
     H, V, n = config.hidden_size, config.vocab_size, config.hc_mult
-    NH, E = config.num_heads, config.num_experts
+    NH, E, Eh = config.num_heads, config.num_experts, config.held_experts
     dn, dr, dv = config.qk_nope_head_dim, config.qk_rope_head_dim, config.v_head_dim
     rq, rkv = config.q_lora_rank, config.kv_lora_rank
     I, Im = config.intermediate_size, config.moe_intermediate_size
@@ -95,7 +111,7 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=None) -> Params:
             "wo": stack(ks[4], count, (NH * dv, H), (NH * dv) ** -0.5),
             "mlp_norm": jnp.ones((count, H), dtype),
         }
-        for i, name in enumerate(("hc_attn", "hc_mlp")):
+        for i, name in enumerate(("hc_attn", "hc_mlp") if n > 1 else ()):
             g[name + "_phi"] = stack(ks[5 + i], count, (n * H, mixer_width(n)),
                                      (n * H) ** -0.5, _F32)
             g[name + "_alpha"] = jnp.full((count, 3), 0.5, _F32)
@@ -107,9 +123,9 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=None) -> Params:
             return g
         g["w_router"] = stack(ks[10], count, (H, E), H ** -0.5)
         g["router_bias"] = jnp.zeros((count, E), _F32)
-        g["w_gate"] = stack(ks[7], count, (E, H, Im), H ** -0.5)
-        g["w_up"] = stack(ks[8], count, (E, H, Im), H ** -0.5)
-        g["w_down"] = stack(ks[9], count, (E, Im, H), Im ** -0.5)
+        g["w_gate"] = stack(ks[7], count, (Eh, H, Im), H ** -0.5)
+        g["w_up"] = stack(ks[8], count, (Eh, H, Im), H ** -0.5)
+        g["w_down"] = stack(ks[9], count, (Eh, Im, H), Im ** -0.5)
         g["ws_gate"] = stack(ks[11], count, (H, Is), H ** -0.5)
         g["ws_up"] = stack(ks[12], count, (H, Is), H ** -0.5)
         g["ws_down"] = stack(ks[13], count, (Is, H), Is ** -0.5)
@@ -118,13 +134,25 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=None) -> Params:
     k_embed, k_dense, k_moe, k_head = jax.random.split(key, 4)
     # The two vocabulary tables in eight slices, for the same reason.
     vr, hr = math.gcd(8, V), math.gcd(8, H)
-    return {
+    params = {
         "embed": stack(k_embed, vr, (V // vr, H), H ** -0.5).reshape(V, H),
         "dense_layers": group(k_dense, Ld, moe=False),
         "layers": group(k_moe, Le, moe=True),
         "final_norm": jnp.ones((H,), dtype),
         "lm_head": stack(k_head, hr, (H // hr, V), H ** -0.5).reshape(H, V),
     }
+    if config.num_nextn_predict_layers:
+        # Its own key, so the main stack's weights are the same preset's with
+        # the module count 0.
+        k_block, k_proj = jax.random.split(jax.random.fold_in(key, 1))
+        params["mtp"] = {
+            "hnorm": jnp.ones((H,), dtype),
+            "enorm": jnp.ones((H,), dtype),
+            "eh_proj": stack(k_proj, 1, (2 * H, H), (2 * H) ** -0.5)[0],
+            "layers": group(k_block, config.num_nextn_predict_layers, moe=True),
+            "final_norm": jnp.ones((H,), dtype),
+        }
+    return params
 
 
 def param_count(config: ModelConfig) -> int:
@@ -164,7 +192,10 @@ def hc_coefficients(config: ModelConfig, layer: Params, name: str, X: jax.Array)
 
 def _hc_sublayer(config: ModelConfig, layer: Params, name: str, norm: str, X, fn):
     """``X <- H_res X + H_post^T fn(RMSNorm(H_pre X))``. The stream arithmetic
-    is multiply-and-sum in float32 (n is 4: no matmul unit, no bf16 pass)."""
+    is multiply-and-sum in float32 (n is 4: no matmul unit, no bf16 pass).
+    Without streams (``hc_mult == 1``) there is no mixer: ``X + fn(RMSNorm(X))``."""
+    if config.hc_mult == 1:
+        return X + fn(rms_norm(X[:, :, 0], layer[norm], config.rms_eps))[:, :, None]
     with jax.named_scope("hc_mix"):
         h_pre, h_post, h_res = hc_coefficients(config, layer, name, X)
         X32 = X.astype(_F32)
@@ -351,11 +382,16 @@ def _every_expert(layer: Params, h: jax.Array, chosen: jax.Array, w: jax.Array) 
 def routed_experts(config: ModelConfig, layer: Params, h: jax.Array,
                    dense_share: Optional[float] = None):
     """h [T, H] -> (sum of the chosen experts' weighted outputs [T, H], tokens
-    per expert [E] int32, chosen [T, K]). The T*K token-expert pairs are sorted
-    by expert and each projection is one grouped product over the stacked
-    expert weights (``layer`` holds one layer's, or all layers' and
+    per held expert [E held] int32, chosen [T, K]). The T*K token-expert pairs
+    are sorted by expert and each projection is one grouped product over the
+    stacked expert weights (``layer`` holds one layer's, or all layers' and
     ``expert_layer``: see :func:`_grouped_dot`). Gated experts (``w_gate`` in
     the layer: three stacks) or non-gated ``W_down relu(W_up h)^2`` (two).
+
+    Where the chip holds a share of the experts (``config.experts_held``), the
+    router's choice and weights are over all ``num_experts``; the pairs of
+    experts held elsewhere sort behind the last held group, where the grouped
+    product gives them no matrix, and add nothing to the sum.
 
     ``dense_share``: the call decides on the device, from the counts it has
     anyway, and computes every expert for every token (:func:`_every_expert`)
@@ -368,17 +404,27 @@ def routed_experts(config: ModelConfig, layer: Params, h: jax.Array,
     T, K = h.shape[0], config.num_experts_per_tok
     chosen, w = route(config, layer, h)
     index = layer.get("expert_layer")
+    held = config.held_experts
+    if dense_share is not None and held != config.num_experts:
+        raise NotImplementedError(
+            f"{config.name}: the whole-expert form (dense_share) over a held share of the experts")
     with jax.named_scope("moe_experts"):
         flat = chosen.reshape(-1)
+        if held != config.num_experts:
+            local = flat - config.expert_offset
+            here = (local >= 0) & (local < held)
+            flat = jnp.where(here, local, held)  # elsewhere: behind the last held group
         order = jnp.argsort(flat, stable=True)
-        counts = jnp.bincount(flat, length=config.num_experts).astype(jnp.int32)
+        counts = jnp.bincount(flat, length=held).astype(jnp.int32)
 
         def grouped():
             x = jnp.take(h, order // K, axis=0)  # [T*K, H], grouped by expert
             act = _expert_act(layer, lambda name: _grouped_dot(x, layer[name], counts, index))
             y = _grouped_dot(act, layer["w_down"], counts, index)  # [T*K, H]
-            y = jnp.take(y, jnp.argsort(order), axis=0).reshape(T, K, -1)  # back to token order
-            return jnp.sum(y.astype(_F32) * w[..., None], axis=1).astype(h.dtype)
+            y = jnp.take(y, jnp.argsort(order), axis=0)  # back to token order
+            if held != config.num_experts:
+                y = jnp.where(here[:, None], y, 0)
+            return jnp.sum(y.reshape(T, K, -1).astype(_F32) * w[..., None], axis=1).astype(h.dtype)
 
         if dense_share is None:
             out = grouped()
@@ -469,6 +515,44 @@ def _collect(aux: Optional[dict], outs) -> None:
                 aux["moe_" + key] = ys[key]
 
 
+def _attend_cache(config, layer, h, positions, cached, write_index, key_mask,
+                  prefix=None, prefix_mask=None):
+    """One layer's attention over its dense cache ``cached`` [B, Smax, 1,
+    width] with this call's rows written in -> (out, the rows [B, Smax,
+    width]). With ``prefix`` ([R, P, 1, width]: a decode or verify step) the
+    absorbed form, without the materialised one."""
+    q_nope, q_rope = _mla_q(config, layer, h, positions)
+    with jax.named_scope("kv_write"):
+        rows = _write_cache(
+            cached[:, :, 0], _mla_kv_latent(config, layer, h, positions), write_index
+        )
+    segments = [(rows, key_mask)]
+    if prefix is not None:
+        segments.insert(0, (prefix[:, :, 0], prefix_mask))
+    out = mla_attend(config, layer, q_nope, q_rope, segments, absorb=prefix is not None)
+    return _attn_out(layer, out), rows
+
+
+def _attend_paged(config, layer, h, positions, pool, flat, layer_no, prefix_idx, gen_idx,
+                  write_index, key_mask, prefix_mask, keep):
+    """One layer's attention over its rows of the page pool ([layers * flat,
+    width]; cache layer ``layer_no``'s begin at its multiple of ``flat``): the
+    rows' pages gathered by block table, this step's rows ([B, Sq, width],
+    handed to ``keep``) inserted, the absorbed form -> out."""
+    q_nope, q_rope = _mla_q(config, layer, h, positions)
+    col = _mla_kv_latent(config, layer, h, positions).astype(pool.dtype)  # [B, Sq, W]
+    keep(col)
+    with jax.named_scope("paged_attn"):
+        base = layer_no * flat
+        prefix_rows = jnp.take(pool, prefix_idx + base, axis=0)  # [B|R, P, W]
+        gen_rows = _write_cache(jnp.take(pool, gen_idx + base, axis=0), col, write_index)
+        out = mla_attend(
+            config, layer, q_nope, q_rope,
+            [(prefix_rows, prefix_mask), (gen_rows, key_mask)], absorb=True,
+        )
+    return _attn_out(layer, out)
+
+
 def apply_stack(
     config: ModelConfig,
     params: Params,
@@ -485,25 +569,20 @@ def apply_stack(
 ) -> Tuple[jax.Array, KVCache]:
     """``llama._apply_stack`` for the latent block. cache.k [L, B, Smax, 1,
     width]; with a ``prefix`` ([L, R, P, 1, width]: a decode or verify step)
-    attention takes the absorbed form, without one the materialised form."""
+    attention takes the absorbed form, without one the materialised form. A
+    next-token module's cache layer (the last) goes through as it came."""
     _refuse(config, mesh=mesh, sp_ring_mesh=sp_ring_mesh)
 
     def body(X, layer, scanned):
         new_rows = []
 
         def attn(h):
-            q_nope, q_rope = _mla_q(config, layer, h, positions)
-            with jax.named_scope("kv_write"):
-                rows = _write_cache(
-                    scanned["kv"][:, :, 0], _mla_kv_latent(config, layer, h, positions),
-                    write_index,
-                )
+            out, rows = _attend_cache(
+                config, layer, h, positions, scanned["kv"], write_index, key_mask,
+                scanned.get("prefix"), prefix_mask,
+            )
             new_rows.append(rows)
-            segments = [(rows, key_mask)]
-            if "prefix" in scanned:
-                segments.insert(0, (scanned["prefix"][:, :, 0], prefix_mask))
-            out = mla_attend(config, layer, q_nope, q_rope, segments, absorb="prefix" in scanned)
-            return _attn_out(layer, out)
+            return out
 
         X = _hc_sublayer(config, layer, "hc_attn", "attn_norm", X, attn)
         X, routed = _mlp_sublayer(config, layer, X)
@@ -514,7 +593,10 @@ def apply_stack(
         per_layer["prefix"] = prefix.k
     X, outs = _scan_groups(config, params, _streams_in(config, x), body, per_layer)
     _collect(aux, outs)
-    new_k = jnp.concatenate([ys["kv"] for ys in outs], axis=0)
+    new_k = [ys["kv"] for ys in outs]
+    if config.num_nextn_predict_layers:
+        new_k.append(cache.k[config.num_layers:])
+    new_k = jnp.concatenate(new_k, axis=0)
     return _streams_out(X), KVCache(k=new_k, v=cache.v)
 
 
@@ -534,33 +616,30 @@ def apply_stack_paged(
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``llama._apply_stack_paged`` for the latent block: each layer gathers
     its rows' latent pages from the whole pool ([L, flat, 1, width]) by block
-    table and layer number, inserts this step's row, and attends in the
+    table and layer number, inserts this step's rows, and attends in the
     absorbed form (XLA; the Pallas paged kernel's (KVH, D) shapes do not fit a
-    latent page). Returns (x, k_cols [L, B, 1, width], v_cols [L, B, 1, 0])."""
+    latent page). Returns (x, k_cols [L, B, 1, width], v_cols [L, B, 1, 0]) at
+    ``Sq == 1``; a step of more positions a row (the drafted step) gets
+    k_cols [L, B, Sq, 1, width]. L counts the stack's layers, not a next-token
+    module's."""
     _refuse(config, mesh=mesh)
     flat, width = pool_kv.k.shape[1], pool_kv.k.shape[-1]
     pool = pool_kv.k.reshape(-1, width)
+    one = x.shape[1] == 1
 
     def body(X, layer, scanned):
         cols = []
 
         def attn(h):
-            q_nope, q_rope = _mla_q(config, layer, h, positions)
-            col = _mla_kv_latent(config, layer, h, positions).astype(pool.dtype)  # [B, Sq=1, W]
-            cols.append(col[:, 0])
-            with jax.named_scope("paged_attn"):
-                base = scanned["layer"] * flat
-                prefix_rows = jnp.take(pool, prefix_idx + base, axis=0)  # [B|R, P, W]
-                gen_rows = _write_cache(jnp.take(pool, gen_idx + base, axis=0), col, write_index)
-                out = mla_attend(
-                    config, layer, q_nope, q_rope,
-                    [(prefix_rows, prefix_mask), (gen_rows, key_mask)], absorb=True,
-                )
-            return _attn_out(layer, out)
+            return _attend_paged(
+                config, layer, h, positions, pool, flat, scanned["layer"],
+                prefix_idx, gen_idx, write_index, key_mask, prefix_mask,
+                keep=lambda col: cols.append(col[:, 0] if one else col),
+            )
 
         X = _hc_sublayer(config, layer, "hc_attn", "attn_norm", X, attn)
         X, routed = _mlp_sublayer(config, layer, X)
-        return X, {"col": cols[0][:, None, :], **routed}
+        return X, {"col": cols[0][..., None, :], **routed}
 
     layers = jnp.arange(config.num_layers, dtype=jnp.int32)
     X, outs = _scan_groups(config, params, _streams_in(config, x), body, {"layer": layers})
@@ -570,3 +649,118 @@ def apply_stack_paged(
         aux["mla_latent_rows_read"] = attended * config.num_layers
     k_cols = jnp.concatenate([ys["col"] for ys in outs], axis=0)  # [L, B, 1, W]
     return _streams_out(X), k_cols, jnp.zeros(k_cols.shape[:-1] + (0,), pool_kv.v.dtype)
+
+
+# ---------------------------------------------------------------------------
+# The next-token module
+# ---------------------------------------------------------------------------
+
+def _mtp_layer(params: Params) -> Params:
+    """The module's one block, off its stack of one (a reshape: nothing is copied)."""
+    return {k: v[0] for k, v in params["mtp"]["layers"].items()}
+
+
+@jax.named_scope("mtp_embed")
+def mtp_input(config: ModelConfig, params: Params, h: jax.Array, emb: jax.Array) -> jax.Array:
+    """``W_eh [RMSNorm_h(h) ; RMSNorm_e(emb)]``: the main stack's output before
+    its final norm, and the embedding of the token one position on."""
+    mtp = params["mtp"]
+    pair = jnp.concatenate([rms_norm(h.astype(emb.dtype), mtp["hnorm"], config.rms_eps),
+                            rms_norm(emb, mtp["enorm"], config.rms_eps)], axis=-1)
+    return _dot(pair, mtp["eh_proj"])
+
+
+def _mtp_block(config: ModelConfig, params: Params, h: jax.Array, emb: jax.Array,
+               attend, aux: Optional[dict]) -> jax.Array:
+    """The module's block on the pairs (``h``, ``emb``) -> its output after the
+    module's own norm. ``attend(layer, x)`` is the caller's attention over the
+    module's cache layer; the router's counts (and choices, where the caller
+    asked for them) join the stack's in ``aux``: one more expert layer."""
+    layer = _mtp_layer(params)
+    with jax.named_scope("mtp_block"):
+        X = _streams_in(config, mtp_input(config, params, h, emb))
+        X = _hc_sublayer(config, layer, "hc_attn", "attn_norm", X, lambda x: attend(layer, x))
+        X, routed = _mlp_sublayer(config, layer, X)
+    for key in ("counts", "chosen") if aux is not None else ():
+        if aux.get("moe_" + key) is not None:
+            aux["moe_" + key] = jnp.concatenate([aux["moe_" + key], routed[key][None]], axis=0)
+    return rms_norm(_streams_out(X), params["mtp"]["final_norm"], config.rms_eps)
+
+
+def mtp_ingest(config: ModelConfig, params: Params, emb: jax.Array, x: jax.Array,
+               positions: jax.Array, cache: KVCache, write_index, last: jax.Array,
+               state: Optional[dict]) -> KVCache:
+    """A prompt's (or a chunk's) rows of the module's cache layer, written
+    beside the main stack's: slot ``p`` holds the latent row of the pair
+    ``(h_{p-1}, t_p)``, which depends on the block's input alone, so no
+    attention and no expert runs here. ``emb`` [B, S, H] the tokens'
+    embeddings, ``x`` the main stack's output at the same positions, ``last``
+    [B] the last valid row. ``state["mtp_h"]``: ``h`` just before these
+    positions going in (a chunk's predecessor; zeros at a prompt's start, whose
+    slot 0 is masked wherever the layer is read), at ``last`` coming out."""
+    B = x.shape[0]
+    before = state.get("mtp_h") if state is not None else None
+    before = jnp.zeros((B, 1, x.shape[-1]), x.dtype) if before is None else before[0][:, None].astype(x.dtype)
+    layer = _mtp_layer(params)
+    with jax.named_scope("mtp_block"):
+        inp = mtp_input(config, params, jnp.concatenate([before, x[:, :-1]], axis=1), emb)
+        h = rms_norm(inp, layer["attn_norm"], config.rms_eps)
+        rows = _write_cache(cache.k[-1, :, :, 0], _mla_kv_latent(config, layer, h, positions),
+                            write_index)
+    if state is not None:
+        state["mtp_h"] = (jnp.take_along_axis(x, last.reshape(B, 1, 1).astype(jnp.int32), axis=1)[:, 0],)
+    return KVCache(k=cache.k.at[-1, :, :, 0].set(rows), v=cache.v)
+
+
+def mtp_forward(config: ModelConfig, params: Params, tokens: jax.Array, x: jax.Array) -> jax.Array:
+    """The module over whole sequences with no cache (tests): tokens [B, S],
+    ``x`` the main stack's output before its final norm -> the module's output
+    after its own norm [B, S - 1, H]; row ``i`` (the pair ``(h_i, t_{i+1})``)
+    predicts ``t_{i+2}``."""
+    B, S = tokens.shape
+    emb = jnp.take(params["embed"], tokens[:, 1:], axis=0)
+    positions = jnp.broadcast_to(jnp.arange(1, S)[None], (B, S - 1))
+    causal = jnp.tril(jnp.ones((S - 1, S - 1), bool))[None]
+    empty = jnp.zeros((B, S - 1, 1, config.cache_widths[1]), x.dtype)
+
+    def attend(layer, h):
+        return _attend_cache(config, layer, h, positions, empty, None, causal)[0]
+
+    return _mtp_block(config, params, x[:, :-1], emb, attend, None)
+
+
+def mtp_paged(
+    config: ModelConfig,
+    params: Params,
+    h: jax.Array,
+    tokens: jax.Array,
+    positions: jax.Array,
+    pool_kv: KVCache,
+    prefix_idx: jax.Array,
+    gen_idx: jax.Array,
+    write_index: jax.Array,
+    key_mask: jax.Array,
+    prefix_mask: jax.Array,
+    aux: Optional[dict] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """The module through the page pool, for the loop: ``h`` [B, Sq, H] the
+    main stack's output at the positions before ``positions``, ``tokens`` [B,
+    Sq] the tokens AT ``positions``. Masks and indices as
+    :func:`apply_stack_paged` takes them (the caller masks position 0, which
+    holds nothing in this layer). Returns (the module's output after its norm
+    [B, Sq, H], its cache rows [B, Sq, 1, width])."""
+    flat, width = pool_kv.k.shape[1], pool_kv.k.shape[-1]
+    pool = pool_kv.k.reshape(-1, width)
+    cols = []
+
+    def attend(layer, x):
+        return _attend_paged(
+            config, layer, x, positions, pool, flat, config.num_layers,
+            prefix_idx, gen_idx, write_index, key_mask, prefix_mask, keep=cols.append,
+        )
+
+    out = _mtp_block(config, params, h, jnp.take(params["embed"], tokens, axis=0), attend, aux)
+    if aux is not None and "mla_latent_rows_read" in aux:
+        aux["mla_latent_rows_read"] += (
+            jnp.sum(prefix_mask, dtype=jnp.int32) + jnp.sum(key_mask, dtype=jnp.int32))
+    return out, cols[0][:, :, None, :]

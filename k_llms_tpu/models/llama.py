@@ -718,9 +718,12 @@ def forward(
     tokens: jax.Array,
     pad_mask: jax.Array,
     mesh=None,
+    with_module: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Full-sequence causal forward (no cache). Returns (logits f32 [B,S,V],
-    final hidden states [B,S,H])."""
+    final hidden states [B,S,H]); ``with_module`` (a model with a next-token
+    module, unpadded rows) adds the module's logits [B,S-1,V], row i for token
+    i+2."""
     B, S = tokens.shape
     positions = jnp.cumsum(pad_mask.astype(jnp.int32), axis=1) - 1
     positions = jnp.maximum(positions, 0)
@@ -752,6 +755,10 @@ def forward(
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     logits = _logits(config, params, h)
+    if with_module:
+        from . import latent
+
+        return logits, h, _logits(config, params, latent.mtp_forward(config, params, tokens, x))
     return logits, h
 
 
@@ -771,7 +778,7 @@ def prefill(
     the prompt's last token in ``state``."""
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-    x = _embed(config, params, tokens)
+    x = emb = _embed(config, params, tokens)
 
     causal = jnp.tril(jnp.ones((S, S), bool))
     valid = jnp.arange(S)[None, :] < prompt_len  # [1, S]
@@ -800,6 +807,11 @@ def prefill(
         valid_len=key_lengths,
         state=state,
     )
+    if config.num_nextn_predict_layers:
+        from . import latent
+
+        cache = latent.mtp_ingest(
+            config, params, emb, x, positions, cache, None, key_lengths - 1, state)
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     last = jnp.take_along_axis(h, (prompt_len - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1)
     logits = _logits(config, params, last[:, 0, :])
@@ -834,7 +846,7 @@ def prefill_continue(
     B, Sq = suffix_tokens.shape
     Btot = cache.k.shape[2]
     positions = prefix_len + jnp.broadcast_to(jnp.arange(Sq)[None, :], (B, Sq))
-    x = _embed(config, params, suffix_tokens)
+    x = emb = _embed(config, params, suffix_tokens)
 
     rows = prefix_len + jnp.arange(Sq)[None, :, None]  # absolute query positions
     cols = jnp.arange(Btot)[None, None, :]
@@ -859,6 +871,12 @@ def prefill_continue(
         valid_len=jnp.broadcast_to(total_len - prefix_len, (B,)).astype(jnp.int32),
         state=state,
     )
+    if config.num_nextn_predict_layers:
+        from . import latent
+
+        cache = latent.mtp_ingest(
+            config, params, emb, x, positions, cache, prefix_len,
+            jnp.broadcast_to(total_len - prefix_len - 1, (B,)), state)
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     last_row = (total_len - prefix_len - 1).reshape(B, 1, 1).astype(jnp.int32)
     last = jnp.take_along_axis(h, last_row, axis=1)
@@ -1311,12 +1329,16 @@ def paged_verify_step(
     aux: Optional[dict] = None,
     state: Optional[dict] = None,
     active: Optional[jax.Array] = None,
+    return_hidden: bool = False,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Paged twin of :func:`verify_step` at ``Sq == 1`` — the continuous
-    decode loop's step when its slots hold block tables into a shared page
-    pool instead of dense per-row caches.
+    """Paged twin of :func:`verify_step` — the continuous decode loop's step
+    when its slots hold block tables into a shared page pool instead of dense
+    per-row caches. ``Sq == 1`` in every loop but the drafted one, whose step
+    verifies ``Sq == 2`` positions a row (the latent block only; k_cols then
+    carry an ``Sq`` axis behind the rows') and asks, by ``return_hidden``,
+    for the stack's output before the final norm as a fourth result.
 
-    tokens: [B, 1] current tokens; lengths: [B] generated counts (also each
+    tokens: [B, Sq] current tokens; lengths: [B] generated counts (also each
     row's write offset into its gen slots); prompt_len: scalar or [R];
     pool_kv: the flat page pool ``[L, flat, KVH, D]``; prefix_idx [B|R, P] /
     gen_idx [B, G]: flat pool slots per logical position. Masks are built
@@ -1378,4 +1400,44 @@ def paged_verify_step(
     )
     h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
     logits = _logits(config, params, h)
+    if return_hidden:
+        return logits, k_cols, v_cols, x
     return logits, k_cols, v_cols
+
+
+def paged_draft_step(
+    config: ModelConfig,
+    params: Params,
+    hidden: jax.Array,
+    tokens: jax.Array,
+    lengths: jax.Array,
+    prompt_len: jax.Array,
+    pool_kv: KVCache,
+    prefix_idx: jax.Array,
+    gen_idx: jax.Array,
+    aux: Optional[dict] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """The next-token module's step through the page pool (models/latent.py),
+    beside :func:`paged_verify_step` in the drafted loop: ``tokens`` [B, Sq]
+    at generated offsets ``lengths + j`` (the slots the module's cache rows
+    land in), ``hidden`` [B, Sq, H] the stack's output before its final norm
+    one position earlier. Positions and masks as there, with position 0, which
+    holds nothing in the module's layer, masked out of the prompt's rows.
+    Returns (logits f32 [B, Sq, V] for the token after each, the module's
+    cache rows [B, Sq, 1, width])."""
+    from . import latent
+
+    B, Sq = tokens.shape
+    pl = jnp.asarray(prompt_len, jnp.int32).reshape(-1)
+    pl_row = jnp.repeat(pl, B // pl.shape[0], total_repeat_length=B)  # [B]
+    lengths = lengths.astype(jnp.int32)
+    offsets = lengths[:, None] + jnp.arange(Sq)[None, :]  # [B, Sq]
+    self_mask = jnp.arange(gen_idx.shape[1])[None, None, :] <= offsets[:, :, None]
+    c = jnp.arange(prefix_idx.shape[1])[None, None, :]
+    prefix_mask = (c >= 1) & (c < pl_row[:, None, None]) & jnp.ones((B, Sq, 1), bool)
+    h, cols = latent.mtp_paged(
+        config, params, hidden, tokens, pl_row[:, None] + offsets, pool_kv,
+        prefix_idx, gen_idx, lengths, self_mask, prefix_mask, aux=aux,
+    )
+    with jax.named_scope("mtp_head"):
+        return _logits(config, params, h), cols

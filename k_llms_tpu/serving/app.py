@@ -427,8 +427,9 @@ class ServingApp:
         # What the model's own stack counted in the loop's programs: one
         # unlabeled cumulative sample each (a gauge family, like the loop's
         # own ``kllms_continuous_steps``), present at zero for every model.
-        # The paged kernel's page counts (PAGED_ATTN_PAGES) go out the same way.
-        for counters in (_obs.MODEL_COUNTERS, _obs.PAGED_ATTN_PAGES):
+        # The paged kernel's page counts (PAGED_ATTN_PAGES) and the drafted
+        # loop's verdicts (SPEC_COUNTERS) go out the same way.
+        for counters in (_obs.MODEL_COUNTERS, _obs.PAGED_ATTN_PAGES, _obs.SPEC_COUNTERS):
             counts = counters.snapshot()
             for name in counters.declared:
                 families.append(_prom.gauge_family(

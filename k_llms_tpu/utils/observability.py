@@ -349,6 +349,19 @@ PAGED_ATTN_PAGES = EventCounters(declared=(
 ))
 
 
+#: What the drafted loop's steps verified (``engine/continuous.py``: a model
+#: with a next-token module), added up from each step's readback and exported
+#: unlabeled as ``kllms_<name>`` on ``/metrics``; zero for every other model.
+#: ``spec_drafts_verified`` — drafts a step checked (one a live row a step);
+#: ``spec_drafts_accepted`` — those whose second token was emitted;
+#: ``spec_tokens_emitted`` — tokens the steps emitted (one or two a row step).
+SPEC_COUNTERS = EventCounters(declared=(
+    "spec_drafts_verified",
+    "spec_drafts_accepted",
+    "spec_tokens_emitted",
+))
+
+
 def note_model_aux(aux: Dict[str, Any]) -> None:
     """Add one program call's ``aux`` (host arrays: see models/latent.py and
     models/hybrid.py) to

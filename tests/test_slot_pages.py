@@ -267,3 +267,89 @@ def test_cow_copies_the_shared_page_on_a_real_pool():
         assert books._tables[slot][-1] != run[-1]
         got = np.asarray(pool.kv.k[0, prefix_idx[slot, :plen], 0, 0])
         assert got.tolist() == list(range(plen))
+
+
+# -- a step that writes P .. P+2 (the drafted loop: lookahead 2) -------------------------
+
+def _ahead_books(ps=8, total=64):
+    pool = _Pool(total, ps)
+    books = SlotPages(ps, W, P, G, lookahead=2)
+    books.attach(pool)
+    return books, pool, pool.allocator
+
+
+def test_lookahead_widens_the_gen_map_and_the_reserve():
+    books, _, alloc = _ahead_books()
+    assert books.gen_idx.shape == (W, G + 2)
+    # A prompt that ends two positions before a page edge: the last step's
+    # look-ahead crosses into one more page than the one-token loop reserves.
+    plen, max_new = 14, 10  # positions 14 .. 23 for tokens, 24 and 25 ahead
+    assert books.need(plen, 2, max_new) == SlotPages(8, W, P, G).need(plen, 2, max_new) + 2
+    _admit(books, alloc, [0, 1], plen, max_new)
+    gen = 0
+    while gen < max_new - 1:  # a row's steps to its end, two tokens at a time
+        _, _, write_idx = books.prepare_step(*_lens([0, 1], plen, gen))
+        assert write_idx.shape == (W, 3)
+        gen += 2
+    for slot in (0, 1):
+        books.release(slot)
+    alloc.verify()
+
+
+@pytest.mark.parametrize("plen", [22, 23, 24])
+def test_a_step_writing_three_positions_grows_at_a_page_edge(plen):
+    """P, P+1 and P+2 land on whichever pages they fall in: the table grows
+    before the write that crosses the edge (page size 8: 22, 23 cross inside
+    the step, 24 starts on a fresh page)."""
+    books, pool, alloc = _ahead_books()
+    _admit(books, alloc, [0], plen, 12)
+    table0 = len(books._tables[0])
+    _, gen_idx, write_idx = books.prepare_step(*_lens([0], plen, 0))
+    want = flat_slots(books._tables[0], plen + np.arange(3), 8)
+    np.testing.assert_array_equal(write_idx[0], want)
+    np.testing.assert_array_equal(gen_idx[0, :3], want)
+    assert len(books._tables[0]) == pages_for(plen + 3, 8) >= table0
+    assert len(set(write_idx[0] // 8)) == (2 if plen in (22, 23) else 1)
+    assert (write_idx[1:] // 8 == TRASH_PAGE).all()  # idle rows write into the trash page
+    # One row alone: its prompt pages are its own, so nothing is copied.
+    assert pool.copies == []
+    books.release(0)
+    alloc.verify()
+
+
+@pytest.mark.parametrize("plen", [17, 22, 23])
+def test_a_shared_prompt_page_under_any_of_the_three_is_copied_once_a_row(plen):
+    books, pool, alloc = _ahead_books()
+    run = _admit(books, alloc, [0, 1, 2], plen, 12, keep_owner=True)
+    shared = run[-1]
+    assert alloc.refcount(shared) == 4
+    _, _, write_idx = books.prepare_step(*_lens([0, 1, 2], plen, 0))
+    (src, dst), = pool.copies  # one padded batch
+    real = [(s, d) for s, d in zip(src, dst) if s != TRASH_PAGE]
+    assert [s for s, _ in real] == [shared] * 3 and len({d for _, d in real}) == 3
+    assert alloc.refcount(shared) == 1  # the owner's: every row now writes its own page
+    pages = {int(p) for p in (write_idx[:3] // 8).reshape(-1)}
+    assert shared not in pages and TRASH_PAGE not in pages
+    for slot in range(3):  # no slot of one row is another row's
+        assert not set(write_idx[slot]) & {int(s) for r in range(3) if r != slot for s in write_idx[r]}
+    books.prepare_step(*_lens([0, 1, 2], plen, 2))  # nothing shared is left to copy
+    assert len(pool.copies) == 1
+    for slot in range(3):
+        books.release(slot)
+    alloc.decref(run)
+    alloc.verify()
+
+
+def test_a_failed_reserve_with_lookahead_rolls_back():
+    plen, max_new, n = 14, 10, 3
+    total = SlotPages(8, W, P, G, lookahead=2).need(plen, n, max_new)  # one short, with the trash page
+    books, _, alloc = _ahead_books(total=total)
+    run = alloc.alloc(pages_for(plen, 8))
+    free0, refs0 = alloc.free_pages, alloc._ref.copy()
+    with pytest.raises(PagePoolExhausted):
+        books.admit(list(range(n)), run, plen, max_new, alloc.alloc)
+    assert alloc.free_pages == free0 and (alloc._ref == refs0).all() and books.held() == 0
+    # The one-token loop's books would have fitted the same request in the same pool.
+    plain = SlotPages(8, W, P, G)
+    plain.attach(_Pool(total, 8))
+    assert plain.need(plen, n, max_new) < total
