@@ -313,9 +313,12 @@ class HbmMemoryModel:
 
     ``kv_bytes_per_token`` is the model's own (``ModelConfig``): K and V over
     every layer's KV heads for the GQA block, one ``kv_lora_rank +
-    qk_rope_head_dim`` latent row a layer and no V for a latent (MLA) model
-    (8,064 B a token for the 7-layer Xing4.0 cut against qwen2-7b's
-    57,344); a hybrid stack counts its attention layers alone (1,024 B a token
+    qk_rope_head_dim`` latent row a layer and no V for a latent (MLA) model,
+    counted as the page pool stores it, in whole tiles of 128 lanes (576 ->
+    640: 8,960 B a token for the 7-layer Xing4.0 cut against qwen2-7b's
+    57,344), while :meth:`max_rows`, the bound for dense rows, counts the
+    dense cache's own 576 (``dense_kv_bytes_per_token``: 8,064 B); a hybrid
+    stack counts its attention layers alone (1,024 B a token
     for the 9-layer Nemotron-3 cut) and charges each row its fixed recurrent
     state (8.5 MB there) in the row margin."""
 
@@ -339,6 +342,7 @@ class HbmMemoryModel:
         # Every layer's cache row for one token; KV heads shard over the
         # model axis with the attention that consumes them.
         self.kv_bytes_per_token = config.kv_bytes_per_token
+        self.dense_kv_bytes_per_token = config.dense_kv_bytes_per_token
         # Per-row non-KV working set: the decode loop materializes f32 logits
         # and sampling buffers per row; 4 bytes * vocab is the dominant term
         # for every model whose rows hold no recurrent state.
@@ -357,7 +361,7 @@ class HbmMemoryModel:
         optimistic estimate into a hard rejection."""
         seq_len = max(1, int(seq_len))
         per_row = (
-            seq_len * self.kv_bytes_per_token // self.tp + self.row_margin_bytes
+            seq_len * self.dense_kv_bytes_per_token // self.tp + self.row_margin_bytes
         )
         rows = self.dp * max(0, self.budget_bytes()) // max(1, per_row)
         return max(1, int(rows))
@@ -1280,11 +1284,17 @@ class TpuBackend(Backend):
         # the paged layout — the live page-pool occupancy (reading the pool
         # stats through the loop's stats property also re-checks the page
         # conservation invariants).
+        paged = getattr(self.engine, "kv_layout", "dense") == "paged"
         hbm: Dict[str, Any] = {
             "param_bytes": self.memory_model.param_bytes,
-            "kv_bytes_per_token": self.memory_model.kv_bytes_per_token,
+            # What a token holds where this engine keeps it: a pool row, or a
+            # dense cache's (they differ for a latent model's padded pool row).
+            "kv_bytes_per_token": (
+                self.memory_model.kv_bytes_per_token if paged
+                else self.memory_model.dense_kv_bytes_per_token
+            ),
             "budget_bytes": self.memory_model.budget_bytes(),
-            "paged": getattr(self.engine, "kv_layout", "dense") == "paged",
+            "paged": paged,
             "page_size": getattr(self.engine, "kv_page_size", None),
         }
         if self._continuous is not None:

@@ -113,7 +113,13 @@ from .engine import (
     _quarantine_error,
     is_resource_exhausted,
 )
-from .paging import PageAccountingError, PagePoolExhausted, SlotPages
+from .paging import (
+    PageAccountingError,
+    PagePoolExhausted,
+    SlotPages,
+    scatter_rows,
+    write_drafted_rows,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -126,32 +132,6 @@ _install_rows = jax.jit(
     ),
     donate_argnums=(0,),
 )
-
-
-@jax.named_scope("kv_write")
-def write_drafted_rows(pool_k, stack_cols, module_cols, write_idx):
-    """A drafted step's (or, with one position, an admission draft's) cache
-    rows into the pool ``[L + 1, flat, 1, width]`` in ONE scatter over its
-    rows ``[(L + 1) * flat, 1, width]``, each cache row addressed by layer and
-    slot: the stack's L layers write the first ``S`` of ``write_idx``'s
-    positions (``stack_cols`` [L, W, S, 1, width]; None: no position), the
-    module's layer the last ``S`` (``module_cols`` [W, S, 1, width]).
-    ``write_idx`` [W, n]: P, P+1, P+2 for a step (S = 2), L alone for an
-    admission (S = 1). On the chip the pool's flat axis is its minor-most, so
-    the step's latent gather already reads a relaid-out copy of the pool; this
-    scatter goes into that same copy, where one per layer axis would lay the
-    pool out a third way (8.3 against 5.6 ms a call; my chip run, PR 34)."""
-    flat, row = pool_k.shape[1], pool_k.shape[2:]
-    L, S = pool_k.shape[0] - 1, module_cols.shape[1]
-    idx = [(L * flat + write_idx[:, write_idx.shape[1] - S:]).reshape(-1)]
-    cols = [module_cols.reshape(-1, *row)]
-    if stack_cols is not None:
-        layer_base = jnp.arange(L, dtype=write_idx.dtype)[:, None, None] * flat
-        idx.insert(0, (layer_base + write_idx[None, :, :S]).reshape(-1))
-        cols.insert(0, stack_cols.reshape(-1, *row))
-    rows = pool_k.reshape(-1, *row).at[jnp.concatenate(idx)].set(
-        jnp.concatenate(cols).astype(pool_k.dtype))
-    return rows.reshape(pool_k.shape)
 
 
 @dataclass
@@ -966,9 +946,7 @@ class ContinuousDecodeLoop:
                     mesh=mesh, aux=aux, state=state, active=active,
                 )
                 with jax.named_scope("kv_write"):
-                    pool_k = pool_k.at[:, write_idx].set(k_cols.astype(pool_k.dtype))
-                    pool_v = pool_v.at[:, write_idx].set(v_cols.astype(pool_v.dtype))
-                new_kv = (pool_k, pool_v)
+                    new_kv = scatter_rows(pool_k, pool_v, write_idx, k_cols, v_cols)
             else:
                 # Write cur's KV at each row's own offset (gen_lens), attend
                 # row-local prefix + generated KV (``verify_step`` with Sq=1:

@@ -1816,6 +1816,8 @@ class LocalEngine:
 
             fn = jax.jit(_loop)
         else:
+            from .paging import scatter_rows
+
             page_size = self.kv_page_size
 
             def _loop(
@@ -1842,9 +1844,7 @@ class LocalEngine:
                     slots = lax.dynamic_index_in_dim(
                         gen_idx, step, axis=1, keepdims=False
                     )
-                    pool_k = pool_k.at[:, slots].set(k_cols)
-                    pool_v = pool_v.at[:, slots].set(v_cols)
-                    return logits[:, 0], (pool_k, pool_v)
+                    return logits[:, 0], scatter_rows(pool_k, pool_v, slots, k_cols, v_cols)
 
                 toks, lps, done, tt, tl, pois, (pool_k, pool_v) = _run_loop(
                     params, (pool_k, pool_v), step_fn, prompt_lens,

@@ -15,8 +15,8 @@ kv_heads, head_dim]`` (kv-head axis sharded over the existing tp mesh axis,
 like every other KV buffer here). What a page row holds is the model's
 (``ModelConfig.cache_widths``): K and V per KV head for the GQA block; for a
 latent (MLA) model one ``[c_kv | k_rope]`` row in ``k``
-(``[L, flat, 1, kv_lora_rank + qk_rope_head_dim]``) and a ``v`` of width 0,
-so the movers below move the pair as always and no V bytes exist. A *block
+(``[L, flat, 1, kv_lora_rank + qk_rope_head_dim`` rounded up to whole tiles
+of 128 lanes``]``) and a ``v`` of width 0, so no V bytes exist. A *block
 table* is a host-side list of page
 ids per logical row; attention consumes it as flat slot indices
 ``page_id * page_size + offset`` through a plain gather
@@ -53,9 +53,12 @@ from __future__ import annotations
 import logging
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..analysis.lockcheck import make_rlock, note_device_dispatch
+from ..ops.attention import pool_gather, pool_index, pool_layers, pool_scatter
 from ..ops.paged_attention import live_pages, table_pages
 from ..reliability import failpoints as _failpoints
 
@@ -283,12 +286,83 @@ def flat_slots(pages: Sequence[int], positions: np.ndarray, page_size: int) -> n
     return (page_ids * page_size + offs).astype(np.int32)
 
 
+# ---------------------------------------------------------------------------
+# The pool's movers
+# ---------------------------------------------------------------------------
+# Every program that writes a pool or reads rows out of one for the engine
+# does it through these four, so what a pool stores and how it is addressed is
+# known here (and, for the model's own gather inside a step, in
+# ops/attention.py) and nowhere else. Two forms, chosen from the arrays' own
+# shapes at trace time: a pool of one row a token and no V (a latent model's)
+# is addressed by (layer, slot) in its flat view, its rows padded to the
+# stored width on the way in and cut back on the way out; a pool of K and V
+# per KV head, whose rows are whole tiles already, is indexed along the layer
+# axis. The first compiles with no copy of the pool for the one-row pool, where
+# the second would lay the whole pool out again and back
+# (tests/test_tpu_compile.py); the second is what the GQA models' programs
+# have always held, and their traces show no pool copy.
+
+def _one_row(pool_k, pool_v) -> bool:
+    return pool_k.shape[2] == 1 and pool_v.shape[-1] == 0
+
+
+def scatter_rows(pool_k, pool_v, slots, k_rows, v_rows):
+    """Rows ``[L, n, heads, width]`` written at flat slots ``slots`` [n] of
+    the pool's first L cache layers -> (pool_k, pool_v): all of them, but for
+    a step that runs a model's stack without its next-token module, whose
+    cache layer is the pool's last. Inside a jitted program."""
+    if _one_row(pool_k, pool_v):
+        return pool_scatter(pool_k, pool_layers(pool_k, slots, k_rows.shape[0]), k_rows), pool_v
+    return (pool_k.at[:, slots].set(k_rows.astype(pool_k.dtype)),
+            pool_v.at[:, slots].set(v_rows.astype(pool_v.dtype)))
+
+
+def gather_rows(pool_k, pool_v, slots, k_width: int):
+    """-> (k, v) ``[L, 1, n, heads, width]`` at the cache's own widths: the
+    dense prefix layout every engine consumer (decode prefix, continuation
+    seed) expects."""
+    if _one_row(pool_k, pool_v):
+        rows = pool_gather(pool_k, pool_layers(pool_k, slots), k_width)  # [L, n, W]
+        return rows[:, None, :, None, :], pool_v[:, slots][:, None]
+    return pool_k[:, slots][:, None], pool_v[:, slots][:, None]
+
+
+def copy_rows(pool_k, pool_v, src_slots, dst_slots):
+    """Slots ``src_slots`` copied onto ``dst_slots`` in every cache layer
+    (copy-on-write pages) -> (pool_k, pool_v)."""
+    if _one_row(pool_k, pool_v):
+        rows = pool_gather(pool_k, pool_layers(pool_k, src_slots), pool_k.shape[-1])
+        return pool_scatter(pool_k, pool_layers(pool_k, dst_slots), rows), pool_v
+    return (pool_k.at[:, dst_slots].set(pool_k[:, src_slots]),
+            pool_v.at[:, dst_slots].set(pool_v[:, src_slots]))
+
+
+@jax.named_scope("kv_write")
+def write_drafted_rows(pool_k, stack_cols, module_cols, write_idx):
+    """A drafted step's (or, with one position, an admission draft's) cache
+    rows into the latent pool ``[L + 1, flat, 1, stored width]``, in ONE
+    scatter: the stack's L layers write the first ``S`` of ``write_idx``'s
+    positions (``stack_cols`` [L, W, S, 1, width]; None: no position), the
+    module's layer the last ``S`` (``module_cols`` [W, S, 1, width]).
+    ``write_idx`` [W, n]: P, P+1, P+2 for a step (S = 2), L alone for an
+    admission (S = 1)."""
+    L, S = pool_k.shape[0] - 1, module_cols.shape[1]
+    idx = [pool_index(pool_k, L, write_idx[:, write_idx.shape[1] - S:]).reshape(-1)]
+    cols = [module_cols.reshape(-1, module_cols.shape[-1])]
+    if stack_cols is not None:
+        idx.insert(0, pool_layers(pool_k, write_idx[:, :S], L).reshape(-1))
+        cols.insert(0, stack_cols.reshape(-1, stack_cols.shape[-1]))
+    return pool_scatter(pool_k, jnp.concatenate(idx), jnp.concatenate(cols))
+
+
 class PagedKVPool:
     """The device-side page pool plus its jitted data movers.
 
     ``kv.k`` / ``kv.v``: ``[L, total_pages * page_size, heads, width]`` with
     (heads, k width, v width) from ``config.cache_widths`` — K and V per KV
-    head, or one latent row and an empty V.
+    head, or one latent row and an empty V. A latent row is stored
+    ``config.pool_row_width`` lanes wide (576 -> 640, pad lanes zero); the
+    public ops take and give rows of the cache's own width (the movers above).
     All device ops that consume-and-replace the pool buffers (scatter, copy)
     dispatch under ``self.lock`` and swap ``self.kv`` atomically, so the
     continuous-loop worker and the scheduler threads never race a donated
@@ -297,9 +371,6 @@ class PagedKVPool:
     """
 
     def __init__(self, config, total_pages: int, page_size: int, dtype=None):
-        import jax
-        import jax.numpy as jnp
-
         from ..models.llama import KVCache
 
         self.config = config
@@ -313,27 +384,19 @@ class PagedKVPool:
         shape = (config.paging_layers, flat, heads)
         dtype = dtype or config.jax_dtype
         self.kv = KVCache(
-            k=jnp.zeros(shape + (k_width,), dtype), v=jnp.zeros(shape + (v_width,), dtype)
+            k=jnp.zeros(shape + (config.pool_row_width,), dtype),
+            v=jnp.zeros(shape + (v_width,), dtype),
         )
 
         # The jitted movers; jax.jit keeps a program per argument shape.
         def _scatter(pool_k, pool_v, k_src, v_src, idx):
-            # k_src/v_src: [L, n, KVH, D]; idx: [n] flat slots.
-            return KVCache(
-                k=pool_k.at[:, idx].set(k_src.astype(pool_k.dtype)),
-                v=pool_v.at[:, idx].set(v_src.astype(pool_v.dtype)),
-            )
+            return KVCache(*scatter_rows(pool_k, pool_v, idx, k_src, v_src))
 
         def _gather(pool_k, pool_v, idx):
-            # -> [L, 1, n, KVH, D]: the dense prefix layout every engine
-            # consumer (decode prefix, continuation seed) expects.
-            return KVCache(k=pool_k[:, idx][:, None], v=pool_v[:, idx][:, None])
+            return KVCache(*gather_rows(pool_k, pool_v, idx, k_width))
 
         def _copy(pool_k, pool_v, src_idx, dst_idx):
-            return KVCache(
-                k=pool_k.at[:, dst_idx].set(pool_k[:, src_idx]),
-                v=pool_v.at[:, dst_idx].set(pool_v[:, src_idx]),
-            )
+            return KVCache(*copy_rows(pool_k, pool_v, src_idx, dst_idx))
 
         self._scatter_fn = jax.jit(_scatter, donate_argnums=(0, 1))
         self._gather_fn = jax.jit(_gather)

@@ -229,8 +229,30 @@ class ModelConfig:
         return self.num_kv_heads, self.head_dim, self.head_dim
 
     @property
+    def pool_row_width(self) -> int:
+        """Lanes the page pool stores for ``cache_widths``' k width. A latent
+        row (one head, no V) is rounded up to whole tiles of 128
+        lanes (576 -> 640: ``[c_kv | k_rope | zeros]``): the chip then keeps a
+        token's row on the pool's minor axis and a mover addresses it in
+        place, where a row of 4.5 tiles lies strided and every program that
+        touches one lays the whole pool out again (engine/paging.py). The pad
+        lanes are written as zeros and never read. Rows of several heads are
+        whole tiles already and stored as they are."""
+        _, k_width, _ = self.cache_widths
+        return -(-k_width // 128) * 128 if self.is_latent else k_width
+
+    @property
     def kv_bytes_per_token(self) -> int:
-        """Cache bytes one token holds over the paging layers, in the model dtype."""
+        """Bytes one token holds in the page pool over the paging layers, in
+        the model dtype (a latent row counts its pad lanes: they are stored)."""
+        heads, _, v_width = self.cache_widths
+        return self.paging_layers * heads * (self.pool_row_width + v_width) * self.jax_dtype.itemsize
+
+    @property
+    def dense_kv_bytes_per_token(self) -> int:
+        """Bytes one token holds in a dense cache (``init_cache``), whose rows
+        are the cache's own width: what :attr:`kv_bytes_per_token` is for every
+        model whose pool rows are not padded."""
         heads, k_width, v_width = self.cache_widths
         return self.paging_layers * heads * (k_width + v_width) * self.jax_dtype.itemsize
 

@@ -11,6 +11,11 @@ when ``config.is_latent``.
 - **Cache.** One row a token a layer: ``[c_kv | k_rope]`` after the latent's
   norm and after RoPE, ``kv_lora_rank + qk_rope_head_dim`` wide, in the ``k``
   array of the usual (k, v) pair as ``[..., 1, width]``; ``v`` has width 0.
+  The page pool stores the row ``config.pool_row_width`` lanes wide (whole
+  tiles of 128: 576 -> 640, the pad lanes zeros nobody reads), and every
+  program addresses it by (layer, slot) in the pool's flat view: the gathers
+  here through ``ops/attention.py::pool_gather``, every writer through the
+  page manager's movers (``engine/paging.py``).
 - **Two attention forms, one function** (:func:`mla_attend`): prefill and
   chunks materialise per-head keys and values from the latent; a decode or
   verify step absorbs the up-projections into the query and the output, so it
@@ -59,6 +64,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.attention import pool_gather, pool_index
 from .config import ModelConfig
 from .llama import KVCache, Params, rms_norm, rope_embed
 
@@ -533,19 +539,20 @@ def _attend_cache(config, layer, h, positions, cached, write_index, key_mask,
     return _attn_out(layer, out), rows
 
 
-def _attend_paged(config, layer, h, positions, pool, flat, layer_no, prefix_idx, gen_idx,
+def _attend_paged(config, layer, h, positions, pool_k, layer_no, prefix_idx, gen_idx,
                   write_index, key_mask, prefix_mask, keep):
-    """One layer's attention over its rows of the page pool ([layers * flat,
-    width]; cache layer ``layer_no``'s begin at its multiple of ``flat``): the
-    rows' pages gathered by block table, this step's rows ([B, Sq, width],
+    """One layer's attention over cache layer ``layer_no`` of the page pool:
+    the rows' pages gathered by block table, this step's rows ([B, Sq, width],
     handed to ``keep``) inserted, the absorbed form -> out."""
     q_nope, q_rope = _mla_q(config, layer, h, positions)
-    col = _mla_kv_latent(config, layer, h, positions).astype(pool.dtype)  # [B, Sq, W]
+    col = _mla_kv_latent(config, layer, h, positions).astype(pool_k.dtype)  # [B, Sq, W]
     keep(col)
     with jax.named_scope("paged_attn"):
-        base = layer_no * flat
-        prefix_rows = jnp.take(pool, prefix_idx + base, axis=0)  # [B|R, P, W]
-        gen_rows = _write_cache(jnp.take(pool, gen_idx + base, axis=0), col, write_index)
+        def gather(slots):  # [B|R, P] -> [B|R, P, W]
+            return pool_gather(pool_k, pool_index(pool_k, layer_no, slots), col.shape[-1])
+
+        prefix_rows = gather(prefix_idx)
+        gen_rows = _write_cache(gather(gen_idx), col, write_index)
         out = mla_attend(
             config, layer, q_nope, q_rope,
             [(prefix_rows, prefix_mask), (gen_rows, key_mask)], absorb=True,
@@ -615,16 +622,14 @@ def apply_stack_paged(
     mesh=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``llama._apply_stack_paged`` for the latent block: each layer gathers
-    its rows' latent pages from the whole pool ([L, flat, 1, width]) by block
-    table and layer number, inserts this step's rows, and attends in the
-    absorbed form (XLA; the Pallas paged kernel's (KVH, D) shapes do not fit a
-    latent page). Returns (x, k_cols [L, B, 1, width], v_cols [L, B, 1, 0]) at
-    ``Sq == 1``; a step of more positions a row (the drafted step) gets
-    k_cols [L, B, Sq, 1, width]. L counts the stack's layers, not a next-token
-    module's."""
+    its rows' latent pages from the whole pool ([L, flat, 1, stored width]) by
+    block table and layer number (:func:`pool_gather`), inserts this step's
+    rows, and attends in the absorbed form (XLA; the Pallas paged kernel's
+    (KVH, D) shapes do not fit a latent page). Returns (x, k_cols [L, B, 1,
+    width], v_cols [L, B, 1, 0]) at ``Sq == 1``; a step of more positions a
+    row (the drafted step) gets k_cols [L, B, Sq, 1, width]. L counts the
+    stack's layers, not a next-token module's."""
     _refuse(config, mesh=mesh)
-    flat, width = pool_kv.k.shape[1], pool_kv.k.shape[-1]
-    pool = pool_kv.k.reshape(-1, width)
     one = x.shape[1] == 1
 
     def body(X, layer, scanned):
@@ -632,7 +637,7 @@ def apply_stack_paged(
 
         def attn(h):
             return _attend_paged(
-                config, layer, h, positions, pool, flat, scanned["layer"],
+                config, layer, h, positions, pool_kv.k, scanned["layer"],
                 prefix_idx, gen_idx, write_index, key_mask, prefix_mask,
                 keep=lambda col: cols.append(col[:, 0] if one else col),
             )
@@ -749,13 +754,11 @@ def mtp_paged(
     :func:`apply_stack_paged` takes them (the caller masks position 0, which
     holds nothing in this layer). Returns (the module's output after its norm
     [B, Sq, H], its cache rows [B, Sq, 1, width])."""
-    flat, width = pool_kv.k.shape[1], pool_kv.k.shape[-1]
-    pool = pool_kv.k.reshape(-1, width)
     cols = []
 
     def attend(layer, x):
         return _attend_paged(
-            config, layer, x, positions, pool, flat, config.num_layers,
+            config, layer, x, positions, pool_kv.k, config.num_layers,
             prefix_idx, gen_idx, write_index, key_mask, prefix_mask, keep=cols.append,
         )
 

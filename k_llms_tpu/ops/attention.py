@@ -506,3 +506,45 @@ def gather_kv_pages(
         jnp.take(pool.reshape(-1, *pool.shape[2:]), idx, axis=0)
         for pool in (pool_k, pool_v)
     )
+
+
+# ---------------------------------------------------------------------------
+# A one-row page pool in its flat view
+# ---------------------------------------------------------------------------
+# A pool whose token holds one row and no V (a latent model's ``[L, flat, 1,
+# W]``, W whole tiles of 128 lanes) is addressed one way: a cache row is row
+# ``layer * flat + slot`` of the view ``[L * flat, W]`` (a reshape: the chip
+# keeps such a row on the minor axis). A gather or a scatter there moves the
+# rows it names. A scatter along the layer axis (``pool.at[:, slots]``) or rows
+# that are no whole tiles make the compiler lay the whole pool out again around
+# the op, and back (tests/test_tpu_compile.py). The model's step reads through
+# :func:`pool_gather`; every writer is one of the page manager's movers
+# (engine/paging.py), and no other module writes a pool.
+
+def pool_index(pool_k: jax.Array, layers, slots) -> jax.Array:
+    """Rows of the flat view for cache layers ``layers`` at flat slots
+    ``slots`` (they broadcast)."""
+    return layers * pool_k.shape[1] + slots
+
+
+def pool_layers(pool_k: jax.Array, slots: jax.Array, count: Optional[int] = None) -> jax.Array:
+    """:func:`pool_index` of ``slots`` [...] in each of the first ``count``
+    cache layers (None: all of them) -> [count, ...]."""
+    layers = jnp.arange(count or pool_k.shape[0], dtype=slots.dtype)
+    return pool_index(pool_k, layers.reshape((-1,) + (1,) * slots.ndim), slots[None])
+
+
+def pool_gather(pool_k: jax.Array, index: jax.Array, width: int) -> jax.Array:
+    """The rows at ``index`` (:func:`pool_index`), cut back to the cache row's
+    own ``width`` -> ``index.shape + (width,)``."""
+    return jnp.take(pool_k.reshape(-1, pool_k.shape[-1]), index, axis=0)[..., :width]
+
+
+def pool_scatter(pool_k: jax.Array, index: jax.Array, rows: jax.Array) -> jax.Array:
+    """``rows`` ``[..., width]``, one a place of ``index``, written into the
+    pool in one scatter, their pad lanes zeros."""
+    stored = pool_k.shape[-1]
+    rows = rows.reshape(-1, rows.shape[-1]).astype(pool_k.dtype)
+    rows = jnp.pad(rows, ((0, 0), (0, stored - rows.shape[-1])))
+    flat_view = pool_k.reshape(-1, stored).at[index.reshape(-1)].set(rows)
+    return flat_view.reshape(pool_k.shape)

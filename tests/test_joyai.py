@@ -91,7 +91,7 @@ def test_reference_copies_are_equal():
 
 def test_cut8_is_the_arithmetic_of_the_configuration_file():
     cut = get_config("joyai-llm-flash-cut8")
-    assert (cut.paging_layers, cut.kv_bytes_per_token) == (9, 10368)
+    assert (cut.paging_layers, cut.kv_bytes_per_token) == (9, 9 * 640 * 2)  # 576 stored 640 wide
     assert (cut.held_experts, cut.num_experts, cut.hc_mult) == (128, 256, 1)
     count = latent.param_count(cut)
     assert abs(count - 5.693e9) < 2e6  # ISSUE 34's 5,693 M
@@ -384,10 +384,16 @@ def test_what_the_module_cannot_ride_is_refused_by_name(params, kw, named):
 
 # -- what other models' programs did not move -------------------------------------------------
 
-#: sha256 of each program's StableHLO text at the parent of PR 34 (53cb5d9), made by
-#: this test's own ``program_texts`` on that tree: the drafted step, the held
-#: share and the plain residual path are static branches that these
-#: configurations never take.
+#: sha256 of each program's StableHLO text on the tree named beside it, made by
+#: this test's own ``program_texts`` there. ``qwen2-shaped``: the parent of PR 34
+#: (53cb5d9); the drafted step, the held share and the plain residual path are
+#: static branches it never takes. ``nemotron3-tiny``: the parent of PR 35
+#: (7ba35d2); its pool ``[1, flat, 2, 128]`` keeps its rows and its layer-axis
+#: movers. ``xing4-tiny``: ``step`` and ``step_g`` are PR 35's own tree. They
+#: moved because the pool they take is stored 128 lanes wide (40 before) and
+#: the step gathers and writes it by (layer, slot) in the flat view
+#: (``paging.scatter_rows``), which is the change; ``chunk`` never touches the pool
+#: and is still PR 34's parent's.
 PARENT_PROGRAMS = {
     "qwen2-shaped": {
         "step": "3776df69d413990ace1e724834e2f4f9e8d7782290cc3c61bb612913e867647a",
@@ -395,9 +401,14 @@ PARENT_PROGRAMS = {
         "chunk": "b1ca8438c73e7a21b7a5cfa2676bde8ec43eeb67660c81dae26499ce9a8f53e4",
     },
     "xing4-tiny": {
-        "step": "7f14d4500dd119fd139ac0b92ac216f74660b62adf613f5e65af1fec4e3b8f38",
-        "step_g": "9eff5cc0101ede0983d91a06428a9f9eea9117a638336ee0f226d511be00074c",
+        "step": "af02ea5c567784b72cf3c9cbbcf0bfc525b2eb5724bb522b55ea7cabe94efb38",
+        "step_g": "679ee68d518a1c94efadbba753988567cf749449d00f046b47dd78745336de3d",
         "chunk": "ac609ad56b00b2c614ea8a4fa6213d824257ad6a4729288f1f239a2cb5e8b999",
+    },
+    "nemotron3-tiny": {
+        "step": "1575d0d0d6c4a7bb3d020e0a6aa372cb7c48af6425e159304c4ff6ba49517cbf",
+        "step_g": "0063ce72f12593099783ca4c6861556922ee8004209fdd60e4465e06af75b8b4",
+        "chunk": "c3775e68172d09a2449a21d312f484c80f4fec94927087cee1838303005af0d2",
     },
 }
 
@@ -433,11 +444,12 @@ def program_texts(config):
 @pytest.fixture(scope="module")
 def lowered():
     shaped = get_config("tiny").with_(name="qwen2-shaped", qkv_bias=True, rope_theta=1e6, rms_eps=1e-6)
-    return {"qwen2-shaped": program_texts(shaped), "xing4-tiny": program_texts(get_config("xing4-tiny"))}
+    return {"qwen2-shaped": program_texts(shaped),
+            **{name: program_texts(get_config(name)) for name in ("xing4-tiny", "nemotron3-tiny")}}
 
 
 @pytest.mark.parametrize("program", ["step", "step_g", "chunk"])
-@pytest.mark.parametrize("model", ["qwen2-shaped", "xing4-tiny"])
+@pytest.mark.parametrize("model", sorted(PARENT_PROGRAMS))
 def test_other_models_loop_programs_are_the_parents(lowered, model, program):
     text = lowered[model][program]
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_PROGRAMS[model][program]

@@ -454,7 +454,7 @@ def test_published_preset_holds_the_published_sizes():
     ("nemotron3-nano-30b-a3b-cut9", 1 * 2 * 2 * 128 * 2, 4 * (64 * 64 * 128 * 4 + 3 * 6144 * 2)),
     ("nemotron3-tiny", 1 * 2 * 2 * 16 * 4, 4 * (8 * 16 * 32 * 4 + 3 * 256 * 4)),
     ("qwen2-7b", 2 * 28 * 512 * 2, 0),
-    ("xing4-tiny", 3 * 40 * 4, 0)])
+    ("xing4-tiny", 3 * 128 * 4, 0)])  # a latent row of 40 is stored in a whole tile of 128 lanes
 def test_pool_and_memory_model_count_paging_layers_and_the_rows_state(name, per_token, per_row):
     from k_llms_tpu.backends.tpu import HbmMemoryModel
     from k_llms_tpu.engine.paging import PagedKVPool

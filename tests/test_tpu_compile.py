@@ -57,3 +57,126 @@ def test_paged_decode_kernel_compiles_for_v5e(name, one_chip):
         column, column, rows, rows,
     ).compile()
     assert "paged_attention_decode" in compiled.as_text()
+
+
+# -- the latent page pool's movers: no program lays the whole pool out again ---------------
+#
+# (cache layers, flat slots) of each latent cell's pool: 1,249 / 1,281 pages of 64
+# (benchmark/configs/*.json `serve`, PERF.md section 4); a row is 576 lanes, stored 640.
+LATENT_POOLS = {
+    "xing4-29b-a4b": ("xing4-29b-a4b-cut7", 7, 79936),
+    "joyai-llm-flash": ("joyai-llm-flash-cut8", 9, 81984),
+}
+LOOP_WIDTH, LOOP_PROMPT, LOOP_NEW, CHUNK = 32, 2048, 64, 128
+
+
+def whole_pool_copies(text, layers, flat):
+    """`copy` / `transpose` results shaped like the whole pool, in either of its
+    views (the latent pool's V, of width 0, holds no byte and does not count)."""
+    import re
+
+    shapes = r"(?:%d,%d|%d)(?:,\d+)*\]" % (layers, flat, layers * flat)
+    found = re.findall(r"= \w+\[(" + shapes + r")\S* (?:copy|transpose)\(", text)
+    return [dims for dims in found if not dims.endswith(",0]")]
+
+
+def latent_mover(program, model, shape):
+    """(the repo's own mover, its arguments at the cell's sizes); the pool is argument 0."""
+    from k_llms_tpu.engine.paging import PagedKVPool, scatter_rows, write_drafted_rows
+    from k_llms_tpu.models.config import get_config
+    from k_llms_tpu.ops.attention import pool_gather, pool_index
+
+    preset, layers, flat = LATENT_POOLS[model]
+    config = get_config(preset)
+    _, width, _ = config.cache_widths
+    assert (config.paging_layers, width, config.pool_row_width) == (layers, 576, 640)
+    movers = PagedKVPool(config.with_(vocab_size=512), total_pages=2, page_size=64)  # a toy pool: its movers
+    pool_k = shape((layers, flat, 1, config.pool_row_width), jnp.bfloat16)
+    pool_v = shape((layers, flat, 1, 0), jnp.bfloat16)
+    slots = lambda *dims: shape(dims, jnp.int32)  # noqa: E731
+    if program == "chunk_scatter":
+        cols = shape((layers, CHUNK, 1, width), jnp.bfloat16)
+        return movers._scatter_fn, (pool_k, pool_v, cols, shape((layers, CHUNK, 1, 0), jnp.bfloat16), slots(CHUNK))
+    if program == "cow_copy":
+        return movers._copy_fn, (pool_k, pool_v, slots(LOOP_WIDTH * 64), slots(LOOP_WIDTH * 64))
+    drafting = config.num_nextn_predict_layers
+    stack = layers - drafting
+
+    def read_rows(pool_k, prefix_idx, gen_idx, count):
+        def layer(acc, number):  # what latent._attend_paged reads, every cache layer in turn
+            rows = [pool_gather(pool_k, pool_index(pool_k, number, idx), width) for idx in (prefix_idx, gen_idx)]
+            return acc + sum(jnp.sum(r.astype(jnp.float32), axis=1) for r in rows), None
+
+        return jax.lax.scan(layer, jnp.zeros((LOOP_WIDTH, width)), jnp.arange(count, dtype=jnp.int32))[0]
+
+    if program == "engine_loop":
+        # engine.py::_get_decode_loop on pages: the pool carried through a
+        # while loop, each turn the stack's gathers and scatter_rows at one
+        # generated position (a drafting model runs undrafted there, so its
+        # module's cache layer, the pool's last, is left alone).
+        def loop(pool_k, pool_v, prefix_idx, gen_idx, cols):
+            def turn(step, carry):
+                pool_k, pool_v, acc = carry
+                read = read_rows(pool_k, prefix_idx, gen_idx, stack)
+                slots_now = jax.lax.dynamic_index_in_dim(gen_idx, step, axis=1, keepdims=False)
+                k_cols = (cols + read[None, :, None, :]).astype(cols.dtype)
+                return scatter_rows(pool_k, pool_v, slots_now, k_cols, k_cols[..., :0]) + (acc + read,)
+
+            return jax.lax.fori_loop(0, LOOP_NEW, turn, (pool_k, pool_v, jnp.zeros((LOOP_WIDTH, width))))
+
+        return jax.jit(loop, donate_argnums=(0, 1)), (
+            pool_k, pool_v, slots(LOOP_WIDTH, LOOP_PROMPT), slots(LOOP_WIDTH, LOOP_NEW),
+            shape((stack, LOOP_WIDTH, 1, width), jnp.bfloat16))
+    assert program == "step"
+
+    def step(pool_k, pool_v, prefix_idx, gen_idx, cols, module_cols, write_idx):
+        read = read_rows(pool_k, prefix_idx, gen_idx, layers)
+        if drafting:
+            return write_drafted_rows(pool_k, cols, module_cols, write_idx), read
+        return scatter_rows(pool_k, pool_v, write_idx, cols, cols[..., :0])[0], read  # _build_step's write
+
+    sq = 1 + drafting
+    stack_cols = (stack, LOOP_WIDTH) + ((sq,) if drafting else ()) + (1, width)
+    return jax.jit(step, donate_argnums=(0,)), (
+        pool_k, pool_v, slots(LOOP_WIDTH, LOOP_PROMPT), slots(LOOP_WIDTH, LOOP_NEW + drafting * 2),
+        shape(stack_cols, jnp.bfloat16), shape((LOOP_WIDTH, sq, 1, width), jnp.bfloat16),
+        slots(LOOP_WIDTH, 3) if drafting else slots(LOOP_WIDTH))
+
+
+@pytest.mark.parametrize("program", ["step", "chunk_scatter", "cow_copy", "engine_loop"])
+@pytest.mark.parametrize("model", sorted(LATENT_POOLS))
+def test_latent_pool_movers_leave_the_pool_where_it_lies(model, program, one_chip):
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    fn, args = latent_mover(program, model, shape)
+    compiled = fn.lower(*args).compile()
+    _, layers, flat = LATENT_POOLS[model]
+    assert whole_pool_copies(compiled.as_text(), layers, flat) == []
+    pool_bytes = layers * flat * 640 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 10
+
+
+@pytest.mark.parametrize("form", ["layer_axis_576", "layer_axis_640", "flat_576"])
+def test_the_forms_this_pool_left_do_copy_the_whole_pool(form, one_chip):
+    """The same assertion bites on what the pool was (a 576-wide row under a
+    scatter along the layer axis) and on either half of the cure alone."""
+    from k_llms_tpu.ops.attention import pool_layers, pool_scatter
+
+    layers, flat = LATENT_POOLS["joyai-llm-flash"][1:]
+    stored = 640 if form.endswith("640") else 576
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def scatter(pool_k, cols, idx):
+        if form.startswith("layer_axis"):
+            cols = jnp.pad(cols, ((0, 0),) * 3 + ((0, stored - 576),))
+            return pool_k.at[:, idx].set(cols)
+        return pool_scatter(pool_k, pool_layers(pool_k, idx), cols)
+
+    compiled = jax.jit(scatter, donate_argnums=(0,)).lower(
+        shape((layers, flat, 1, stored), jnp.bfloat16), shape((layers, CHUNK, 1, 576), jnp.bfloat16),
+        shape((CHUNK,), jnp.int32)).compile()
+    assert whole_pool_copies(compiled.as_text(), layers, flat)
+    assert compiled.memory_analysis().temp_size_in_bytes > layers * flat * stored * 2 / 2

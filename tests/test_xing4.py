@@ -374,8 +374,9 @@ def test_published_preset_holds_the_published_sizes():
     assert cut.attn_scale == pytest.approx(192 ** -0.5 * (0.1 * np.log(64) + 1) ** 2)
 
 
-@pytest.mark.parametrize("name,per_token", [("xing4-29b-a4b-cut7", 7 * 576 * 2),
-                                            ("xing4-tiny", 3 * 40 * 4),
+# A latent row is stored in whole tiles of 128 lanes (576 -> 640, 40 -> 128): PR 35.
+@pytest.mark.parametrize("name,per_token", [("xing4-29b-a4b-cut7", 7 * 640 * 2),
+                                            ("xing4-tiny", 3 * 128 * 4),
                                             ("qwen2-7b", 2 * 28 * 512 * 2)])
 def test_pool_and_memory_model_count_the_models_own_cache_row(name, per_token):
     from k_llms_tpu.backends.tpu import HbmMemoryModel
@@ -389,7 +390,8 @@ def test_pool_and_memory_model_count_the_models_own_cache_row(name, per_token):
         pool = PagedKVPool(cfg.with_(vocab_size=512), total_pages=3, page_size=16)
         assert pool.pool_bytes() == 3 * 16 * per_token
         heads, k_width, v_width = cfg.cache_widths
-        assert pool.kv.k.shape[2:] == (heads, k_width) and pool.kv.v.shape[2:] == (heads, v_width)
+        assert pool.kv.k.shape[2:] == (heads, cfg.pool_row_width) and pool.kv.v.shape[2:] == (heads, v_width)
+        assert cfg.pool_row_width >= k_width and cfg.pool_row_width % 128 == 0
     rows = model.paged_max_rows(2048, 256, 64, fanout=8)
     assert rows == model.budget_bytes() // (
         4 * 64 * per_token + -(-32 * 64 * per_token // 8) + model.row_margin_bytes)
