@@ -69,8 +69,9 @@ import queue as _queue_mod
 import threading
 import time
 from collections import deque
+from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -430,7 +431,10 @@ class ContinuousDecodeLoop:
             "_pool",
             "_state",
             "_results_at",
+            "_gap_device_s",
             "_host_annotation",
+            "_host_opened_at",
+            "_active_s",
         )
         self.width = int(width)
         self.max_prompt = int(max_prompt)
@@ -473,8 +477,19 @@ class ContinuousDecodeLoop:
         # line, cleared by the worker where the loop pauses.
         # kllms: unguarded — the hand-off orders the step thread's write before the worker's accesses
         self._results_at: Optional[float] = None
+        # ``continuous.admit_device`` seconds since ``_results_at`` was last
+        # taken: what ``continuous.wait`` leaves out of the gap they fell in.
+        # kllms: unguarded — worker thread between hand-offs, the step thread inside one; the hand-off orders them
+        self._gap_device_s = 0.0
         # kllms: unguarded — the open ``continuous.host`` annotation; worker thread only
         self._host_annotation: Optional[Any] = None
+        # kllms: unguarded — host clock at which it was opened; worker thread only
+        self._host_opened_at = 0.0
+        # The worker's wall clock outside idle waits and recoveries, seconds:
+        # the ``continuous.host`` intervals and the hand-offs, summed as each
+        # ends (``stats["active_seconds"]``).
+        # kllms: unguarded — written by the worker thread alone; a reader may see the sum one interval late
+        self._active_s = 0.0
         # Epoch fence: bumped on every recovery; an abandoned step thread
         # waking into a newer epoch discards its work instead of committing
         # device state that belongs to a torn-down engine.
@@ -614,6 +629,7 @@ class ContinuousDecodeLoop:
             out["active_rows"] = active_rows
             out["occupancy"] = active_rows / self.width if self.width else 0.0
             out["queue_depth"] = len(self._queue)
+            out["active_seconds"] = self._active_s
             out["last_recovery_reason"] = self._last_recovery_reason
             if self._pool is not None:  # a paged loop, once built
                 if self._pool_fault is None:
@@ -1286,10 +1302,12 @@ class ContinuousDecodeLoop:
         loop's on the trace."""
         self._host_annotation = jax.profiler.TraceAnnotation("continuous.host")
         self._host_annotation.__enter__()
+        self._host_opened_at = time.perf_counter()
 
     def _close_host(self) -> None:
         annotation, self._host_annotation = self._host_annotation, None
         if annotation is not None:
+            self._active_s += time.perf_counter() - self._host_opened_at
             annotation.__exit__(None, None, None)
 
     def _pause_host_clock(self) -> None:
@@ -1297,23 +1315,70 @@ class ContinuousDecodeLoop:
         host keeping the device waiting, so neither clock charges it."""
         self._close_host()
         self._results_at = None
+        self._gap_device_s = 0.0
 
     def _observe_gap(self) -> None:
         """First line of a step's or chunk's dispatch: ``continuous.gap`` is
-        the host clock since the previous program's results reached the host."""
+        the host clock since the previous program's results reached the host,
+        and ``continuous.wait`` that gap less the admissions' device work
+        inside it (``continuous.admit_device``): the host clock's statement
+        that the chip had nothing to run."""
         results_at, self._results_at = self._results_at, None
+        device_s, self._gap_device_s = self._gap_device_s, 0.0
         if results_at is not None:
-            LATENCY.observe("continuous.gap", time.perf_counter() - results_at)
+            gap_s = time.perf_counter() - results_at
+            LATENCY.observe("continuous.gap", gap_s)
+            LATENCY.observe("continuous.wait", gap_s - device_s)
+
+    def _readback(self, outputs: Any) -> Any:
+        """A program's results brought to the host (the step thread under a
+        watchdog), ``continuous.readback``: the wait for the device to finish,
+        then ``continuous.fetch`` around the copy of the (already computed)
+        outputs, which ends where ``_results_at`` is set. What is left of the
+        readback ahead of the fetch is the device running; the fetch is the
+        device waiting for the host again."""
+        with LATENCY.span("continuous.readback"):
+            # Queue the copies behind the program first, as ``device_get``
+            # itself does: waiting for the program and only then asking for
+            # them would put a round trip between the two.
+            for leaf in jax.tree.leaves(outputs):
+                queue_copy = getattr(leaf, "copy_to_host_async", None)
+                if queue_copy is not None:
+                    queue_copy()
+            # kllms: ignore[host-sync-hot-path] — the by-design completion sync of a step or chunk, split from its copy so each is timed
+            jax.block_until_ready(outputs)
+            with LATENCY.span("continuous.fetch"):
+                # kllms: ignore[host-sync-hot-path] — the per-program result readback; everything after it is host-side bookkeeping
+                fetched = jax.device_get(outputs)
+                self._results_at = time.perf_counter()
+        return fetched
+
+    @contextmanager
+    def _admit_device_span(self) -> Iterator[None]:
+        """``continuous.admit_device`` round an admission's device work (lock
+        held, worker thread): from its first jitted call to the return of the
+        ``device_get`` that ends it, the host work in between included (it
+        overlaps the device). Its seconds are also taken out of the gap they
+        fall in, for ``continuous.wait``. A stretch that raised (no pages) is
+        no sample, but its seconds were still no wait."""
+        span = LATENCY.span("continuous.admit_device")
+        try:
+            with span:
+                yield
+        finally:
+            self._gap_device_s += span.seconds
 
     def _hand_off(self, dispatch: Callable[[], Any], what: str) -> Any:
         """Run one device program's ``dispatch`` closure: on the disposable
         step thread under the watchdog budget where the loop has a budget
         model, else inline. Returns ``(result, run_seconds)``, the latter None
-        inline."""
-        if self.budget_model is None:
-            LATENCY.observe("continuous.handoff", 0.0)
-            return dispatch(), None
+        inline. The call's wall clock, like a ``continuous.host`` interval's,
+        is the loop being active (``stats["active_seconds"]``)."""
+        t0 = time.perf_counter()
         try:
+            if self.budget_model is None:
+                LATENCY.observe("continuous.handoff", 0.0)
+                return dispatch(), None
             return self._dispatcher.run(dispatch, self.budget_model)
         except _StepHung:
             with self._lock:
@@ -1324,6 +1389,8 @@ class ContinuousDecodeLoop:
                 "dispatch thread and rebuilding", what,
             )
             raise
+        finally:
+            self._active_s += time.perf_counter() - t0
 
     # -- recovery ----------------------------------------------------------
 
@@ -1644,30 +1711,32 @@ class ContinuousDecodeLoop:
     def _admit_device(self, req, rows) -> None:
         engine = self.engine
         _ids, _plen, bucket = engine._prep_prompt(req.ids)
-        if self.paged:
-            # The prompt KV as shared, refcounted pool pages: the prefill's
-            # (or the cache entry's) page run, a reference for each row, and
-            # each row's generation reserve; PagePoolExhausted leaves here
-            # with everything rolled back.
-            first_logits, run, transient, lane_state = engine.paged_admit_prefix(
-                _ids, _plen, bucket
-            )
-            try:
-                with engine._paged_mutex:
-                    self._pages.admit(
-                        rows, run.pages, _plen, req.max_new,
-                        engine._alloc_pages_with_evict,
-                    )
-            finally:
-                if transient:
-                    # Uncached prefill: the run was a scratch owner of the
-                    # prompt pages; the rows' references now keep them alive.
-                    run.release()
-            self._install_state(rows, lane_state)
-        else:
-            first_logits, prefix = engine._prefill_routed(_ids, _plen, bucket)
-            self._dense.install(rows, prefix, bucket)
-        self._admit_rows(req, rows, first_logits)
+        with self._admit_device_span():
+            if self.paged:
+                # The prompt KV as shared, refcounted pool pages: the prefill's
+                # (or the cache entry's) page run, a reference for each row, and
+                # each row's generation reserve; PagePoolExhausted leaves here
+                # with everything rolled back.
+                first_logits, run, transient, lane_state = engine.paged_admit_prefix(
+                    _ids, _plen, bucket
+                )
+                try:
+                    with engine._paged_mutex:
+                        self._pages.admit(
+                            rows, run.pages, _plen, req.max_new,
+                            engine._alloc_pages_with_evict,
+                        )
+                finally:
+                    if transient:
+                        # Uncached prefill: the run was a scratch owner of the
+                        # prompt pages; the rows' references now keep them alive.
+                        run.release()
+                self._install_state(rows, lane_state)
+            else:
+                first_logits, prefix = engine._prefill_routed(_ids, _plen, bucket)
+                self._dense.install(rows, prefix, bucket)
+            first = self._sample_first(req, len(rows), first_logits)
+        self._admit_rows(req, rows, first)
 
     def _install_state(self, rows: List[int], lane_state: Dict[str, Any]) -> None:
         """Fork the prompt's final recurrent state (one row: the chunk lane's,
@@ -1682,15 +1751,14 @@ class ContinuousDecodeLoop:
             idx[: len(rows)] = rows
             self._state = _install_rows(self._state, lane_state, jnp.asarray(idx))
 
-    def _admit_rows(self, req, rows, first_logits) -> None:
-        """The layout-independent admission tail, shared by whole-prompt
-        admission and the chunked-prefill finish: sample each row's first
-        token from the prefill logits with the submission-pinned seed at
-        step 0 (so chunked-on/off token streams are byte-identical), install
-        the slot mirrors, and run first-step retirement/delivery."""
-        prompt_len = req.prompt_len
+    def _sample_first(self, req, n: int, first_logits) -> tuple:
+        """The device end of the admission tail, shared by whole-prompt
+        admission and the chunked-prefill finish (inside their
+        ``continuous.admit_device``): sample each of the request's ``n`` rows'
+        first token from the prefill logits with the submission-pinned seed at
+        step 0 (so chunked-on/off token streams are byte-identical) and bring
+        them to the host: ``(tok0, lp0, bad0, st0)``, each ``[n]``."""
         seed, temperature, top_p = req.seed, req.temperature, req.top_p
-        n = len(rows)
         # First-token sampling at admission (step 0), padded to W rows.
         W = self.width
         V = first_logits.shape[-1]
@@ -1724,7 +1792,17 @@ class ContinuousDecodeLoop:
         tok0, lp0, bad0, *st0 = (np.asarray(a)[:n] for a in jax.device_get(outs))
         if st0:
             GRAMMAR_EVENTS.record("grammar.masked_steps", n)
-        st0 = st0[0] if st0 else np.zeros((n,), np.int32)
+        return tok0, lp0, bad0, st0[0] if st0 else np.zeros((n,), np.int32)
+
+    def _admit_rows(self, req, rows, first: tuple) -> None:
+        """The host end of the admission tail: install the slot mirrors from
+        :meth:`_sample_first`'s tokens and run first-step retirement/delivery
+        (a drafting loop leaves each row its first draft in between, device
+        work of its own)."""
+        prompt_len = req.prompt_len
+        seed, temperature, top_p = req.seed, req.temperature, req.top_p
+        n = len(rows)
+        tok0, lp0, bad0, st0 = first
 
         quarantined = 0
         for j, slot in enumerate(rows):
@@ -1757,7 +1835,8 @@ class ContinuousDecodeLoop:
             if note is not None:
                 note(quarantined, n)
         if self._drafting:
-            self._admit_drafts(req, rows)
+            with self._admit_device_span():
+                self._admit_drafts(req, rows)
         # The rows are installed: the request's prefill_wall (everything since
         # its dequeue, other requests' steps and chunks included) ends here
         # and its decode_wall begins.
@@ -1900,10 +1979,7 @@ class ContinuousDecodeLoop:
                     pool.scatter_tokens(*cols, slot_idx)
             # Synchronize on the (tiny) logits readback so the watchdog
             # budget covers the device work, like the step's readback.
-            with LATENCY.span("continuous.readback"):
-                # kllms: ignore[host-sync-hot-path] — the per-chunk completion sync; the cache stays on device
-                _, aux = jax.device_get((logits, aux))
-            self._results_at = time.perf_counter()
+            _, aux = self._readback((logits, aux))
             note_model_aux(aux)
             return logits, new_cache, new_state
 
@@ -1945,8 +2021,9 @@ class ContinuousDecodeLoop:
         engine = self.engine
         req, rows = pf.req, pf.rows
         cached = getattr(engine, "prefix_cache_size", 0) > 0
-        self._install_state(rows, pf.state)
         if self.paged:
+            # The page books first: they are the host's alone, and the device
+            # has nothing to run until the programs below are launched.
             self._pages.install(rows, pf.run_pages, pf.reserved, pf.plen)
             if cached:
                 # The entry's reference is one more on the run.
@@ -1954,11 +2031,14 @@ class ContinuousDecodeLoop:
                     pf.ids, first_logits,
                     self._pages.prefix_run(pf.run_pages, pf.plen, pf.bucket),
                 )
-        else:
-            self._dense.install(rows, pf.cache, pf.bucket)
-            if cached:
-                engine._prefix_store(pf.ids, first_logits, pf.cache)
-        self._admit_rows(req, rows, first_logits)
+        with self._admit_device_span():
+            self._install_state(rows, pf.state)
+            if not self.paged:
+                self._dense.install(rows, pf.cache, pf.bucket)
+                if cached:
+                    engine._prefix_store(pf.ids, first_logits, pf.cache)
+            first = self._sample_first(req, len(rows), first_logits)
+        self._admit_rows(req, rows, first)
 
     def _retire_prefilling_locked(
         self, exc: BaseException, abort: bool = False
@@ -1987,48 +2067,55 @@ class ContinuousDecodeLoop:
 
     def _step_once(self) -> None:
         with LATENCY.span("continuous.prepare"):
+            # The span's three parts, each a span of its own: the wait for the
+            # loop lock (submitters hold it), the page books, the uploads.
+            lock_wait = LATENCY.span("continuous.lock_wait")
+            lock_wait.__enter__()
             with self._lock:
+                lock_wait.__exit__(None, None, None)
                 epoch = self._loop_epoch
                 step_no = self._stats["steps"]
-                row_args = tuple(map(jnp.asarray, (
-                    self._cur, self._gen_lens, self._prompt_lens,
-                    self._active_mask, self._seeds, self._sample_idx,
-                    self._temps, self._top_ps,
-                )))
-                if self._drafting:
-                    # len(tokens) is gen_lens + 1: room for two more.
-                    room = self._gen_lens + 3 <= self._max_news
-                    row_args += (jnp.asarray(self._draft), jnp.asarray(room))
                 live_rows = np.flatnonzero(self._active_mask)
                 # Grammar twins run only when a constrained row is live: steps
                 # with no grammar work dispatch the ORIGINAL programs, so the
                 # unconstrained loop stays byte-identical (and
                 # program-identical).
                 n_masked = int((self._g_flags & self._active_mask).sum())
-                step_fn, grammar_args = self._step_fn, ()
-                if n_masked:
-                    step_fn = self._grammar_programs()["step"]
-                    grammar_args = (
-                        jnp.asarray(self._g_states), jnp.asarray(self._g_flags),
-                        *self._g_tabs(),
-                    )
-                # A loop with page books keeps them here: table growth and
-                # copy-on-write for the rows' next write, which yield the
-                # step's index arguments.
+                step_fn = self._grammar_programs()["step"] if n_masked else self._step_fn
+                # A loop with page books keeps them here, ahead of the uploads:
+                # table growth and copy-on-write for the rows' next write,
+                # which yield the step's index arguments (host arrays still).
                 pool, dense, state = self._pool, self._dense, self._state
-                layout_idx: tuple = ()
+                layout_np: tuple = ()
                 pages = None
                 if self._pages is not None:
-                    lens = (self._active_mask, self._prompt_lens, self._gen_lens)
-                    layout_idx = tuple(
-                        map(jnp.asarray, self._pages.prepare_step(*lens))
-                    )
-                    # The fused kernel's walk, counted here where the
-                    # lengths are coherent (the XLA path gathers whole tables).
-                    if self._paged_attn_impl != "xla":
-                        pages = self._pages.walk_counts(
-                            *lens, window=self.engine.config.sliding_window
+                    with LATENCY.span("continuous.pages"):
+                        lens = (self._active_mask, self._prompt_lens, self._gen_lens)
+                        layout_np = self._pages.prepare_step(*lens)
+                        # The fused kernel's walk, counted here where the
+                        # lengths are coherent (the XLA path gathers whole tables).
+                        if self._paged_attn_impl != "xla":
+                            pages = self._pages.walk_counts(
+                                *lens, window=self.engine.config.sliding_window
+                            )
+                # Every host array the step takes goes up in this one block.
+                with LATENCY.span("continuous.stage"):
+                    row_args = tuple(map(jnp.asarray, (
+                        self._cur, self._gen_lens, self._prompt_lens,
+                        self._active_mask, self._seeds, self._sample_idx,
+                        self._temps, self._top_ps,
+                    )))
+                    if self._drafting:
+                        # len(tokens) is gen_lens + 1: room for two more.
+                        room = self._gen_lens + 3 <= self._max_news
+                        row_args += (jnp.asarray(self._draft), jnp.asarray(room))
+                    grammar_args: tuple = ()
+                    if n_masked:
+                        grammar_args = (
+                            jnp.asarray(self._g_states), jnp.asarray(self._g_flags),
+                            *self._g_tabs(),
                         )
+                    layout_idx = tuple(map(jnp.asarray, layout_np))
             # All-False in production; with an active ``engine.logits`` nan
             # failpoint, a seeded subset of the LIVE rows is poisoned — the
             # loop-scoped twin of the batch path's first-step injection.
@@ -2085,10 +2172,7 @@ class ContinuousDecodeLoop:
             # the sampled token ids on the host, and it runs outside both
             # locks (advanced grammar states ride the same fetch).
             outs = (tok, lp, bad, *new_g)
-            with LATENCY.span("continuous.readback"):
-                # kllms: ignore[host-sync-hot-path] — the per-step result readback; everything after it is host-side bookkeeping
-                fetched, aux = jax.device_get((outs, aux))
-            self._results_at = time.perf_counter()
+            fetched, aux = self._readback((outs, aux))
             note_model_aux(aux)
             return list(map(np.asarray, fetched))
 

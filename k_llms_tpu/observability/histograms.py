@@ -207,6 +207,27 @@ class LatencyHistograms:
 #: jitted call until it returns) and ``continuous.readback`` (the
 #: ``device_get``: host blocked, device busy); ``continuous.idle`` — the
 #: worker's wait when it has neither a live row nor a PREFILLING admission.
+#: Whose wait each millisecond of a gap is (ISSUE 36). Inside
+#: ``continuous.prepare``, its three parts, a span each and one sample a step
+#: each: ``continuous.lock_wait`` (``_step_once`` entry until the loop lock is
+#: held: submitters and ``stats`` readers hold it), ``continuous.pages`` (the
+#: page books' ``prepare_step`` and ``walk_counts``: table growth,
+#: copy-on-write, the step's index arrays still on the host; no sample for a
+#: dense loop) and ``continuous.stage`` (every ``jnp.asarray`` of the step in
+#: one block: the row arrays, a drafting loop's draft and room, the grammar
+#: states and flags, the page books' index arrays). Inside
+#: ``continuous.admit``: ``continuous.admit_device`` — an admission's device
+#: work, from its first jitted call (the prefill, or the state install and the
+#: first-token program where the last chunk finishes a prompt) to the return
+#: of the ``device_get`` that ends it, host work in between included; a
+#: drafting loop's first drafts leave a second sample an admission.
+#: ``continuous.wait`` (observe only, one sample a ``continuous.gap``) — the
+#: gap less the ``admit_device`` seconds that fell inside it: the host clock's
+#: "the chip had nothing to run". Inside ``continuous.readback``, after the
+#: outputs' copies are queued and ``block_until_ready`` has returned:
+#: ``continuous.fetch`` — the ``device_get`` of results the device has
+#: finished, ending where the next gap begins; the readback ahead of it is the
+#: device running (or not yet begun).
 #: ``continuous.state_install`` — an admission's copy of the prefill lane's
 #: recurrent state into the request's rows (no sample for a model without
 #: such state).
@@ -220,13 +241,19 @@ LATENCY = LatencyHistograms(declared=(
     "continuous.step",
     "continuous.prefill_chunk",
     "continuous.gap",
+    "continuous.wait",
     "continuous.prepare",
+    "continuous.lock_wait",
+    "continuous.pages",
+    "continuous.stage",
     "continuous.handoff",
     "continuous.dispatch",
     "continuous.readback",
+    "continuous.fetch",
     "continuous.bookkeep",
     "continuous.emit",
     "continuous.admit",
+    "continuous.admit_device",
     "continuous.state_install",
     "continuous.idle",
     "continuous.prefill_wall",

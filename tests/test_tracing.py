@@ -27,8 +27,10 @@ The load-bearing pins:
 
 import asyncio
 import glob
+import importlib
 import os
 import re
+import sys
 import threading
 import time
 from types import SimpleNamespace
@@ -204,6 +206,14 @@ def test_metrics_scrape_validity():
         "kllms_consensus_consolidate_seconds",
     ):
         assert fam in hists, fam
+    # PR 36's families are declared exactly, so a scrape holds them (full
+    # triples, checked below with the rest) before any loop has run: a group
+    # of the same vocabulary exports them at zero samples.
+    fresh = LatencyHistograms(declared=LATENCY.declared).snapshot()
+    for span in ("wait", "lock_wait", "stage", "pages", "admit_device", "fetch"):
+        assert f"kllms_continuous_{span}_seconds" in hists, span
+        assert fresh[f"continuous.{span}"]["count"] == 0
+        assert fresh[f"continuous.{span}"]["buckets"][-1][1] == 0
     for fam, data in hists.items():
         # Labeled families (per-tenant *_by_tenant_seconds) carry one full
         # _bucket/_sum/_count triple PER label value: group by the non-le
@@ -383,6 +393,13 @@ def eng():
     return shared_engine(model="tiny")
 
 
+@pytest.fixture(scope="module")
+def paged_eng():
+    from conftest import shared_engine
+
+    return shared_engine(model="tiny", kv_layout="paged", kv_page_size=16)
+
+
 def test_rebuild_replay_yields_one_flight_record_annotated(eng):
     """Epoch safety: a request that survives a hung step, engine rebuild,
     and journal replay finishes with EXACTLY ONE flight record for its
@@ -487,6 +504,8 @@ LOOP_FAMILIES = (
     "gap", "prepare", "handoff", "bookkeep", "admit", "emit", "idle",
     "dispatch", "readback", "step", "prefill_chunk", "prefill_wall",
     "decode_wall",
+    # PR 36: whose wait each part of a gap is.
+    "wait", "lock_wait", "stage", "pages", "admit_device", "fetch",
 )
 
 
@@ -513,13 +532,16 @@ def _submit_mix(loop, sink):
     return [f.result(timeout=120) for f in futs]
 
 
-def test_loop_spans_split_the_gap(eng):
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_loop_spans_split_the_gap(eng, paged_eng, layout):
     """A run of streamed and unstreamed requests through the watchdogged
     loop: every step leaves one prepare, one bookkeep and one hand-off, every
     program but the first after a pause leaves a gap, and the four parts of
-    the gap add up to it."""
+    the gap add up to it. Since PR 36 the prepare splits into the lock's wait,
+    the page books and the uploads, a readback ends in a fetch, and the gap
+    less the admissions' device work is the wait."""
     loop = ContinuousDecodeLoop(
-        eng, width=4, max_prompt=64, max_new=32, prefill_chunk_tokens=32,
+        paged_eng if layout == "paged" else eng, width=4, max_prompt=64, max_new=32, prefill_chunk_tokens=32,
         budget_model=_step_budget(60.0),
     )
     sunk = []
@@ -555,6 +577,39 @@ def test_loop_spans_split_the_gap(eng):
     parts = sum(total[n] for n in ("prepare", "handoff", "bookkeep", "admit"))
     assert total["gap"] > 0
     assert abs(parts - total["gap"]) <= 0.35 * total["gap"], (total, count)
+    # The prepare's three parts, one of each a step (page books are the paged
+    # loop's alone), and they account for it to within the lines between them.
+    assert count["lock_wait"] == count["stage"] == steps
+    assert count["pages"] == (steps if layout == "paged" else 0)
+    prepare_parts = sum(total[n] for n in ("lock_wait", "stage", "pages"))
+    assert prepare_parts <= total["prepare"]
+    assert prepare_parts >= 0.8 * total["prepare"], (total, count)
+    assert total["lock_wait"] <= total["stage"]  # nobody holds the lock here
+    # A fetch a readback, the tail of it.
+    assert count["fetch"] == count["readback"]
+    assert 0 < total["fetch"] <= total["readback"]
+    # An admission's device work: once for each whole-prompt admission and for
+    # each prompt's last chunk, inside the admit span.
+    assert count["admit_device"] == 4
+    assert 0 < total["admit_device"] <= total["admit"]
+    # The wait is the gap less the device work inside it: a sample a gap, and
+    # with the device work it gives the gap back. What it may add is an
+    # admission ahead of the first program after a pause, which no gap covers.
+    # Stated tolerance: 10 %.
+    assert count["wait"] == count["gap"]
+    assert 0 < total["wait"] <= total["gap"]
+    both = total["wait"] + total["admit_device"]
+    assert total["gap"] - 1e-6 <= both <= 1.1 * total["gap"], (total, count)
+    # The loop's active clock holds the programs and the waits between them.
+    # A hand-off lies in both (inside its program's span and inside the gap
+    # round it), so it is taken out once; what the active clock has beyond
+    # that is the admissions' device work and the bookkeeping ahead of a
+    # pause. Slack: the few lines a program between one clock and the other.
+    active = stats1["active_seconds"] - stats0["active_seconds"]
+    programs = total["step"] + total["prefill_chunk"]
+    assert active >= programs, (active, total)
+    assert active + 0.05 * programs >= programs + total["wait"] - total["handoff"], (
+        active, total)
     # The sinks run inside the bookkeeping (the first delta's inside admit).
     assert count["emit"] >= len(sunk) - sunk0 > 0
     assert total["emit"] <= total["bookkeep"]
@@ -601,9 +656,10 @@ def _host_event_names(log_dir):
 
 
 @pytest.mark.parametrize("python_tracer", [False, True])
-def test_debug_profile_capture_holds_the_loops_spans(eng, tmp_path, python_tracer):
+def test_debug_profile_capture_holds_the_loops_spans(paged_eng, tmp_path, python_tracer):
     """A ``/debug/profile`` capture taken while the loop decodes holds the
-    loop's spans under their plain names on a host plane. The Python tracer
+    loop's spans under their plain names on a host plane (a paged loop's: the
+    page books' span is one of them). The Python tracer
     is off unless the payload asks for it: its one-event-per-call frames
     (``$file.py:line function``) slow the traced threads enough to misread the
     device's idle share."""
@@ -611,7 +667,7 @@ def test_debug_profile_capture_holds_the_loops_spans(eng, tmp_path, python_trace
     client.backend.backend_config = SimpleNamespace(debug_endpoints=True)
     app = ServingApp(client)
     loop = ContinuousDecodeLoop(
-        eng, width=4, max_prompt=64, max_new=32, budget_model=_step_budget(60.0),
+        paged_eng, width=4, max_prompt=64, max_new=32, budget_model=_step_budget(60.0),
     )
     stop = threading.Event()
 
@@ -646,7 +702,9 @@ def test_debug_profile_capture_holds_the_loops_spans(eng, tmp_path, python_trace
     assert resp.json()["python_tracer"] is python_tracer
     assert resp.json()["host_tracer_level"] == 2
     names = _host_event_names(str(tmp_path))
-    for span in ("step", "prepare", "dispatch", "readback", "bookkeep", "emit", "host"):
+    for span in ("step", "prepare", "dispatch", "readback", "bookkeep", "emit", "host",
+                 # PR 36: the parts, under their own names.
+                 "lock_wait", "stage", "pages", "admit_device", "fetch"):
         assert f"continuous.{span}" in names, sorted(n for n in names if "continuous" in n)
     python_frames = {n for n in names if n.startswith("$")}
     assert bool(python_frames) is python_tracer, sorted(python_frames)[:5]
@@ -665,6 +723,125 @@ def test_debug_profile_rejects_bad_options():
             ]
 
     assert [r.status_code for r in _run(go())] == [400, 400]
+
+
+# -- what the benchmark makes of the new spans (PR 36) ---------------------------------
+
+
+def _benchmark_module(name):
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    try:
+        return importlib.import_module(name)
+    finally:
+        sys.path.pop(0)
+
+
+_MS = 1_000_000
+
+
+def _planes(host_events):
+    """A device that ran 0–10 ms and 20–30 ms (one idle gap of 10 ms) beside
+    the given host events, as ``reduce_trace.reduce`` takes them."""
+    return {
+        "/device:TPU:0": {"XLA Ops": [("%a = f32[] add()", 0, 10 * _MS),
+                                      ("%a = f32[] add()", 20 * _MS, 30 * _MS)]},
+        "/host:CPU": {"kllms-continuous": host_events},
+    }
+
+
+@pytest.mark.parametrize("children, owner", [
+    # A child that covers 60 % of the gap by itself takes it from its parent.
+    ([("continuous.stage", 11 * _MS, 17 * _MS)], "continuous.stage"),
+    # Three children of a third each: none covers half, the parent keeps it.
+    ([("continuous.lock_wait", 10 * _MS, 13 * _MS), ("continuous.pages", 13 * _MS, 16 * _MS),
+      ("continuous.stage", 16 * _MS, 19 * _MS)], "continuous.prepare"),
+    # Exactly half is enough, and of two that qualify the shorter wins.
+    ([("continuous.pages", 10 * _MS, 15 * _MS), ("continuous.stage", 13 * _MS, 19 * _MS)],
+     "continuous.pages"),
+    # A fetch that is the tail of its readback owns the gap after the device
+    # finished; the readback alone would have.
+    ([("continuous.readback", 2 * _MS, 18 * _MS), ("continuous.fetch", 10 * _MS, 18 * _MS)],
+     "continuous.fetch"),
+    # A lock's wait is a span of ours, not one of the waits the reduction
+    # skips by its last word ("acquire", "wait", ...).
+    ([("continuous.lock_wait", 10 * _MS, 19 * _MS)], "continuous.lock_wait"),
+    # No child at all: as before this PR.
+    ([], "continuous.prepare"),
+], ids=["one_child_60", "three_thirds", "half_and_shorter", "fetch_in_readback",
+        "lock_wait_is_no_skipped_wait", "no_child"])
+def test_nested_spans_narrow_an_idle_gaps_owner_and_never_widen_it(children, owner):
+    """The contract the nesting relies on (``benchmark/reduce_trace.py``, the
+    benchmark's: only read here): a gap goes to the shortest host event that
+    covers half of it, so a span inside ``continuous.prepare`` wins a gap only
+    when it covers half of it by itself, and else leaves it where it was."""
+    reduce_trace = _benchmark_module("reduce_trace")
+    host = [("continuous.host", 0, 30 * _MS), ("continuous.prepare", 10 * _MS, 20 * _MS)]
+    out = reduce_trace.reduce(_planes(host + children))
+    assert out["gaps"] == 1 and out["gap_s"] == pytest.approx(0.010)
+    assert out["breakdown"]["idle_gaps"] == [[owner, pytest.approx(0.010)]]
+
+
+NEW_LOOP_METRICS = (
+    "loop_wait_ms", "host_wait_share", "loop_admit_device_ms", "loop_lock_wait_ms",
+    "loop_stage_ms", "loop_pages_ms", "loop_launch_ms", "loop_fetch_ms",
+)
+
+
+def test_new_loop_metrics_read_what_they_say():
+    """Each new ``read`` through the benchmark's own evaluator on a canned
+    pair of scrapes; the manifest stays without a fault; a program without the
+    families (this PR's parent) yields nothing and does not raise."""
+    run = _benchmark_module("run")
+    assert run.check_manifest() == []
+    manifest = run.load_json(run.ROOT, "BENCHMARK.json")
+    kernel_cells = {"qwen2-7b.extract", "qwen2-7b.chat", "mistral-7b.chat",
+                    "nemotron3-nano-30b-a3b.chat"}
+    for w in manifest["workloads"]:
+        names = {m["name"] for m in run.load_cell(w["name"])[5]}
+        assert set(NEW_LOOP_METRICS) <= names, w["name"]
+        assert ("paged_attn_walked_share" in names) is (w["name"] in kernel_cells)
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    assert all(by_name[n]["moves"] == "tokens_per_s" for n in NEW_LOOP_METRICS)
+    assert by_name["loop_pages_ms"]["layer"] == "KV page manager"
+    assert by_name["host_wait_share"]["layer"] == "Device"
+    # What they add must lie at the end: nothing that was there moved.
+    assert [m["name"] for m in manifest["per_layer"]][-9:] == [
+        *NEW_LOOP_METRICS, "paged_attn_walked_share"]
+
+    def sums(**seconds):
+        return {f"kllms_continuous_{k}_seconds_sum": v for k, v in seconds.items()}
+
+    start = dict(sums(wait=1.0, admit_device=0.5, lock_wait=0.01, stage=0.4, pages=0.2,
+                      dispatch=0.3, fetch=0.1),
+                 kllms_continuous_steps=100.0, kllms_continuous_active_seconds=10.0,
+                 kllms_continuous_dispatch_seconds_count=150.0,
+                 kllms_continuous_fetch_seconds_count=150.0,
+                 kllms_paged_attn_pages_walked=1000.0, kllms_paged_attn_pages_tabled=10000.0)
+    end = dict(sums(wait=6.0, admit_device=1.5, lock_wait=0.03, stage=2.4, pages=1.2,
+                    dispatch=1.8, fetch=0.7),
+               kllms_continuous_steps=1100.0, kllms_continuous_active_seconds=50.0,
+               kllms_continuous_dispatch_seconds_count=1650.0,
+               kllms_continuous_fetch_seconds_count=1650.0,
+               kllms_paged_attn_pages_walked=101000.0, kllms_paged_attn_pages_tabled=410000.0)
+    src = {"metrics_start": start, "metrics_end": end}
+    read = {n: run.load_json(run.HERE, "layer_metrics", n + ".json")["read"]
+            for n in (*NEW_LOOP_METRICS, "paged_attn_walked_share")}
+    values = {n: run.evaluate(r, src) for n, r in read.items()}
+    assert values == {
+        "loop_wait_ms": pytest.approx(5.0), "host_wait_share": pytest.approx(12.5),
+        "loop_admit_device_ms": pytest.approx(1.0), "loop_lock_wait_ms": pytest.approx(0.02),
+        "loop_stage_ms": pytest.approx(2.0), "loop_pages_ms": pytest.approx(1.0),
+        "loop_launch_ms": pytest.approx(1.0), "loop_fetch_ms": pytest.approx(0.4),
+        "paged_attn_walked_share": pytest.approx(25.0),
+    }
+    # The parent: no such families, no such gauge; the kernel's counters at
+    # zero where the XLA path serves. Left out, never zero, never an error.
+    bare = {"metrics_start": {"kllms_continuous_steps": 100.0},
+            "metrics_end": {"kllms_continuous_steps": 1100.0,
+                            "kllms_paged_attn_pages_walked": 0.0,
+                            "kllms_paged_attn_pages_tabled": 0.0}}
+    assert all(run.evaluate(r, bare) is None for r in read.values())
 
 
 def test_paged_step_program_carries_scope_names():
