@@ -17,6 +17,11 @@ Design:
   (``_DenseSlots``). ONE step body (``_build_step``) advances all W slots
   regardless of which request each row belongs to; its per-row ``lengths``
   write offsets are exactly the mid-flight join primitive.
+- A step's host inputs are ONE array (``_pack_step`` / ``_unpack_step``): a
+  snapshot of the slot mirrors, a column each, then for a paged loop the
+  rows' write slots and block tables, which the program spreads into gather
+  indices itself (``paging.expand_tables``). The host mirrors stay the only
+  truth; admission, retirement, quarantine and replay write them alone.
 - Sampling is a per-ROW array sampler (temperature[W] / top_p[W]) so requests
   with different sampling configs share the batch — the coalescing scheduler's
   batch_key compatibility restriction disappears. temperature 0 is greedy per
@@ -71,7 +76,7 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Iterator, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -118,6 +123,7 @@ from .paging import (
     PageAccountingError,
     PagePoolExhausted,
     SlotPages,
+    expand_tables,
     scatter_rows,
     write_drafted_rows,
 )
@@ -133,6 +139,47 @@ _install_rows = jax.jit(
     ),
     donate_argnums=(0,),
 )
+
+
+class _StepRows(NamedTuple):
+    """What a decode step's one host array holds, unpacked: the slot mirrors
+    ``[W]`` as the programs took them one by one before, then ``write_idx``
+    ``[W, 1 + lookahead]`` and ``tables`` ``[W, T]`` (both zero columns wide in
+    a dense loop). ``draft`` and ``room`` are None unless the loop drafts."""
+
+    cur: Any
+    gen_lens: Any
+    prompt_lens: Any
+    active: Any
+    seeds: Any
+    sample_idx: Any
+    temps: Any
+    top_ps: Any
+    g_states: Any
+    g_flags: Any
+    draft: Any
+    room: Any
+    write_idx: Any
+    tables: Any
+
+
+def _unpack_step(packed, drafting: bool, writes: int) -> _StepRows:
+    """The columns of ``ContinuousDecodeLoop._pack_step``'s ``int32 [W, C]``
+    array, by static slices (traceable): uint32 and float32 mirrors come back
+    by their bits, flags as ``!= 0``, so every value is the mirror's own."""
+    def bits(column, dtype):
+        return jax.lax.bitcast_convert_type(packed[:, column], dtype)
+
+    named = 12 if drafting else 10
+    return _StepRows(
+        cur=packed[:, 0], gen_lens=packed[:, 1], prompt_lens=packed[:, 2],
+        active=packed[:, 3] != 0, seeds=bits(4, jnp.uint32), sample_idx=packed[:, 5],
+        temps=bits(6, jnp.float32), top_ps=bits(7, jnp.float32),
+        g_states=packed[:, 8], g_flags=packed[:, 9] != 0,
+        draft=packed[:, 10] if drafting else None,
+        room=packed[:, 11] != 0 if drafting else None,
+        write_idx=packed[:, named:named + writes], tables=packed[:, named + writes:],
+    )
 
 
 @dataclass
@@ -588,6 +635,11 @@ class ContinuousDecodeLoop:
         self._stats: Dict[str, Any] = {
             "steps": 0,
             "row_steps": 0,
+            # Host -> device arrays, and their bytes, that the decode steps'
+            # stages sent (one packed array a step; the ``engine.logits``
+            # failpoint's mask is a second).
+            "stage_uploads": 0,
+            "stage_bytes": 0,
             "admitted": 0,
             "joined_in_flight": 0,
             "completed": 0,
@@ -917,10 +969,11 @@ class ContinuousDecodeLoop:
         slots; the four programs that come out keep the names every profile
         and the ledger's breakdown know them by: ``_step``, ``_step_paged``,
         ``_step_g``, ``_step_paged_g``. Arguments: ``(params, *layout_state,
-        *row_args, *layout_idx, poison, *grammar_args, state=...)``; results
-        ``(tok, lp, bad, *new_kv[, g_states], aux, state)``. ``state`` is the
-        rows' recurrent state, by keyword (an empty dict, so no operand, for
-        most models)."""
+        packed, poison, *grammar_tables, state=...)``, ``packed`` the step's
+        one host array (:meth:`_pack_step`; a plain program ignores its two
+        grammar columns); results ``(tok, lp, bad, *new_kv[, g_states], aux,
+        state)``. ``state`` is the rows' recurrent state, by keyword (an empty
+        dict, so no operand, for most models)."""
         if self._drafting:
             return self._build_drafted_step(grammar)
         config = self.engine.config
@@ -932,11 +985,12 @@ class ContinuousDecodeLoop:
             apply_mask, advance = self._grammar_ops()
         if paged:
             attn_impl, page_size = self._paged_attn_impl, self._pool.page_size
-        n_idx = 3 if paged else 0
+            spread = (page_size, self.max_prompt, self._pages.gen_idx.shape[1])
 
-        def _body(params, kv_a, kv_b, cur, gen_lens, prompt_lens, active,
-                  seeds, sample_idx, temps, top_ps, *rest, state=None):
-            layout_idx, (poison, *g_args) = rest[:n_idx], rest[n_idx:]
+        def _body(params, kv_a, kv_b, packed, poison, *tabs, state=None):
+            rows = _unpack_step(packed, False, 1 if paged else 0)
+            cur, gen_lens, prompt_lens, active = (
+                rows.cur, rows.gen_lens, rows.prompt_lens, rows.active)
             # ``aux``: what the model's stack counts (router loads, cache
             # rows read: utils/observability.py::note_model_aux adds them at
             # readback); empty for a model that counts nothing.
@@ -948,21 +1002,21 @@ class ContinuousDecodeLoop:
                 # flat slot. Same masks, same sampler, same key schedule —
                 # byte-identical tokens to the dense layout.
                 pool_k, pool_v = kv_a, kv_b
-                prefix_idx, gen_idx, write_idx = layout_idx
                 # A retired slot keeps its last tenant's lengths on the host;
                 # the model is given none for it, so the paged kernel walks
                 # no page of an idle row (its output is discarded below
-                # either way).
+                # either way), and its empty table spreads as from length 0.
+                plens = jnp.where(active, prompt_lens, 0)
+                prefix_idx, gen_idx = expand_tables(rows.tables, plens, *spread)
                 logits, k_cols, v_cols = paged_verify_step(
                     config, params, cur[:, None],
-                    jnp.where(active, gen_lens, 0),
-                    jnp.where(active, prompt_lens, 0),
+                    jnp.where(active, gen_lens, 0), plens,
                     KVCache(k=pool_k, v=pool_v), prefix_idx, gen_idx,
                     attn_impl=attn_impl, page_size=page_size,
                     mesh=mesh, aux=aux, state=state, active=active,
                 )
                 with jax.named_scope("kv_write"):
-                    new_kv = scatter_rows(pool_k, pool_v, write_idx, k_cols, v_cols)
+                    new_kv = scatter_rows(pool_k, pool_v, rows.write_idx[:, 0], k_cols, v_cols)
             else:
                 # Write cur's KV at each row's own offset (gen_lens), attend
                 # row-local prefix + generated KV (``verify_step`` with Sq=1:
@@ -981,10 +1035,10 @@ class ContinuousDecodeLoop:
             )
             logits = mask_pad(logits)
             if grammar:
-                g_states, g_flags, *tabs = g_args
+                g_states, g_flags = rows.g_states, rows.g_flags
                 logits = apply_mask(logits, g_states, g_flags, tabs)
-            keys = row_keys(seeds, gen_lens + 1, sample_idx)
-            tok, lp, bad = sample_rows(logits, keys, temps, top_ps)
+            keys = row_keys(rows.seeds, gen_lens + 1, rows.sample_idx)
+            tok, lp, bad = sample_rows(logits, keys, rows.temps, rows.top_ps)
             tok = jnp.where(active, tok, jnp.int32(pad_id))
             lp = jnp.where(active, lp, 0.0)
             out = (tok, lp, bad & active) + new_kv
@@ -1005,9 +1059,9 @@ class ContinuousDecodeLoop:
     def _build_drafted_step(self, grammar: bool):
         """The decode step of a model with a next-token module (paged layout
         only): ``_step_paged_mtp`` / ``_step_paged_mtp_g``. Arguments as
-        :meth:`_build_step`'s with two more row arrays behind ``top_ps``,
-        ``draft`` [W] and ``room`` [W] (the row's ``max_tokens`` leaves room
-        for two), and ``write_idx`` [W, 3]: positions P, P+1, P+2. Results
+        :meth:`_build_step`'s; the packed array carries two more columns,
+        ``draft`` and ``room`` (the row's ``max_tokens`` leaves room for two),
+        and three write slots a row: positions P, P+1, P+2. Results
         ``(toks [W, 2], lps [W, 2], bad, pool_k, pool_v[, g_states], emitted
         [W], next draft [W], aux, state)``."""
         config = self.engine.config
@@ -1018,14 +1072,21 @@ class ContinuousDecodeLoop:
             apply_mask, advance = self._grammar_ops()
         attn_impl, page_size = self._paged_attn_impl, self._pool.page_size
         eos_arr = jnp.asarray(self.eos_ids, jnp.int32)
+        writes = 1 + self._pages.lookahead
+        spread = (page_size, self.max_prompt, self._pages.gen_idx.shape[1])
 
-        def _body(params, pool_k, pool_v, cur, gen_lens, prompt_lens, active,
-                  seeds, sample_idx, temps, top_ps, draft, room,
-                  prefix_idx, gen_idx, write_idx, poison, *g_args, state=None):
+        def _body(params, pool_k, pool_v, packed, poison, *tabs, state=None):
+            rows = _unpack_step(packed, True, writes)
+            cur, gen_lens, prompt_lens, active = (
+                rows.cur, rows.gen_lens, rows.prompt_lens, rows.active)
+            seeds, sample_idx, temps, top_ps = (
+                rows.seeds, rows.sample_idx, rows.temps, rows.top_ps)
+            draft, room = rows.draft, rows.room
             aux: Dict[str, Any] = {}
             state = dict(state or {})
             lens = jnp.where(active, gen_lens, 0)
             plens = jnp.where(active, prompt_lens, 0)
+            prefix_idx, gen_idx = expand_tables(rows.tables, plens, *spread)
             pool = KVCache(k=pool_k, v=pool_v)
             # The stack over both positions: the draft's row attends cur's
             # fresh latent beside the pages.
@@ -1037,7 +1098,7 @@ class ContinuousDecodeLoop:
             logits = jnp.where(poison[:, None, None], jnp.float32(jnp.nan), logits)
             after_cur, after_draft = mask_pad(logits[:, 0]), mask_pad(logits[:, 1])
             if grammar:
-                g_states, g_flags, *tabs = g_args
+                g_states, g_flags = rows.g_states, rows.g_flags
                 drafted = advance(draft, g_states, g_flags, tabs)
                 after_cur = apply_mask(after_cur, g_states, g_flags, tabs)
                 after_draft = apply_mask(after_draft, drafted, g_flags, tabs)
@@ -1067,7 +1128,7 @@ class ContinuousDecodeLoop:
                 mlogits = apply_mask(mlogits, g_next, g_flags, tabs)
                 out_g = (g_next,)
             next_draft = jnp.argmax(mlogits, axis=-1).astype(jnp.int32)
-            pool_k = write_drafted_rows(pool_k, k_cols, m_cols, write_idx)
+            pool_k = write_drafted_rows(pool_k, k_cols, m_cols, rows.write_idx)
             toks = jnp.where(active[:, None], toks, jnp.int32(pad_id))
             lps = jnp.where(active[:, None], jnp.stack([lp1, lp2], axis=1), 0.0)
             emitted = jnp.where(active, 1 + accept.astype(jnp.int32), 0)
@@ -1116,8 +1177,8 @@ class ContinuousDecodeLoop:
         W = self.width
         mask = np.zeros((W,), bool)
         mask[rows] = True
-        prefix_idx, gen_idx, write_idx = self._pages.prepare_step(
-            mask, self._prompt_lens, self._gen_lens)
+        write_idx, _ = self._pages.prepare_step(mask, self._prompt_lens, self._gen_lens)
+        prefix_idx, gen_idx = self._pages.prefix_idx, self._pages.gen_idx
         fn, grammar_args = self._admit_draft_fn, ()
         if req.grammar is not None:
             fn = self._grammar_programs()["draft"]
@@ -2065,6 +2126,33 @@ class ContinuousDecodeLoop:
             req.future.set_exception(exc)
         self._lock.notify_all()
 
+    def _pack_step(self, *books: np.ndarray) -> np.ndarray:
+        """A decode step's host inputs as one fresh ``int32 [W, C]`` array
+        (lock held): a copy of the slot mirrors, a column each in
+        :class:`_StepRows`' order (``seeds``, ``temps`` and ``top_ps`` by their
+        bits, a drafting loop's ``draft`` and ``room``: ``len(tokens)`` is
+        ``gen_lens + 1``, room for two more), then ``books``, what
+        :meth:`SlotPages.prepare_step` returned: the rows' write slots and
+        their block tables (nothing in a dense loop). The columns are fixed
+        for a loop build."""
+        columns = [
+            self._cur, self._gen_lens, self._prompt_lens, self._active_mask,
+            self._seeds.view(np.int32), self._sample_idx,
+            self._temps.view(np.int32), self._top_ps.view(np.int32),
+            self._g_states, self._g_flags,
+        ]
+        if self._drafting:
+            columns += [self._draft, self._gen_lens + 3 <= self._max_news]
+        named = len(columns)
+        packed = np.empty((self.width, named + sum(a.shape[1] for a in books)), np.int32)
+        for at, column in enumerate(columns):
+            packed[:, at] = column
+        at = named
+        for block in books:
+            packed[:, at:at + block.shape[1]] = block
+            at += block.shape[1]
+        return packed
+
     def _step_once(self) -> None:
         with LATENCY.span("continuous.prepare"):
             # The span's three parts, each a span of its own: the wait for the
@@ -2082,40 +2170,30 @@ class ContinuousDecodeLoop:
                 # program-identical).
                 n_masked = int((self._g_flags & self._active_mask).sum())
                 step_fn = self._grammar_programs()["step"] if n_masked else self._step_fn
-                # A loop with page books keeps them here, ahead of the uploads:
+                # A loop with page books keeps them here, ahead of the upload:
                 # table growth and copy-on-write for the rows' next write,
-                # which yield the step's index arguments (host arrays still).
+                # which yield the rows' tables and write slots.
                 pool, dense, state = self._pool, self._dense, self._state
-                layout_np: tuple = ()
+                books: tuple = ()
                 pages = None
                 if self._pages is not None:
                     with LATENCY.span("continuous.pages"):
                         lens = (self._active_mask, self._prompt_lens, self._gen_lens)
-                        layout_np = self._pages.prepare_step(*lens)
+                        books = self._pages.prepare_step(*lens)
                         # The fused kernel's walk, counted here where the
                         # lengths are coherent (the XLA path gathers whole tables).
                         if self._paged_attn_impl != "xla":
                             pages = self._pages.walk_counts(
                                 *lens, window=self.engine.config.sliding_window
                             )
-                # Every host array the step takes goes up in this one block.
+                # Everything the step takes from the host goes up here, as
+                # one array: a snapshot of the mirrors (a copy, so the
+                # dispatch thread's view of it stays coherent) and the books.
                 with LATENCY.span("continuous.stage"):
-                    row_args = tuple(map(jnp.asarray, (
-                        self._cur, self._gen_lens, self._prompt_lens,
-                        self._active_mask, self._seeds, self._sample_idx,
-                        self._temps, self._top_ps,
-                    )))
-                    if self._drafting:
-                        # len(tokens) is gen_lens + 1: room for two more.
-                        room = self._gen_lens + 3 <= self._max_news
-                        row_args += (jnp.asarray(self._draft), jnp.asarray(room))
-                    grammar_args: tuple = ()
-                    if n_masked:
-                        grammar_args = (
-                            jnp.asarray(self._g_states), jnp.asarray(self._g_flags),
-                            *self._g_tabs(),
-                        )
-                    layout_idx = tuple(map(jnp.asarray, layout_np))
+                    packed = jnp.asarray(self._pack_step(*books))
+                uploaded = [packed]
+                # The resident grammar's tables live on the device already.
+                grammar_tabs = self._g_tabs() if n_masked else ()
             # All-False in production; with an active ``engine.logits`` nan
             # failpoint, a seeded subset of the LIVE rows is poisoned — the
             # loop-scoped twin of the batch path's first-step injection.
@@ -2123,6 +2201,8 @@ class ContinuousDecodeLoop:
                 # kllms: ignore[host-sync-hot-path] — live_rows is np.flatnonzero output (already host memory); this tolist is pure host bookkeeping, not a device readback
                 self.width, live_rows=live_rows.tolist()
             )
+            if poison is not self.engine._no_poison(self.width):
+                uploaded.append(poison)
 
         def _dispatch():
             self._observe_gap()
@@ -2137,8 +2217,8 @@ class ContinuousDecodeLoop:
                 note_device_dispatch(what)
                 with LATENCY.span("continuous.dispatch", step=step_no):
                     out = step_fn(
-                        self.engine.params, *layout_state, *row_args,
-                        *layout_idx, poison, *grammar_args, state=state,
+                        self.engine.params, *layout_state, packed, poison,
+                        *grammar_tabs, state=state,
                     )
                 # An abandoned thread waking into a rebuilt loop must not
                 # clobber the new KV with the old epoch's.
@@ -2213,6 +2293,8 @@ class ContinuousDecodeLoop:
                     SPEC_COUNTERS.record("spec_tokens_emitted", int(emitted_np.sum()))
                 self._stats["steps"] += 1
                 self._stats["row_steps"] += int(self._active_mask.sum())
+                self._stats["stage_uploads"] += len(uploaded)
+                self._stats["stage_bytes"] += sum(int(a.nbytes) for a in uploaded)
                 self._stats["max_active_rows"] = max(
                     self._stats["max_active_rows"], int(self._active_mask.sum())
                 )

@@ -1481,6 +1481,11 @@ class LocalEngine:
             mask = np.zeros((n_rows,), np.bool_)
             mask[chosen] = True
             return jnp.asarray(mask)
+        return self._no_poison(n_rows)
+
+    def _no_poison(self, n_rows: int) -> jax.Array:
+        """The all-False mask ``[n_rows]`` bool, one cached device array a
+        width: a caller that counts its uploads knows it by identity."""
         cache = getattr(self, "_zero_poison", None)
         if cache is None:
             cache = {}

@@ -286,6 +286,47 @@ def flat_slots(pages: Sequence[int], positions: np.ndarray, page_size: int) -> n
     return (page_ids * page_size + offs).astype(np.int32)
 
 
+def table_width(max_prompt: int, gen_slots: int, page_size: int) -> int:
+    """Pages a row's block table can come to hold: its prompt and every
+    position its steps write."""
+    return pages_for(max_prompt + gen_slots, page_size)
+
+
+def expand_tables(tables, prompt_lens, page_size: int, max_prompt: int, gen_slots: int):
+    """:meth:`SlotPages._refresh` inside a step program: ``(prefix_idx [W,
+    max_prompt], gen_idx [W, gen_slots])``, one flat pool slot a position,
+    from the rows' block tables ``[W, T]`` (padded with ``TRASH_PAGE``) and
+    prompt lengths ``[W]`` (0 for an idle row, whose table is empty), element
+    for element what the host mirrors hold. Traceable, and written as
+    broadcasts, compares and selects alone (a gather a position, or a window
+    a row, is a loop on the chip): the compiler fuses it into the consumers."""
+    ps = page_size
+    W, T = tables.shape
+    if T * ps < max_prompt + gen_slots:
+        raise ValueError(f"tables of {T} pages span {T * ps} positions, under {max_prompt + gen_slots}")
+    within = jnp.arange(ps, dtype=jnp.int32)
+    at = jnp.arange(max_prompt, dtype=jnp.int32)[None, :]
+    plen = prompt_lens[:, None]
+    # The prompt side: each page id over its page. Positions at or past the
+    # prompt's end read through gen_idx instead, and point into the trash page.
+    n_prefix = pages_for(max_prompt, ps)
+    spanned = (tables[:, :n_prefix, None] * ps + within).reshape(W, n_prefix * ps)[:, :max_prompt]
+    prefix_idx = jnp.where(at < plen, spanned, TRASH_PAGE * ps + (at - plen) % ps)
+    # The generated side starts inside the page the prompt ends in: the few
+    # pages its positions can span, picked out of the table by comparison
+    # (past the table's end nothing matches: the trash page) ...
+    n_gen = pages_for(ps - 1 + gen_slots, ps)
+    wanted = (plen // ps + jnp.arange(n_gen, dtype=jnp.int32)[None, :])[:, :, None]
+    own = jnp.arange(T, dtype=jnp.int32)[None, None, :] == wanted  # [W, n_gen, T]
+    gen_pages = TRASH_PAGE + jnp.sum(
+        jnp.where(own, tables[:, None, :] - TRASH_PAGE, 0), axis=-1, dtype=jnp.int32)
+    # ... then each position's page among them, the same way.
+    offset = plen % ps + jnp.arange(gen_slots, dtype=jnp.int32)[None, :]  # [W, gen_slots]
+    mine = (offset // ps)[:, :, None] == jnp.arange(n_gen, dtype=jnp.int32)[None, None, :]
+    page = jnp.sum(jnp.where(mine, gen_pages[:, None, :], 0), axis=-1, dtype=jnp.int32)
+    return prefix_idx, page * ps + offset % ps
+
+
 # ---------------------------------------------------------------------------
 # The pool's movers
 # ---------------------------------------------------------------------------
@@ -497,9 +538,12 @@ class PagedPrefixRun:
 
 class SlotPages:
     """The page books of one paged decode loop: per slot a block TABLE of pool
-    pages and the RESERVE its decode steps draw from, plus the flat index
-    mirrors the step program reads KV through. The protocol (sharing and
-    copy-on-write as in the module docstring):
+    pages and the RESERVE its decode steps draw from. A decode step takes the
+    tables themselves (``tables``, a row each, padded with the trash page) and
+    spreads them into one flat pool slot a position on the device
+    (:func:`expand_tables`); the host keeps the same spread in ``prefix_idx``
+    / ``gen_idx`` for admission's own programs and for the walk's phase. The
+    protocol (sharing and copy-on-write as in the module docstring):
 
     - Admission shares ONE page run of the prompt between a request's n rows,
       a reference each, and reserves every row's private generation pages up
@@ -529,13 +573,18 @@ class SlotPages:
         self.planned_pages = int(pool_pages or self.default_pool_pages())
         # All that follows — kllms: guarded-by[engine.continuous]
         self.pool: Optional[PagedKVPool] = None
-        self._tables: List[List[int]] = [[] for _ in range(self.width)]
-        self._reserved: List[List[int]] = [[] for _ in range(self.width)]
-        # Token-level gather indices, one flat pool slot a position: what the
-        # step program takes today (the kernel turns them back into page
-        # tables on the device).
+        self._tables: List[List[int]] = []
+        self._reserved: List[List[int]] = []
+        # What a decode step is handed: every slot's table as one array.
+        gen_slots = self.max_new + self.lookahead
+        self.tables = np.full(
+            (self.width, table_width(self.max_prompt, gen_slots, self.page_size)),
+            TRASH_PAGE, np.int32)
+        # The tables spread into one flat pool slot a position: what the step
+        # program rebuilds on the device, kept here for admission's programs.
         self.prefix_idx = np.zeros((self.width, self.max_prompt), np.int32)
-        self.gen_idx = np.zeros((self.width, self.max_new + self.lookahead), np.int32)
+        self.gen_idx = np.zeros((self.width, gen_slots), np.int32)
+        self._clear()
 
     # -- sizing ------------------------------------------------------------
 
@@ -575,10 +624,16 @@ class SlotPages:
         its torn-down engine, and a decref against a replaced allocator would
         corrupt the new pool's accounting."""
         self.pool = None
+        self._clear()
+
+    def _clear(self) -> None:
+        """Every slot as a release leaves it: no table, no reserve, and the
+        spread of an empty table (which is what a step program makes of an
+        idle row's) in the index mirrors."""
         self._tables = [[] for _ in range(self.width)]
         self._reserved = [[] for _ in range(self.width)]
-        self.prefix_idx[:] = 0
-        self.gen_idx[:] = 0
+        for slot in range(self.width):
+            self._refresh(slot, 0)
 
     def held(self) -> int:
         """Page references the slots hold (tables and reserves)."""
@@ -677,11 +732,14 @@ class SlotPages:
     # -- the decode step ---------------------------------------------------
 
     def _refresh(self, slot: int, plen: int) -> None:
-        """Rebuild one slot's flat gather indices from its block table. Must
-        run after ANY table change (admit, extension, CoW, release): a stale
-        index could keep gathering a page that was freed and reused."""
+        """Rebuild one slot's row of ``tables`` and its flat gather indices
+        from its block table. Must run after ANY table change (admit,
+        extension, CoW, release): a stale index could keep gathering a page
+        that was freed and reused."""
         ps = self.page_size
         table = self._tables[slot]
+        self.tables[slot, :len(table)] = table
+        self.tables[slot, len(table):] = TRASH_PAGE
         P, G = self.max_prompt, self.gen_idx.shape[1]
         pidx = flat_slots(table, np.arange(P), ps)
         # Positions at/after the prompt end read through gen_idx instead;
@@ -691,16 +749,16 @@ class SlotPages:
         self.gen_idx[slot] = flat_slots(table, plen + np.arange(G), ps)
 
     def prepare_step(self, active: np.ndarray, prompt_lens: np.ndarray,
-                     gen_lens: np.ndarray) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+                     gen_lens: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """Resolve each row's write slots for the upcoming step, performing
         page-table maintenance on the way: append a reserved page when a
         write crosses a page boundary, copy-on-write when a target page is
-        still shared with other readers. Returns the step's index arguments
-        ``(prefix_idx [W, P], gen_idx [W, G], write_idx)`` (inactive rows
-        write into the trash page); ``write_idx`` is ``[W]``, the row's next
-        position, or with a ``lookahead`` ``[W, 1 + lookahead]``, that position
-        and the ones after it. Never allocates — admission reserved
-        every page this can pop."""
+        still shared with other readers. Returns what the step takes of the
+        books, ``(write_idx [W, 1 + lookahead], tables [W, T])``: the flat
+        slots of the row's next position and the ``lookahead`` after it
+        (inactive rows write into the trash page), and every row's table (the
+        books' own array: copy it to keep it). Never allocates — admission
+        reserved every page this can pop."""
         pool = self.pool
         ps = self.page_size
         allocator = pool.allocator
@@ -738,7 +796,7 @@ class SlotPages:
             # that reads it — decref only after the copy is enqueued (the
             # pool swap orders it before the next step's gathers).
             allocator.decref(cow_src)
-        return self.prefix_idx, self.gen_idx, write_idx if ahead else write_idx[:, 0]
+        return write_idx, self.tables
 
     def walk_counts(self, active: np.ndarray, prompt_lens: np.ndarray,
                     gen_lens: np.ndarray, window: Optional[int] = None
@@ -780,3 +838,4 @@ class SlotPages:
         """Drop a slot's references WITHOUT decref: containment over an
         allocator that is already corrupt (and quarantined)."""
         self._tables[slot], self._reserved[slot] = [], []
+        self._refresh(slot, 0)

@@ -212,10 +212,10 @@ class LatencyHistograms:
 #: each: ``continuous.lock_wait`` (``_step_once`` entry until the loop lock is
 #: held: submitters and ``stats`` readers hold it), ``continuous.pages`` (the
 #: page books' ``prepare_step`` and ``walk_counts``: table growth,
-#: copy-on-write, the step's index arrays still on the host; no sample for a
-#: dense loop) and ``continuous.stage`` (every ``jnp.asarray`` of the step in
-#: one block: the row arrays, a drafting loop's draft and room, the grammar
-#: states and flags, the page books' index arrays). Inside
+#: copy-on-write, the rows' write slots; no sample for a dense loop) and
+#: ``continuous.stage`` (the step's one upload: the slot mirrors, a drafting
+#: loop's draft and room, the grammar states and flags, the rows' write slots
+#: and block tables packed into one array, and its ``jnp.asarray``). Inside
 #: ``continuous.admit``: ``continuous.admit_device`` — an admission's device
 #: work, from its first jitted call (the prefill, or the state install and the
 #: first-token program where the last chunk finishes a prompt) to the return

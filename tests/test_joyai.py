@@ -385,29 +385,30 @@ def test_what_the_module_cannot_ride_is_refused_by_name(params, kw, named):
 # -- what other models' programs did not move -------------------------------------------------
 
 #: sha256 of each program's StableHLO text on the tree named beside it, made by
-#: this test's own ``program_texts`` there. ``qwen2-shaped``: the parent of PR 34
-#: (53cb5d9); the drafted step, the held share and the plain residual path are
-#: static branches it never takes. ``nemotron3-tiny``: the parent of PR 35
-#: (7ba35d2); its pool ``[1, flat, 2, 128]`` keeps its rows and its layer-axis
-#: movers. ``xing4-tiny``: ``step`` and ``step_g`` are PR 35's own tree. They
-#: moved because the pool they take is stored 128 lanes wide (40 before) and
-#: the step gathers and writes it by (layer, slot) in the flat view
-#: (``paging.scatter_rows``), which is the change; ``chunk`` never touches the pool
-#: and is still PR 34's parent's.
+#: this test's own ``program_texts`` there. The three ``chunk`` programs:
+#: ``qwen2-shaped`` and ``xing4-tiny`` the parent of PR 34 (53cb5d9), where the
+#: drafted step, the held share and the plain residual path are static branches
+#: they never take; ``nemotron3-tiny`` the parent of PR 35 (7ba35d2). The six
+#: ``step`` / ``step_g`` programs are PR 37's own tree (the child of a70e348):
+#: their text changed by construction, since a step takes one packed host array
+#: and block tables where it took 11 to 13 arrays and token-level slot indices
+#: (``continuous._unpack_step``, ``paging.expand_tables``), which is that change.
+#: They pin every later PR; that PR 37 itself moved no model's loop is held by
+#: the tokens below (``PARENT_STREAMS``, recorded on a70e348).
 PARENT_PROGRAMS = {
     "qwen2-shaped": {
-        "step": "3776df69d413990ace1e724834e2f4f9e8d7782290cc3c61bb612913e867647a",
-        "step_g": "e1a6f10c778e0fc9d140081bb7bfe63f1633656083caa2ebb9abf564b8dd9d74",
+        "step": "fd0f940b943ea991e211d3a4d3025ded4ecc9548c6866ad9a24eb66406579503",
+        "step_g": "51ab6ada7b0f434a045f3d409395fa9c6a058c77e5599d9d791245f1eda6b2e4",
         "chunk": "b1ca8438c73e7a21b7a5cfa2676bde8ec43eeb67660c81dae26499ce9a8f53e4",
     },
     "xing4-tiny": {
-        "step": "af02ea5c567784b72cf3c9cbbcf0bfc525b2eb5724bb522b55ea7cabe94efb38",
-        "step_g": "679ee68d518a1c94efadbba753988567cf749449d00f046b47dd78745336de3d",
+        "step": "8a80ec3a8e8d1dd7353e495c2b0f8a72d71fb6cdef9965b620cb970a375cffdf",
+        "step_g": "ff0ddadba9a602394d8c338999dae7af07e8c43bf99a08ba979fff3f301192ec",
         "chunk": "ac609ad56b00b2c614ea8a4fa6213d824257ad6a4729288f1f239a2cb5e8b999",
     },
     "nemotron3-tiny": {
-        "step": "1575d0d0d6c4a7bb3d020e0a6aa372cb7c48af6425e159304c4ff6ba49517cbf",
-        "step_g": "0063ce72f12593099783ca4c6861556922ee8004209fdd60e4465e06af75b8b4",
+        "step": "6d06aba1dbb8878811c2b36b9c41c9e27204bdec0a9cf8067cebfa503b7d7d57",
+        "step_g": "c77c4f82b0e0f37f7a60e78ede5218f6489b31938051ae10a812644380ee88d4",
         "chunk": "c3775e68172d09a2449a21d312f484c80f4fec94927087cee1838303005af0d2",
     },
 }
@@ -421,19 +422,18 @@ def program_texts(config):
     engine = LocalEngine(config, use_mesh=False, kv_layout="paged", kv_page_size=8)
     loop = ContinuousDecodeLoop(engine, width=4, max_prompt=32, max_new=8)
     loop._build_device_state()
-    z = lambda dtype: jnp.zeros((4,), dtype)  # noqa: E731
-    rows = (z(jnp.int32), z(jnp.int32), z(jnp.int32), z(bool), z(jnp.uint32), z(jnp.int32),
-            z(jnp.float32), z(jnp.float32))
-    layout = (jnp.asarray(loop._pages.prefix_idx), jnp.asarray(loop._pages.gen_idx), z(jnp.int32))
+    # A step's one host array: the mirrors as a new loop holds them, every table empty.
+    packed = jnp.asarray(loop._pack_step(np.zeros((4, 1), np.int32), loop._pages.tables))
+    no_poison = jnp.zeros((4,), bool)
     pool, out = loop._pool, {}
     out["step"] = loop._step_fn.lower(
-        engine.params, pool.kv.k, pool.kv.v, *rows, *layout, z(bool), state=loop._state).as_text()
+        engine.params, pool.kv.k, pool.kv.v, packed, no_poison, state=loop._state).as_text()
     tiny_schema = {"type": "object", "properties": {"a": {"type": "boolean"}}, "required": ["a"],
                    "additionalProperties": False}
     loop._install_grammar(grammar_for_schema(tiny_schema, grammar_vocab(get_tokenizer(None))))
     out["step_g"] = loop._grammar_programs()["step"].lower(
-        engine.params, pool.kv.k, pool.kv.v, *rows, *layout, z(bool), z(jnp.int32), z(bool),
-        *loop._g_tabs(), state=loop._state).as_text()
+        engine.params, pool.kv.k, pool.kv.v, packed, no_poison, *loop._g_tabs(),
+        state=loop._state).as_text()
     out["chunk"] = engine._get_prefill_chunk(32, 64, True).lower(
         engine.params, jnp.zeros((1, 32), jnp.int32), llama.init_cache(config, 1, 64),
         jnp.int32(0), jnp.int32(32), state=llama.init_state(config, 1)).as_text()
@@ -441,10 +441,12 @@ def program_texts(config):
     return out
 
 
+SHAPED = get_config("tiny").with_(name="qwen2-shaped", qkv_bias=True, rope_theta=1e6, rms_eps=1e-6)
+
+
 @pytest.fixture(scope="module")
 def lowered():
-    shaped = get_config("tiny").with_(name="qwen2-shaped", qkv_bias=True, rope_theta=1e6, rms_eps=1e-6)
-    return {"qwen2-shaped": program_texts(shaped),
+    return {"qwen2-shaped": program_texts(SHAPED),
             **{name: program_texts(get_config(name)) for name in ("xing4-tiny", "nemotron3-tiny")}}
 
 
@@ -453,3 +455,67 @@ def lowered():
 def test_other_models_loop_programs_are_the_parents(lowered, model, program):
     text = lowered[model][program]
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_PROGRAMS[model][program]
+
+
+# -- the loop's tokens are the parent's --------------------------------------------------------
+
+#: sha256 over the tokens, lengths and finish reasons of ``loop_streams`` as the
+#: parent of PR 37 (a70e348) emitted them on the CPU: that PR re-made the six
+#: step hashes above (a step takes one packed array now), so the proof that no
+#: model's loop moved is what comes out of it.
+PARENT_STREAMS = {
+    "joyai-tiny-drafted": "92a50f4de477d2b0cd43c2a4a145e7efcbe15dd81335ea2f0d3acd714305b2a6",
+    "joyai-tiny-drafted-grammar": "9222b97ad9fe9b733b0e91d35b3a0da57780f56ade5bf432ba733eda68855867",
+    "nemotron3-tiny-paged": "0db372a287dda3a282cdf517d83ca799b9f98827b0bc7d090773c4096851403f",
+    "nemotron3-tiny-paged-grammar": "e512348d95da253a2777e3a010cb293efdf10fd075c2fae5352665dcb688e946",
+    "qwen2-shaped-dense": "a423609e1c1bbb87e7e2ce148a099c30aa1fd55f1f7389c730d4f75247a8fa4a",
+    "qwen2-shaped-dense-grammar": "782bf52ff12f601c6c6329ec1c7ffba791a6ce7426b56536234a74fd11f0518a",
+    "qwen2-shaped-paged": "a423609e1c1bbb87e7e2ce148a099c30aa1fd55f1f7389c730d4f75247a8fa4a",
+    "qwen2-shaped-paged-grammar": "782bf52ff12f601c6c6329ec1c7ffba791a6ce7426b56536234a74fd11f0518a",
+    "xing4-tiny-paged": "916411c142e43e1b4844d6bb483fd774e4f7a13cc2da306e4db83896f4ad699e",
+    "xing4-tiny-paged-grammar": "30857165657fedad2619e802a08c78ee0da8764c10117f6859d9f6bf7ea80b78",
+}
+
+STREAM_CASES = {
+    "qwen2-shaped-dense": ("qwen2-shaped", "dense", False),
+    "qwen2-shaped-dense-grammar": ("qwen2-shaped", "dense", True),
+    "qwen2-shaped-paged": ("qwen2-shaped", "paged", False),
+    "qwen2-shaped-paged-grammar": ("qwen2-shaped", "paged", True),
+    "xing4-tiny-paged": ("xing4-tiny", "paged", False),
+    "xing4-tiny-paged-grammar": ("xing4-tiny", "paged", True),
+    "nemotron3-tiny-paged": ("nemotron3-tiny", "paged", False),
+    "nemotron3-tiny-paged-grammar": ("nemotron3-tiny", "paged", True),
+    "joyai-tiny-drafted": ("joyai-tiny", "paged", False),
+    "joyai-tiny-drafted-grammar": ("joyai-tiny", "paged", True),
+}
+
+
+def loop_streams(model, layout, grammar):
+    """Two seeded requests through one loop, the second into slots the first
+    left (and one more): four rows over a prompt that ends mid-page, then
+    three with a seed past 2**31, greedy beside sampled."""
+    from k_llms_tpu.engine.tokenizer import get_tokenizer
+
+    config = SHAPED if model == "qwen2-shaped" else get_config(model)
+    engine = shared_engine(config, kv_layout=layout, kv_page_size=8)
+    loop = ContinuousDecodeLoop(engine, width=6, max_prompt=64, max_new=64,
+                                eos_ids=get_tokenizer(None).stop_ids)
+    rng = np.random.RandomState(2)
+    digest = hashlib.sha256()
+    try:
+        for n, plen, temperature, seed in ((4, 37, 0.8, 5), (3, 48, 0.0, 2**31 + 11)):
+            prompt = [int(t) for t in rng.randint(32, 127, size=plen)]
+            got = loop.submit(prompt, n=n, max_new=64 if grammar else 20, temperature=temperature,
+                              top_p=0.95, seed=seed, grammar=grammar).result(timeout=300)
+            digest.update(np.asarray(got.tokens, np.int32).tobytes())
+            digest.update(np.asarray(got.lengths, np.int32).tobytes())
+            digest.update(",".join(got.finish_reasons).encode())
+    finally:
+        loop.stop()
+    return digest.hexdigest()
+
+
+@pytest.mark.parametrize("case", sorted(STREAM_CASES))
+def test_seeded_loop_streams_are_the_parents(grammar, case):
+    model, layout, constrained = STREAM_CASES[case]
+    assert loop_streams(model, layout, grammar if constrained else None) == PARENT_STREAMS[case]

@@ -288,19 +288,17 @@ def test_a_model_without_recurrent_state_adds_no_operand_to_the_step_program(mod
     try:
         loop._build_device_state()
         W = 4
-        rows = (jnp.zeros((W,), jnp.int32),) * 3 + (
-            jnp.zeros((W,), bool), jnp.zeros((W,), jnp.uint32), jnp.zeros((W,), jnp.int32),
-            jnp.zeros((W,), jnp.float32), jnp.zeros((W,), jnp.float32))
-        idx = (jnp.zeros((W, 64), jnp.int32), jnp.zeros((W, 16), jnp.int32), jnp.zeros((W,), jnp.int32))
+        packed = jnp.asarray(loop._pack_step(np.zeros((W, 1), np.int32), loop._pages.tables))
         step = jax.make_jaxpr(loop._step_fn)(
-            engine.params, loop._pool.kv.k, loop._pool.kv.v, *rows, *idx,
+            engine.params, loop._pool.kv.k, loop._pool.kv.v, packed,
             jnp.zeros((W,), bool), state=loop._state)
         chunk = jax.make_jaxpr(engine._get_prefill_chunk(32, 64, True))(
             engine.params, jnp.zeros((1, 32), jnp.int32), llama.init_cache(engine.config, 1, 64),
             jnp.int32(0), jnp.int32(3), state=llama.init_state(engine.config, 1))
         n_params = len(jax.tree.leaves(engine.params))
         n_state = 2 * engine.config.layer_pattern.count("M")
-        assert len(step.jaxpr.invars) == n_params + 2 + n_state + 8 + 3 + 1
+        # The pool's pair, the state, the step's one packed array, the poison mask.
+        assert len(step.jaxpr.invars) == n_params + 2 + n_state + 1 + 1
         assert len(chunk.jaxpr.invars) == n_params + 1 + 2 + n_state + 2
         if model != "nemotron3-tiny":
             assert loop._state == {}

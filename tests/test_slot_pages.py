@@ -12,11 +12,17 @@ from k_llms_tpu.engine.paging import (
     PageAllocator,
     PagePoolExhausted,
     SlotPages,
+    expand_tables,
     flat_slots,
     pages_for,
     row_reserve_pages,
+    table_width,
 )
-from k_llms_tpu.ops.paged_attention import live_pages, table_pages
+from k_llms_tpu.ops.paged_attention import (
+    live_pages,
+    paged_attention_page_tables,
+    table_pages,
+)
 
 W, P, G = 4, 64, 32
 
@@ -103,8 +109,12 @@ def test_cow_fires_at_the_first_divergent_write_iff_the_prompt_ends_mid_page(ple
     books, pool, alloc = _books(ps)
     run = _admit(books, alloc, rows, plen, max_new=12, keep_owner=True)
     reserve0 = [len(books._reserved[s]) for s in rows]
-    _, _, write_idx = books.prepare_step(*_lens(rows, plen, 0))
+    write_idx, tables = books.prepare_step(*_lens(rows, plen, 0))
+    write_idx = write_idx[:, 0]  # a one-token loop's rows write one slot each
     own = [books._tables[s][plen // ps] for s in rows]
+    for slot in range(W):  # what the step is handed: every row's table, trash past its end
+        held = books._tables[slot]
+        assert tables[slot].tolist() == held + [TRASH_PAGE] * (tables.shape[1] - len(held))
     if plen % ps:
         # One padded copy program: each row's private page from the shared one.
         ((src, dst),) = pool.copies
@@ -137,10 +147,11 @@ def test_a_page_boundary_pops_exactly_one_reserved_page():
     _admit(books, alloc, [2], plen, max_new)
     sizes = []
     for step in range(max_new):
-        _, gen_idx, write_idx = books.prepare_step(*_lens([2], plen, step))
+        write_idx, _ = books.prepare_step(*_lens([2], plen, step))
+        assert write_idx.shape == (W, 1)
         sizes.append((len(books._tables[2]), len(books._reserved[2])))
         # The step reads its own write back through the gen map next step.
-        assert write_idx[2] == gen_idx[2, step]
+        assert write_idx[2, 0] == books.gen_idx[2, step]
     grew = [i for i in range(1, max_new) if sizes[i] != sizes[i - 1]]
     assert grew == [8, 16]  # positions 24 and 32
     assert all(t + r == sizes[0][0] + sizes[0][1] for t, r in sizes)
@@ -262,10 +273,10 @@ def test_cow_copies_the_shared_page_on_a_real_pool():
     cols = jnp.broadcast_to(marks[None, :, None, None], shape)
     pool.scatter_tokens(cols, cols, flat_slots(run, np.arange(plen), ps))
     books.admit([0, 1], run, plen, 4, alloc.alloc)
-    prefix_idx, _, _ = books.prepare_step(*_lens([0, 1], plen, 0))
+    books.prepare_step(*_lens([0, 1], plen, 0))
     for slot in (0, 1):
         assert books._tables[slot][-1] != run[-1]
-        got = np.asarray(pool.kv.k[0, prefix_idx[slot, :plen], 0, 0])
+        got = np.asarray(pool.kv.k[0, books.prefix_idx[slot, :plen], 0, 0])
         assert got.tolist() == list(range(plen))
 
 
@@ -288,7 +299,7 @@ def test_lookahead_widens_the_gen_map_and_the_reserve():
     _admit(books, alloc, [0, 1], plen, max_new)
     gen = 0
     while gen < max_new - 1:  # a row's steps to its end, two tokens at a time
-        _, _, write_idx = books.prepare_step(*_lens([0, 1], plen, gen))
+        write_idx, _ = books.prepare_step(*_lens([0, 1], plen, gen))
         assert write_idx.shape == (W, 3)
         gen += 2
     for slot in (0, 1):
@@ -304,10 +315,10 @@ def test_a_step_writing_three_positions_grows_at_a_page_edge(plen):
     books, pool, alloc = _ahead_books()
     _admit(books, alloc, [0], plen, 12)
     table0 = len(books._tables[0])
-    _, gen_idx, write_idx = books.prepare_step(*_lens([0], plen, 0))
+    write_idx, _ = books.prepare_step(*_lens([0], plen, 0))
     want = flat_slots(books._tables[0], plen + np.arange(3), 8)
     np.testing.assert_array_equal(write_idx[0], want)
-    np.testing.assert_array_equal(gen_idx[0, :3], want)
+    np.testing.assert_array_equal(books.gen_idx[0, :3], want)
     assert len(books._tables[0]) == pages_for(plen + 3, 8) >= table0
     assert len(set(write_idx[0] // 8)) == (2 if plen in (22, 23) else 1)
     assert (write_idx[1:] // 8 == TRASH_PAGE).all()  # idle rows write into the trash page
@@ -323,7 +334,7 @@ def test_a_shared_prompt_page_under_any_of_the_three_is_copied_once_a_row(plen):
     run = _admit(books, alloc, [0, 1, 2], plen, 12, keep_owner=True)
     shared = run[-1]
     assert alloc.refcount(shared) == 4
-    _, _, write_idx = books.prepare_step(*_lens([0, 1, 2], plen, 0))
+    write_idx, _ = books.prepare_step(*_lens([0, 1, 2], plen, 0))
     (src, dst), = pool.copies  # one padded batch
     real = [(s, d) for s, d in zip(src, dst) if s != TRASH_PAGE]
     assert [s for s, _ in real] == [shared] * 3 and len({d for _, d in real}) == 3
@@ -353,3 +364,113 @@ def test_a_failed_reserve_with_lookahead_rolls_back():
     plain = SlotPages(8, W, P, G)
     plain.attach(_Pool(total, 8))
     assert plain.need(plen, n, max_new) < total
+
+
+# -- the tables a step is handed, spread on the device ------------------------------------
+
+def _spread(books, plens):
+    """``expand_tables`` of the books' own array, jitted as a step program holds it."""
+    import jax
+
+    G_ = books.gen_idx.shape[1]
+    fn = jax.jit(lambda t, p: expand_tables(t, p, books.page_size, books.max_prompt, G_))
+    return [np.asarray(a) for a in fn(books.tables, np.asarray(plens, np.int32))]
+
+
+def _assert_spread_is_the_mirrors(books, plens):
+    prefix_idx, gen_idx = _spread(books, plens)
+    assert prefix_idx.dtype == gen_idx.dtype == np.int32
+    np.testing.assert_array_equal(prefix_idx, books.prefix_idx)
+    np.testing.assert_array_equal(gen_idx, books.gen_idx)
+
+
+@pytest.mark.parametrize("ps", [4, 8, 16])
+@pytest.mark.parametrize("lookahead", [0, 2])
+def test_the_spread_of_random_tables_is_refresh_element_for_element(ps, lookahead):
+    """Every row's ``(table, plen)`` through ``_refresh`` on the host and
+    through ``expand_tables`` in a program: an empty table, ``plen`` 0 over a
+    table, a table shorter than the row's width, a ``plen`` mid-page and on a
+    page edge, a full prompt, a table as wide as a row can make it."""
+    rng = np.random.RandomState(ps + lookahead)
+    width = 8
+    books = SlotPages(ps, width, P, G, lookahead=lookahead)
+    T = table_width(P, G + lookahead, ps)
+    assert books.tables.shape == (width, T)
+    plens = [0, 0, 5, ps + 3, 2 * ps, P, P - 1, 17]
+    held = [0, 3, 1, 4, 2, pages_for(P, ps), T, T]
+    for slot in range(width):
+        books._tables[slot] = [int(p) for p in rng.randint(1, 200, size=held[slot])]
+        books._refresh(slot, plens[slot])
+        # _refresh itself is flat_slots of the table, prompt and generated side.
+        want = flat_slots(books._tables[slot], plens[slot] + np.arange(G + lookahead), ps)
+        np.testing.assert_array_equal(books.gen_idx[slot], want)
+        want = flat_slots(books._tables[slot], np.arange(plens[slot]), ps)
+        np.testing.assert_array_equal(books.prefix_idx[slot, :plens[slot]], want)
+        assert (books.prefix_idx[slot, plens[slot]:] // ps == TRASH_PAGE).all()
+    _assert_spread_is_the_mirrors(books, plens)
+
+
+@pytest.mark.parametrize("lookahead", [0, 2])
+def test_the_spread_follows_growth_copy_on_write_and_release(lookahead):
+    """The books a loop keeps, step by step: after admission, after each
+    step's growth and copies, after a release (an idle row's lengths are a
+    retired tenant's on the host: the program is handed 0 for it)."""
+    ps = 8
+    pool = _Pool(96, ps)
+    books = SlotPages(ps, W, P, G, lookahead=lookahead)
+    books.attach(pool)
+    alloc = pool.allocator
+    _admit(books, alloc, [0, 1], 21, 12, keep_owner=True)  # shared, ends mid-page
+    _admit(books, alloc, [3], 40, 20)  # alone, ends on a page edge
+    active = np.array([True, True, False, True])
+    plens = np.array([21, 21, 33, 40], np.int32)  # slot 2: a retired tenant's
+    for gen in range(0, 12, 1 + lookahead // 2):
+        glens = np.minimum(gen, np.array([11, 11, 0, 19], np.int32))
+        write_idx, tables = books.prepare_step(active, plens, glens)
+        assert tables is books.tables
+        _assert_spread_is_the_mirrors(books, np.where(active, plens, 0))
+        _, gen_idx = _spread(books, np.where(active, plens, 0))
+        for slot in np.flatnonzero(active):  # the write slots are the gen map's
+            np.testing.assert_array_equal(
+                write_idx[slot], gen_idx[slot, glens[slot]:glens[slot] + 1 + lookahead])
+    assert len(pool.copies) == 1 and alloc.snapshot()["cow_copies"] == 2
+    books.release(1)
+    active[1] = False
+    assert books.tables[1].tolist() == [TRASH_PAGE] * books.tables.shape[1]
+    _assert_spread_is_the_mirrors(books, np.where(active, plens, 0))
+    books.reset()
+    _assert_spread_is_the_mirrors(books, np.zeros((W,), np.int32))
+
+
+@pytest.mark.parametrize("ps", [8, 16])
+def test_the_kernels_page_tables_of_the_spread_are_the_tables_pages(ps):
+    """``paged_attention_page_tables`` turns the spread straight back: the
+    prompt's pages, the generated side's pages from the page ``plen`` lies
+    in, and the phase ``plen % page_size``."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(ps)
+    books = SlotPages(ps, W, P, G)
+    plens = [ps + 3, 2 * ps, 0, P - 1]
+    for slot, plen in enumerate(plens):
+        books._tables[slot] = [int(p) for p in rng.randint(1, 200, size=pages_for(plen + G, ps))]
+        books._refresh(slot, plen)
+    prefix_idx, gen_idx = _spread(books, plens)
+    prefix_pages, gen_pages, phase = (np.asarray(a) for a in paged_attention_page_tables(
+        jnp.asarray(prefix_idx), jnp.asarray(gen_idx), ps))
+    NP, NG = table_pages(P, G, ps)
+    assert prefix_pages.shape == (W, NP) and gen_pages.shape == (W, NG)
+    for slot, plen in enumerate(plens):
+        table = books._tables[slot]
+        assert phase[slot] == plen % ps
+        whole = pages_for(plen, ps)  # a page the prompt reaches into is the table's
+        assert prefix_pages[slot, :whole].tolist() == table[:whole]
+        assert (prefix_pages[slot, whole:] == TRASH_PAGE).all()
+        first = plen // ps
+        n_gen = pages_for(plen % ps + G, ps)  # pages the generated positions span
+        assert gen_pages[slot, :n_gen].tolist() == table[first:first + n_gen]
+
+
+def test_a_table_too_narrow_for_the_row_is_refused_at_trace_time():
+    with pytest.raises(ValueError, match="span"):
+        expand_tables(np.zeros((W, 2), np.int32), np.zeros((W,), np.int32), 8, P, G)

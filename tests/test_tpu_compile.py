@@ -180,3 +180,48 @@ def test_the_forms_this_pool_left_do_copy_the_whole_pool(form, one_chip):
         shape((CHUNK,), jnp.int32)).compile()
     assert whole_pool_copies(compiled.as_text(), layers, flat)
     assert compiled.memory_analysis().temp_size_in_bytes > layers * flat * stored * 2 / 2
+
+
+# -- a step's block tables, spread inside the program ---------------------------------------
+#
+# (max_prompt, max_new, lookahead, drafting) of a cell's loop at width 32 and pages of 64: the
+# packed array a step takes (engine/continuous.py::_pack_step) and paging.expand_tables.
+STEP_LOOPS = {
+    "qwen2-7b": (2048, 256, 0),
+    "mistral-7b": (512, 256, 0),
+    "joyai-llm-flash": (2048, 256, 2),
+}
+
+
+@pytest.mark.parametrize("form", ["compare_select", "window_a_row"])
+@pytest.mark.parametrize("name", sorted(STEP_LOOPS))
+def test_the_steps_table_spread_is_fusions_alone_on_v5e(name, form, one_chip):
+    """``expand_tables`` compiles to elementwise fusions: no gather, no loop over
+    the rows. The form it is not written in, one ``dynamic_slice`` a row of the
+    spanned table (shorter to read), becomes a loop of 32 turns on the chip, and
+    the assertion bites on it."""
+    import re
+
+    from k_llms_tpu.engine.continuous import _unpack_step
+    from k_llms_tpu.engine.paging import expand_tables, table_width
+
+    P, max_new, ahead = STEP_LOOPS[name]
+    W, ps, G = 32, 64, max_new + ahead
+    T = table_width(P, G, ps)
+    named = 12 if ahead else 10
+
+    def window_a_row(tables, plens):
+        spanned = (tables[:, :, None] * ps + jnp.arange(ps, dtype=jnp.int32)).reshape(W, T * ps)
+        return jax.vmap(lambda row, start: jax.lax.dynamic_slice_in_dim(row, start, G))(spanned, plens)
+
+    def spread(packed):
+        rows = _unpack_step(packed, bool(ahead), 1 + ahead)
+        plens = jnp.where(rows.active, rows.prompt_lens, 0)
+        if form == "window_a_row":
+            return window_a_row(rows.tables, plens)
+        return expand_tables(rows.tables, plens, ps, P, G)
+
+    packed = jax.ShapeDtypeStruct((W, named + 1 + ahead + T), jnp.int32, sharding=one_chip)
+    text = jax.jit(spread).lower(packed).compile().as_text()
+    loops = re.findall(r" (while|gather)\(", text)
+    assert bool(loops) is (form == "window_a_row"), loops

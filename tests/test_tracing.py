@@ -806,8 +806,12 @@ def test_new_loop_metrics_read_what_they_say():
     assert by_name["loop_pages_ms"]["layer"] == "KV page manager"
     assert by_name["host_wait_share"]["layer"] == "Device"
     # What they add must lie at the end: nothing that was there moved.
-    assert [m["name"] for m in manifest["per_layer"]][-9:] == [
-        *NEW_LOOP_METRICS, "paged_attn_walked_share"]
+    # (ISSUE 37's one counter metric behind them, in every cell as they are.)
+    assert [m["name"] for m in manifest["per_layer"]][-10:] == [
+        *NEW_LOOP_METRICS, "paged_attn_walked_share", "loop_stage_uploads"]
+    assert by_name["loop_stage_uploads"] == {
+        "name": "loop_stage_uploads", "unit": "arrays", "better": "lower",
+        "source": "program_counter", "layer": "Admission and schedulers", "moves": "tokens_per_s"}
 
     def sums(**seconds):
         return {f"kllms_continuous_{k}_seconds_sum": v for k, v in seconds.items()}
@@ -815,18 +819,20 @@ def test_new_loop_metrics_read_what_they_say():
     start = dict(sums(wait=1.0, admit_device=0.5, lock_wait=0.01, stage=0.4, pages=0.2,
                       dispatch=0.3, fetch=0.1),
                  kllms_continuous_steps=100.0, kllms_continuous_active_seconds=10.0,
+                 kllms_continuous_stage_uploads=100.0,
                  kllms_continuous_dispatch_seconds_count=150.0,
                  kllms_continuous_fetch_seconds_count=150.0,
                  kllms_paged_attn_pages_walked=1000.0, kllms_paged_attn_pages_tabled=10000.0)
     end = dict(sums(wait=6.0, admit_device=1.5, lock_wait=0.03, stage=2.4, pages=1.2,
                     dispatch=1.8, fetch=0.7),
                kllms_continuous_steps=1100.0, kllms_continuous_active_seconds=50.0,
+               kllms_continuous_stage_uploads=1102.0,  # two steps under the failpoint's mask
                kllms_continuous_dispatch_seconds_count=1650.0,
                kllms_continuous_fetch_seconds_count=1650.0,
                kllms_paged_attn_pages_walked=101000.0, kllms_paged_attn_pages_tabled=410000.0)
     src = {"metrics_start": start, "metrics_end": end}
     read = {n: run.load_json(run.HERE, "layer_metrics", n + ".json")["read"]
-            for n in (*NEW_LOOP_METRICS, "paged_attn_walked_share")}
+            for n in (*NEW_LOOP_METRICS, "paged_attn_walked_share", "loop_stage_uploads")}
     values = {n: run.evaluate(r, src) for n, r in read.items()}
     assert values == {
         "loop_wait_ms": pytest.approx(5.0), "host_wait_share": pytest.approx(12.5),
@@ -834,6 +840,7 @@ def test_new_loop_metrics_read_what_they_say():
         "loop_stage_ms": pytest.approx(2.0), "loop_pages_ms": pytest.approx(1.0),
         "loop_launch_ms": pytest.approx(1.0), "loop_fetch_ms": pytest.approx(0.4),
         "paged_attn_walked_share": pytest.approx(25.0),
+        "loop_stage_uploads": pytest.approx(1.002),
     }
     # The parent: no such families, no such gauge; the kernel's counters at
     # zero where the XLA path serves. Left out, never zero, never an error.
@@ -857,13 +864,10 @@ def test_paged_step_program_carries_scope_names():
             [5, 6, 7, 8], n=2, max_new=2, temperature=0.8, top_p=0.9, seed=1,
         ).result(timeout=120)
         W = loop.width
-        i32, f32 = (lambda: jnp.zeros((W,), jnp.int32)), (lambda: jnp.ones((W,), jnp.float32))
+        packed = loop._pack_step(np.zeros((W, 1), np.int32), loop._pages.tables)
         lowered = loop._step_fn.lower(
-            paged.params, loop._pool.kv.k, loop._pool.kv.v, i32(), i32(),
-            jnp.ones((W,), jnp.int32), jnp.ones((W,), bool),
-            jnp.zeros((W,), jnp.uint32), i32(), f32(), f32(),
-            jnp.asarray(loop._pages.prefix_idx), jnp.asarray(loop._pages.gen_idx),
-            i32(), jnp.zeros((W,), bool),
+            paged.params, loop._pool.kv.k, loop._pool.kv.v, jnp.asarray(packed),
+            jnp.zeros((W,), bool),
         ).as_text(debug_info=True)
     finally:
         loop.stop()
