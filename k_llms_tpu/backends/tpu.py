@@ -208,8 +208,8 @@ class BackendConfig(BaseModel):
     kv_pool_pages: Optional[int] = None
     # -- paged decode everywhere (PR 11) ----------------------------------
     # Paged-attention implementation for paged decode steps: "auto" picks
-    # the fused Pallas kernel on TPU (a model whose every layer has a sliding
-    # window included: the kernel's walk and mask take it) and the jittable
+    # the fused Pallas kernel on TPU (sliding windows included, on every layer
+    # or on some: each layer's walk and mask take its own) and the jittable
     # XLA reference elsewhere; "pallas" requests the kernel explicitly
     # (COUNTED fallback to XLA when unavailable —
     # kernel.paged_attn_fallback.<reason>); "xla" forces the reference. See
@@ -538,8 +538,9 @@ class TpuBackend(Backend):
             )
         if model_config.is_hybrid and not cfg.continuous_batching:
             raise NotImplementedError(
-                f"{model_config.name}: only the continuous loop carries the rows' recurrent "
-                "state; build with continuous_batching=True"
+                f"{model_config.name}: only the continuous loop serves the hybrid stack (it "
+                "carries the rows' recurrent state; the parallel block has no dense decode "
+                "step); build with continuous_batching=True"
             )
         self._model_config = model_config
         self._mesh = mesh

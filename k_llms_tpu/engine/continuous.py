@@ -310,6 +310,10 @@ class _DenseSlots:
             pad = [(0, 0)] * 5
             pad[2] = (0, self.max_prompt - bucket)
             pk, pv = jnp.pad(pk, pad), jnp.pad(pv, pad)
+        elif bucket > self.max_prompt:
+            # A bucket is a power of two and max_prompt need not be one: the
+            # prompt fits the slots, what lies past them is the bucket's padding.
+            pk, pv = pk[:, :, :self.max_prompt], pv[:, :, :self.max_prompt]
         rows_arr = jnp.asarray(np.asarray(rows, np.int32))
         rep_k = jnp.broadcast_to(pk[:, 0:1], (pk.shape[0], n) + pk.shape[2:])
         rep_v = jnp.broadcast_to(pv[:, 0:1], (pv.shape[0], n) + pv.shape[2:])
@@ -2184,7 +2188,7 @@ class ContinuousDecodeLoop:
                         # lengths are coherent (the XLA path gathers whole tables).
                         if self._paged_attn_impl != "xla":
                             pages = self._pages.walk_counts(
-                                *lens, window=self.engine.config.sliding_window
+                                *lens, windows=self.engine.config.layer_windows
                             )
                 # Everything the step takes from the host goes up here, as
                 # one array: a snapshot of the mirrors (a copy, so the

@@ -314,6 +314,13 @@ class LocalEngine:
             # not written (a snapshot at page boundaries; a decode loop other
             # than the paged continuous one that carries it).
             hybrid = self.config.is_hybrid
+            # A hybrid stack without a Mamba-2 layer (the parallel block's) has
+            # no state; what it lacks is the dense decode path, unwritten.
+            why = ("a recurrent state cannot be rolled back; a cached prefix needs the state "
+                   "at its page boundary; only the paged continuous loop carries the state"
+                   if "M" in self.config.layer_pattern else
+                   "the stack's dense decode and verify steps are not written: the paged "
+                   "continuous loop alone serves it")
             # A next-token module drafts in the paged continuous loop alone:
             # its cache layer is a paging layer and its first draft needs the
             # prompt's last hidden state, which a cached prefix does not hold.
@@ -326,12 +333,11 @@ class LocalEngine:
                     (f"quantize={quantize!r} (int8/int4 expert stacks)", bool(quantize)),
                     ("sp_prefill_min_tokens (sequence-parallel prefill)",
                      sp_prefill_min_tokens is not None),
-                    (f"speculative={speculative!r} (the dense-cache speculation path; a "
-                     "recurrent state cannot be rolled back)", speculative is not None),
-                    (f"prefix_cache_size={prefix_cache_size} (a cached prefix needs the "
-                     "state at its page boundary)", hybrid and prefix_cache_size > 0),
-                    (f"kv_layout={kv_layout!r} (only the paged continuous loop carries the "
-                     "state; build with kv_layout='paged')", hybrid and kv_layout != "paged"),
+                    (f"speculative={speculative!r} (the dense-cache speculation path)",
+                     speculative is not None),
+                    (f"prefix_cache_size={prefix_cache_size}", hybrid and prefix_cache_size > 0),
+                    (f"kv_layout={kv_layout!r} (build with kv_layout='paged')",
+                     hybrid and kv_layout != "paged"),
                     (f"prefix_cache_size={prefix_cache_size} (the next-token module's first "
                      "draft needs the prompt's last hidden state, which a cached prefix does "
                      "not hold)", drafts and prefix_cache_size > 0),
@@ -341,8 +347,7 @@ class LocalEngine:
                 ) if asked
             ]
             if refused:
-                block = ("the hybrid stack (recurrent state beside the cache)" if hybrid
-                         else "the latent block")
+                block = (f"the hybrid stack ({why})" if hybrid else "the latent block")
                 raise NotImplementedError(
                     f"{self.config.name}: {block} is not implemented for " + "; ".join(refused)
                 )
@@ -2459,16 +2464,17 @@ class LocalEngine:
         return jnp.asarray(v)
 
     def _refuse_outside_loop(self, what: str) -> None:
-        """The engine's own decode loops carry no recurrent state: a model
-        that has one is served by the paged continuous loop alone, and a
-        request that loop does not take (top_logprobs, penalties, logit_bias,
-        a prompt or n beyond its bounds) fails here, by name."""
+        """The engine's own decode loops carry no recurrent state and have no
+        dense decode step for the parallel block: a hybrid stack is served by
+        the paged continuous loop alone, and a request that loop does not take
+        (top_logprobs, penalties, logit_bias, a prompt or n beyond its bounds)
+        fails here, by name."""
         if self.config.is_hybrid:
             raise NotImplementedError(
                 f"{self.config.name}: {what} outside the continuous loop is not implemented "
-                "for a model with recurrent state beside the cache (the request asked for "
-                "top_logprobs, penalties, logit_bias, or a prompt, n or max_tokens beyond "
-                "the loop's bounds)"
+                "for the hybrid stack (recurrent state beside the cache, or the parallel block, "
+                "whose dense decode step is not written; the request asked for top_logprobs, "
+                "penalties, logit_bias, or a prompt, n or max_tokens beyond the loop's bounds)"
             )
 
     # -- request prep -----------------------------------------------------

@@ -51,6 +51,7 @@ launch is already a numeric-quarantine event on the dense path too.
 from __future__ import annotations
 
 import logging
+from collections import Counter
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -799,21 +800,28 @@ class SlotPages:
         return write_idx, self.tables
 
     def walk_counts(self, active: np.ndarray, prompt_lens: np.ndarray,
-                    gen_lens: np.ndarray, window: Optional[int] = None
+                    gen_lens: np.ndarray, windows: Sequence[Optional[int]] = (None,)
                     ) -> Tuple[int, int, int]:
-        """(pages the live rows' walks hold, pages the step's tables hold,
-        pages holding a pool position that lie before the window's first page
-        and so are not walked) for the upcoming decode step, from what the step
-        program hands the paged kernel: the lengths with idle slots zeroed,
-        the phase out of the gen slot map, the model's sliding window."""
+        """(layer-pages the live rows' walks hold, layer-pages the step's
+        tables hold, layer-pages holding a pool position that lie before a
+        layer's window's first page and so are not walked) for the upcoming
+        decode step, each summed over the paging layers, from what the step
+        program hands the paged kernel: the lengths with idle slots zeroed, the
+        phase out of the gen slot map, and ``windows``, each paging layer's
+        sliding window (``ModelConfig.layer_windows``). Layers with one window
+        walk alike, so a uniform stack's three numbers are one layer's times
+        its depth."""
         ps = self.page_size
-        (p0, n_prefix), (g0, n_gen) = live_pages(
-            np.where(active, prompt_lens, 0), np.where(active, gen_lens, 0),
-            self.gen_idx[:, 0] % ps, ps, window,
-        )
+        lens = (np.where(active, prompt_lens, 0), np.where(active, gen_lens, 0),
+                self.gen_idx[:, 0] % ps, ps)
         tabled = self.width * sum(table_pages(self.max_prompt, self.max_new, ps))
-        windowed_out = int(np.sum(p0) + np.sum(g0))
-        return int(n_prefix.sum() + n_gen.sum()) - windowed_out, tabled, windowed_out
+        walked = windowed_out = 0
+        for window, layers in Counter(windows).items():
+            (p0, n_prefix), (g0, n_gen) = live_pages(*lens, window)
+            out = int(np.sum(p0) + np.sum(g0))
+            walked += layers * (int(n_prefix.sum() + n_gen.sum()) - out)
+            windowed_out += layers * out
+        return walked, len(windows) * tabled, windowed_out
 
     # -- retirement --------------------------------------------------------
 

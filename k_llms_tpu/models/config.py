@@ -14,6 +14,10 @@ from typing import Dict
 import jax.numpy as jnp
 
 
+#: The characters of ``layer_pattern`` whose layer holds keys and values.
+PAGING_KINDS = "*LG"
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -58,10 +62,13 @@ class ModelConfig:
     # - qkv_bias: additive bias on q/k/v projections (Qwen2 family).
     # - sliding_window: each query attends only to the last W keys
     #   (Mistral family); None = full causal. Forces the XLA path of the
-    #   dense flash kernels; the paged decode kernel takes a window that every
-    #   layer has (ops/paged_attention.py).
-    # - sliding_window_layers: "all" (every layer windowed — Mistral) or
-    #   "alternating" (even layers windowed, odd layers global — Gemma-2).
+    #   dense flash kernels; the paged decode kernel takes each layer's own
+    #   window, static in its call (ops/paged_attention.py).
+    # - sliding_window_layers: which layers the window binds: "all" (every
+    #   layer — Mistral) or "alternating" (even layers windowed, odd layers
+    #   global — Gemma-2). A hybrid stack's pattern says it layer by layer
+    #   ("L" windowed, "G" global). Either way the per-layer list every reader
+    #   takes is :attr:`layer_windows`, the window of each paging layer.
     qkv_bias: bool = False
     sliding_window: "int | None" = None
     sliding_window_layers: str = "all"
@@ -133,8 +140,18 @@ class ModelConfig:
     # moe_shared_intermediate_size), "*" GQA attention (use_rope False: no
     # rotary embedding). Only "*" layers page; an "M" layer keeps a
     # fixed-size recurrent state a row (:attr:`state_shapes`).
+    # "L" and "G" are the parallel block (Cohere's ``use_parallel_block``): one
+    # mean-centred LayerNorm, then GQA attention and the expert layer (the
+    # latent block's router over gated experts of moe_intermediate_size, plus
+    # n_shared_experts always-on ones fused into one of
+    # moe_shared_intermediate_size, averaged when shared_experts_averaged) side
+    # by side on the normed input, one residual add. "L" attends inside
+    # sliding_window under RoPE, "G" over everything with no positional
+    # embedding; both page. tie_embeddings: the head is the embedding table.
     layer_pattern: str = ""
     use_rope: bool = True
+    shared_experts_averaged: bool = False
+    tie_embeddings: bool = False
     mamba_num_heads: int = 0
     mamba_head_dim: int = 0
     mamba_n_groups: int = 1
@@ -172,18 +189,31 @@ class ModelConfig:
         return bool(self.layer_pattern)
 
     @property
+    def layer_windows(self) -> "tuple":
+        """The sliding window of each paging layer, in cache-layer order: W
+        where the layer attends inside ``sliding_window``, None where it
+        attends over everything."""
+        W = self.sliding_window
+        if self.is_hybrid:
+            return tuple(None if kind == "G" else W
+                         for kind in self.layer_pattern if kind in PAGING_KINDS)
+        if self.sliding_window_layers == "alternating":
+            return tuple(W if i % 2 == 0 else None for i in range(self.paging_layers))
+        return (W,) * self.paging_layers
+
+    @property
     def mixes_windowed_layers(self) -> bool:
-        """Windowed and global layers in one stack (Gemma-2's "alternating"),
-        against no window or one that every layer has."""
-        return self.sliding_window is not None and self.sliding_window_layers != "all"
+        """Windowed and global layers in one stack (Gemma-2's "alternating",
+        a "LLLG" pattern), against no window or one that every layer has."""
+        return len(set(self.layer_windows)) > 1
 
     @property
     def paging_layers(self) -> int:
         """Layers that hold keys and values, so the leading axis of every
         cache and of the page pool: all of them (a next-token module's layer
-        behind the stack's), or a hybrid stack's "*"."""
+        behind the stack's), or a hybrid stack's attention layers."""
         if self.is_hybrid:
-            return self.layer_pattern.count("*")
+            return sum(kind in PAGING_KINDS for kind in self.layer_pattern)
         return self.num_layers + self.num_nextn_predict_layers
 
     @property
@@ -688,6 +718,73 @@ register_config(
         mamba_n_groups=2,
         ssm_state_size=32,
         mamba_chunk=16,
+    )
+)
+
+# command-a-plus-05-2026 (CohereLabs, model_type cohere2_moe, "Command A+
+# 218B-A25B"; https://huggingface.co/CohereLabs/command-a-plus-05-2026): 32
+# parallel blocks (one mean-centred LayerNorm, GQA attention 128q/8kv of 128
+# and the expert layer side by side, one residual add), three windowed layers
+# (window 4,096, RoPE at theta 5e4) to one global layer without a positional
+# embedding; 128 SwiGLU experts of 4,096 top-8 by sigmoid scores, normalised,
+# plus 4 shared experts averaged; tied embeddings. The published preset is for
+# shape arithmetic (218 B parameters). ``-cut4`` is what the benchmark serves
+# on one chip: one period of the pattern (4 of 32 layers), every width as
+# published, and one chip's share of a layer that eight chips hold: 16 of the
+# 128 experts and 32,768 of the 262,144 rows of the vocabulary
+# (benchmark/configs/command-a-plus.json has the arithmetic, the deployment
+# and what is assumed). bfloat16, the paged continuous loop only; the text
+# model alone (the vision tower is not built).
+_COMMAND_A_PLUS = ModelConfig(
+    name="command-a-plus",
+    vocab_size=262144,
+    hidden_size=4096,
+    intermediate_size=4096,  # read as one expert's width: moe_intermediate_size
+    num_layers=32,
+    num_heads=128,
+    num_kv_heads=8,
+    head_dim=128,
+    rope_theta=50000.0,
+    rms_eps=1e-5,  # layer_norm_eps
+    max_seq_len=8192,  # served context; the config declares 200,000
+    sliding_window=4096,
+    num_experts=128,
+    num_experts_per_tok=8,
+    moe_intermediate_size=4096,
+    n_shared_experts=4,
+    moe_shared_intermediate_size=16384,
+    shared_experts_averaged=True,
+    routed_scaling_factor=1.0,
+    tie_embeddings=True,
+    layer_pattern="LLLG" * 8,
+)
+register_config(_COMMAND_A_PLUS)
+register_config(
+    _COMMAND_A_PLUS.with_(name="command-a-plus-cut4", num_layers=4, layer_pattern="LLLG",
+                          experts_held=16, vocab_size=32768)
+)
+# CPU test size of the same block: one period, a window of 12 (it binds within
+# two pages of 8), 16 experts top-2 of which 2 are held, 2 shared.
+register_config(
+    _COMMAND_A_PLUS.with_(
+        name="command-a-plus-tiny",
+        vocab_size=512,
+        hidden_size=64,
+        intermediate_size=32,
+        num_layers=4,
+        num_heads=8,
+        num_kv_heads=2,
+        head_dim=16,
+        max_seq_len=4096,
+        dtype="float32",
+        sliding_window=12,
+        num_experts=16,
+        num_experts_per_tok=2,
+        experts_held=2,
+        moe_intermediate_size=32,
+        n_shared_experts=2,
+        moe_shared_intermediate_size=64,
+        layer_pattern="LLLG",
     )
 )
 

@@ -23,6 +23,19 @@ mixer, chosen by the layer's character in ``config.layer_pattern``:
 - ``*`` **attention**: ``llama._block`` / ``_block_paged`` as they are (the
   layer has no MLP and ``config.use_rope`` is False), so the flash, XLA and
   paged kernels are the GQA models' own.
+- ``L`` / ``G`` **the parallel block** (Cohere's ``use_parallel_block``;
+  :func:`parallel_block`): ``n = LN(x)``; ``x <- x + Attn(n) + MoE(n)``, one
+  norm, two branches, one residual add. ``LN`` is the mean-centred LayerNorm
+  without bias (:func:`layer_norm`; the final norm too). ``Attn`` is GQA
+  without bias: ``L`` inside ``config.sliding_window`` under RoPE, ``G`` over
+  everything with no positional embedding. ``MoE`` is the latent block's
+  router and grouped products over gated experts (a held share of them:
+  ``config.experts_held``) plus the shared experts as one fused SwiGLU, times
+  ``1 / n_shared_experts`` where the model averages them. A step reads the
+  pool through the paged kernel with the layer's own window, static in its
+  call; prompts take the XLA masks over the dense staging cache, scored one kv
+  head's group of queries at a time (128 heads x a 8,192-key bucket in one
+  float32 array is 0.5 GB). No recurrent state: ``state`` stays empty.
 
 **Two kinds of per-row state.** Only ``*`` layers have keys and values: cache
 and pool have ``config.paging_layers`` leading entries, cache layer ``a`` is
@@ -63,9 +76,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import llama
-from .config import ModelConfig
-from .latent import _dot, _refuse, routed_experts
-from .llama import KVCache, Params, rms_norm
+from .config import PAGING_KINDS, ModelConfig
+from .latent import _dot, _moe_mlp, _refuse, _write_cache, routed_experts
+from .llama import KVCache, Params, rms_norm, rope_embed
 
 _F32 = jnp.float32
 
@@ -148,6 +161,24 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=None) -> Params:
                 "wv": normal(ks[2], (H, KV), H ** -0.5),
                 "wo": normal(ks[3], (Q, H), Q ** -0.5),
             }
+        if kind in PARALLEL_KINDS:
+            ke = jax.random.split(ks[5], 6)
+            Eh = config.held_experts
+            return {
+                "norm": jnp.ones((H,), dtype),
+                "wq": normal(ks[0], (H, Q), H ** -0.5),
+                "wk": normal(ks[1], (H, KV), H ** -0.5),
+                "wv": normal(ks[2], (H, KV), H ** -0.5),
+                "wo": normal(ks[3], (Q, H), Q ** -0.5),
+                "w_router": normal(ks[4], (H, E), H ** -0.5),
+                "router_bias": jnp.zeros((E,), _F32),
+                "w_gate": stack(ke[0], Eh, (H, Im), H ** -0.5),
+                "w_up": stack(ke[1], Eh, (H, Im), H ** -0.5),
+                "w_down": stack(ke[2], Eh, (Im, H), Im ** -0.5),
+                "ws_gate": normal(ke[3], (H, Is), H ** -0.5),
+                "ws_up": normal(ke[4], (H, Is), H ** -0.5),
+                "ws_down": normal(ke[5], (Is, H), Is ** -0.5),
+            }
         raise ValueError(f"{config.name}: layer kind {kind!r} in {config.layer_pattern!r}")
 
     if len(config.layer_pattern) != config.num_layers:
@@ -157,12 +188,14 @@ def init_params(config: ModelConfig, key: jax.Array, dtype=None) -> Params:
         )
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     layer_keys = jax.random.split(k_layers, config.num_layers)
-    return {
+    params = {
         "embed": normal(k_embed, (V, H), H ** -0.5),
         "layers": [layer(kind, k) for kind, k in zip(config.layer_pattern, layer_keys)],
         "final_norm": jnp.ones((H,), dtype),
-        "lm_head": normal(k_head, (H, V), H ** -0.5),
     }
+    if not config.tie_embeddings:
+        params["lm_head"] = normal(k_head, (H, V), H ** -0.5)
+    return params
 
 
 def param_count(config: ModelConfig) -> int:
@@ -300,6 +333,80 @@ def moe_mixer(config: ModelConfig, layer: Params, h: jax.Array):
 
 
 # ---------------------------------------------------------------------------
+# The parallel block
+# ---------------------------------------------------------------------------
+
+#: The pattern's characters whose layer is the parallel block: "L" windowed
+#: under RoPE, "G" global without a positional embedding.
+PARALLEL_KINDS = "LG"
+
+
+@jax.named_scope("layer_norm")
+def layer_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    """Cohere's LayerNorm: subtract the mean, divide by sqrt(var + eps), times
+    a weight; no bias."""
+    x32 = x.astype(_F32)
+    x32 = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    scale = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * scale).astype(x.dtype) * weight
+
+
+def stack_norm(config: ModelConfig, x: jax.Array, weight: jax.Array) -> jax.Array:
+    """The stack's norm: the parallel block's LayerNorm where the pattern has
+    one, else RMSNorm."""
+    if any(kind in PARALLEL_KINDS for kind in config.layer_pattern):
+        return layer_norm(x, weight, config.rms_eps)
+    return rms_norm(x, weight, config.rms_eps)
+
+
+@jax.named_scope("attn_qkv")
+def _parallel_qkv(config: ModelConfig, layer: Params, n: jax.Array, positions: jax.Array,
+                  rope: bool):
+    B_, S, _ = n.shape
+    q = _dot(n, layer["wq"]).reshape(B_, S, config.num_heads, config.head_dim)
+    k = _dot(n, layer["wk"]).reshape(B_, S, config.num_kv_heads, config.head_dim)
+    v = _dot(n, layer["wv"]).reshape(B_, S, config.num_kv_heads, config.head_dim)
+    if rope:
+        q = rope_embed(q, positions, config.rope_theta, config.rope_scaling)
+        k = rope_embed(k, positions, config.rope_theta, config.rope_scaling)
+    return q, k, v
+
+
+@jax.named_scope("attn")
+def _attend_masked(config: ModelConfig, q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
+                   key_mask: jax.Array) -> jax.Array:
+    """q [B, Sq, QH, D] against a dense cache [B, Smax, KVH, D] under
+    ``key_mask`` [B|1, Sq, Smax], one kv head's group of queries at a time
+    -> [B, Sq, QH * D]."""
+    B_, Sq, QH, D = q.shape
+    KVH = cache_k.shape[2]
+    qg = q.reshape(B_, Sq, KVH, QH // KVH, D)
+
+    def one(h):
+        k, v = cache_k[:, :, h], cache_v[:, :, h]  # [B, Smax, D]
+        s = jnp.einsum("bqgd,bkd->bgqk", qg[:, :, h], k,
+                       preferred_element_type=_F32) * config.attn_scale
+        s = jnp.where(key_mask[:, None], s, jnp.finfo(_F32).min)
+        w = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        return jnp.einsum("bgqk,bkd->bqgd", w, v, preferred_element_type=_F32)
+
+    out = lax.map(one, jnp.arange(KVH))  # [KVH, B, Sq, G, D]
+    return jnp.moveaxis(out, 0, 2).astype(q.dtype).reshape(B_, Sq, QH * D)
+
+
+def parallel_block(config: ModelConfig, layer: Params, x: jax.Array, attention):
+    """``x + Attn(n) + MoE(n)`` with ``n = LN(x)``. ``attention(n) -> (attn
+    [B, S, QH * D], kv)`` is the caller's (dense cache or page pool)."""
+    n = layer_norm(x, layer["norm"], config.rms_eps)
+    attn, kv = attention(n)
+    with jax.named_scope("attn_out"):
+        attn = _dot(attn, layer["wo"])
+    moe, routed = _moe_mlp(config, layer, n)
+    with jax.named_scope("parallel_add"):
+        return x + attn + moe, kv, routed
+
+
+# ---------------------------------------------------------------------------
 # The stack
 # ---------------------------------------------------------------------------
 
@@ -308,16 +415,20 @@ _BLOCK = "the hybrid stack (recurrent state beside the cache)"
 
 def _run(config: ModelConfig, params: Params, x: jax.Array, valid_len: jax.Array,
          state: Optional[dict], aux: Optional[dict], attend):
-    """Every layer in the pattern's order. ``attend(layer, x, a) -> (x, kv)``
-    runs attention layer number ``a`` (block and residual); returns (x, the
-    list of what ``attend`` gave back) and fills ``state`` and ``aux``."""
+    """Every layer in the pattern's order. ``attend(kind, layer, x, a) -> (x,
+    kv, routed)`` runs paging layer number ``a`` (block and residual; ``routed``
+    the parallel block's {counts, chosen}, None for "*"); returns (x, the list
+    of the ``kv`` it gave back) and fills ``state`` and ``aux``."""
+    recurrent = "M" in config.layer_pattern
     state_in = state if state else llama.init_state(config, x.shape[0])
-    ssm_in, conv_in = state_in["ssm"], state_in["conv"]
+    ssm_in, conv_in = state_in.get("ssm"), state_in.get("conv")
     ssm_out, conv_out, seen, routed, kvs = [], [], [], [], []
     for kind, layer in zip(config.layer_pattern, params["layers"]):
-        if kind == "*":
-            x, kv = attend(layer, x, len(kvs))
+        if kind in PAGING_KINDS:
+            x, kv, r = attend(kind, layer, x, len(kvs))
             kvs.append(kv)
+            if r is not None:
+                routed.append(r)
             continue
         h = rms_norm(x, layer["norm"], config.rms_eps)
         if kind == "M":
@@ -331,15 +442,16 @@ def _run(config: ModelConfig, params: Params, x: jax.Array, valid_len: jax.Array
             out, r = moe_mixer(config, layer, h)
             routed.append(r)
         x = x + out
-    if state is not None:
+    if state is not None and recurrent:
         state["ssm"], state["conv"] = tuple(ssm_out), tuple(conv_out)
     if aux is not None:
         for key in ("counts", "chosen") if "moe_chosen" in aux else ("counts",):
             aux["moe_" + key] = jnp.stack([r[key] for r in routed])
         if "ssm_inputs" in aux:
             aux["ssm_inputs"] = seen
-        aux["ssm_rows_updated"] = jnp.sum(valid_len > 0, dtype=jnp.int32) * len(ssm_out)
-        aux["ssm_tokens_scanned"] = jnp.sum(valid_len, dtype=jnp.int32) * len(ssm_out)
+        if recurrent:
+            aux["ssm_rows_updated"] = jnp.sum(valid_len > 0, dtype=jnp.int32) * len(ssm_out)
+            aux["ssm_tokens_scanned"] = jnp.sum(valid_len, dtype=jnp.int32) * len(ssm_out)
     return x, kvs
 
 
@@ -358,17 +470,32 @@ def apply_stack(
     aux: Optional[dict] = None,
     sp_ring_mesh=None,
     mesh=None,
+    key_mask_global: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, KVCache]:
     """``llama._apply_stack`` for the hybrid stack, over dense caches
     ``[paging layers, B, Smax, KVH, D]``: the full forward, whole-prompt
     prefill and the chunk steps. ``valid_len`` [B]: how many of each row's
-    positions are tokens (the rest is right padding the state must not see)."""
+    positions are tokens (the rest is right padding the state must not see).
+    Where windowed and global layers mix, ``key_mask`` is the windowed mask
+    and ``key_mask_global`` the full-causal one, as in ``llama._apply_stack``;
+    each layer takes its own by its kind."""
     _refuse(config, _BLOCK, mesh=mesh, sp_ring_mesh=sp_ring_mesh,
             **{"a shared-prefix decode or verify step (the dense decode path)": prefix})
 
-    def attend(layer, x, a):
-        return llama._block(config, layer, x, positions, (cache.k[a], cache.v[a]),
-                            write_index, key_mask, key_lengths=key_lengths)
+    def attend(kind, layer, x, a):
+        if kind == "*":
+            return llama._block(config, layer, x, positions, (cache.k[a], cache.v[a]),
+                                write_index, key_mask, key_lengths=key_lengths) + (None,)
+        mask = key_mask if kind == "L" or key_mask_global is None else key_mask_global
+
+        def attention(n):
+            q, k, v = _parallel_qkv(config, layer, n, positions, rope=kind == "L")
+            with jax.named_scope("kv_write"):
+                ck = _write_cache(cache.k[a], k, write_index)
+                cv = _write_cache(cache.v[a], v, write_index)
+            return _attend_masked(config, q, ck, cv, mask), (ck, cv)
+
+        return parallel_block(config, layer, x, attention)
 
     x, kvs = _run(config, params, x, valid_len, state, aux, attend)
     return x, KVCache(k=jnp.stack([k for k, _ in kvs]), v=jnp.stack([v for _, v in kvs]))
@@ -392,11 +519,16 @@ def apply_stack_paged(
     state: Optional[dict] = None,
     aux: Optional[dict] = None,
     mesh=None,
+    key_mask_global: Optional[jax.Array] = None,
+    prefix_mask_global: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """``llama._apply_stack_paged`` for the hybrid stack: the attention layers
-    read the pool ``[paging layers, flat, KVH, D]`` through ``_block_paged``,
-    the Mamba-2 layers advance the rows whose ``valid_len`` ([B] bool or 0/1;
-    None: all) is set. Returns (x,
+    read the pool ``[paging layers, flat, KVH, D]`` through ``_block_paged``
+    (the parallel block: through its attention, ``_paged_attend``), each with its
+    own window (``config.layer_windows``) static in the kernel's call and, on
+    the XLA path, its own masks (``*_global`` for a layer without a window, as
+    in ``llama._apply_stack_paged``); the Mamba-2 layers advance the rows
+    whose ``valid_len`` ([B] bool or 0/1; None: all) is set. Returns (x,
     k_cols, v_cols ``[paging layers, B, KVH, D]``)."""
     _refuse(config, _BLOCK, mesh=mesh)
     B_ = x.shape[0]
@@ -406,12 +538,24 @@ def apply_stack_paged(
         from ..ops.paged_attention import paged_attention_page_tables
 
         page_tables = paged_attention_page_tables(prefix_idx, gen_idx, page_size)
+    windows = config.layer_windows
 
-    def attend(layer, x, a):
-        return llama._block_paged(
-            config, layer, x, positions, pool_kv, jnp.int32(a), prefix_idx, gen_idx,
-            write_index, key_mask, prefix_mask, prefix_lengths=prefix_lengths,
-            page_tables=page_tables, page_size=page_size, attn_impl=attn_impl)
+    def attend(kind, layer, x, a):
+        glob = windows[a] is None and key_mask_global is not None
+        paged = dict(
+            pool_kv=pool_kv, layer_idx=jnp.int32(a), prefix_idx=prefix_idx, gen_idx=gen_idx,
+            write_index=write_index, key_mask=key_mask_global if glob else key_mask,
+            prefix_mask=prefix_mask_global if glob else prefix_mask,
+            prefix_lengths=prefix_lengths, page_tables=page_tables, page_size=page_size,
+            attn_impl=attn_impl, window=windows[a])
+        if kind == "*":
+            return llama._block_paged(config, layer, x, positions, **paged) + (None,)
+
+        def attention(n):
+            q, k, v = _parallel_qkv(config, layer, n, positions, rope=kind == "L")
+            return llama._paged_attend(config, q, k, v, **paged)
+
+        return parallel_block(config, layer, x, attention)
 
     x, cols = _run(config, params, x, valid_len, state, aux, attend)
     return x, jnp.stack([k for k, _ in cols]), jnp.stack([v for _, v in cols])

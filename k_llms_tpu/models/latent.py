@@ -441,10 +441,17 @@ def routed_experts(config: ModelConfig, layer: Params, h: jax.Array,
 
 
 def _moe_mlp(config: ModelConfig, layer: Params, h: jax.Array):
+    """h [B, S, H] -> (routed + shared [B, S, H], {counts, chosen}): the held
+    experts' share of the routed sum, and the shared experts as one fused
+    SwiGLU (their sum), times 1 / n_shared_experts where the model averages
+    them (``config.shared_experts_averaged``: the parallel block's,
+    models/hybrid.py)."""
     B, S, H = h.shape
     out, counts, chosen = routed_experts(config, layer, h.reshape(B * S, H))
     with jax.named_scope("moe_shared"):
         shared = _swiglu(h, layer["ws_gate"], layer["ws_up"], layer["ws_down"])
+        if config.shared_experts_averaged:
+            shared = shared * jnp.asarray(1.0 / config.n_shared_experts, shared.dtype)
     return out.reshape(B, S, H) + shared, {"counts": counts, "chosen": chosen}
 
 

@@ -569,8 +569,7 @@ def _local_layer_flags(config: ModelConfig) -> Optional[jax.Array]:
     (full causal everywhere, or every layer windowed)."""
     if not config.mixes_windowed_layers:
         return None
-    # "alternating" (Gemma-2): even layers local, odd layers global.
-    return jnp.arange(config.num_layers) % 2 == 0
+    return jnp.asarray([w is not None for w in config.layer_windows[: config.num_layers]])
 
 
 def _apply_stack(
@@ -614,7 +613,7 @@ def _apply_stack(
         return hybrid.apply_stack(
             config, params, x, positions, cache, write_index, key_mask, valid_len,
             key_lengths=key_lengths, prefix=prefix, state=state, aux=aux,
-            sp_ring_mesh=sp_ring_mesh, mesh=mesh,
+            sp_ring_mesh=sp_ring_mesh, mesh=mesh, key_mask_global=key_mask_global,
         )
     if config.is_latent:
         from . import latent
@@ -690,9 +689,21 @@ def _embed(config: ModelConfig, params: Params, tokens: jax.Array) -> jax.Array:
     return x
 
 
+def _final_norm(config: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
+    if config.is_hybrid:
+        from . import hybrid
+
+        return hybrid.stack_norm(config, x, params["final_norm"])
+    return rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
+
+
 @jax.named_scope("lm_head")
 def _logits(config: ModelConfig, params: Params, h: jax.Array) -> jax.Array:
-    logits = qdot(h, params["lm_head"]).astype(jnp.float32)
+    if config.tie_embeddings:  # the head is the embedding table, read in place
+        logits = jnp.einsum("...h,vh->...v", h, params["embed"],
+                            preferred_element_type=jnp.float32)
+    else:
+        logits = qdot(h, params["lm_head"]).astype(jnp.float32)
     if config.logit_softcap is not None:
         logits = _softcap(logits, config.logit_softcap)
     return logits
@@ -733,7 +744,7 @@ def forward(
     key_mask_global = None
     if config.sliding_window is not None:  # query i sees keys (i-W, i]
         band = causal & jnp.triu(jnp.ones((S, S), bool), -(config.sliding_window - 1))
-        if config.sliding_window_layers == "alternating":
+        if config.mixes_windowed_layers:
             key_mask_global = causal[None, :, :] & pad_mask[:, None, :].astype(bool)
         causal = band
     key_mask = causal[None, :, :] & pad_mask[:, None, :].astype(bool)
@@ -753,7 +764,7 @@ def forward(
         mesh=mesh,
         valid_len=key_lengths,  # a recurrent state takes the padding to be on the right
     )
-    h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
+    h = _final_norm(config, params, x)
     logits = _logits(config, params, h)
     if with_module:
         from . import latent
@@ -785,7 +796,7 @@ def prefill(
     key_mask_global = None
     if config.sliding_window is not None:
         band = causal & jnp.triu(jnp.ones((S, S), bool), -(config.sliding_window - 1))
-        if config.sliding_window_layers == "alternating":
+        if config.mixes_windowed_layers:
             key_mask_global = causal[None, :, :] & valid[:, None, :]
         causal = band
     key_mask = causal[None, :, :] & valid[:, None, :]
@@ -812,7 +823,7 @@ def prefill(
 
         cache = latent.mtp_ingest(
             config, params, emb, x, positions, cache, None, key_lengths - 1, state)
-    h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
+    h = _final_norm(config, params, x)
     last = jnp.take_along_axis(h, (prompt_len - 1).reshape(B, 1, 1).astype(jnp.int32), axis=1)
     logits = _logits(config, params, last[:, 0, :])
     return logits, cache
@@ -854,7 +865,7 @@ def prefill_continue(
     key_mask_global = None
     if config.sliding_window is not None:
         band = causal_abs & (cols > rows - config.sliding_window)
-        if config.sliding_window_layers == "alternating":
+        if config.mixes_windowed_layers:
             key_mask_global = causal_abs
         causal_abs = band
     x, cache = _apply_stack(
@@ -877,7 +888,7 @@ def prefill_continue(
         cache = latent.mtp_ingest(
             config, params, emb, x, positions, cache, prefix_len,
             jnp.broadcast_to(total_len - prefix_len - 1, (B,)), state)
-    h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
+    h = _final_norm(config, params, x)
     last_row = (total_len - prefix_len - 1).reshape(B, 1, 1).astype(jnp.int32)
     last = jnp.take_along_axis(h, last_row, axis=1)
     logits = _logits(config, params, last[:, 0, :])
@@ -987,7 +998,7 @@ def decode_step(
         # Query position is prompt_len + step; key position k is visible iff
         # q_pos - k_pos < W. Gen slot s sits at position prompt_len + s.
         W = config.sliding_window
-        if config.sliding_window_layers == "alternating":
+        if config.mixes_windowed_layers:
             self_mask_global, prefix_mask_global = self_mask, prefix_mask
         self_mask = self_mask & (jnp.arange(G)[None, None, :] > step - W)
         prefix_mask = prefix_mask & (
@@ -1010,7 +1021,7 @@ def decode_step(
         sp_ring_mesh=sp_ring_mesh,
         mesh=mesh,
     )
-    h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
+    h = _final_norm(config, params, x)
     logits = _logits(config, params, h[:, 0, :])
     return logits, gen_cache
 
@@ -1062,7 +1073,7 @@ def verify_step(
     self_mask_global = prefix_mask_global = None
     if config.sliding_window is not None:
         W = config.sliding_window
-        if config.sliding_window_layers == "alternating":
+        if config.mixes_windowed_layers:
             self_mask_global, prefix_mask_global = self_mask, prefix_mask
         qpos_gen = (lengths[:, None] + j)[:, :, None]  # query's gen position
         self_mask = self_mask & (s > qpos_gen - W)
@@ -1085,7 +1096,7 @@ def verify_step(
         mesh=mesh,
         aux=aux,
     )
-    h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
+    h = _final_norm(config, params, x)
     logits = _logits(config, params, h)
     return logits, gen_cache
 
@@ -1094,11 +1105,11 @@ def verify_step(
 # Paged KV path (block-table gather over a flat page pool)
 # ---------------------------------------------------------------------------
 
-def _block_paged(
+def _paged_attend(
     config: ModelConfig,
-    layer: Params,
-    x: jax.Array,
-    positions: jax.Array,
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
     pool_kv: KVCache,
     layer_idx: jax.Array,
     prefix_idx: jax.Array,
@@ -1111,31 +1122,20 @@ def _block_paged(
     page_size: Optional[int] = None,
     attn_impl: str = "xla",
     mesh=None,
+    window: Optional[int] = None,
 ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    """Paged twin of :func:`_block` for the ``Sq == 1`` decode/verify step.
-
-    KV comes from the whole flat page pool (``pool_kv``,
-    ``[L, flat, KVH, D]``) through block tables and this layer's number
-    (``layer_idx``, int32 scalar): the pool is never sliced, the attention op
-    addresses (layer, slot) itself. Attention runs in
-    ``ops/paged_attention.py`` — the fused Pallas kernel when ``attn_impl``
-    selects it (block-table gather folded into the K/V load, no materialized
-    copy) or the byte-identical XLA reference otherwise. Returns
-    ``(x, (k_col, v_col))`` where the cols ``[B, KVH, D]``
-    are this step's freshly computed column in pool dtype — the caller
-    scatters them into the pool (the old path extracted the same column from
-    the written gather transient via ``take_along_axis``; taking it straight
-    from the projection is bit-identical and skips the round-trip).
-    """
+    """The attention of :func:`_block_paged`, for any block that has its
+    queries, keys and values ``[B, Sq, heads, D]`` (the parallel block's too,
+    models/hybrid.py): the fused kernel or the XLA reference over the pool ->
+    (attn ``[B, Sq, q_dim]``, this step's (k_col, v_col) in pool dtype)."""
     from ..ops.attention import resolve_attention_impl
     from ..ops.paged_attention import (
         paged_decode_attention_pallas,
         paged_decode_attention_xla,
     )
 
-    B, Sq, H = x.shape
+    B, Sq = q.shape[:2]
     scale = config.attn_scale
-    q, k, v = _attn_qkv(config, layer, x, positions)
     k_col = k[:, 0].astype(pool_kv.k.dtype)
     v_col = v[:, 0].astype(pool_kv.v.dtype)
 
@@ -1146,7 +1146,6 @@ def _block_paged(
             and page_tables is not None
             and prefix_lengths is not None
             and config.attn_softcap is None
-            and not config.mixes_windowed_layers  # the kernel takes one window
         ):
             prefix_pages, gen_pages, gen_phase = page_tables
             plen = jnp.asarray(prefix_lengths, jnp.int32).reshape(-1)
@@ -1165,7 +1164,7 @@ def _block_paged(
                 write_index.astype(jnp.int32),
                 page_size=page_size,
                 sm_scale=scale,
-                window=config.sliding_window,
+                window=window,
                 interpret=attn_impl == "pallas_interpret",
                 mesh=mesh,
             )[:, None]  # [B, 1, QH, D]
@@ -1199,9 +1198,52 @@ def _block_paged(
                 flash_prefix=decode_impl if flash_prefix else None,
                 mesh=mesh,
             )
-    attn = attn.astype(x.dtype).reshape(B, Sq, config.q_dim)
+    return attn.astype(q.dtype).reshape(B, Sq, config.q_dim), (k_col, v_col)
+
+
+def _block_paged(
+    config: ModelConfig,
+    layer: Params,
+    x: jax.Array,
+    positions: jax.Array,
+    pool_kv: KVCache,
+    layer_idx: jax.Array,
+    prefix_idx: jax.Array,
+    gen_idx: jax.Array,
+    write_index: jax.Array,
+    key_mask: jax.Array,
+    prefix_mask: jax.Array,
+    prefix_lengths: Optional[jax.Array] = None,
+    page_tables=None,
+    page_size: Optional[int] = None,
+    attn_impl: str = "xla",
+    mesh=None,
+    window: Optional[int] = None,
+) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
+    """Paged twin of :func:`_block` for the ``Sq == 1`` decode/verify step.
+
+    KV comes from the whole flat page pool (``pool_kv``,
+    ``[L, flat, KVH, D]``) through block tables and this layer's number
+    (``layer_idx``, int32 scalar): the pool is never sliced, the attention op
+    addresses (layer, slot) itself. Attention runs in
+    ``ops/paged_attention.py`` — the fused Pallas kernel when ``attn_impl``
+    selects it (block-table gather folded into the K/V load, no materialized
+    copy) or the byte-identical XLA reference otherwise. Returns
+    ``(x, (k_col, v_col))`` where the cols ``[B, KVH, D]``
+    are this step's freshly computed column in pool dtype — the caller
+    scatters them into the pool (the old path extracted the same column from
+    the written gather transient via ``take_along_axis``; taking it straight
+    from the projection is bit-identical and skips the round-trip).
+    ``window``: this layer's sliding window (``config.layer_windows``), static:
+    the kernel's walk and mask take it; the XLA path has it in its masks.
+    """
+    q, k, v = _attn_qkv(config, layer, x, positions)
+    attn, cols = _paged_attend(
+        config, q, k, v, pool_kv, layer_idx, prefix_idx, gen_idx, write_index, key_mask,
+        prefix_mask, prefix_lengths=prefix_lengths, page_tables=page_tables, page_size=page_size,
+        attn_impl=attn_impl, mesh=mesh, window=window)
     x = _attn_residual(config, layer, x, attn)
-    return _mlp_sublayer(config, layer, x), (k_col, v_col)
+    return _mlp_sublayer(config, layer, x), cols
 
 
 def _apply_stack_paged(
@@ -1258,6 +1300,7 @@ def _apply_stack_paged(
             config, params, x, positions, pool_kv, prefix_idx, gen_idx, write_index,
             key_mask, prefix_mask, valid_len, prefix_lengths=prefix_lengths,
             attn_impl=attn_impl, page_size=page_size, state=state, aux=aux, mesh=mesh,
+            key_mask_global=key_mask_global, prefix_mask_global=prefix_mask_global,
         )
     if config.is_latent:
         from . import latent
@@ -1269,7 +1312,11 @@ def _apply_stack_paged(
     local_flags = _local_layer_flags(config) if key_mask_global is not None else None
 
     page_tables = None
-    if attn_impl in ("pallas", "pallas_interpret"):
+    # The kernel's window is static in its call and this scan has one body for
+    # every layer: a stack that mixes windowed and global layers stays on the
+    # XLA masks here (resolve_paged_attention_impl says so by name); one whose
+    # layers are unrolled (models/hybrid.py) hands each call its own window.
+    if attn_impl in ("pallas", "pallas_interpret") and not config.mixes_windowed_layers:
         from ..ops.paged_attention import paged_attention_page_tables
 
         # Layer-invariant: hoisted out of the scan so the slot->page
@@ -1301,6 +1348,7 @@ def _apply_stack_paged(
             page_size=page_size,
             attn_impl=attn_impl,
             mesh=mesh,
+            window=config.sliding_window,
         )
         return x, cols
 
@@ -1371,7 +1419,7 @@ def paged_verify_step(
     self_mask_global = prefix_mask_global = None
     if config.sliding_window is not None:
         W = config.sliding_window
-        if config.sliding_window_layers == "alternating":
+        if config.mixes_windowed_layers:
             self_mask_global, prefix_mask_global = self_mask, prefix_mask
         qpos_gen = (lengths[:, None] + j)[:, :, None]
         self_mask = self_mask & (s > qpos_gen - W)
@@ -1398,7 +1446,7 @@ def paged_verify_step(
         valid_len=active,
         state=state,
     )
-    h = rms_norm(x, params["final_norm"], config.rms_eps, config.norm_offset)
+    h = _final_norm(config, params, x)
     logits = _logits(config, params, h)
     if return_hidden:
         return logits, k_cols, v_cols, x

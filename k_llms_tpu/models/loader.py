@@ -303,7 +303,7 @@ def load_checkpoint(path: str, config: ModelConfig, dtype=None) -> Dict[str, Any
     if config.is_hybrid:
         raise NotImplementedError(
             f"{config.name}: no checkpoint mapping for the hybrid stack (Mamba-2 mixers, "
-            "non-gated expert stacks, a per-layer pattern); it runs on seeded weights"
+            "expert stacks, parallel blocks, a per-layer pattern); it runs on seeded weights"
         )
     if os.path.isdir(path) and any(f.endswith(".safetensors") for f in os.listdir(path)):
         params = load_safetensors(path, config, dtype)

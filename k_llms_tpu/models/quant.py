@@ -330,7 +330,7 @@ def init_params_quantized(
     if config.is_hybrid:
         raise NotImplementedError(
             f"{config.name}: no quantized init for the hybrid stack; it is served in "
-            f"{config.dtype} (int8/int4 for the non-gated expert stacks are not written)"
+            f"{config.dtype} (int8/int4 for its per-layer expert stacks are not written)"
         )
     cheap = dist == "cheap"
     dtype = dtype or config.jax_dtype

@@ -34,10 +34,13 @@ sliding_window / mla / platform) when "pallas" is requested but can't run, or
 when "auto" on a TPU meets a model outside the kernel's support; "auto"
 choosing XLA off-TPU is the documented CPU posture, not a fallback, so it is
 not counted. The ``ops.paged_attn`` failpoint forces the fallback branch for
-drills. A sliding window that every layer has (Mistral) is inside the kernel's
-support: it is part of the walk and of the mask (:func:`live_pages`). Only a
-per-layer mix of windowed and global layers (Gemma-2's "alternating") is
-``sliding_window`` now.
+drills. A sliding window is inside the kernel's support: it is part of the
+walk and of the mask (:func:`live_pages`), static in each call. A stack whose
+layers are unrolled (models/hybrid.py) hands each layer's call its own
+(``ModelConfig.layer_windows``), so windowed and global layers mix under the
+kernel there. The scanned GQA stack (models/llama.py) has one call for every
+layer: a window that every layer has (Mistral) is served, a per-layer mix
+(Gemma-2's "alternating", which its softcap blocks first) is ``sliding_window``.
 
 Masking contract (shared with `gather_kv_pages`): out-of-table positions
 point into the trash page; their values are arbitrary-but-finite and every
@@ -83,11 +86,14 @@ def resolve_paged_attention_impl(
     """Pick the paged-attention implementation for the current process.
 
     requested: "auto" | "pallas" | "xla"; config: optional ModelConfig — a
-    model using attention softcap, a latent (MLA) cache, or a sliding window
-    on some layers and not on others (``sliding_window_layers ==
-    "alternating"``: the kernel takes one window for the whole stack) is
-    outside the kernel's support and resolves to "xla". A window on every
-    layer is served by the kernel. Resolution is host-side and happens once
+    model using attention softcap, a latent (MLA) cache, or a scanned GQA
+    stack with a sliding window on some layers and not on others
+    (``sliding_window_layers == "alternating"``: the scan has one kernel call
+    and so one window for every layer) is outside the kernel's support and
+    resolves to "xla". A window on every layer is served by the kernel, and so
+    is a hybrid stack's mix of windowed and global layers (its layers are
+    unrolled: each call takes its own window).
+    Resolution is host-side and happens once
     per loop/launch build, not per step. An explicit "pallas" request that
     cannot be honored records ``kernel.paged_attn_fallback.<reason>``, where
     the reason distinguishes config-driven fallbacks (``softcap``,
@@ -115,8 +121,8 @@ def resolve_paged_attention_impl(
         blocked: Optional[str] = "mla"  # a latent page is no (KVH, D) tile
     elif config is not None and config.attn_softcap is not None:
         blocked = "softcap"
-    elif config is not None and config.mixes_windowed_layers:
-        blocked = "sliding_window"
+    elif config is not None and config.mixes_windowed_layers and not config.is_hybrid:
+        blocked = "sliding_window"  # one scanned kernel call, one window
     else:
         blocked = None
     on_tpu = jax.default_backend() == "tpu"
@@ -538,8 +544,8 @@ def paged_decode_attention_pallas(
     new_k/new_v [B, KVH, D]: this step's fresh column; prompt_lens /
     gen_lens [B]: per-row valid counts — they are the walk's trip count too
     (:func:`live_pages`), so a row whose lengths are zero reads no page;
-    window: the sliding window every layer of the model has, or None — the
-    walk starts at the window's first page and the mask ends at its edge.
+    window: this layer's sliding window, or None — the walk starts at the
+    window's first page and the mask ends at its edge.
     Returns [B, QH, D] f32 — the normalized output the XLA reference
     produces, up to the float ordering of an online softmax over blocks
     (f32 accumulation: 2e-5 beside the reference over an f32 pool, bf16's own

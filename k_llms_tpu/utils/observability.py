@@ -270,10 +270,9 @@ CONSENSUS_EVENTS = EventCounters(declared=(
 #: launch, not per token; kernel.paged_attn_fallback.<reason> — an explicit
 #: "pallas" request degraded to the XLA reference, with the reason suffix
 #: naming what blocked it: ``failpoint`` (the ops.paged_attn failpoint),
-#: ``softcap`` / ``sliding_window`` / ``mla`` (model config the kernel doesn't
-#: cover — capability-driven; ``sliding_window`` is a per-layer mix of windowed
-#: and global layers, a window on every layer is served), or ``platform`` (no
-#: TPU — environment-driven); "auto"
+#: ``softcap`` / ``mla`` (model config the kernel doesn't cover —
+#: capability-driven; a sliding window is served, on every layer or on some),
+#: or ``platform`` (no TPU — environment-driven); "auto"
 #: choosing XLA on CPU is the documented posture and is NOT counted as a
 #: fallback), fed by ops/paged_attention.py and surfaced via scheduler
 #: stats/health and ``/metrics`` as ``kllms_kernel_*``.
@@ -335,13 +334,15 @@ MODEL_COUNTERS = EventCounters(declared=(
 #: How much of its block tables the fused paged-decode kernel walks, added up
 #: a continuous decode step on the host (``engine/continuous.py::_step_once``,
 #: beside the dispatch counter) and exported unlabeled as ``kllms_<name>`` on
-#: ``/metrics``. ``paged_attn_pages_walked`` — pages holding a position some
-#: live row attends to (``live_pages``, summed over rows: what the kernel
-#: fetches a layer); ``paged_attn_pages_tabled`` — rows x table pages, what a
-#: walk of whole tables would fetch; ``paged_attn_pages_windowed_out`` — pages
-#: that hold a position in the pool and lie before the sliding window's first
-#: page, so the walk starts past them (0 while no row outgrows its window).
-#: Zero where the XLA path serves.
+#: ``/metrics``. All three count layer-pages: a page once for every paging
+#: layer (``SlotPages.walk_counts``), each layer under its own window.
+#: ``paged_attn_pages_walked`` — pages holding a position some live row
+#: attends to (``live_pages``, summed over rows and layers: what the kernel
+#: fetches a step); ``paged_attn_pages_tabled`` — rows x table pages x layers,
+#: what a walk of whole tables would fetch; ``paged_attn_pages_windowed_out`` —
+#: pages that hold a position in the pool and lie before a layer's sliding
+#: window's first page, so that layer's walk starts past them (0 while no row
+#: outgrows a window). Zero where the XLA path serves.
 PAGED_ATTN_PAGES = EventCounters(declared=(
     "paged_attn_pages_walked",
     "paged_attn_pages_tabled",

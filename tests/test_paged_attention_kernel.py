@@ -335,9 +335,11 @@ def test_live_pages_counts_the_pages_the_reference_masks_leave(page_size, window
 def test_loop_counts_the_pages_its_steps_walk(window, monkeypatch):
     """A small paged loop on the interpreted kernel: greedy tokens equal the
     XLA-paged loop's, and the ``/metrics`` gauges advance by the sums the
-    rows' lengths give — idle slots walking nothing, and under a sliding
-    window (3: it leaves the prompt's first page, then the prompt, then the
-    first generated page) the pages before its first one counted apart."""
+    rows' lengths give, in layer-pages (a page once for every paging layer) —
+    idle slots walking nothing, and under a sliding window (3: it leaves the
+    prompt's first page, then the prompt, then the first generated page) the
+    pages before its first one counted apart (a stack that mixes windowed and
+    global layers under the kernel: tests/test_command_a_plus.py)."""
     import asyncio
 
     from conftest import shared_engine
@@ -348,9 +350,10 @@ def test_loop_counts_the_pages_its_steps_walk(window, monkeypatch):
 
     ps, width, max_prompt, max_new, n, new = 8, 4, 64, 16, 2, 10
     prompt = [5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 15]  # 11 tokens: phase 3
-    engine = shared_engine(
-        model=CONFIG.with_(sliding_window=window), kv_layout="paged", kv_page_size=ps
-    )
+    config = CONFIG.with_(sliding_window=window)
+    windows = config.layer_windows
+    assert windows == (window, window)
+    engine = shared_engine(model=config, kv_layout="paged", kv_page_size=ps)
     runs = {}
     for impl in ("xla", "pallas_interpret"):
         monkeypatch.setattr(
@@ -382,19 +385,21 @@ def test_loop_counts_the_pages_its_steps_walk(window, monkeypatch):
     for t in range(steps):
         # Table pages with a position in the pool, and those of them with a
         # position the query at plen + t still sees.
-        prompt_pages = {c // ps for c in range(plen)}
-        gen_pages = {(phase + g) // ps for g in range(t)}
-        held += n * (len(prompt_pages) + len(gen_pages))
-        if window is not None:
-            first = plen + t - window + 1
-            prompt_pages -= {c // ps for c in range(plen) if c >= first}
-            gen_pages -= {(phase + g) // ps for g in range(t) if plen + g >= first}
-            windowed_out += n * (len(prompt_pages) + len(gen_pages))
+        for layer_window in windows:
+            prompt_pages = {c // ps for c in range(plen)}
+            gen_pages = {(phase + g) // ps for g in range(t)}
+            held += n * (len(prompt_pages) + len(gen_pages))
+            if layer_window is not None:
+                first = plen + t - layer_window + 1
+                prompt_pages -= {c // ps for c in range(plen) if c >= first}
+                gen_pages -= {(phase + g) // ps for g in range(t) if plen + g >= first}
+                windowed_out += n * (len(prompt_pages) + len(gen_pages))
     walked = held - windowed_out
     assert (windowed_out > 0) == (window is not None)
     assert grew == {
         "paged_attn_pages_walked": walked,
-        "paged_attn_pages_tabled": steps * width * sum(table_pages(max_prompt, max_new, ps)),
+        "paged_attn_pages_tabled": (
+            len(windows) * steps * width * sum(table_pages(max_prompt, max_new, ps))),
         "paged_attn_pages_windowed_out": windowed_out,
     }
     assert set(runs["xla"][2].values()) == {0}  # the XLA path gathers whole tables
@@ -603,13 +608,18 @@ def _step_case(ps, *, fork_gen_page=False, seed=0):
     )
 
 
-@pytest.mark.parametrize("window", [None, 6])
+@pytest.mark.parametrize(
+    "window, which", [(None, "all"), (6, "all"), (6, "alternating")],
+    ids=["None", "6", "6-alternating"])
 @pytest.mark.parametrize("page_size", PAGE_SIZES)
-def test_step_xla_bitwise_dense_pallas_greedy(page_size, window):
+def test_step_xla_bitwise_dense_pallas_greedy(page_size, window, which):
     """The whole step on the coalesced layout ([R, P] shared prefix tables).
     Under the window, 6: it starts inside one request's prompt and has left
-    the other's, whose rows then see generated tokens alone."""
-    config = CONFIG.with_(sliding_window=window)
+    the other's, whose rows then see generated tokens alone. A scanned stack
+    that mixes windowed and global layers ("alternating") has one kernel call
+    and so one window for every layer: asked for the kernel, it stays on the
+    XLA masks, bit for bit."""
+    config = CONFIG.with_(sliding_window=window, sliding_window_layers=which)
     params = _params()
     tokens, lengths, plens, dense, paged = _step_case(page_size)
 
@@ -639,6 +649,8 @@ def test_step_xla_bitwise_dense_pallas_greedy(page_size, window):
         paged["pool_kv"], paged["prefix_idx"], paged["gen_idx"],
         attn_impl="pallas_interpret", page_size=page_size,
     )
+    if config.mixes_windowed_layers:
+        np.testing.assert_array_equal(np.asarray(logits_p), np.asarray(logits_x))
     # Online softmax reorders float accumulation: a tight numeric band is the
     # kernel's bar. In f32 at this size it lies far inside every top-two gap,
     # so the greedy tokens are equal as well.
@@ -755,10 +767,12 @@ def test_resolve_cpu_posture_counts_only_unsatisfied_pallas():
 
 def test_resolve_names_the_unsupported_feature_in_the_fallback_key():
     """Config-driven fallbacks are distinguishable from platform ones on
-    /metrics: softcap models and those that window some layers and not others
-    record their own reason suffix, and the config reason wins over the
-    platform reason. A window on every layer is the kernel's to serve: on a
-    CPU an explicit "pallas" for such a model lacks the platform alone."""
+    /metrics: softcap models and scanned stacks that window some layers and
+    not others record their own reason suffix, and the config reason wins over
+    the platform reason. A window on every layer is the kernel's to serve, and
+    so is a mix of windowed and global layers in a stack whose layers are
+    unrolled (``command-a-plus``: each layer's call takes its own window): on
+    a CPU an explicit "pallas" for such a model lacks the platform alone."""
     import dataclasses
 
     before = _snap()
@@ -767,6 +781,7 @@ def test_resolve_names_the_unsupported_feature_in_the_fallback_key():
     mixed = dataclasses.replace(
         CONFIG, sliding_window=128, sliding_window_layers="alternating"
     )
+    assert mixed.layer_windows == (128, None)
     assert resolve_paged_attention_impl("pallas", config=mixed) == "xla"
     after = _snap()
     assert _delta(before, after, "kernel.paged_attn_fallback.softcap") == 1
@@ -774,11 +789,14 @@ def test_resolve_names_the_unsupported_feature_in_the_fallback_key():
     assert _delta(before, after, "kernel.paged_attn_fallback.platform") == 0
 
     sliding = dataclasses.replace(CONFIG, sliding_window=128)
-    assert sliding.sliding_window_layers == "all"
-    assert resolve_paged_attention_impl("pallas", config=sliding) == "xla"
+    assert sliding.sliding_window_layers == "all" and not sliding.mixes_windowed_layers
+    unrolled = get_config("command-a-plus-tiny")
+    assert unrolled.mixes_windowed_layers and unrolled.attn_softcap is None
+    for config in (sliding, unrolled):
+        assert resolve_paged_attention_impl("pallas", config=config) == "xla"
     windowed = _snap()
     assert _delta(after, windowed, "kernel.paged_attn_fallback.sliding_window") == 0
-    assert _delta(after, windowed, "kernel.paged_attn_fallback.platform") == 1
+    assert _delta(after, windowed, "kernel.paged_attn_fallback.platform") == 2
 
 
 @pytest.mark.parametrize(
@@ -788,17 +806,20 @@ def test_resolve_names_the_unsupported_feature_in_the_fallback_key():
         (dict(sliding_window=4096), "pallas"),
         (dict(sliding_window=4096, sliding_window_layers="alternating"), "xla"),
         (dict(attn_softcap=30.0), "xla"),
+        (dict(attn_softcap=30.0, sliding_window=4096, sliding_window_layers="alternating"), "xla"),
+        ("command-a-plus-tiny", "pallas"),
     ],
 )
 def test_resolve_on_a_tpu_takes_the_kernel_for_a_window_on_every_layer(
     overrides, impl, monkeypatch
 ):
     """What "auto" picks where the platform is a TPU: the kernel for a model
-    without a window and for one whose every layer has it (``mistral-7b``),
-    the counted XLA fallback for a per-layer mix and for softcap."""
+    without a window, for one whose every layer has it (``mistral-7b``) and
+    for windowed and global layers mixed in an unrolled stack; the counted XLA
+    fallback for the scanned stack's mix and for softcap, which blocks first."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     before = _snap()
-    config = CONFIG.with_(**overrides)
+    config = get_config(overrides) if isinstance(overrides, str) else CONFIG.with_(**overrides)
     assert resolve_paged_attention_impl("auto", config=config) == impl
     fallbacks = {k: v for k, v in _snap().items() if "fallback" in k and v != before.get(k, 0)}
     if impl == "pallas":

@@ -228,8 +228,15 @@ def test_the_default_pool_fits_the_widest_request_and_nothing_wider():
     assert SlotPages(16, W, P, G, pool_pages=books.need(P, W, G) + 1).fits(P, W, G)
 
 
-@pytest.mark.parametrize("window", [None, 64, 16, 4])
-def test_walk_counts_are_live_pages_of_the_same_lengths(window):
+#: A stack's windows by paging layer: uniform ones of 1, 3 and 32 layers (one
+#: layer's numbers times the depth), then mixes (each layer under its own).
+STACKS = [(None,), (64,), (16,), (4,), (16,) * 3, (None,) * 32, (4,) * 32,
+          (16, 16, 16, None), (4, None), (4, 16, None, 4, 16, None)]
+
+
+@pytest.mark.parametrize("windows", STACKS, ids=lambda w: "-".join(map(str, w)) if len(w) < 8
+                         else f"{len(w)}x{w[0]}")
+def test_walk_counts_are_layer_pages_of_the_same_lengths(windows):
     ps = 8
     books, _, alloc = _books(ps)
     _admit(books, alloc, [0, 1], 21, 12)
@@ -239,21 +246,29 @@ def test_walk_counts_are_live_pages_of_the_same_lengths(window):
     glens = np.array([5, 0, 7, 11], np.int32)
     for g in range(int(glens.max()) + 1):  # the rows' writes so far, in order
         books.prepare_step(active, plens, np.minimum(g, glens))
-    walked, tabled, windowed_out = books.walk_counts(active, plens, glens, window=window)
+    walked, tabled, windowed_out = books.walk_counts(active, plens, glens, windows=windows)
     phase = np.array([21 % ps, 21 % ps, 0, 40 % ps])
-    (p0, n_prefix), (g0, n_gen) = live_pages(
-        np.where(active, plens, 0), np.where(active, glens, 0), phase, ps, window
-    )
     held = (3 + 2) + (3 + 0) + 0 + (5 + 2)  # pages with a position in the pool
-    assert int(n_prefix.sum() + n_gen.sum()) == held
-    assert windowed_out == int(np.sum(p0) + np.sum(g0))
-    assert walked == held - windowed_out
     # Queries at 26, 21 and 51: W = 16 sees from 11, 6 and 36; W = 4 from 23
     # (past the prompt's 21: all 3 prompt pages out; gen 2 is on gen page 0),
     # 18 (prompt page 2) and 48 (past the prompt's 40: 5 pages; gen 8 with
     # 40 % 8 = 0 is on gen page 1).
-    assert windowed_out == {None: 0, 64: 0, 16: 1 + 0 + 4, 4: 3 + 2 + (5 + 1)}[window]
-    assert tabled == W * sum(table_pages(P, G, ps))
+    out_of = {None: 0, 64: 0, 16: 1 + 0 + 4, 4: 3 + 2 + (5 + 1)}
+    want_out = 0
+    for window in windows:  # a layer at a time, each the kernel's own arithmetic
+        (p0, n_prefix), (g0, n_gen) = live_pages(
+            np.where(active, plens, 0), np.where(active, glens, 0), phase, ps, window
+        )
+        assert int(n_prefix.sum() + n_gen.sum()) == held
+        assert int(np.sum(p0) + np.sum(g0)) == out_of[window]
+        want_out += out_of[window]
+    assert windowed_out == want_out
+    assert walked == len(windows) * held - windowed_out
+    assert tabled == len(windows) * W * sum(table_pages(P, G, ps))
+    # A uniform stack's three numbers are one layer's, L-fold: every share reads the same.
+    if len(set(windows)) == 1:
+        one = books.walk_counts(active, plens, glens, windows=windows[:1])
+        assert (walked, tabled, windowed_out) == tuple(len(windows) * x for x in one)
 
 
 def test_cow_copies_the_shared_page_on_a_real_pool():

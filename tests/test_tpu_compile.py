@@ -15,12 +15,16 @@ from jax.sharding import SingleDeviceSharding
 
 from k_llms_tpu.ops.paged_attention import paged_decode_attention_pallas, table_pages
 
-# (query heads, kv heads, window): qwen2-7b's and mistral-7b's attention at
-# the benchmark loop's shapes, and a window that binds inside those rows.
+# (query heads, kv heads, window, prompt slots): qwen2-7b's and mistral-7b's
+# attention at the benchmark loop's shapes, a window that binds inside those
+# rows, and command-a-plus's two kinds of layer (16 queries a kv head, tables of
+# 112 + 5 pages a row) with the window binding inside the longer prompts.
 GEOMETRIES = {
-    "qwen2-7b": (28, 4, None),
-    "mistral-7b": (32, 8, 4096),
-    "mistral-7b-window-binds": (32, 8, 96),
+    "qwen2-7b": (28, 4, None, 512),
+    "mistral-7b": (32, 8, 4096, 512),
+    "mistral-7b-window-binds": (32, 8, 96, 512),
+    "command-a-plus-windowed": (128, 8, 4096, 7168),
+    "command-a-plus-global": (128, 8, None, 7168),
 }
 
 
@@ -37,9 +41,9 @@ def one_chip():
 
 @pytest.mark.parametrize("name", sorted(GEOMETRIES))
 def test_paged_decode_kernel_compiles_for_v5e(name, one_chip):
-    QH, KVH, window = GEOMETRIES[name]
+    QH, KVH, window, prompt_slots = GEOMETRIES[name]
     B, D, ps, L, pages = 32, 128, 64, 4, 500
-    NP, NG = table_pages(512, 256, ps)
+    NP, NG = table_pages(prompt_slots, 256, ps)
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -57,6 +61,44 @@ def test_paged_decode_kernel_compiles_for_v5e(name, one_chip):
         column, column, rows, rows,
     ).compile()
     assert "paged_attention_decode" in compiled.as_text()
+
+
+def test_a_step_over_windowed_and_global_layers_is_the_kernel_in_every_layer(one_chip):
+    """command-a-plus-cut4's decode step at the cell's sizes (width 32, 7,168
+    prompt slots, pages of 64): one ``paged_attention_decode`` custom call a
+    layer, each with its own static window, and no gather of a row's pages
+    (the XLA path's ``[32, 7168, ...]`` and ``[32, 256, ...]``)."""
+    import re
+
+    from k_llms_tpu.models import llama
+    from k_llms_tpu.models.config import get_config
+
+    config = get_config("command-a-plus-cut4")
+    W, P, G, ps, pages = 32, 7168, 256, 64, 3800
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: shape(a.shape, a.dtype),
+                          jax.eval_shape(lambda: llama.init_params(config, jax.random.key(0))))
+    pool = shape((config.paging_layers, pages * ps, config.num_kv_heads, config.head_dim),
+                 jnp.bfloat16)
+
+    def step(params, pool_k, pool_v, tokens, lengths, plens, prefix_idx, gen_idx):
+        aux = {}
+        return llama.paged_verify_step(
+            config, params, tokens, lengths, plens, llama.KVCache(k=pool_k, v=pool_v),
+            prefix_idx, gen_idx, attn_impl="pallas", page_size=ps, aux=aux) + (aux,)
+
+    rows = shape((W,), jnp.int32)
+    compiled = jax.jit(step).lower(
+        params, pool, pool, shape((W, 1), jnp.int32), rows, rows, shape((W, P), jnp.int32),
+        shape((W, G), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%paged_attention_decode\S* = \S+ custom-call\(", text)) == 4
+    assert re.findall(r"= \w+\[32,(?:7168|7424|256),\S* gather\(", text) == []
+    # The step's transients beside 9.5 GB of weights and a 3.9 GB pool.
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
 
 
 # -- the latent page pool's movers: no program lays the whole pool out again ---------------

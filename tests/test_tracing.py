@@ -806,9 +806,12 @@ def test_new_loop_metrics_read_what_they_say():
     assert by_name["loop_pages_ms"]["layer"] == "KV page manager"
     assert by_name["host_wait_share"]["layer"] == "Device"
     # What they add must lie at the end: nothing that was there moved.
-    # (ISSUE 37's one counter metric behind them, in every cell as they are.)
-    assert [m["name"] for m in manifest["per_layer"]][-10:] == [
+    # (ISSUE 37's one counter metric behind them, in every cell as they are;
+    # ISSUE 38's seven for its own cell behind that.)
+    assert [m["name"] for m in manifest["per_layer"]][-17:-7] == [
         *NEW_LOOP_METRICS, "paged_attn_walked_share", "loop_stage_uploads"]
+    assert all(m["workloads"] == ["command-a-plus.extract-long"]
+               for m in manifest["per_layer"][-7:])
     assert by_name["loop_stage_uploads"] == {
         "name": "loop_stage_uploads", "unit": "arrays", "better": "lower",
         "source": "program_counter", "layer": "Admission and schedulers", "moves": "tokens_per_s"}
