@@ -14,13 +14,13 @@ import hashlib
 import logging
 import os
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 from pydantic import BaseModel
 
 from ..consensus.prompts import SYSTEM_PROMPT_STRING_CONSENSUS_LLM
-from ..engine.engine import LocalEngine
+from ..engine.engine import LocalEngine, _bucket
 from ..engine.paging import row_reserve_pages
 from ..engine.tokenizer import get_tokenizer
 from ..models.config import get_config
@@ -186,11 +186,13 @@ class BackendConfig(BaseModel):
     # Prompts longer than this many tokens are ingested into the continuous
     # loop chunk by chunk, one chunk interleaved between decode steps, so a
     # long admission no longer stalls every in-flight row for a whole
-    # prefill. None = auto (HbmMemoryModel.prefill_chunk_tokens sizes a
-    # chunk at a small multiple of one decode step's row work); 0 = off —
-    # the whole-prompt admission path, byte-identical output either way
-    # (pinned by tests/test_chunked_prefill.py). Values are normalized down
-    # to a power of two >= 32 by the loop.
+    # prefill. None = auto: HbmMemoryModel.prefill_chunk_tokens gives the
+    # threshold C (4 x the loop's width), and a prompt past it is taken in
+    # turns of C, 2C or 4C, the longest that what is left of it fills
+    # (HbmMemoryModel.prefill_chunk_ladder). A number = that one length for
+    # every turn, normalized down to a power of two >= 32 by the loop; 0 =
+    # off — the whole-prompt admission path. Byte-identical output every way
+    # (pinned by tests/test_chunked_prefill.py).
     prefill_chunk_tokens: Optional[int] = None
     # -- paged KV cache (PR 7) --------------------------------------------
     # Paged layout for the continuous loop's KV: a fixed pool of fixed-size
@@ -390,11 +392,12 @@ class HbmMemoryModel:
         return max(1, int(rows))
 
     def prefill_chunk_tokens(self, width: int, max_prompt: int) -> int:
-        """Auto chunk size for interleaved prefill. A decode step computes
-        one token-row per active slot (<= ``width``); a C-token chunk costs
-        ~C token-rows of the same per-layer work, so C ~= 4*width keeps the
-        chunk's step-budget share within a small multiple of a decode step
-        (a stall of at most ~3x a steady-state step).
+        """Auto chunk size for interleaved prefill: the shortest length of a
+        lane turn, and the prompt length past which an admission takes the
+        lane at all. A decode step computes one token-row per active slot
+        (<= ``width``); a C-token chunk costs ~C token-rows of the same
+        per-layer work, so C ~= 4*width is a stall of ~3x a steady-state
+        step at most. That is the bound :meth:`prefill_chunk_ladder` trades.
         Power of two, floored at 32, capped at max_prompt // 2 so chunking
         actually splits any prompt it engages on; 0 (off) when the prompt
         bound is too small for chunking to ever help."""
@@ -405,6 +408,23 @@ class HbmMemoryModel:
         while c * 2 <= target:
             c *= 2
         return c
+
+    def prefill_chunk_ladder(self, width: int, max_prompt: int) -> Tuple[int, ...]:
+        """The lengths a lane turn may take under the automatic chunk size:
+        C, 2C and 4C for C = :meth:`prefill_chunk_tokens` (4, 8 and 16 x the
+        loop's width), none longer than the largest prompt bucket; ``()``
+        where chunking is off. Each turn the loop takes the longest while
+        what is left of the prompt fills it, and the shortest that covers the
+        rest in the prompt's last turn (``engine/continuous.py::pick_chunk``):
+        a prompt is ceil(len / 4C) turns where chunks of C alone made it
+        ceil(len / C), each a launch, a fetch and a decode step in between
+        while the request's rows stood empty and the queue waited for the one
+        lane (PERF.md, PR 39). The price is the stall one turn puts on the
+        live rows: a 16 x width chunk is ~3-5 decode steps long, where C
+        alone held it to ~3x a step at most. Nothing here reads a model or a
+        prompt bound: the remainder of the prompt in hand decides."""
+        c = self.prefill_chunk_tokens(width, max_prompt)
+        return tuple(r for r in (c, 2 * c, 4 * c) if 0 < r <= _bucket(int(max_prompt)))
 
     def describe(self) -> Dict[str, Any]:
         return {
@@ -677,11 +697,12 @@ class TpuBackend(Backend):
         # clamp envelope, independent learned state.
         from ..reliability.supervisor import LaunchBudgetModel
 
-        chunk = cfg.prefill_chunk_tokens
+        chunk, ladder = cfg.prefill_chunk_tokens, ()
         if chunk is None:
-            chunk = self.memory_model.prefill_chunk_tokens(
+            ladder = self.memory_model.prefill_chunk_ladder(
                 max(1, width), cfg.continuous_max_prompt
             )
+            chunk = ladder[0] if ladder else 0
         return ContinuousDecodeLoop(
             self.engine,
             width=max(1, width),
@@ -702,6 +723,7 @@ class TpuBackend(Backend):
             on_rebuilt=self.scheduler.note_rebuilt,
             on_rebuild_failed=self.scheduler.note_rebuild_failed,
             prefill_chunk_tokens=max(0, int(chunk)),
+            prefill_chunk_ladder=ladder,
         )
 
     # -- engine lifecycle --------------------------------------------------

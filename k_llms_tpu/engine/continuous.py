@@ -76,7 +76,9 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Iterator, List, NamedTuple, Optional
+from typing import (
+    Any, Callable, Dict, Iterator, List, NamedTuple, Optional, Sequence, Tuple,
+)
 
 import jax
 import jax.numpy as jnp
@@ -267,6 +269,43 @@ class _Prefilling:
     reserved: List[List[int]]
     state: Dict[str, Any]
     cursor: int = 0
+
+
+def pick_chunk(rungs: Sequence[int], remainder: int, room: int) -> int:
+    """The length of one PREFILLING lane turn: of the compiled chunk lengths
+    ``rungs`` (ascending, each double the last) that fit the staging cache's
+    ``room`` past the cursor, the longest while ``remainder`` (the prompt
+    tokens still to ingest) is at least that long, else the shortest that
+    covers it (the prompt's last turn). Cursors are therefore multiples of
+    the longest rung until the last turn, and a power-of-two bucket past the
+    prompt always has room for the rung chosen; should none fit (a bucket cut
+    to a ``max_seq_len`` that is no multiple of the shortest), the shortest
+    runs as a single length always has."""
+    fits = [c for c in rungs if c <= room] or list(rungs[:1])
+    if remainder >= fits[-1]:
+        return fits[-1]
+    return next(c for c in fits if c >= remainder)
+
+
+def bucket_rungs(rungs: Sequence[int], bucket: int) -> Tuple[int, ...]:
+    """The chunk lengths :func:`pick_chunk` can choose for the prompts of one
+    staging ``bucket`` (those longer than the shortest rung and than the
+    bucket below this one), by walking the rule itself over them: what the
+    bucket's first chunk compiles, so that no later prompt of the bucket, with
+    whatever tail, meets a length that is not built."""
+    if len(rungs) < 2:
+        return tuple(rungs)
+    below = 1 << ((bucket - 1).bit_length() - 1)
+    used: set = set()
+    for plen in range(max(rungs[0], below) + 1, bucket + 1):
+        cursor = 0
+        while cursor < plen:
+            c = pick_chunk(rungs, plen - cursor, bucket - cursor)
+            used.add(c)
+            cursor += c
+        if len(used) == len(rungs):
+            break
+    return tuple(sorted(used))
 
 
 def _req_tenant_name(req: "_SlotRequest") -> str:
@@ -460,6 +499,7 @@ class ContinuousDecodeLoop:
         on_rebuilt: Optional[Callable[[], None]] = None,
         on_rebuild_failed: Optional[Callable[[BaseException], None]] = None,
         prefill_chunk_tokens: int = 0,
+        prefill_chunk_ladder: Sequence[int] = (),
     ) -> None:
         # Only the worker swaps in an epoch-fenced replacement during
         # recovery; readers tolerate either generation, and admission
@@ -504,6 +544,24 @@ class ContinuousDecodeLoop:
         elif c > 32:
             c = 1 << (c.bit_length() - 1)
         self.prefill_chunk_tokens = c
+        # The lengths a lane turn may take (:func:`pick_chunk` chooses one
+        # each turn from what is left of the prompt): C alone, as an explicit
+        # chunk size always is, or with ``prefill_chunk_ladder`` the longer
+        # rungs beside it (2C, 4C: the automatic size's, from
+        # ``HbmMemoryModel.prefill_chunk_ladder``). C stays the threshold of
+        # the lane (``_chunk_eligible``) and what ``stats`` reports; the
+        # invariant above holds for every rung, since a cursor is a multiple
+        # of the longest until a prompt's last turn.
+        rungs = tuple(sorted({c, *map(int, prefill_chunk_ladder)})) if c else ()
+        if rungs and (rungs[0] != c or any(b != 2 * a for a, b in zip(rungs, rungs[1:]))):
+            raise ValueError(
+                f"prefill_chunk_ladder {tuple(prefill_chunk_ladder)} must double up from "
+                f"prefill_chunk_tokens ({c})"
+            )
+        self._chunk_rungs: Tuple[int, ...] = rungs
+        # Staging buckets whose rungs are all built (worker thread; emptied
+        # with the device state, since a rebuilt engine has compiled nothing).
+        self._chunk_built: set = set()
         # The single in-flight chunked admission (at most one PREFILLING
         # request at a time — one chunk rides alongside each decode step).
         self._prefilling: Optional[_Prefilling] = None
@@ -656,6 +714,10 @@ class ContinuousDecodeLoop:
             # with decode rows in flight (the interleaving the feature buys).
             "prefill_chunks": 0,
             "prefill_interleaved": 0,
+            # The prompt tokens those chunks ingested (a chunk's ``valid``
+            # ones, its padding left out): over ``prefill_chunks``, how long
+            # a lane turn was.
+            "prefill_tokens": 0,
             # Times _admit_locked left a non-empty queue's head behind, by
             # what it lacked: free slots, the one PREFILLING lane, pool pages.
             "blocked_slots": 0,
@@ -1350,7 +1412,10 @@ class ContinuousDecodeLoop:
             # The interleave: one decode step for the active batch, then one
             # prompt chunk for the (at most one) PREFILLING admission — a
             # long prompt's ingestion is spread across decode steps instead
-            # of stalling every in-flight row for a whole prefill.
+            # of stalling every in-flight row for a whole prefill. How long
+            # that chunk is, ``pick_chunk`` reads each turn from what is left
+            # of the prompt: the live rows wait one turn of at most the
+            # ladder's longest rung (16 x width under the automatic size).
             if has_decode:
                 self._step_once()
             if prefilling:
@@ -1616,6 +1681,7 @@ class ContinuousDecodeLoop:
         # Like the slots' tables: the holder's page references die with the
         # pool, no decref against a replaced allocator.
         self._prefilling = None
+        self._chunk_built.clear()
         self._built = False
 
     def adopt_engine(self, new_engine: Any) -> None:
@@ -2003,8 +2069,8 @@ class ContinuousDecodeLoop:
                     return
                 epoch = self._loop_epoch
                 chunk_no = self._stats["prefill_chunks"]
-                C = self.prefill_chunk_tokens
                 start = pf.cursor
+                C = pick_chunk(self._chunk_rungs, pf.plen - start, pf.bucket - start)
                 end = min(start + C, pf.plen)
                 valid = end - start
                 final = end >= pf.plen
@@ -2018,6 +2084,11 @@ class ContinuousDecodeLoop:
                 slot_idx = (
                     self._pages.chunk_slots(pf.run_pages, start, C, valid)
                     if pool is not None else None
+                )
+                # A bucket's first chunk builds every length its prompts can
+                # take, so a later prompt with another tail compiles nothing.
+                unbuilt = () if bucket in self._chunk_built else tuple(
+                    c for c in bucket_rungs(self._chunk_rungs, bucket) if c != C
                 )
             fn = self.engine._get_prefill_chunk(C, bucket, pool is not None)
 
@@ -2042,6 +2113,11 @@ class ContinuousDecodeLoop:
                     raise _StaleStep("prefill chunk fenced post-dispatch")
                 if pool is not None:
                     pool.scatter_tokens(*cols, slot_idx)
+            # While the device runs this turn, build the bucket's other
+            # lengths from what it returned: a later turn's arguments but for
+            # the tokens' length.
+            for c in unbuilt:
+                self._compile_chunk(c, bucket, new_cache, new_state, pool, cols)
             # Synchronize on the (tiny) logits readback so the watchdog
             # budget covers the device work, like the step's readback.
             _, aux = self._readback((logits, aux))
@@ -2064,7 +2140,9 @@ class ContinuousDecodeLoop:
                 pf.state = new_state
                 pf.cursor = end
                 req.chunk_cursor = end
+                self._chunk_built.add(bucket)
                 self._stats["prefill_chunks"] += 1
+                self._stats["prefill_tokens"] += valid
                 if self._active_mask.any():
                     self._stats["prefill_interleaved"] += 1
                 # A completed chunk is proof of life, like a completed step.
@@ -2075,6 +2153,30 @@ class ContinuousDecodeLoop:
                     self._prefilling = None
                     self._finish_prefilling_locked(pf, first_logits)
                     self._lock.notify_all()
+
+    def _compile_chunk(self, c: int, bucket: int, cache, lane_state, pool, cols) -> None:
+        """Build the ``c``-token chunk program of ``bucket`` ahead of its
+        first turn (step thread, under the watchdog's compile exemption): the
+        jitted call's own lowering and executable, from a turn's arguments
+        with the tokens' length changed, and for a paged loop the pool's
+        scatter of that many columns (``cols``: another length's, as a turn
+        hands them to ``scatter_tokens``)."""
+        fn = self.engine._get_prefill_chunk(c, bucket, pool is not None)
+        scalar = jax.ShapeDtypeStruct((), jnp.int32)
+        fn.lower(
+            self.engine.params, jax.ShapeDtypeStruct((1, c), jnp.int32), cache,
+            scalar, scalar, state=lane_state,
+        ).compile()
+        if pool is not None:
+            # A committed array's sharding is part of the jitted call's cache
+            # key, an uncommitted one's is not: say what the real columns say.
+            pool.compile_scatter(*(
+                jax.ShapeDtypeStruct(
+                    (col.shape[0], c, *col.shape[2:]), col.dtype,
+                    sharding=col.sharding if col.committed else None,
+                )
+                for col in cols
+            ))
 
     def _finish_prefilling_locked(self, pf: _Prefilling, first_logits) -> None:
         """Transition PREFILLING -> DECODING (lock held): install the fully

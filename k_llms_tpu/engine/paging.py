@@ -460,6 +460,16 @@ class PagedKVPool:
             note_device_dispatch("paged kv scatter")
             self.kv = self._scatter_fn(self.kv.k, self.kv.v, k_src, v_src, idx)
 
+    def compile_scatter(self, k_src, v_src) -> None:
+        """Build :meth:`scatter_tokens`' program for sources of these shapes
+        (``jax.ShapeDtypeStruct``) ahead of its first call with them."""
+        import jax
+        import jax.numpy as jnp
+
+        idx = jax.ShapeDtypeStruct((k_src.shape[1],), jnp.int32)
+        with self.lock:
+            self._scatter_fn.lower(self.kv.k, self.kv.v, k_src, v_src, idx).compile()
+
     def gather_tokens(self, slot_idx: np.ndarray):
         """Dense [L, 1, n, KVH, D] view of the given flat slots."""
         import jax.numpy as jnp

@@ -279,8 +279,8 @@ def test_the_shared_branch_is_the_average_of_the_shared_experts():
 
 # -- the loop ----------------------------------------------------------------------------
 
-def run_loop(config, *, layout="paged", chunk=32, max_prompt=88, plens=(70, 88, 10), impl=None,
-             monkeypatch=None):
+def run_loop(config, *, layout="paged", chunk=32, ladder=(), max_prompt=88, plens=(70, 88, 10),
+             impl=None, monkeypatch=None):
     from k_llms_tpu.engine.tokenizer import get_tokenizer
     from k_llms_tpu.ops import paged_attention as ops
 
@@ -288,7 +288,8 @@ def run_loop(config, *, layout="paged", chunk=32, max_prompt=88, plens=(70, 88, 
         monkeypatch.setattr(ops, "resolve_paged_attention_impl", lambda requested, **kw: impl)
     engine = shared_engine(config, kv_layout=layout, kv_page_size=8)
     loop = ContinuousDecodeLoop(engine, width=6, max_prompt=max_prompt, max_new=16,
-                                eos_ids=get_tokenizer(None).stop_ids, prefill_chunk_tokens=chunk)
+                                eos_ids=get_tokenizer(None).stop_ids, prefill_chunk_tokens=chunk,
+                                prefill_chunk_ladder=ladder)
     rng, out = np.random.RandomState(2), []
     try:
         for n, plen in zip((4, 2, 2), plens):
@@ -312,6 +313,20 @@ def test_a_prompt_chunks_into_a_bucket_above_max_prompt(model, layout):
     chunked, stats = run_loop(config, layout=layout, chunk=32)
     whole, _ = run_loop(config, layout=layout, chunk=0)
     assert stats["prefill_chunks"] == 3 + 3  # 70 and 88 tokens in chunks of 32; 10 in none
+    for a, b in zip(chunked, whole):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("plens", [(161, 250, 10), (130, 256, 10)])
+def test_the_ladders_turns_keep_each_layers_window(plens):
+    """Two prompts on the ladder (a turn of 128, then one of 64, 128 or 32
+    padded, or of 128 whole, in a bucket of 256): the three windowed layers'
+    masks over the staging cache, many windows deep into a turn and across
+    the two, give whole-prompt admission's tokens."""
+    kw = dict(max_prompt=256, plens=plens)
+    chunked, stats = run_loop(CFG, chunk=32, ladder=(32, 64, 128), **kw)
+    whole, _ = run_loop(CFG, chunk=0, **kw)
+    assert (stats["prefill_chunks"], stats["prefill_tokens"]) == (2 + 2, sum(plens[:2]))
     for a, b in zip(chunked, whole):
         np.testing.assert_array_equal(a, b)
 

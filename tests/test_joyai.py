@@ -219,13 +219,13 @@ def test_the_two_halves_of_the_experts_add_up_to_the_uncut_layer(tokens):
 # -- the drafted loop against the undrafted one -------------------------------------------
 
 def run_loop(config, *, grammar=None, n=4, max_new=64, temperature=0.8, seed=5, plen=37,
-             chunk=0, eos_ids=None, sink=None, loop_max_new=64):
+             chunk=0, ladder=(), max_prompt=64, eos_ids=None, sink=None, loop_max_new=64):
     from k_llms_tpu.engine.tokenizer import get_tokenizer
 
     engine = shared_engine(config, kv_layout="paged", kv_page_size=8)
     loop = ContinuousDecodeLoop(
-        engine, width=8, max_prompt=64, max_new=loop_max_new, prefill_chunk_tokens=chunk,
-        eos_ids=eos_ids or get_tokenizer(None).stop_ids)
+        engine, width=8, max_prompt=max_prompt, max_new=loop_max_new, prefill_chunk_tokens=chunk,
+        prefill_chunk_ladder=ladder, eos_ids=eos_ids or get_tokenizer(None).stop_ids)
     prompt = [int(t) for t in np.random.RandomState(1).randint(32, 127, size=plen)]
     try:
         result = loop.submit(prompt, n=n, max_new=max_new, temperature=temperature, top_p=0.95,
@@ -256,6 +256,19 @@ def test_drafted_streams_equal_undrafted(grammar, constrained, temperature, n):
         assert set(json.loads(text)) == {"kind", "paid", "currency"}
     if n == 8:  # the rows' first writes copied the shared prompt page
         assert stats["pages"]["cow_copies"] >= 8
+
+
+@pytest.mark.parametrize("plen", [161, 250])
+def test_drafted_admission_after_a_ladder_turn_equals_whole_prompt_undrafted(grammar, plen):
+    """The lane on the ladder (a turn of 128, then a padded one of 64 or of
+    128): the module's cache rows beside the stack's from both turns, ``h`` at
+    the last valid position into ``_admit_drafts``. The drafted streams are
+    the undrafted loop's over whole-prompt admission."""
+    kw = dict(grammar=grammar, n=8, max_new=24, plen=plen, max_prompt=256)
+    drafted, stats = run_loop(CFG, chunk=32, ladder=(32, 64, 128), **kw)
+    plain, _ = run_loop(PLAIN, chunk=0, **kw)
+    same_streams(drafted, plain)
+    assert (stats["prefill_chunks"], stats["prefill_tokens"]) == (2, plen)
 
 
 @pytest.mark.parametrize("max_new", [1, 2, 7, 8])

@@ -200,24 +200,27 @@ def test_reference_given_the_programs_routing_agrees_and_measures_its_slack(para
 
 # -- through the engine and the continuous loop ------------------------------------------
 
-def make_loop(width=8, chunk=32):
+def make_loop(width=8, chunk=32, ladder=()):
     from k_llms_tpu.engine.continuous import ContinuousDecodeLoop
 
     engine = shared_engine("nemotron3-tiny", kv_layout="paged", kv_page_size=16)
     return ContinuousDecodeLoop(engine, width=width, max_prompt=256, max_new=16, eos_ids=[257],
-                                prefill_chunk_tokens=chunk)
+                                prefill_chunk_tokens=chunk, prefill_chunk_ladder=ladder)
 
 
-@pytest.mark.parametrize("plen", [100, 20])
-def test_n8_forks_one_state_into_eight_rows_that_diverge(params, plen):
+@pytest.mark.parametrize("plen,ladder,prefill_calls", [
+    (100, (), 4), (20, (), 1), (200, (32, 64, 128), 2), (161, (32, 64, 128), 2)])
+def test_n8_forks_one_state_into_eight_rows_that_diverge(params, plen, ladder, prefill_calls):
     """n = 8 sampled rows of one prompt (100 tokens: four chunks, the last
-    padded; 20: whole-prompt admission): pages are shared, the state is
-    forked, the rows take different tokens — and every row's log-probabilities
-    are the reference's along that row's own tokens. The counters add up."""
+    padded; 20: whole-prompt admission; 200 and 161 on the ladder: a turn of
+    128, then the state carried into a padded turn of 128 or of 64): pages are
+    shared, the state is forked, the rows take different tokens — and every
+    row's log-probabilities are the reference's along that row's own tokens.
+    The counters add up."""
     from k_llms_tpu.utils.observability import LATENCY, MODEL_COUNTERS
 
     prompt = [int(t) for t in np.random.RandomState(1).randint(0, 250, plen)]
-    loop = make_loop()
+    loop = make_loop(ladder=ladder)
     before, installs = MODEL_COUNTERS.snapshot(), LATENCY.snapshot()["continuous.state_install"]["count"]
     try:
         got = loop.submit(prompt, n=8, max_new=10, temperature=1.0, top_p=1.0,
@@ -234,7 +237,6 @@ def test_n8_forks_one_state_into_eight_rows_that_diverge(params, plen):
         want = np.asarray(jax.nn.log_softmax(logits, axis=-1))[np.arange(10), toks[row]]
         np.testing.assert_allclose(lps[row], want, atol=5e-4, rtol=0)
     grew = {k: v - before.get(k, 0) for k, v in MODEL_COUNTERS.snapshot().items()}
-    prefill_calls = -(-plen // 32) if plen > 32 else 1
     # kllms_ssm_state_updates: rows x 4 a step, one row x 4 a chunk or whole prompt.
     assert grew["ssm_state_updates"] == (prefill_calls + 9 * 8) * N_M
     assert grew["ssm_tokens_scanned"] == (plen + 9 * 8) * N_M

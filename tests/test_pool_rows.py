@@ -211,17 +211,19 @@ def test_the_drafted_steps_write_lands_each_layers_rows_at_its_positions():
 
 # -- through the loop: chunks, steps, copy-on-write ------------------------------------
 
+@pytest.mark.parametrize("ladder,plen", [((), 75), ((32, 64, 128), 161)])
 @pytest.mark.parametrize("model", LATENT)
-def test_pad_lanes_are_zero_after_chunks_steps_and_copy_on_write(model):
-    """A chunked prompt for n = 4 rows (its partial last page copied for each
-    row), decoded a few steps: the rows written hold numbers in the cache's
-    own lanes and zeros in the pad lanes, everywhere in the pool."""
+def test_pad_lanes_are_zero_after_chunks_steps_and_copy_on_write(model, ladder, plen):
+    """A chunked prompt for n = 4 rows (chunks of 32, or the ladder's turn of
+    128 and a padded one of 64; its partial last page copied for each row),
+    decoded a few steps: the rows written hold numbers in the cache's own
+    lanes and zeros in the pad lanes, everywhere in the pool."""
     config = get_config(model)
     _, width, _ = config.cache_widths
     engine = shared_engine(model, kv_layout="paged", kv_page_size=16)
-    loop = ContinuousDecodeLoop(engine, width=4, max_prompt=128, max_new=8, eos_ids=[257],
-                                prefill_chunk_tokens=32)
-    prompt = [int(t) for t in np.random.RandomState(2).randint(0, 250, 75)]
+    loop = ContinuousDecodeLoop(engine, width=4, max_prompt=256, max_new=8, eos_ids=[257],
+                                prefill_chunk_tokens=32, prefill_chunk_ladder=ladder)
+    prompt = [int(t) for t in np.random.RandomState(2).randint(0, 250, plen)]
     try:
         out = loop.submit(prompt, n=4, max_new=6, temperature=0.8, top_p=0.95, seed=5).result(timeout=300)
         pages = loop.stats.get("pages")
@@ -232,7 +234,7 @@ def test_pad_lanes_are_zero_after_chunks_steps_and_copy_on_write(model):
     assert pool_k.shape[-1] == config.pool_row_width > width
     assert not pool_k[..., width:].any()
     written = np.abs(pool_k[..., :width]).sum(axis=(2, 3)) > 0  # [L, flat]
-    assert written.sum(axis=1).min() >= 75 + 4 * 5  # every cache layer: the prompt, then each row's steps
+    assert written.sum(axis=1).min() >= plen + 4 * 5  # every cache layer: the prompt, then each row's steps
 
 
 @pytest.mark.parametrize("model", LATENT)

@@ -807,11 +807,15 @@ def test_new_loop_metrics_read_what_they_say():
     assert by_name["host_wait_share"]["layer"] == "Device"
     # What they add must lie at the end: nothing that was there moved.
     # (ISSUE 37's one counter metric behind them, in every cell as they are;
-    # ISSUE 38's seven for its own cell behind that.)
-    assert [m["name"] for m in manifest["per_layer"]][-17:-7] == [
+    # ISSUE 38's seven for its own cell behind that; ISSUE 39's one counter
+    # metric, in every cell, last.)
+    assert [m["name"] for m in manifest["per_layer"]][-18:-8] == [
         *NEW_LOOP_METRICS, "paged_attn_walked_share", "loop_stage_uploads"]
     assert all(m["workloads"] == ["command-a-plus.extract-long"]
-               for m in manifest["per_layer"][-7:])
+               for m in manifest["per_layer"][-8:-1])
+    assert manifest["per_layer"][-1] == {
+        "name": "prefill_tokens_per_chunk", "unit": "tokens", "better": "higher",
+        "source": "program_counter", "layer": "Model step: prefill", "moves": "tokens_per_s"}
     assert by_name["loop_stage_uploads"] == {
         "name": "loop_stage_uploads", "unit": "arrays", "better": "lower",
         "source": "program_counter", "layer": "Admission and schedulers", "moves": "tokens_per_s"}

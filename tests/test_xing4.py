@@ -166,20 +166,24 @@ def test_reference_given_the_programs_routing_agrees_and_measures_its_slack(para
 
 # -- through the engine and the continuous loop ------------------------------------------
 
+@pytest.mark.parametrize("ladder,chunks,chunk_tokens", [((), 4, 32), ((32, 64, 128), 1, 128)])
 @pytest.mark.parametrize("layout", ["dense", "paged"])
-def test_greedy_tokens_equal_through_the_loop_and_the_dense_path(layout):
-    """n = 8 greedy samples of one chunked prompt through the continuous loop
-    (paged: on shared latent pages, copy-on-write for the partial last page)
-    equal ``generate``'s dense decode; the loop's programs count the router's
-    loads on the way."""
+def test_greedy_tokens_equal_through_the_loop_and_the_dense_path(layout, ladder, chunks, chunk_tokens):
+    """n = 8 greedy samples of one chunked prompt (four chunks of 32, or on
+    the ladder one turn of 128) through the continuous loop (paged: on shared
+    latent pages, copy-on-write for the partial last page) equal
+    ``generate``'s dense decode; the loop's programs count the router's loads
+    on the way."""
     from k_llms_tpu.engine.continuous import ContinuousDecodeLoop
     from k_llms_tpu.utils.observability import MODEL_COUNTERS
 
     prompt = [int(t) for t in np.random.RandomState(1).randint(0, 250, 100)]
     engine = shared_engine("xing4-tiny", kv_layout=layout, kv_page_size=16)
     loop = ContinuousDecodeLoop(engine, width=8, max_prompt=256, max_new=16, eos_ids=[257],
-                                prefill_chunk_tokens=32)
+                                prefill_chunk_tokens=32, prefill_chunk_ladder=ladder)
     before = MODEL_COUNTERS.snapshot()
+    pool = getattr(engine, "_kv_pool", None)  # the engine's, so another case's copies are in it
+    copies = pool.allocator.snapshot()["cow_copies"] if pool is not None else 0
     try:
         got = loop.submit(prompt, n=8, max_new=12, temperature=0.0, top_p=1.0,
                           seed=3).result(timeout=300)
@@ -190,13 +194,13 @@ def test_greedy_tokens_equal_through_the_loop_and_the_dense_path(layout):
     grew = {k: v - before.get(k, 0) for k, v in MODEL_COUNTERS.snapshot().items()}
     want = shared_engine("xing4-tiny").generate(prompt, n=8, max_new_tokens=12, temperature=0.0, seed=3)
     np.testing.assert_array_equal(np.asarray(got.tokens)[:, :12], np.asarray(want.tokens)[:, :12])
-    # 4 chunks and 11 steps, 2 expert layers each; 32 tokens a chunk, 8 rows a step, top-2.
-    assert grew["moe_layer_calls"] == (4 + 11) * 2
-    assert grew["moe_pairs"] == (4 * 32 + 11 * 8) * 2 * 2
+    # The chunks and 11 steps, 2 expert layers each; a chunk's tokens, 8 rows a step, top-2.
+    assert grew["moe_layer_calls"] == (chunks + 11) * 2
+    assert grew["moe_pairs"] == (chunks * chunk_tokens + 11 * 8) * 2 * 2
     assert 0 < grew["moe_experts_touched"] <= grew["moe_layer_calls"] * CFG.num_experts
     assert grew["moe_max_load"] * CFG.num_experts >= grew["moe_pairs"]
     if layout == "paged":
-        assert pages["cow_copies"] == 8 and pages["in_use"] == 0
+        assert pages["cow_copies"] - copies == 8 and pages["in_use"] == 0
         assert grew["mla_latent_rows_read"] == sum(8 * (100 + t + 1) for t in range(11)) * 3
 
 
