@@ -1183,7 +1183,7 @@ class ContinuousDecodeLoop:
             # position on, and the second pair counts only where t2 was emitted.
             mlogits, m_cols = paged_draft_step(
                 config, params, hidden, toks, lens + 1, plens, pool, prefix_idx, gen_idx,
-                aux=aux,
+                aux=aux, attn_impl=attn_impl, page_size=page_size,
             )
             mlogits = mask_pad(jnp.where(accept[:, None], mlogits[:, 1], mlogits[:, 0]))
             out_g = ()

@@ -319,7 +319,8 @@ class ModelConfig:
         token's row on the pool's minor axis and a mover addresses it in
         place, where a row of 4.5 tiles lies strided and every program that
         touches one lays the whole pool out again (engine/paging.py). The pad
-        lanes are written as zeros and never read. Rows of several heads are
+        lanes are written as zeros (the paged kernel for latent pages scores
+        them against zero query lanes). Rows of several heads are
         whole tiles already and stored as they are."""
         _, k_width, _ = self.cache_widths
         return -(-k_width // 128) * 128 if self.is_latent else k_width

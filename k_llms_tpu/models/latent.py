@@ -12,10 +12,12 @@ when ``config.is_latent``.
   norm and after RoPE, ``kv_lora_rank + qk_rope_head_dim`` wide, in the ``k``
   array of the usual (k, v) pair as ``[..., 1, width]``; ``v`` has width 0.
   The page pool stores the row ``config.pool_row_width`` lanes wide (whole
-  tiles of 128: 576 -> 640, the pad lanes zeros nobody reads), and every
-  program addresses it by (layer, slot) in the pool's flat view: the gathers
-  here through ``ops/attention.py::pool_gather``, every writer through the
-  page manager's movers (``engine/paging.py``).
+  tiles of 128: 576 -> 640, the pad lanes zeros that change no score), and
+  every program addresses it by (layer, slot) in the pool's flat view: the
+  gathers here through ``ops/attention.py::pool_gather``, a TPU's paged decode
+  through ``ops/paged_attention.py::paged_latent_decode_pallas`` (each row's
+  live pages, read in place), every writer through the page manager's movers
+  (``engine/paging.py``).
 - **Two attention forms, one function** (:func:`mla_attend`): prefill and
   chunks materialise per-head keys and values from the latent; a decode or
   verify step absorbs the up-projections into the query and the output, so it
@@ -266,10 +268,7 @@ def mla_attend(
         return x.reshape(R, B // R, *x.shape[1:])
 
     if absorb:
-        with jax.named_scope("mla_absorb"):
-            q_lat = jnp.einsum("bqhd,chd->bqhc", q_nope, wkv_b[..., :dn],
-                               preferred_element_type=_F32).astype(dtype)
-        q_full = jnp.concatenate([q_lat, q_rope], axis=-1)  # [B, Sq, NH, rkv + dr]
+        q_full = _absorb_query(wkv_b, q_nope, q_rope)
     scores, values = [], []
     for rows, mask in segments:
         R = rows.shape[0]
@@ -296,10 +295,26 @@ def mla_attend(
         eq = "rnhqk,rkc->rnqhc" if absorb else "rnhqk,rkhd->rnqhd"
         out = out + jnp.einsum(eq, p, v, preferred_element_type=_F32).reshape(B, Sq, NH, -1)
     if absorb:
-        with jax.named_scope("mla_absorb"):
-            out = jnp.einsum("bqhc,chd->bqhd", out.astype(dtype), wkv_b[..., dn:],
-                             preferred_element_type=_F32)
+        out = _absorb_output(wkv_b, dn, out, dtype)
     return out.astype(dtype).reshape(B, Sq, NH * dv)
+
+
+def _absorb_query(wkv_b: jax.Array, q_nope: jax.Array, q_rope: jax.Array) -> jax.Array:
+    """The absorbed form's query ``[q_nope W_kvb^k | q_rope]`` [B, Sq, NH, rkv
+    + dr]: ``wkv_b`` [rkv, NH, dn + dv] folded into ``q_nope``'s dn lanes."""
+    dn = q_nope.shape[-1]
+    with jax.named_scope("mla_absorb"):
+        q_lat = jnp.einsum("bqhd,chd->bqhc", q_nope, wkv_b[..., :dn],
+                           preferred_element_type=_F32).astype(q_nope.dtype)
+    return jnp.concatenate([q_lat, q_rope], axis=-1)
+
+
+def _absorb_output(wkv_b: jax.Array, dn: int, out: jax.Array, dtype) -> jax.Array:
+    """The absorbed form's attention over latents [B, Sq, NH, rkv] -> per-head
+    values [B, Sq, NH, dv] f32, through ``wkv_b``'s value half (past ``dn``)."""
+    with jax.named_scope("mla_absorb"):
+        return jnp.einsum("bqhc,chd->bqhd", out.astype(dtype), wkv_b[..., dn:],
+                          preferred_element_type=_F32)
 
 
 @jax.named_scope("attn_out")
@@ -546,24 +561,69 @@ def _attend_cache(config, layer, h, positions, cached, write_index, key_mask,
     return _attn_out(layer, out), rows
 
 
+def _paged_kernel(config: ModelConfig, attn_impl: str, page_size: Optional[int],
+                  prefix_idx: jax.Array, gen_idx: jax.Array, prompt_lens, gen_lens,
+                  first_position: int = 0):
+    """The Pallas paged kernel for latent pages as one step's layers call it,
+    ``(q_full [B, Sq, NH, rkv + dr], this step's rows [B, Sq, width], pool,
+    cache layer) -> [B, Sq, NH, rkv]`` f32, with the page tables derived once
+    here, outside the layer scan; None where ``attn_impl`` takes the XLA
+    gather. ``prompt_lens`` [B|R], ``gen_lens`` [B]: the pool positions each
+    row attends (the prompt's from ``first_position``)."""
+    if attn_impl not in ("pallas", "pallas_interpret"):
+        return None
+    from ..ops.paged_attention import paged_attention_page_tables, paged_latent_decode_pallas
+
+    tables = paged_attention_page_tables(prefix_idx, gen_idx, page_size)
+    B = gen_idx.shape[0]
+    plen = jnp.asarray(prompt_lens, jnp.int32).reshape(-1)
+    plen = jnp.repeat(plen, B // plen.shape[0], total_repeat_length=B)
+
+    def attend(q_full, col, pool_k, layer_no):
+        Sq, NH, width = q_full.shape[1:]
+        W = pool_k.shape[-1]
+
+        def stored(x):  # zero lanes up to the pool's stored width
+            return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, W - x.shape[-1]),))
+
+        out = paged_latent_decode_pallas(
+            stored(q_full).reshape(B, Sq * NH, W), pool_k, layer_no, *tables, stored(col),
+            plen, gen_lens, page_size=page_size, sm_scale=config.attn_scale,
+            value_width=config.kv_lora_rank, first_position=first_position,
+            interpret=attn_impl == "pallas_interpret",
+        )
+        return out.reshape(B, Sq, NH, -1)
+
+    return attend
+
+
 def _attend_paged(config, layer, h, positions, pool_k, layer_no, prefix_idx, gen_idx,
-                  write_index, key_mask, prefix_mask, keep):
-    """One layer's attention over cache layer ``layer_no`` of the page pool:
-    the rows' pages gathered by block table, this step's rows ([B, Sq, width],
-    handed to ``keep``) inserted, the absorbed form -> out."""
+                  write_index, key_mask, prefix_mask, keep, kernel=None):
+    """One layer's attention over cache layer ``layer_no`` of the page pool in
+    the absorbed form, this step's rows ([B, Sq, width]) handed to ``keep`` ->
+    out. ``kernel`` (:func:`_paged_kernel`) reads the rows' live pages where
+    they lie; without it the rows' pages are gathered by block table and this
+    step's rows inserted (XLA)."""
     q_nope, q_rope = _mla_q(config, layer, h, positions)
     col = _mla_kv_latent(config, layer, h, positions).astype(pool_k.dtype)  # [B, Sq, W]
     keep(col)
     with jax.named_scope("paged_attn"):
-        def gather(slots):  # [B|R, P] -> [B|R, P, W]
-            return pool_gather(pool_k, pool_index(pool_k, layer_no, slots), col.shape[-1])
+        if kernel is None:
+            def gather(slots):  # [B|R, P] -> [B|R, P, W]
+                return pool_gather(pool_k, pool_index(pool_k, layer_no, slots), col.shape[-1])
 
-        prefix_rows = gather(prefix_idx)
-        gen_rows = _write_cache(gather(gen_idx), col, write_index)
-        out = mla_attend(
-            config, layer, q_nope, q_rope,
-            [(prefix_rows, prefix_mask), (gen_rows, key_mask)], absorb=True,
-        )
+            prefix_rows = gather(prefix_idx)
+            gen_rows = _write_cache(gather(gen_idx), col, write_index)
+            out = mla_attend(
+                config, layer, q_nope, q_rope,
+                [(prefix_rows, prefix_mask), (gen_rows, key_mask)], absorb=True,
+            )
+        else:  # mla_attend's absorbed form, the kernel between its absorptions
+            B, Sq, NH, dn = q_nope.shape
+            wkv_b = layer["wkv_b"].reshape(config.kv_lora_rank, NH, dn + config.v_head_dim)
+            out = kernel(_absorb_query(wkv_b, q_nope, q_rope), col, pool_k, layer_no)
+            out = _absorb_output(wkv_b, dn, out, q_nope.dtype)
+            out = out.astype(q_nope.dtype).reshape(B, Sq, -1)
     return _attn_out(layer, out)
 
 
@@ -627,17 +687,25 @@ def apply_stack_paged(
     prefix_mask: jax.Array,
     aux: Optional[dict] = None,
     mesh=None,
+    prefix_lengths: Optional[jax.Array] = None,
+    attn_impl: str = "xla",
+    page_size: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """``llama._apply_stack_paged`` for the latent block: each layer gathers
-    its rows' latent pages from the whole pool ([L, flat, 1, stored width]) by
-    block table and layer number (:func:`pool_gather`), inserts this step's
-    rows, and attends in the absorbed form (XLA; the Pallas paged kernel's
-    (KVH, D) shapes do not fit a latent page). Returns (x, k_cols [L, B, 1,
-    width], v_cols [L, B, 1, 0]) at ``Sq == 1``; a step of more positions a
-    row (the drafted step) gets k_cols [L, B, Sq, 1, width]. L counts the
-    stack's layers, not a next-token module's."""
+    """``llama._apply_stack_paged`` for the latent block: each layer attends
+    in the absorbed form over its rows' latent pages in the whole pool ([L,
+    flat, 1, stored width]), addressed by block table and layer number, and
+    this step's rows. ``attn_impl`` "pallas" (or the tests' "pallas_interpret")
+    reads each row's live pages in place (``ops/paged_attention.py::
+    paged_latent_decode_pallas``, ``page_size`` and ``prefix_lengths`` [B|R]
+    required); "xla" gathers whole block tables (:func:`pool_gather`) and
+    inserts this step's rows. Returns (x, k_cols [L, B, 1, width], v_cols [L,
+    B, 1, 0]) at ``Sq == 1``; a step of more positions a row (the drafted
+    step) gets k_cols [L, B, Sq, 1, width]. L counts the stack's layers, not a
+    next-token module's."""
     _refuse(config, mesh=mesh)
     one = x.shape[1] == 1
+    kernel = _paged_kernel(config, attn_impl, page_size, prefix_idx, gen_idx,
+                           prefix_lengths, write_index)
 
     def body(X, layer, scanned):
         cols = []
@@ -646,7 +714,7 @@ def apply_stack_paged(
             return _attend_paged(
                 config, layer, h, positions, pool_kv.k, scanned["layer"],
                 prefix_idx, gen_idx, write_index, key_mask, prefix_mask,
-                keep=lambda col: cols.append(col[:, 0] if one else col),
+                keep=lambda col: cols.append(col[:, 0] if one else col), kernel=kernel,
             )
 
         X = _hc_sublayer(config, layer, "hc_attn", "attn_norm", X, attn)
@@ -754,19 +822,26 @@ def mtp_paged(
     key_mask: jax.Array,
     prefix_mask: jax.Array,
     aux: Optional[dict] = None,
+    prompt_lens: Optional[jax.Array] = None,
+    attn_impl: str = "xla",
+    page_size: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """The module through the page pool, for the loop: ``h`` [B, Sq, H] the
     main stack's output at the positions before ``positions``, ``tokens`` [B,
-    Sq] the tokens AT ``positions``. Masks and indices as
-    :func:`apply_stack_paged` takes them (the caller masks position 0, which
-    holds nothing in this layer). Returns (the module's output after its norm
+    Sq] the tokens AT ``positions``. Masks, indices and the attention's
+    implementation as :func:`apply_stack_paged` takes them (the caller masks
+    position 0, which holds nothing in this layer; the kernel starts the
+    prompt's positions at 1). Returns (the module's output after its norm
     [B, Sq, H], its cache rows [B, Sq, 1, width])."""
     cols = []
+    kernel = _paged_kernel(config, attn_impl, page_size, prefix_idx, gen_idx,
+                           prompt_lens, write_index, first_position=1)
 
     def attend(layer, x):
         return _attend_paged(
             config, layer, x, positions, pool_kv.k, config.num_layers,
             prefix_idx, gen_idx, write_index, key_mask, prefix_mask, keep=cols.append,
+            kernel=kernel,
         )
 
     out = _mtp_block(config, params, h, jnp.take(params["embed"], tokens, axis=0), attend, aux)

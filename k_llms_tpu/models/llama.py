@@ -1308,6 +1308,7 @@ def _apply_stack_paged(
         return latent.apply_stack_paged(
             config, params, x, positions, pool_kv, prefix_idx, gen_idx,
             write_index, key_mask, prefix_mask, aux=aux, mesh=mesh,
+            prefix_lengths=prefix_lengths, attn_impl=attn_impl, page_size=page_size,
         )
     local_flags = _local_layer_flags(config) if key_mask_global is not None else None
 
@@ -1464,15 +1465,17 @@ def paged_draft_step(
     prefix_idx: jax.Array,
     gen_idx: jax.Array,
     aux: Optional[dict] = None,
+    attn_impl: str = "xla",
+    page_size: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """The next-token module's step through the page pool (models/latent.py),
     beside :func:`paged_verify_step` in the drafted loop: ``tokens`` [B, Sq]
     at generated offsets ``lengths + j`` (the slots the module's cache rows
     land in), ``hidden`` [B, Sq, H] the stack's output before its final norm
-    one position earlier. Positions and masks as there, with position 0, which
-    holds nothing in the module's layer, masked out of the prompt's rows.
-    Returns (logits f32 [B, Sq, V] for the token after each, the module's
-    cache rows [B, Sq, 1, width])."""
+    one position earlier. Positions, masks and ``attn_impl`` as there, with
+    position 0, which holds nothing in the module's layer, masked out of the
+    prompt's rows. Returns (logits f32 [B, Sq, V] for the token after each,
+    the module's cache rows [B, Sq, 1, width])."""
     from . import latent
 
     B, Sq = tokens.shape
@@ -1486,6 +1489,7 @@ def paged_draft_step(
     h, cols = latent.mtp_paged(
         config, params, hidden, tokens, pl_row[:, None] + offsets, pool_kv,
         prefix_idx, gen_idx, lengths, self_mask, prefix_mask, aux=aux,
+        prompt_lens=pl_row, attn_impl=attn_impl, page_size=page_size,
     )
     with jax.named_scope("mtp_head"):
         return _logits(config, params, h), cols

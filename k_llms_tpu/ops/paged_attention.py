@@ -26,11 +26,17 @@ Two implementations, one contract:
   is unavailable (tier-1 CI is `JAX_PLATFORMS=cpu`; interpret mode is never
   used for serving).
 
+A latent (MLA) pool has a kernel of its own, ``paged_latent_decode_pallas``:
+the same walk over the same tables, but a page is one tensor of one row a
+token, the key on all its lanes and the value on the first ``kv_lora_rank``,
+fetched once; its XLA reference is ``models/latent.py``'s gather and
+``mla_attend(absorb=True)``.
+
 Selection is ``resolve_paged_attention_impl`` (backed by
 ``BackendConfig.paged_attention_impl``): "xla" | "pallas" | "auto", with an
 automatic COUNTED fallback (``kernel.paged_attn_fallback.<reason>``, where
 the suffix names what blocked the kernel: failpoint / softcap /
-sliding_window / mla / platform) when "pallas" is requested but can't run, or
+sliding_window / platform) when "pallas" is requested but can't run, or
 when "auto" on a TPU meets a model outside the kernel's support; "auto"
 choosing XLA off-TPU is the documented CPU posture, not a fallback, so it is
 not counted. The ``ops.paged_attn`` failpoint forces the fallback branch for
@@ -86,18 +92,19 @@ def resolve_paged_attention_impl(
     """Pick the paged-attention implementation for the current process.
 
     requested: "auto" | "pallas" | "xla"; config: optional ModelConfig — a
-    model using attention softcap, a latent (MLA) cache, or a scanned GQA
-    stack with a sliding window on some layers and not on others
-    (``sliding_window_layers == "alternating"``: the scan has one kernel call
-    and so one window for every layer) is outside the kernel's support and
-    resolves to "xla". A window on every layer is served by the kernel, and so
-    is a hybrid stack's mix of windowed and global layers (its layers are
-    unrolled: each call takes its own window).
+    model using attention softcap, or a scanned GQA stack with a sliding
+    window on some layers and not on others (``sliding_window_layers ==
+    "alternating"``: the scan has one kernel call and so one window for every
+    layer) is outside the kernels' support and resolves to "xla". A window on
+    every layer is served by the kernel, and so is a hybrid stack's mix of
+    windowed and global layers (its layers are unrolled: each call takes its
+    own window); a latent (MLA) cache is served by its own kernel
+    (:func:`paged_latent_decode_pallas`).
     Resolution is host-side and happens once
     per loop/launch build, not per step. An explicit "pallas" request that
     cannot be honored records ``kernel.paged_attn_fallback.<reason>``, where
     the reason distinguishes config-driven fallbacks (``softcap``,
-    ``sliding_window``, ``mla`` — the model is outside the kernel's support;
+    ``sliding_window`` — the model is outside the kernels' support;
     on a TPU these are counted under "auto" too) from
     environment-driven ones (``platform`` — no TPU) and drills
     (``failpoint``); "auto" picking XLA off-TPU is the expected CPU posture
@@ -117,10 +124,8 @@ def resolve_paged_attention_impl(
         return "xla"
     if requested == "xla":
         return "xla"
-    if config is not None and config.is_latent:
-        blocked: Optional[str] = "mla"  # a latent page is no (KVH, D) tile
-    elif config is not None and config.attn_softcap is not None:
-        blocked = "softcap"
+    if config is not None and config.attn_softcap is not None:
+        blocked: Optional[str] = "softcap"
     elif config is not None and config.mixes_windowed_layers and not config.is_hybrid:
         blocked = "sliding_window"  # one scanned kernel call, one window
     else:
@@ -297,8 +302,9 @@ def paged_attention_page_tables(
 # ---------------------------------------------------------------------------
 
 #: VMEM the kernel's page buffers may take: two slots (one scored while the
-#: other fills) of K pages of keys and K of values. K follows from this and
-#: the page's own bytes (:func:`pages_per_block`).
+#: other fills) of K pages of keys and K of values — of K latent pages, each
+#: both, in the latent kernel. K follows from this and the page's own bytes
+#: (:func:`pages_per_block`).
 PAGE_BUFFER_BYTES = 2 * 1024 * 1024
 
 
@@ -661,4 +667,254 @@ def _paged_decode_local(
         pv,
         jnp.repeat(new_k, G, axis=1),
         jnp.repeat(new_v, G, axis=1),
+    )
+
+
+# ---------------------------------------------------------------------------
+# Latent pages (MLA, absorbed form)
+# ---------------------------------------------------------------------------
+
+
+def _paged_latent_kernel(
+    # scalar prefetch (SMEM) -------------------------------------------------
+    tables_ref,  # [B, NP + NG] int32: pool page per (row, table page)
+    plen_ref,  # [B] int32: valid prefix length per row
+    glen_ref,  # [B] int32: generated rows in the pool per row (this step's excluded)
+    phase_ref,  # [B] int32: in-page offset of gen position 0
+    layer_ref,  # [1] int32: the layer whose pages are read
+    # data -------------------------------------------------------------------
+    q_ref,  # [1, Sq * NH, W] — one row's absorbed queries, (position, head) rows
+    pages_hbm,  # [L * npages, page_size, W] — the whole pool, left in HBM
+    col_ref,  # [1, Sq, W] f32 — this step's fresh rows (not yet in the pool)
+    o_ref,  # [1, Sq * NH, value_width] f32
+    # scratch ----------------------------------------------------------------
+    buf,  # VMEM [2, K, page_size, W]: two slots of K pages
+    sems,  # DMA semaphores [2]: one a slot
+    *,
+    sm_scale: float,
+    page_size: int,
+    num_prefix_pages: int,
+    num_gen_pages: int,
+    pages_per_layer: int,
+    block_pages: int,
+    queries: int,
+    first_position: int,
+    value_width: int,
+):
+    # Grid (row,), the walk of _paged_decode_kernel: a row's live pages and
+    # no others, the prefix table's then the generated table's, K pages a
+    # block, two slots. A latent page is one tensor: a token's row
+    # ``[c_kv | k_rope | pad]`` is the key on all W lanes (the queries' pad
+    # lanes are zeros) and its first ``value_width`` lanes are the value, so a
+    # page is fetched once and read twice. Every query of the row shares one
+    # KV row a position (multi-query attention), so a block is one product of
+    # all Sq * NH queries against its K * ps rows, every score kept.
+    b = pl.program_id(0)
+    ps, K, DV = page_size, block_pages, value_width
+    plen, glen, phase = plen_ref[b], glen_ref[b], phase_ref[b]
+    (_, n_prefix), (_, n_gen) = live_pages(plen, glen, phase, ps)
+    n_prefix = jnp.minimum(n_prefix, num_prefix_pages)
+    n_gen = jnp.minimum(n_gen, num_gen_pages)
+    live = n_prefix + n_gen
+    n_blocks = (live + (K - 1)) // K
+    base = layer_ref[0] * pages_per_layer
+
+    def page_copy(page, slot, t):
+        return pltpu.make_async_copy(pages_hbm.at[page], buf.at[slot, t], sems.at[slot])
+
+    def start_block(blk, slot):
+        for t in range(K):  # static unroll
+            i = blk * K + t
+
+            @pl.when(i < live)
+            def _start():
+                col = jnp.where(i < n_prefix, i, num_prefix_pages + i - n_prefix)
+                page_copy(base + tables_ref[b, col], slot, t).start()
+
+    def wait_block(blk, slot):
+        for t in range(K):
+
+            @pl.when(blk * K + t < live)
+            def _wait():
+                page_copy(0, slot, t).wait()  # the shape is what is waited on
+
+    @pl.when(b == 0)
+    def _first_row():
+        # A block's tail past the row's last page is not fetched: it scores
+        # NEG_INF and its weight, an exact 0, multiplies whatever the slot
+        # last held. Pool rows are finite; uninitialised VMEM need not be.
+        buf[...] = jnp.zeros_like(buf)
+
+    start_block(0, 0)
+
+    M, W = q_ref.shape[1], q_ref.shape[2]
+    T = K * ps
+    tok = lax.broadcasted_iota(jnp.int32, (1, T), 1)
+    q = q_ref[0]  # [M, W], the pool's dtype: the MXU's operands as the XLA path's
+
+    def block(blk, carry):
+        m, l, acc = carry
+        slot = lax.rem(blk, 2)
+
+        @pl.when(blk + 1 < n_blocks)
+        def _next():
+            start_block(blk + 1, 1 - slot)
+
+        # Logical positions as in _paged_decode_kernel. Every query sees the
+        # same pool positions: prompt positions in [first_position, plen)
+        # and generated ones in [0, glen); the rest scores NEG_INF.
+        prefix_toks = (n_prefix - blk * K) * ps
+        is_prefix = tok < prefix_toks
+        pos = jnp.where(is_prefix, tok + blk * T, tok - prefix_toks - phase)
+        lo = jnp.where(is_prefix, first_position, 0)
+        valid = (pos >= lo) & (pos < jnp.where(is_prefix, plen, glen))  # [1, T]
+
+        wait_block(blk, slot)
+        rows = buf[slot].reshape(T, W)
+        s = lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        )
+        s = jnp.where(valid, s * sm_scale, NEG_INF)  # [M, T]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        values = buf[slot, :, :, :DV].reshape(T, DV)
+        acc_new = acc * alpha + lax.dot_general(
+            p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l_new, acc_new
+
+    m, l, acc = lax.fori_loop(
+        0,
+        n_blocks,
+        block,
+        (
+            jnp.full((M, 1), NEG_INF, jnp.float32),
+            jnp.zeros((M, 1), jnp.float32),
+            jnp.zeros((M, DV), jnp.float32),
+        ),
+    )
+
+    # This step's rows, not yet in the pool: query position j sees fresh row
+    # c iff c <= j (the XLA path writes them at glen.. and masks causally).
+    # Row 0 is seen by every query, so after it no running max is NEG_INF.
+    qf = q.astype(jnp.float32)
+    at = lax.broadcasted_iota(jnp.int32, (M, 1), 0) // (M // queries)
+    for c in range(queries):
+        row = col_ref[0, c:c + 1, :]  # [1, W] f32
+        s = jnp.sum(qf * row, axis=1, keepdims=True) * sm_scale
+        if c:
+            s = jnp.where(at >= c, s, NEG_INF)
+        m_new = jnp.maximum(m, s)
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)  # [M, 1]
+        l = l * alpha + p
+        acc = acc * alpha + p * row[:, :DV]
+        m = m_new
+    o_ref[0] = acc / l
+
+
+def paged_latent_decode_pallas(
+    q: jax.Array,
+    pool: jax.Array,
+    layer: jax.Array,
+    prefix_pages: jax.Array,
+    gen_pages: jax.Array,
+    gen_phase: jax.Array,
+    cols: jax.Array,
+    prompt_lens: jax.Array,
+    gen_lens: jax.Array,
+    *,
+    page_size: int,
+    sm_scale: float,
+    value_width: int,
+    first_position: int = 0,
+    interpret: bool = False,
+) -> jax.Array:
+    """Paged decode attention over latent pages, the absorbed form of MLA
+    (``models/latent.py``): every query of a row attends one KV row a
+    position, the key on the row's whole stored width and the value on its
+    first ``value_width`` lanes.
+
+    q: ``[B, Sq * NH, W]`` — the absorbed queries ``[q_lat | q_rope]``
+    zero-padded to the pool's stored width W, rows ordered (position, head);
+    pool: the whole latent pool ``[L, flat, 1, W]``, read in place; layer:
+    int32 scalar, the cache layer whose pages the kernel's copies address;
+    prefix_pages ``[B|R, NP]`` / gen_pages ``[B, NG]`` / gen_phase ``[B]``:
+    from :func:`paged_attention_page_tables` (an ``[R, NP]`` prefix table is
+    repeated to rows); cols ``[B, Sq, W]``: this step's rows, not yet in the
+    pool (query position j sees rows 0..j of them); prompt_lens / gen_lens
+    ``[B]``: pool positions attended, the prompt's from ``first_position``
+    (static: 1 for the next-token module's layer, whose position 0 holds
+    nothing) and the generated ones from 0 — they are the walk's trip count
+    too (:func:`live_pages`), so a row whose lengths are zero reads no page.
+
+    Returns ``[B, Sq * NH, value_width]`` f32: the attention of
+    ``mla_attend(absorb=True)`` before its output absorption, up to the float
+    ordering of an online softmax (bf16 operands into the MXU as the XLA
+    path's, f32 accumulation and softmax). The pool's pad lanes meet the
+    queries' zeros and change no score. One device: the latent block runs
+    under no mesh.
+    """
+    B, M, W = q.shape
+    Sq = cols.shape[1]
+    L, flat = pool.shape[:2]
+    ps = page_size
+    npages = flat // ps
+    if prefix_pages.shape[0] != B:  # [R, NP] shared prefix -> per-row table
+        prefix_pages = jnp.repeat(
+            prefix_pages, B // prefix_pages.shape[0], axis=0, total_repeat_length=B,
+        )
+    NP, NG = prefix_pages.shape[1], gen_pages.shape[1]
+    tables = jnp.concatenate([prefix_pages, gen_pages], axis=1).astype(jnp.int32)
+    # Two slots of K pages, a page holding keys and values both.
+    K = max(1, min(PAGE_BUFFER_BYTES // (2 * ps * W * pool.dtype.itemsize), NP + NG))
+
+    kernel = functools.partial(
+        _paged_latent_kernel,
+        sm_scale=sm_scale,
+        page_size=ps,
+        num_prefix_pages=NP,
+        num_gen_pages=NG,
+        pages_per_layer=npages,
+        block_pages=K,
+        queries=Sq,
+        first_position=first_position,
+        value_width=value_width,
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, M, W), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, Sq, W), lambda b, *_: (b, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, M, value_width), lambda b, *_: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, K, ps, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, M, value_width), jnp.float32),
+        # Rows run in order on one core: the first clears the page buffers.
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_latent_attention_decode",
+    )(
+        tables,
+        prompt_lens.astype(jnp.int32),
+        gen_lens.astype(jnp.int32),
+        gen_phase.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        q.astype(pool.dtype),
+        # Every layer's pages in one page axis: page p of layer l is page
+        # l * npages + p, a free reshape of the pool as the device lays it out.
+        pool.reshape(L * npages, ps, W),
+        cols.astype(jnp.float32),
     )

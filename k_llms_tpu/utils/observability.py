@@ -270,8 +270,9 @@ CONSENSUS_EVENTS = EventCounters(declared=(
 #: launch, not per token; kernel.paged_attn_fallback.<reason> — an explicit
 #: "pallas" request degraded to the XLA reference, with the reason suffix
 #: naming what blocked it: ``failpoint`` (the ops.paged_attn failpoint),
-#: ``softcap`` / ``mla`` (model config the kernel doesn't cover —
-#: capability-driven; a sliding window is served, on every layer or on some),
+#: ``softcap`` / ``sliding_window`` (model config the kernels don't cover —
+#: capability-driven; a sliding window is served, on every layer or on some
+#: unrolled ones, and a latent cache by its own kernel),
 #: or ``platform`` (no TPU — environment-driven); "auto"
 #: choosing XLA on CPU is the documented posture and is NOT counted as a
 #: fallback), fed by ops/paged_attention.py and surfaced via scheduler

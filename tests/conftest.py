@@ -82,6 +82,31 @@ def shared_engine(model="tiny", *, param_key=0, mesh_shape=None, **kwargs):
     return eng
 
 
+# -- memory mappings ---------------------------------------------------------
+# Every compiled XLA:CPU program holds memory mappings of its own, and a test
+# process that compiles many (an xdist worker runs a dozen files) reaches the
+# kernel's per-process limit (vm.max_map_count, 65,530 by default): the next
+# compile then aborts or segfaults the process, wherever it happens to be. So
+# when a test file ends with the process past half that limit, JAX's compiled
+# programs are dropped; engines compile theirs again on next use.
+_MAPS_HIGH = 32768
+
+
+def pytest_runtest_teardown(item, nextitem):
+    if nextitem is not None and nextitem.module is item.module:
+        return
+    try:
+        with open("/proc/self/maps") as f:
+            maps = sum(1 for _ in f)
+    except OSError:  # no procfs: nothing to count
+        return
+    if maps > _MAPS_HIGH:
+        import gc
+
+        jax.clear_caches()
+        gc.collect()
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",

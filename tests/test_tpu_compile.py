@@ -322,3 +322,66 @@ def test_the_steps_table_spread_is_fusions_alone_on_v5e(name, form, one_chip):
     text = jax.jit(spread).lower(packed).compile().as_text()
     loops = re.findall(r" (while|gather)\(", text)
     assert bool(loops) is (form == "window_a_row"), loops
+
+
+# -- the latent step on the Pallas kernel for latent pages -----------------------------------
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("model", sorted(LATENT_POOLS))
+def test_the_latent_step_reads_live_pages_in_place_on_the_kernel(model, impl, one_chip):
+    """A latent cell's decode step at the loop's sizes (width 32, 2,048
+    prompt slots, pages of 64; joyai's drafted step: the stack at ``Sq == 2``
+    and the module): on the kernel, one ``paged_latent_attention_decode`` a
+    scan and one for the module, its pool operand a bitcast of the pool (no
+    copy, no layout change), and no gather of every row's whole table
+    (``[32 x 2048, 640]``, the line the XLA path's step leads with; the
+    assertion bites there)."""
+    import re
+
+    from k_llms_tpu.models import llama
+    from k_llms_tpu.models.config import get_config
+
+    preset, layers, flat = LATENT_POOLS[model]
+    config = get_config(preset)
+    drafting = config.num_nextn_predict_layers
+    sq, ps = 1 + drafting, 64
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda a: shape(a.shape, a.dtype),
+                          jax.eval_shape(lambda: llama.init_params(config, jax.random.key(0))))
+
+    def step(params, pool_k, pool_v, tokens, lengths, plens, prefix_idx, gen_idx):
+        aux, pool = {}, llama.KVCache(k=pool_k, v=pool_v)
+        logits, k_cols, _, hidden = llama.paged_verify_step(
+            config, params, tokens, lengths, plens, pool, prefix_idx, gen_idx,
+            attn_impl=impl, page_size=ps, aux=aux, return_hidden=True)
+        if not drafting:
+            return logits, k_cols, aux
+        return llama.paged_draft_step(
+            config, params, hidden, tokens, lengths + 1, plens, pool, prefix_idx, gen_idx,
+            aux=aux, attn_impl=impl, page_size=ps) + (logits, k_cols, aux)
+
+    rows = shape((LOOP_WIDTH,), jnp.int32)
+    compiled = jax.jit(step).lower(
+        params, shape((layers, flat, 1, config.pool_row_width), jnp.bfloat16),
+        shape((layers, flat, 1, 0), jnp.bfloat16), shape((LOOP_WIDTH, sq), jnp.int32), rows,
+        rows, shape((LOOP_WIDTH, LOOP_PROMPT), jnp.int32),
+        shape((LOOP_WIDTH, LOOP_NEW + 2 * drafting), jnp.int32)).compile()
+    text = compiled.as_text()
+    whole_tables = re.findall(r"= bf16\[(?:65536,640|32,2048,640)\]\S* (?:fusion|gather)\(", text)
+    calls = re.findall(r"%paged_latent_attention_decode\S* = \S+ custom-call\(([^)]*)\)", text)
+    assert whole_pool_copies(text, layers, flat) == []
+    if impl == "xla":
+        assert whole_tables and not calls
+        return
+    assert whole_tables == []
+    assert len(calls) == 2 + drafting  # the dense-leading scan, the expert scan, the module
+    pool_view = "bf16[%d,%d,%d]" % (layers * flat // ps, ps, config.pool_row_width)
+    for operands in calls:
+        pool_operand = operands.split(", ")[6].split("*/")[-1]  # after five scalar operands and q
+        defined = re.search(re.escape(pool_operand) + r" = (\S+) (\w+)\(", text)
+        assert defined.group(1).startswith(pool_view) and defined.group(2) == "bitcast"
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9

@@ -806,16 +806,19 @@ def test_new_loop_metrics_read_what_they_say():
     assert by_name["loop_pages_ms"]["layer"] == "KV page manager"
     assert by_name["host_wait_share"]["layer"] == "Device"
     # What they add must lie at the end: nothing that was there moved.
-    # (ISSUE 37's one counter metric behind them, in every cell as they are;
-    # ISSUE 38's seven for its own cell behind that; ISSUE 39's one counter
-    # metric, in every cell; ISSUE 40's one for its own cell, last.)
-    assert [m["name"] for m in manifest["per_layer"]][-19:-9] == [
+    # (The upload counter's metric behind them, in every cell as they are;
+    # the long-document cell's seven behind that; the prefill-chunk counter's
+    # metric, in every cell; the olmo chat cell's one; the latent kernel's two
+    # for the two latent cells, last.)
+    assert [m["name"] for m in manifest["per_layer"]][-21:-11] == [
         *NEW_LOOP_METRICS, "paged_attn_walked_share", "loop_stage_uploads"]
     assert all(m["workloads"] == ["command-a-plus.extract-long"]
                + ["olmo-hybrid-7b.chat"] * (m["name"] == "paged_attn_kv_stream_share")
-               for m in manifest["per_layer"][-9:-2])
-    assert manifest["per_layer"][-1]["workloads"] == ["olmo-hybrid-7b.chat"]
-    assert manifest["per_layer"][-2] == {
+               for m in manifest["per_layer"][-11:-4])
+    assert manifest["per_layer"][-3]["workloads"] == ["olmo-hybrid-7b.chat"]
+    assert all(m["workloads"] == ["xing4-29b-a4b.extract", "joyai-llm-flash.extract"]
+               and m["layer"] == "Kernels" for m in manifest["per_layer"][-2:])
+    assert manifest["per_layer"][-4] == {
         "name": "prefill_tokens_per_chunk", "unit": "tokens", "better": "higher",
         "source": "program_counter", "layer": "Model step: prefill", "moves": "tokens_per_s"}
     assert by_name["loop_stage_uploads"] == {

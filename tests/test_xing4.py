@@ -432,15 +432,21 @@ def test_what_the_latent_block_cannot_do_raises_by_name(what, tmp_path, params):
 
 @pytest.mark.parametrize("requested", ["auto", "pallas"])
 def test_paged_kernel_fallback_for_latent_pages_is_counted_on_a_tpu(monkeypatch, requested):
+    """On a TPU a latent model takes the Pallas kernel for latent pages, as a
+    GQA model takes its own: no fallback is counted, under any reason."""
     from k_llms_tpu.ops import paged_attention
     from k_llms_tpu.utils.observability import KERNEL_EVENTS
 
     monkeypatch.setattr(paged_attention.jax, "default_backend", lambda: "tpu")
-    before = KERNEL_EVENTS.get("kernel.paged_attn_fallback.mla")
-    assert paged_attention.resolve_paged_attention_impl(requested, config=CFG) == "xla"
-    assert paged_attention.resolve_paged_attention_impl(requested, config=CFG, record=False) == "xla"
-    assert KERNEL_EVENTS.get("kernel.paged_attn_fallback.mla") == before + 1
-    assert paged_attention.resolve_paged_attention_impl(requested, config=get_config("tiny")) == "pallas"
+    before = KERNEL_EVENTS.snapshot()
+    for config in (CFG, get_config("joyai-tiny"), get_config("tiny")):
+        assert paged_attention.resolve_paged_attention_impl(requested, config=config) == "pallas"
+        assert paged_attention.resolve_paged_attention_impl(
+            requested, config=config, record=False) == "pallas"
+    grew = {k: v - before.get(k, 0) for k, v in KERNEL_EVENTS.snapshot().items()}
+    assert not any(v for k, v in grew.items() if "fallback" in k)
+    assert KERNEL_EVENTS.get("kernel.paged_attn_fallback.mla") == before.get(
+        "kernel.paged_attn_fallback.mla", 0)
 
 
 # -- the benchmark's side ---------------------------------------------------------------------------
