@@ -216,6 +216,35 @@ def rope_embed(
     return out.astype(x.dtype)
 
 
+def rope_lanes(
+    x: jax.Array, positions: jax.Array, theta: float, scaling=None
+) -> jax.Array:
+    """:func:`rope_embed` without splitting the head: one expression over all
+    D lanes, ``a·c + b·s`` with ``a = (x1, x1)``, ``b = (-x2, x2)`` (both from
+    a roll of ``x`` by D/2), ``c = (cos, sin)`` and ``s = (sin, cos)``. Lane
+    by lane that is the split form's own expression in its own operand order
+    (which matters where the compiler fuses the first product into a
+    multiply-add), so the value is the same bit for bit: ``(-x2)·sin`` is
+    ``-(x2·sin)`` exactly.
+
+    The GQA block's form. Under the split and the concatenate after it,
+    layout assignment wants the q and k projections heads-major, and copies
+    each int8 weight out of the scanned stack every layer to get that; here
+    the products read the weights where they lie."""
+    d = x.shape[-1]
+    inv_freq = _rope_inv_freq(d, theta, scaling)
+    angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B, S, D/2]
+    cos, sin = jnp.cos(angles)[:, :, None, :], jnp.sin(angles)[:, :, None, :]
+    c = jnp.concatenate([cos, sin], axis=-1)
+    s = jnp.concatenate([sin, cos], axis=-1)
+    first = jnp.arange(d) < d // 2
+    xf = x.astype(jnp.float32)
+    rolled = jnp.roll(xf, d // 2, axis=-1)  # (x2, x1)
+    a = jnp.where(first, xf, rolled)
+    b = jnp.where(first, -rolled, xf)
+    return (a * c + b * s).astype(x.dtype)
+
+
 def _gqa_scores(q: jax.Array, k: jax.Array) -> jax.Array:
     """q: [B, Sq, QH, D], k: [B, Sk, KVH, D] -> scores [B, QH, Sq, Sk]."""
     B, Sq, QH, D = q.shape
@@ -285,8 +314,8 @@ def _attn_qkv(
     v = v.reshape(B, Sq, config.num_kv_heads, config.head_dim)
 
     if config.use_rope:
-        q = rope_embed(q, positions, config.rope_theta, config.rope_scaling)
-        k = rope_embed(k, positions, config.rope_theta, config.rope_scaling)
+        q = rope_lanes(q, positions, config.rope_theta, config.rope_scaling)
+        k = rope_lanes(k, positions, config.rope_theta, config.rope_scaling)
     return q, k, v
 
 

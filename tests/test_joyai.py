@@ -407,12 +407,15 @@ def test_what_the_module_cannot_ride_is_refused_by_name(params, kw, named):
 #: and block tables where it took 11 to 13 arrays and token-level slot indices
 #: (``continuous._unpack_step``, ``paging.expand_tables``), which is that change.
 #: They pin every later PR; that PR 37 itself moved no model's loop is held by
-#: the tokens below (``PARENT_STREAMS``, recorded on a70e348).
+#: the tokens below (``PARENT_STREAMS``, recorded on a70e348). ``qwen2-shaped``'s
+#: three were made again where its block's rotary embedding became a rotation of
+#: the head's lanes (``llama.rope_lanes``): a new program text with the same
+#: values, which the same ``PARENT_STREAMS`` hold.
 PARENT_PROGRAMS = {
     "qwen2-shaped": {
-        "step": "fd0f940b943ea991e211d3a4d3025ded4ecc9548c6866ad9a24eb66406579503",
-        "step_g": "51ab6ada7b0f434a045f3d409395fa9c6a058c77e5599d9d791245f1eda6b2e4",
-        "chunk": "b1ca8438c73e7a21b7a5cfa2676bde8ec43eeb67660c81dae26499ce9a8f53e4",
+        "step": "dfeb6fdf80d878b155804f352b1f22e31249e848ac67aa6270e81f7a6941dc96",
+        "step_g": "7bd4532584220564d45b973c0d367c380dafc670a576368efde41d61c89151fc",
+        "chunk": "b9910b6ab864d49f39454861eb2bd36ff1fb301ed724beccc7865393496c3525",
     },
     "xing4-tiny": {
         "step": "8a80ec3a8e8d1dd7353e495c2b0f8a72d71fb6cdef9965b620cb970a375cffdf",

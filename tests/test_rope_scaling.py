@@ -172,3 +172,24 @@ def test_registered_xing4_config_carries_yarn_and_its_score_scale():
     assert cfg.attn_scale == pytest.approx(192 ** -0.5 * mscale ** 2)
     assert get_config("qwen2-7b").attn_scale == 1.0 / np.sqrt(128)
     assert get_config("gemma-2-2b").attn_scale == 256.0 ** -0.5
+
+
+# -- the GQA block's lane rotation (models/llama.py::rope_lanes) ------------------------------
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("scaling", [None, (8.0, 1.0, 4.0, 8192)], ids=["plain", "llama3"])
+@pytest.mark.parametrize("d", [64, 128])
+def test_the_lane_rotation_is_rope_embed_bit_for_bit(d, scaling, dtype):
+    """Random heads at positions 0..8,191, each form compiled on its own: the
+    same bits, in float32 and in bfloat16."""
+    import jax.numpy as jnp
+
+    from k_llms_tpu.models.llama import rope_embed, rope_lanes
+
+    x = (jax.random.normal(jax.random.key(d), (2, 4096, 4, d), jnp.float32) * 3).astype(dtype)
+    positions = jnp.arange(8192, dtype=jnp.int32).reshape(2, 4096)
+    split, lanes = (np.asarray(jax.jit(f, static_argnums=(2, 3))(x, positions, 1e6, scaling))
+                    for f in (rope_embed, rope_lanes))
+    bits = np.uint32 if dtype == "float32" else np.uint16
+    assert np.array_equal(split.view(bits), lanes.view(bits))

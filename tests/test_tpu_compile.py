@@ -385,3 +385,106 @@ def test_the_latent_step_reads_live_pages_in_place_on_the_kernel(model, impl, on
         defined = re.search(re.escape(pool_operand) + r" = (\S+) (\w+)\(", text)
         assert defined.group(1).startswith(pool_view) and defined.group(2) == "bitcast"
     assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9
+
+
+# -- the int8 GQA stack's projections, read where they lie ----------------------------------
+#
+# (preset, prompt slots) of the int8 GQA cells' loops at width 32 and pages of 64
+# (benchmark/configs/*.json `serve`: int8, `continuous_max_prompt`).
+INT8_GQA = {"qwen2-7b": 2048, "mistral-7b": 512}
+
+
+def int8_weight_moves(text):
+    """Copies of one layer's int8 weight (``s8[1, ...]``, taken out of the
+    scanned stack), and the ``dynamic-slice`` fusions that materialise one."""
+    import re
+
+    found = re.findall(r"%(\S+) = (s8\[1,[\d,]+\])\S* (copy|fusion)\(", text)
+    return [(name, dims) for name, dims, op in found if op == "copy" or "dynamic-slice" in name]
+
+
+def int8_stack(preset, shape):
+    from k_llms_tpu.models import quant
+    from k_llms_tpu.models.config import get_config
+
+    config = get_config(preset)
+    params = jax.eval_shape(
+        lambda: quant.init_params_quantized(config, jax.random.key(0), dist="cheap"))
+    return config, jax.tree.map(lambda a: shape(a.shape, a.dtype), params)
+
+
+@pytest.mark.parametrize("rope", ["lanes", "split"])
+@pytest.mark.parametrize("model", sorted(INT8_GQA))
+def test_the_int8_gqa_step_reads_wq_and_wk_where_they_lie(model, rope, one_chip, monkeypatch):
+    """The loop's decode step of an int8 GQA cell (width 32, pages of 64, 256
+    generated slots, the Pallas paged kernel): no layer's weight is copied or
+    materialised out of the stack. Under rope's split and concatenate
+    (``rope_embed``, the witness) layout assignment wants q's and k's
+    projections heads-major and pays for it by copying wq and wk, transposed,
+    every layer; the lane rotation (``rope_lanes``) leaves them where they lie."""
+    from k_llms_tpu.models import llama
+
+    if rope == "split":
+        monkeypatch.setattr(llama, "rope_lanes", llama.rope_embed)
+    W, G, ps, pages = 32, 256, 64, 500
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    config, params = int8_stack(model, shape)
+    pool = shape((config.paging_layers, pages * ps, config.num_kv_heads, config.head_dim),
+                 jnp.bfloat16)
+
+    def step(params, pool_k, pool_v, tokens, lengths, plens, prefix_idx, gen_idx):
+        aux = {}
+        return llama.paged_verify_step(
+            config, params, tokens, lengths, plens, llama.KVCache(k=pool_k, v=pool_v),
+            prefix_idx, gen_idx, attn_impl="pallas", page_size=ps, aux=aux) + (aux,)
+
+    rows = shape((W,), jnp.int32)
+    compiled = jax.jit(step).lower(
+        params, pool, pool, shape((W, 1), jnp.int32), rows, rows,
+        shape((W, INT8_GQA[model]), jnp.int32), shape((W, G), jnp.int32)).compile()
+    moves = int8_weight_moves(compiled.as_text())
+    if rope == "split":
+        square = "s8[1,%d,%d]" % (config.hidden_size, config.q_dim)
+        assert square in {dims for _, dims in moves}  # wq's copy, and its slice
+        return
+    assert moves == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e6
+
+
+@pytest.mark.parametrize("rope", ["lanes", "split"])
+@pytest.mark.parametrize("model", sorted(INT8_GQA))
+def test_the_int8_gqa_chunk_on_xla_attention_reads_wq_where_it_lies(
+        model, rope, one_chip, monkeypatch):
+    """A 512-token prompt chunk (``prefill_chunk_step_paged`` into a staging
+    cache) on XLA's attention, the path ``mistral-7b`` serves its chunks on:
+    no copy of wq, the square weight. At these sizes wk and wv stay copied
+    (the attention's operands set their layout), and ``qwen2-7b``'s served chunk
+    runs the flash kernel, whose heads-major operands keep all three copies
+    whatever rope's form."""
+    from k_llms_tpu.models import llama
+
+    if rope == "split":
+        monkeypatch.setattr(llama, "rope_lanes", llama.rope_embed)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    config, params = int8_stack(model, shape)
+    config = config.with_(attention_impl="xla")
+    C, total = 512, INT8_GQA[model] + 512
+    cache = shape((config.num_layers, 1, total, config.num_kv_heads, config.head_dim),
+                  jnp.bfloat16)
+
+    def chunk(params, tokens, cache_k, cache_v, cursor, valid):
+        return llama.prefill_chunk_step_paged(
+            config, params, tokens, llama.KVCache(k=cache_k, v=cache_v), cursor, valid)
+
+    compiled = jax.jit(chunk, donate_argnums=(2, 3)).lower(
+        params, shape((1, C), jnp.int32), cache, cache, shape((), jnp.int32),
+        shape((), jnp.int32)).compile()
+    copied = {dims for _, dims in int8_weight_moves(compiled.as_text())}
+    square = "s8[1,%d,%d]" % (config.hidden_size, config.q_dim)
+    assert (square in copied) is (rope == "split")
