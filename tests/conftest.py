@@ -8,6 +8,7 @@ through jax.config as well as the tier-1 command's JAX_PLATFORMS=cpu, so a
 bare ``pytest`` on a machine with a chip still runs on the host.
 """
 
+import importlib
 import os
 import sys
 
@@ -19,8 +20,21 @@ import jax
 
 jax.config.update("jax_platforms", "cpu")
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+
+def benchmark_module(name):
+    """A module of ``benchmark/`` (a plain reference, ``run``, ``reduce_trace``),
+    imported from there. The directory is on ``sys.path`` only while the
+    module loads, so none of its names shadows another module."""
+    where = os.path.join(ROOT, "benchmark")
+    sys.path.insert(0, where)
+    try:
+        return importlib.import_module(name)
+    finally:
+        sys.path.remove(where)
 
 
 # -- shared engines ----------------------------------------------------------
@@ -80,6 +94,40 @@ def shared_engine(model="tiny", *, param_key=0, mesh_shape=None, **kwargs):
             )
         _ENGINE_CACHE[key] = eng
     return eng
+
+
+_LOOP_CACHE = {}
+
+
+def shared_loop(model, *, kv_layout="paged", kv_page_size=16, **kwargs):
+    """One ContinuousDecodeLoop per (shared engine, loop knobs) for the
+    session: a loop's step and chunk programs are closures of the instance, so
+    a test that builds its own compiles them again. Extra kwargs go to the
+    loop verbatim. A test that reads the loop's counters takes them before and
+    after its request; a test that needs a fresh loop, a hung step, a
+    rebuild or a patched kernel builds its own."""
+    engine = shared_engine(model, kv_layout=kv_layout, kv_page_size=kv_page_size)
+    key = (id(engine), tuple(sorted((k, tuple(v) if isinstance(v, list) else v)
+                                    for k, v in kwargs.items())))
+    loop = _LOOP_CACHE.get(key)
+    if loop is None:
+        from k_llms_tpu.engine.continuous import ContinuousDecodeLoop
+
+        loop = _LOOP_CACHE[key] = ContinuousDecodeLoop(engine, **kwargs)
+    return loop
+
+
+def stats_grown(before, after):
+    """What a loop's counters (``loop.stats``, the page pool's among them)
+    gained between two reads; other entries as the second read has them."""
+    return {k: stats_grown(before.get(k, {}), v) if isinstance(v, dict)
+            else v - before.get(k, 0) if type(v) in (int, float) else v
+            for k, v in after.items()}
+
+
+def pytest_sessionfinish(session, exitstatus):
+    for loop in _LOOP_CACHE.values():
+        loop.stop()
 
 
 # -- memory mappings ---------------------------------------------------------

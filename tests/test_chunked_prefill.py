@@ -457,16 +457,9 @@ def test_prefill_tokens_per_chunk_reads_the_two_counters():
     window's prompt tokens over its lane turns; on a program without the
     counter (this PR's parent) nothing, and no error. It is reported in every
     cell and lies last in the manifest."""
-    import importlib
-    import os
-    import sys
+    from conftest import benchmark_module
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, os.path.join(root, "benchmark"))
-    try:
-        run = importlib.import_module("run")
-    finally:
-        sys.path.pop(0)
+    run = benchmark_module("run")
     read = run.load_json(run.HERE, "layer_metrics", "prefill_tokens_per_chunk.json")["read"]
     src = {"metrics_start": {"kllms_continuous_prefill_tokens": 5000.0,
                              "kllms_continuous_prefill_chunks": 40.0},

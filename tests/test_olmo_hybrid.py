@@ -1,8 +1,8 @@
 """Olmo-Hybrid (models/hybrid.py's post-norm block: gated delta-rule layers with
 negative eigenvalues beside full attention with QK-norm, 3 : 1, a SwiGLU MLP in
-every block) against the plain reference (tests/olmo_hybrid_reference.py, the
-benchmark's copy byte for byte) at the ``olmo-hybrid-tiny`` size on the CPU,
-in float32.
+every block): the checks no other model has, against the plain reference
+(benchmark/olmo_hybrid_reference.py) at the ``olmo-hybrid-tiny`` size on the
+CPU, in float32. What every served model owes is tests/test_served_models.py's.
 
 Tolerances. Both sides compute in float32, so what separates them is the order
 of summation (the chunked WY form and its triangular solve against the
@@ -18,42 +18,19 @@ delta rule alone, chunked against the recurrence on random inputs, is held to
 1e-4 of its scale against the float64 recurrence (it reads ~1e-6).
 """
 
-import filecmp
-import hashlib
-import json
-import os
-import re
-import sys
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import olmo_hybrid_reference as ref
-from conftest import shared_engine, shared_params
-from k_llms_tpu.models import get_config, hybrid, llama
-from k_llms_tpu.models.llama import KVCache
+from conftest import shared_params
+from k_llms_tpu.models import hybrid, llama
+from served_models import ROWS
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 2e-3
-CFG = get_config("olmo-hybrid-tiny")
+ROW = ROWS["olmo-hybrid-tiny"]
+CFG = ROW.cfg
 N_D = CFG.layer_pattern.count("D")
-KINDS = {"D": "linear_attention", "F": "full_attention"}
-
-
-def hf_dict(c):
-    """The preset in the published config.json's own key names (``head_dim``
-    is the configuration file's assumption)."""
-    return dict(
-        hidden_size=c.hidden_size, intermediate_size=c.intermediate_size,
-        num_attention_heads=c.num_heads, num_key_value_heads=c.num_kv_heads, head_dim=c.head_dim,
-        rms_norm_eps=c.rms_eps, num_hidden_layers=c.num_layers, vocab_size=c.vocab_size,
-        layer_types=[KINDS[k] for k in c.layer_pattern],
-        linear_num_key_heads=c.linear_num_key_heads, linear_num_value_heads=c.linear_num_value_heads,
-        linear_key_head_dim=c.linear_key_head_dim, linear_value_head_dim=c.linear_value_head_dim,
-        linear_conv_kernel_dim=c.linear_conv_kernel, linear_allow_neg_eigval=c.linear_allow_neg_eigval,
-    )
 
 
 @pytest.fixture(scope="module")
@@ -64,11 +41,6 @@ def params():
 @pytest.fixture(scope="module")
 def tokens():
     return np.random.RandomState(0).randint(0, 500, (2, 72)).astype(np.int32)
-
-
-@pytest.fixture(scope="module")
-def reference_logits(params, tokens):
-    return [np.asarray(ref.forward(hf_dict(CFG), params, tokens[b])) for b in range(2)]
 
 
 def state_leaves(state):
@@ -171,89 +143,16 @@ def test_beta_reaches_past_one_and_padding_leaves_the_state_and_tail_as_they_wer
 
 # -- the program against the reference ---------------------------------------------------------
 
-@pytest.mark.parametrize("row", [0, 1])
-def test_forward_matches_reference(params, tokens, reference_logits, row):
-    logits, _ = llama.forward(CFG, params, jnp.asarray(tokens), jnp.ones(tokens.shape, jnp.int32))
-    close(logits[row], reference_logits[row])
+@jax.jit
+def whole_prefill(params, padded, plen):
+    whole = {}
+    return llama.prefill(CFG, params, padded, plen, state=whole)[0], whole
 
 
-def test_forward_moves_with_what_the_block_is_made_of(params, tokens, reference_logits):
-    """The comparison sees each piece: beta without its factor 2, a pre-norm
-    in place of the post-norm, and rope on the attention layer each move the
-    logits far past the tolerance."""
-    seq, want = tokens[0][:40], reference_logits[0][:40]
-
-    def moved(config=CFG, p=params):
-        logits, _ = llama.forward(config, p, jnp.asarray(seq[None]), jnp.ones((1, 40), jnp.int32))
-        return float(np.abs(np.asarray(logits[0]) - want).max())
-
-    assert moved() < TOL
-    assert moved(CFG.with_(linear_allow_neg_eigval=False)) > 0.05
-    assert moved(CFG.with_(use_rope=True)) < TOL  # the F layer takes no positions, whatever the flag
-    post = [dict(layer, post_mlp_norm=layer["post_mlp_norm"] * 2) for layer in params["layers"]]
-    assert moved(p=dict(params, layers=post)) > 0.05
-
-
-@pytest.mark.parametrize("chunk,attn_impl", [(16, "xla"), (32, "pallas_interpret")])
-def test_chunked_prefill_then_paged_decode_matches_reference(params, tokens, reference_logits,
-                                                             chunk, attn_impl):
-    """The loop's two programs by hand: chunks into a staging cache, a page
-    pool and the lane's state (every chunk's last-token logits compared; the
-    last chunk is padded), then ``paged_verify_step`` with three rows reading
-    the shared prompt pages, each from its own copy of the state, on the XLA
-    path and on the interpreted paged kernel (30 KV heads at the cut: here 4,
-    one query head each); the third row is idle and must neither move nor be
-    right."""
-    want, seq, plen, ps, rows = reference_logits[1], tokens[1], 50, 8, 3
-    bucket, flat, L = 64, 40 * 8, CFG.paging_layers
-
-    @jax.jit
-    def chunk_step(params, toks, cache, start, valid, state):
-        aux, state = {}, dict(state)
-        return llama.prefill_chunk_step_paged(CFG, params, toks, cache, start, valid, aux=aux,
-                                              state=state) + (aux, state)
-
-    @jax.jit
-    def step(params, cur, lengths, pool, pidx, gidx, state, active):
-        aux, state = {}, dict(state)
-        return llama.paged_verify_step(
-            CFG, params, cur, lengths, jnp.full((rows,), plen), pool, pidx, gidx, aux=aux,
-            state=state, active=active, attn_impl=attn_impl, page_size=ps) + (aux, state)
-
-    cache, state = llama.init_cache(CFG, 1, bucket), llama.init_state(CFG, 1)
-    pool = KVCache(k=jnp.zeros((L, flat, 4, 64)), v=jnp.zeros((L, flat, 4, 64)))
-    for start in range(0, plen, chunk):
-        valid = min(chunk, plen - start)
-        toks = np.zeros((1, chunk), np.int32)
-        toks[0, :valid] = seq[start:start + valid]
-        logits, cache, k_cols, v_cols, aux, state = chunk_step(
-            params, jnp.asarray(toks), cache, jnp.int32(start), jnp.int32(valid), state)
-        assert int(aux["gdn_rows_updated"]) == N_D and int(aux["gdn_tokens_scanned"]) == N_D * valid
-        assert "ssm_rows_updated" not in aux and "moe_counts" not in aux
-        close(logits[0], want[start + valid - 1])
-        slots = ps + start + np.arange(valid)  # page 0 is the trash page
-        pool = KVCache(k=pool.k.at[:, slots].set(k_cols[:, :valid]),
-                       v=pool.v.at[:, slots].set(v_cols[:, :valid]))
-    assert k_cols.shape == (L, chunk, 4, 64) and L == 2
-    P, G = 64, 8
-    pidx = np.tile(np.arange(P) % ps, (rows, 1)).astype(np.int32)
-    pidx[:, :plen] = ps + np.arange(plen)
-    gidx = np.stack([100 + 10 * r + np.arange(G) for r in range(rows)]).astype(np.int32)
-    state = jax.tree.map(lambda a: jnp.repeat(a, rows, axis=0), state)
-    active = jnp.asarray([True, True, False])
-    for g in range(5):
-        before = state_leaves(state)
-        logits, k_cols, v_cols, aux, state = step(
-            params, jnp.full((rows, 1), seq[plen + g]), jnp.full((rows,), g), pool,
-            jnp.asarray(pidx), jnp.asarray(gidx), state, active)
-        assert int(aux["gdn_rows_updated"]) == 2 * N_D
-        pool = KVCache(k=pool.k.at[:, gidx[:, g]].set(k_cols), v=pool.v.at[:, gidx[:, g]].set(v_cols))
-        for row in range(2):
-            close(logits[row, 0], want[plen + g])
-        for old, new in zip(before, state_leaves(state)):
-            np.testing.assert_array_equal(old[2], new[2])  # the idle row's state, bit for bit
-            assert not np.array_equal(old[0], new[0])
-    assert np.abs(np.asarray(logits[2, 0]) - want[plen + 4]).max() > 0.05  # it stopped at the prompt
+@jax.jit
+def chunk_step(params, toks, cache, start, valid, state):
+    state = dict(state)
+    return llama.prefill_chunk_step(CFG, params, toks, cache, start, valid, state=state) + (state,)
 
 
 @pytest.mark.parametrize("plen,chunk", [(50, 16), (33, 32), (64, 16), (100, 64)])
@@ -265,13 +164,7 @@ def test_chunked_prefill_equals_whole_prefill_and_the_reference_state(params, to
     seq = np.concatenate([tokens[0], tokens[1]])
     bucket = 128
     padded = np.pad(seq[None, :plen], ((0, 0), (0, bucket - plen)))
-    whole = {}
-    want, _ = llama.prefill(CFG, params, jnp.asarray(padded), jnp.int32(plen), state=whole)
-    @jax.jit
-    def chunk_step(params, toks, cache, start, valid, state):
-        state = dict(state)
-        return llama.prefill_chunk_step(CFG, params, toks, cache, start, valid, state=state) + (state,)
-
+    want, whole = whole_prefill(params, jnp.asarray(padded), jnp.int32(plen))
     cache, state = llama.init_cache(CFG, 1, bucket), llama.init_state(CFG, 1)
     for start in range(0, plen, chunk):
         valid = min(chunk, plen - start)
@@ -286,334 +179,7 @@ def test_chunked_prefill_equals_whole_prefill_and_the_reference_state(params, to
         np.testing.assert_allclose(a, b, atol=5e-4, rtol=0)
     assert state["gdn"][0].dtype == jnp.float32 and state["gdn"][0].shape == (1, 4, 32, 64)
     assert state["gdn_conv"][0].shape == (1, CFG.linear_conv_kernel - 1, CFG.gdn_conv_dim)
-    states = []
-    ref.forward(hf_dict(CFG), params, seq[:plen], positions=[plen - 1], states=states)
+    states = []  # the reference's one call that stays eager: it hands its states out by a list
+    ROW.ref_call(params, seq[:plen], positions=[plen - 1], states=states)
     for mine, theirs in zip(state["gdn"], states):  # entries of magnitude ~1; they read 1.4e-4
         np.testing.assert_allclose(np.asarray(mine[0]), np.asarray(theirs), atol=5e-4, rtol=0)
-
-
-# -- through the engine and the continuous loop ------------------------------------------------
-
-def make_loop(width=8, chunk=32, ladder=()):
-    from k_llms_tpu.engine.continuous import ContinuousDecodeLoop
-
-    engine = shared_engine("olmo-hybrid-tiny", kv_layout="paged", kv_page_size=16)
-    return ContinuousDecodeLoop(engine, width=width, max_prompt=256, max_new=16, eos_ids=[257],
-                                prefill_chunk_tokens=chunk, prefill_chunk_ladder=ladder)
-
-
-@pytest.mark.parametrize("plen,ladder,prefill_calls", [
-    (100, (), 4), (20, (), 1), (200, (32, 64, 128), 2), (161, (32, 64, 128), 2)])
-def test_n8_forks_one_state_into_eight_rows_that_diverge(params, plen, ladder, prefill_calls):
-    """n = 8 sampled rows of one prompt (100 tokens: four chunks of 32, the
-    last padded; 20: whole-prompt admission; on the ladder 200 and 161: a turn
-    of 128, then the state carried into a padded turn of 128 or of 64; each of
-    the loop's three compiled lengths): pages are shared, the state is forked, the rows take different
-    tokens, and every row's log-probabilities are the reference's along that
-    row's own tokens. The counters add up."""
-    from k_llms_tpu.utils.observability import LATENCY, MODEL_COUNTERS
-
-    prompt = [int(t) for t in np.random.RandomState(1).randint(0, 250, plen)]
-    loop = make_loop(ladder=ladder)
-    before, installs = MODEL_COUNTERS.snapshot(), LATENCY.snapshot()["continuous.state_install"]["count"]
-    try:
-        got = loop.submit(prompt, n=8, max_new=10, temperature=1.0, top_p=1.0,
-                          seed=5).result(timeout=300)
-        stats = loop.stats
-    finally:
-        loop.stop()
-    toks, lps = np.asarray(got.tokens)[:, :10], np.asarray(got.logprobs)[:, :10]
-    lengths = np.asarray(got.lengths)  # a row that samples the stop token ends early
-    assert len({tuple(r) for r in toks.tolist()}) > 4  # they diverged
-    for row in range(8):
-        n = int(lengths[row])
-        seq = np.asarray(prompt + toks[row, :n].tolist(), np.int32)
-        logits = ref.forward(hf_dict(CFG), params, seq, positions=plen - 1 + np.arange(n))
-        logits = logits.at[:, CFG.pad_token_id].set(-jnp.inf)  # the loop never samples pad
-        want = np.asarray(jax.nn.log_softmax(logits, axis=-1))[np.arange(n), toks[row, :n]]
-        np.testing.assert_allclose(lps[row, :n], want, atol=TOL, rtol=0)
-    grew = {k: v - before.get(k, 0) for k, v in MODEL_COUNTERS.snapshot().items()}
-    # kllms_gdn_state_updates: live rows x 6 a step, one row x 6 a chunk or whole prompt.
-    steps = int((lengths - 1).sum())
-    assert grew["gdn_state_updates"] == (prefill_calls + steps) * N_D
-    assert grew["gdn_tokens_scanned"] == (plen + steps) * N_D
-    assert grew.get("ssm_state_updates", 0) == grew.get("moe_layer_calls", 0) == 0
-    assert LATENCY.snapshot()["continuous.state_install"]["count"] == installs + 1
-    assert stats["state_bytes"] == 8 * CFG.state_bytes_per_row and stats["pages"]["in_use"] == 0
-
-
-def test_an_idle_slots_state_is_unchanged_and_a_reused_slot_equals_a_fresh_one():
-    """A release does no device work, so a slot keeps its last tenant's state
-    until admission overwrites it: the steps of a later request move its own
-    slot's state and no other's, and what it generates on a used loop is what
-    it generates on a fresh one, bit for bit."""
-    rs = np.random.RandomState(2)
-    first = [int(t) for t in rs.randint(0, 250, 70)]
-    second = [int(t) for t in rs.randint(0, 250, 45)]
-    kw = dict(max_new=8, temperature=0.7, top_p=0.9)
-    fresh = make_loop()
-    try:
-        want = fresh.submit(second, n=1, seed=11, **kw).result(timeout=300)
-    finally:
-        fresh.stop()
-    loop = make_loop()
-    try:
-        loop.submit(first, n=8, seed=3, **kw).result(timeout=300)  # every slot has had a tenant
-        used = state_leaves(loop._state)
-        got = loop.submit(second, n=1, seed=11, **kw).result(timeout=300)
-        after = state_leaves(loop._state)
-    finally:
-        loop.stop()
-    np.testing.assert_array_equal(np.asarray(got.tokens), np.asarray(want.tokens))
-    np.testing.assert_array_equal(np.asarray(got.logprobs), np.asarray(want.logprobs))
-    assert len(used) == 2 * N_D
-    for old, new in zip(used, after):
-        moved = [slot for slot in range(8) if not np.array_equal(old[slot], new[slot])]
-        assert len(moved) == 1  # the one slot the second request took; seven idle ones untouched
-        assert np.abs(old).max() > 0
-
-
-def test_the_front_door_serves_it_and_health_and_metrics_carry_the_state():
-    """``create_app`` -> the continuous loop, n = 1 and n = 3 under a schema;
-    no fallback counter moves; ``/healthz``'s hbm block and ``/metrics`` carry
-    the state's bytes, the delta-rule counters and the install span."""
-    import asyncio
-
-    from k_llms_tpu import KLLMs
-    from k_llms_tpu.backends.tpu import TpuBackend
-    from k_llms_tpu.serving.app import create_app
-    from k_llms_tpu.utils.observability import FAILURE_EVENTS, GRAMMAR_EVENTS, KERNEL_EVENTS
-
-    # The suite's eight virtual devices would make a mesh, which is refused: hand over an engine.
-    backend = TpuBackend(model=CFG.name, continuous_batching=True, continuous_max_prompt=512,
-                         engine=shared_engine(CFG.name, kv_layout="paged", kv_page_size=64))
-    app = create_app(client=KLLMs(backend=backend, model=CFG.name))
-    client = app.client
-    before = {**FAILURE_EVENTS.snapshot(), **GRAMMAR_EVENTS.snapshot(), **KERNEL_EVENTS.snapshot()}
-    schema = {"type": "object", "properties": {"a": {"type": "string", "enum": ["x", "y"]}},
-              "required": ["a"], "additionalProperties": False}
-    try:
-        kw = dict(messages=[{"role": "user", "content": "hello there " * 15}],
-                  model=CFG.name, seed=7, max_tokens=12, temperature=0.8)
-        one = client.chat.completions.create(n=1, **kw)
-        three = client.chat.completions.create(n=3, response_format={
-            "type": "json_schema", "json_schema": {"name": "d", "schema": schema}}, **kw)
-        health = client.backend.health()
-        sent = []
-
-        async def send(message):
-            sent.append(message)
-
-        asyncio.run(app._metrics({}, None, send, {}))
-    finally:
-        client.backend.close()
-    assert len(one.choices) == 1 and len(three.choices) == 4
-    assert all(json.loads(c.message.content)["a"] in ("x", "y") for c in three.choices)
-    after = {**FAILURE_EVENTS.snapshot(), **GRAMMAR_EVENTS.snapshot(), **KERNEL_EVENTS.snapshot()}
-    assert not {k for k in after if "fallback" in k and after[k] != before.get(k, 0)}
-    width = health["continuous"]["width"]
-    assert health["hbm"]["state_bytes"] == width * CFG.state_bytes_per_row
-    assert health["hbm"]["kv_bytes_per_token"] == CFG.kv_bytes_per_token == 2 * 4 * 2 * 64 * 4
-    assert health["hbm"]["param_bytes"] == 4 * hybrid.param_count(CFG)  # float32 here
-    assert health["continuous"]["admitted"] == 2
-    body = b"".join(m.get("body", b"") for m in sent).decode()
-    lines = dict(l.rsplit(" ", 1) for l in body.splitlines() if l and not l.startswith("#")
-                 and "{" not in l)
-    assert float(lines["kllms_hbm_state_bytes"]) == health["hbm"]["state_bytes"]
-    assert float(lines["kllms_continuous_state_install_seconds_count"]) >= 2
-    assert float(lines["kllms_gdn_state_updates"]) > 0 and float(lines["kllms_gdn_tokens_scanned"]) > 0
-
-
-# -- shapes, bytes and refusals ------------------------------------------------------------------
-
-@pytest.mark.parametrize("name,want", [("olmo-hybrid-7b", 7_430_870_688),
-                                       ("olmo-hybrid-7b-cut20", 4_933_309_380)])
-def test_parameter_count_is_the_configuration_files_arithmetic(name, want):
-    """A delta-rule layer 215,570,172, an attention layer 185,809,920, the
-    embedding and the head 385,351,680 each, the final norm 3,840."""
-    cfg = get_config(name)
-    periods = cfg.num_layers // 4
-    assert want == periods * (3 * 215_570_172 + 185_809_920) + 2 * 385_351_680 + 3840
-    assert hybrid.param_count(cfg) == want
-
-
-def test_published_preset_holds_the_published_sizes():
-    with open(os.path.join(ROOT, "benchmark", "configs", "olmo-hybrid-7b.json")) as f:
-        hf = json.load(f)
-    full, cut = get_config("olmo-hybrid-7b"), get_config(hf["serve"]["model"])
-    mine = hf_dict(cut)
-    mine.pop("linear_conv_kernel_dim")
-    assert mine == {k: hf[k] for k in mine}
-    assert hf["linear_conv_kernel_dim"] == cut.linear_conv_kernel and hf["max_position_embeddings"] == 65536
-    assert full.with_(name=cut.name, num_layers=20, layer_pattern="DDDF" * 5) == cut
-    assert [KINDS[k] for k in full.layer_pattern] == hf["published"]["layer_types"]
-    assert hf["published"]["num_hidden_layers"] == full.num_layers == 32
-    assert hf["reduced"] == ["num_hidden_layers", "layer_types"]
-    assert cut.attn_scale == pytest.approx(128 ** -0.5) and cut.dtype == "bfloat16"
-    assert not cut.use_rope and hf["rope_parameters"] == {"rope_theta": None}
-    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
-    if os.path.exists(catalog):
-        with open(catalog) as f:
-            row = next(r for r in map(json.loads, f) if r["source_url"] == hf["source"])
-        changed = {k for k, v in row["config"].items() if hf.get(k) != v}
-        assert changed == set(hf["reduced"])
-
-
-@pytest.mark.parametrize("name,per_token,per_row", [
-    ("olmo-hybrid-7b-cut20", 5 * 32 * 128 * 2 * 2, 15 * (30 * 96 * 192 * 4 + 3 * 11520 * 2)),
-    ("olmo-hybrid-tiny", 2 * 4 * 64 * 2 * 4, 6 * (4 * 32 * 64 * 4 + 3 * 512 * 4))])
-def test_pool_and_memory_model_count_paging_layers_and_the_rows_state(name, per_token, per_row):
-    """81.9 KB of cache a token (30 KV heads stored at 32: ``cache_heads``) and
-    34.2 MB of state a row at the cut."""
-    from k_llms_tpu.backends.tpu import HbmMemoryModel
-
-    cfg = get_config(name)
-    model = HbmMemoryModel(cfg, param_bytes=0, hbm_bytes=16 << 30)
-    assert model.kv_bytes_per_token == cfg.kv_bytes_per_token == per_token
-    assert cfg.state_bytes_per_row == per_row and cfg.is_recurrent
-    assert model.row_margin_bytes == 4 * cfg.vocab_size + (64 << 10) + per_row
-    shapes = cfg.state_shapes(3)
-    assert list(shapes) == ["gdn", "gdn_conv"] and shapes["gdn"][0] == cfg.layer_pattern.count("D")
-    assert sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(jax.eval_shape(
-        lambda: llama.init_state(cfg, 5)))) == 5 * per_row
-
-
-@pytest.mark.parametrize("what", ["mesh", "int8", "speculative", "prefix_cache", "dense_layout",
-                                  "param_specs", "quantize_params", "load_checkpoint",
-                                  "config_from_hf", "generate", "no_continuous_batching"])
-def test_what_the_delta_rule_state_has_no_answer_for_raises_by_name(what, tmp_path, params):
-    from k_llms_tpu.backends.tpu import TpuBackend
-    from k_llms_tpu.engine.engine import LocalEngine
-    from k_llms_tpu.models import loader, quant
-    from k_llms_tpu.parallel.sharding import param_specs
-
-    def engine(**kw):
-        return LocalEngine(CFG, params=params, **{"use_mesh": False, "kv_layout": "paged", **kw})
-
-    calls = {
-        "mesh": lambda: engine(use_mesh=True),  # eight virtual devices: a mesh would be built
-        "int8": lambda: engine(quantize="int8"),
-        "speculative": lambda: engine(speculative="prompt_lookup"),
-        "prefix_cache": lambda: engine(prefix_cache_size=4),
-        "dense_layout": lambda: engine(kv_layout="dense"),
-        "param_specs": lambda: param_specs(CFG),
-        "quantize_params": lambda: quant.quantize_params(params),
-        "load_checkpoint": lambda: loader.load_checkpoint(str(tmp_path), CFG),
-        "config_from_hf": lambda: loader.config_from_hf(str(tmp_path)),
-        "generate": lambda: shared_engine(CFG.name, kv_layout="paged", kv_page_size=16)
-        .generate([1, 2, 3], n=2, max_new_tokens=2),
-        "no_continuous_batching": lambda: TpuBackend(model=CFG.name),
-    }
-    (tmp_path / "config.json").write_text(json.dumps(
-        {"model_type": "olmo_hybrid", "layer_types": ["linear_attention", "full_attention"],
-         "hidden_size": 64, "num_attention_heads": 4}))
-    with pytest.raises(NotImplementedError, match="hybrid|recurrent state"):
-        calls[what]()
-
-
-# -- the other models' programs are the parent's ------------------------------------------------
-
-#: sha256 of the StableHLO of the cut presets' decode step (``paged_verify_step``
-#: at the loop's width 32 on the Pallas paged kernel, lowered for the TPU, the
-#: state donated) and chunk program (``prefill_chunk_step_paged``, 128 tokens
-#: into a staging cache of 512) as the parent of PR 40 (c50bcd8) lowers them.
-#: The kernels' serialized bodies are cut out before hashing: they carry the
-#: checkout's absolute paths. PR 40 touched the hybrid stack and the state
-#: plumbing both models run; these say that neither program moved.
-PARENT_PROGRAMS = {
-    "nemotron3-nano-30b-a3b-cut9": {
-        "step": "e48f35a7393e6803dd27782064578765018d38670be63be841da78b83b00346b",
-        "chunk": "f82fd80f55663efb019c0fc9791ba9e55089cd20ec73a0892bcbc6dbb8e949a0",
-    },
-    "command-a-plus-cut4": {
-        "step": "7c649870199036f5de0f211e6eff2456e0c28c54a55596129399ce4d3cbc38b6",
-        "chunk": "94fb8e230939734618059824aee99254eb4c38496cbd2e9d591431d75f924f4c",
-    },
-}
-
-
-def cut_programs(name, W=32, P=2048, G=128, ps=64, C=128, bucket=512):
-    """The two programs' StableHLO at the cut's widths, from shapes alone."""
-    cfg = get_config(name)
-    params = jax.eval_shape(lambda: hybrid.init_params(cfg, jax.random.key(0)))
-    heads, kw, vw = cfg.cache_widths
-    pool = [jax.ShapeDtypeStruct((cfg.paging_layers, 64 * ps, heads, w), cfg.jax_dtype)
-            for w in (kw, vw)]
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-    def step(params, pool_k, pool_v, cur, lens, plens, active, pidx, gidx, state):
-        aux, state = {}, dict(state)
-        return llama.paged_verify_step(
-            cfg, params, cur[:, None], lens, plens, KVCache(k=pool_k, v=pool_v), pidx, gidx,
-            attn_impl="pallas", page_size=ps, aux=aux, state=state, active=active) + (aux, state)
-
-    def chunk(params, toks, cache, cursor, valid, state):
-        aux, state = {}, dict(state)
-        return llama.prefill_chunk_step_paged(cfg, params, toks, cache, cursor, valid,
-                                              aux=aux, state=state) + (aux, state)
-
-    lowered = {
-        "step": jax.jit(step, donate_argnums=(1, 2, 9)).trace(
-            params, *pool, i32(W), i32(W), i32(W), jax.ShapeDtypeStruct((W,), bool),
-            i32(W, P), i32(W, G), jax.eval_shape(lambda: llama.init_state(cfg, W))),
-        "chunk": jax.jit(chunk, donate_argnums=(2, 5)).trace(
-            params, i32(1, C), jax.eval_shape(lambda: llama.init_cache(cfg, 1, bucket)), i32(),
-            i32(), jax.eval_shape(lambda: llama.init_state(cfg, 1))),
-    }
-    return {k: re.sub(r'\\22body\\22: \\22[A-Za-z0-9+/=]+\\22', "BODY",
-                      v.lower(lowering_platforms=("tpu",)).as_text())
-            for k, v in lowered.items()}
-
-
-@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
-@pytest.mark.parametrize("name", sorted(PARENT_PROGRAMS))
-def test_the_other_hybrid_models_programs_are_the_parents(name):
-    for program, text in cut_programs(name).items():
-        assert hashlib.sha256(text.encode()).hexdigest() == PARENT_PROGRAMS[name][program], program
-
-
-# -- the benchmark's side ------------------------------------------------------------------------
-
-def test_the_two_copies_of_the_reference_are_identical():
-    assert filecmp.cmp(os.path.join(ROOT, "tests", "olmo_hybrid_reference.py"),
-                       os.path.join(ROOT, "benchmark", "olmo_hybrid_reference.py"), shallow=False)
-
-
-def test_benchmark_manifest_has_no_fault_and_the_new_metric_reads_what_it_says():
-    sys.path.insert(0, os.path.join(ROOT, "benchmark"))
-    try:
-        import run
-    finally:
-        sys.path.pop(0)
-    assert run.check_manifest() == []
-    cell = "olmo-hybrid-7b.chat"
-    cells = {w["name"]: w for w in run.load_json(ROOT, "BENCHMARK.json")["workloads"]}
-    assert cells[cell] == dict(cells[cell], config="olmo-hybrid-7b", traffic="chat", chips=1)
-    assert len(cells[cell]["why"]) <= 200
-    entry, _, config, traffic, e2e, layer_specs = run.load_cell(cell)
-    names = {m["name"] for m in layer_specs}
-    assert {"gdn_state_stream_share", "state_install_ms", "paged_attn_walked_share",
-            "paged_attn_kv_stream_share", "weight_stream_share", "loop_step_ms",
-            "prefill_chunk_ms.chat", "loop_emit_ms"} <= names
-    assert not {"ssm_state_stream_share", "moe_expert_stream_share"} & names
-    assert set(e2e) == {"latency_p50_ms", "tokens_per_s", "setup_s"}
-    for other in cells:
-        if other != cell:
-            assert "gdn_state_stream_share" not in {m["name"] for m in run.load_cell(other)[5]}
-    src = {"capture": {"start": {}, "end": {"kllms_gdn_state_updates": 9000.0,
-                                            "kllms_paged_attn_pages_walked": 4000.0},
-                       "seconds": 3.0},
-           "trace": {"busy_s": 2.2, "window_s": 3.0}, "config": config,
-           "peaks": {"hbm_GB_per_s": 819}}
-    values = {m["name"]: run.evaluate(m["read"], src) for m in layer_specs
-              if m["name"] in ("gdn_state_stream_share", "paged_attn_kv_stream_share")}
-    assert values["gdn_state_stream_share"] == pytest.approx(100 * 9000 * 30 * 96 * 192 * 8 / 2.2 / 819e9)
-    assert values["paged_attn_kv_stream_share"] == pytest.approx(
-        100 * 4000 * 64 * 30 * 128 * 2 * 2 / 2.2 / 819e9)
-    assert all(0 < v < 100 for v in values.values())
-    # On a program without the counter (the parent) the reader finds nothing.
-    bare = dict(src, capture={"start": {}, "end": {}, "seconds": 3.0})
-    assert run.evaluate(next(m["read"] for m in layer_specs
-                             if m["name"] == "gdn_state_stream_share"), bare) is None

@@ -27,10 +27,8 @@ The load-bearing pins:
 
 import asyncio
 import glob
-import importlib
 import os
 import re
-import sys
 import threading
 import time
 from types import SimpleNamespace
@@ -41,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import benchmark_module
 from k_llms_tpu import KLLMs
 from k_llms_tpu.backends.fake import FakeBackend
 from k_llms_tpu.engine.continuous import ContinuousDecodeLoop
@@ -728,15 +727,6 @@ def test_debug_profile_rejects_bad_options():
 # -- what the benchmark makes of the new spans (PR 36) ---------------------------------
 
 
-def _benchmark_module(name):
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.path.insert(0, os.path.join(root, "benchmark"))
-    try:
-        return importlib.import_module(name)
-    finally:
-        sys.path.pop(0)
-
-
 _MS = 1_000_000
 
 
@@ -775,7 +765,7 @@ def test_nested_spans_narrow_an_idle_gaps_owner_and_never_widen_it(children, own
     benchmark's: only read here): a gap goes to the shortest host event that
     covers half of it, so a span inside ``continuous.prepare`` wins a gap only
     when it covers half of it by itself, and else leaves it where it was."""
-    reduce_trace = _benchmark_module("reduce_trace")
+    reduce_trace = benchmark_module("reduce_trace")
     host = [("continuous.host", 0, 30 * _MS), ("continuous.prepare", 10 * _MS, 20 * _MS)]
     out = reduce_trace.reduce(_planes(host + children))
     assert out["gaps"] == 1 and out["gap_s"] == pytest.approx(0.010)
@@ -792,7 +782,7 @@ def test_new_loop_metrics_read_what_they_say():
     """Each new ``read`` through the benchmark's own evaluator on a canned
     pair of scrapes; the manifest stays without a fault; a program without the
     families (this PR's parent) yields nothing and does not raise."""
-    run = _benchmark_module("run")
+    run = benchmark_module("run")
     assert run.check_manifest() == []
     manifest = run.load_json(run.ROOT, "BENCHMARK.json")
     kernel_cells = {"qwen2-7b.extract", "qwen2-7b.chat", "mistral-7b.chat",
